@@ -1,6 +1,6 @@
-// Typed client failure taxonomy (the reference's dotnet exception
+// Typed client failure classes (the reference's dotnet exception
 // classes — src/clients/dotnet/TigerBeetle/Exceptions.cs).  All
-// extend IOException so pre-taxonomy call sites keep compiling;
+// extend IOException so earlier call sites keep compiling;
 // catch the subtypes to distinguish retryable timeouts from fatal
 // session states.
 using System.IO;

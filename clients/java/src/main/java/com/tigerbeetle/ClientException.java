@@ -1,7 +1,7 @@
-// Typed client failure taxonomy (the reference's per-condition
+// Typed client failure classes (the reference's per-condition
 // exception classes — src/clients/java/src/main/java/com/tigerbeetle/
 // RequestException.java and friends).  All extend IOException so
-// pre-taxonomy call sites keep compiling; catch the subtypes to
+// earlier call sites keep compiling; catch the subtypes to
 // distinguish retryable timeouts from fatal session states.
 package com.tigerbeetle;
 

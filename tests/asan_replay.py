@@ -1,5 +1,5 @@
 """Sanitizer replay driver (run by tests/test_sanitizers.py, or by
-hand — see experiments/README.md):
+hand):
 
     make -C native asan
     LD_PRELOAD="$(gcc -print-file-name=libasan.so)" \

@@ -38,7 +38,7 @@ def test_soak_cli_entry_point():
         [sys.executable, "-m", "tigerbeetle_tpu.testing.soak", "all",
          "--n", "2", "--seed-base", "5", "--out", out],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "TB_FORCE_CPU_JAX": "1"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     assert proc.returncode == 0, proc.stderr
     records = [json.loads(line) for line in open(out)]

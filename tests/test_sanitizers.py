@@ -97,9 +97,12 @@ def test_asan_build_failure_names_flavor(tmp_path, monkeypatch):
     )
     monkeypatch.setenv("TB_NATIVE_SANITIZE", "asan")
     code = (
-        "import warnings; warnings.simplefilter('ignore');"
-        "from tigerbeetle_tpu.runtime import native;"
-        "native._run_make(native._LIB_PATH);"
+        "from tigerbeetle_tpu.runtime import native\n"
+        "try:\n"
+        "    native._run_make()\n"
+        "    raise SystemExit('make did not fail')\n"
+        "except native.NativeBuildError as exc:\n"
+        "    assert str(exc) == native.build_error()\n"
         "print(native.build_error())"
     )
     proc = subprocess.run(

@@ -82,6 +82,12 @@ def _sm_factory(use_cpu: bool, cache_accounts: int = CACHE_DEFAULT,
                 file=sys.stderr,
             )
         return lambda: CpuStateMachine(cfg.PRODUCTION)
+    # Every JAX-backed serving process: compile cache on before the
+    # first compile, and no silent CPU backend (tigerbeetle_tpu/device.py).
+    from tigerbeetle_tpu import device
+
+    device.enable_compile_cache()
+    device.require_accelerator()
     from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine
 
     return lambda: TpuStateMachine(
@@ -145,7 +151,13 @@ def cmd_start(args: list[str]) -> None:
         trace_path=opts["trace"] or None,
         standby_count=opts["standby_count"],
     )
-    print(f"listening on port {server.port}", flush=True)
+    # The one line that says what this server holds: launchers that
+    # must stay off JAX (a chip belongs to one process) read it here.
+    print(
+        f"listening on port {server.port} "
+        f"device={json.dumps(server.device_report())}",
+        flush=True,
+    )
     # Graceful shutdown on SIGTERM/SIGINT: flush the AOF and write the
     # trace file (close() is the only writer of --trace output).
     import signal

@@ -59,6 +59,11 @@ class Client:
 
     # ------------------------------------------------------------------
 
+    def request(self, operation: Operation, body: bytes) -> bytes:
+        """One request with an already-encoded body (numpy wire rows
+        `.tobytes()`); returns the raw reply body."""
+        return self._native.request(operation, body, self.timeout_ms)
+
     def _rows(self, dtype: np.dtype, events, u128_fields) -> bytes:
         arr = np.zeros(len(events), dtype=dtype)
         for i, ev in enumerate(events):

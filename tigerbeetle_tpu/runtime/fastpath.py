@@ -8,7 +8,9 @@ the native library and wrapped zero-copy as numpy arrays, so exact-path
 (JAX kernel) commits and expiry mutations are immediately visible to
 the native admission checks and vice versa.
 
-Falls back to None (pure-Python path) when no compiler/library exists.
+Built from the tracked sources on first use; a failed build raises
+(runtime/native.py NativeBuildError).  TB_FASTPATH_DISABLE asks for
+the pure-Python path by name.
 """
 
 from __future__ import annotations
@@ -92,23 +94,19 @@ def _load():
         if _lib is not None:
             return _lib
         if _lib_failed:
-            # This round's drain/commit hot paths probe availability
-            # per call: without this, a host where the build fails
-            # would fork a `make` per server drain instead of
-            # degrading to the pure-Python fallback.
             return None
         if envcheck.env_is_set("TB_FASTPATH_DISABLE"):
             return None
-        _lib_failed = True  # cleared on success below
-        # Always invoke make: a no-op when fresh, and it rebuilds a
-        # stale prebuilt .so whose missing symbols would fail the
-        # argtypes registration below.  Build failures are recorded +
-        # warned (runtime/native.py _run_make), never silently eaten —
-        # a bench must not report pure-Python fallback numbers as
-        # native.
+        # Always invoke make: a no-op when fresh, a rebuild when a
+        # source changed, and NativeBuildError when the build fails —
+        # on this call and on every later one (one attempt per
+        # process, runtime/native.py _run_make), so the hot paths that
+        # probe availability per call never fork a `make` each and
+        # never serve pure-Python numbers as native.
         from tigerbeetle_tpu.runtime import native as native_mod
 
-        native_mod._run_make(_LIB_PATH)
+        native_mod._run_make()
+        _lib_failed = True  # cleared on success below
         if not os.path.exists(_LIB_PATH):
             return None
         try:

@@ -4,8 +4,11 @@ The C++ library provides the epoll event loop, the header-framed TCP
 message bus, and the C-ABI client session (the reference's io /
 message_bus / tb_client components, reference: src/io/linux.zig,
 src/message_bus.zig, src/clients/c/tb_client.zig).  Python loads it
-via ctypes; if it hasn't been built yet and a compiler exists, it is
-built on first use (make -C native).
+via ctypes; the library is built from the tracked sources on first
+use (make -C native; no binary is tracked), and a failed build is an
+error — never a quiet fall-back to a stale library or to pure Python.
+Tests that want the pure-Python arms ask for them by name
+(TB_FASTPATH_DISABLE, TB_NATIVE_PIPELINE=0, TB_NATIVE_DRAIN=0, ...).
 """
 
 from __future__ import annotations
@@ -38,14 +41,16 @@ _LIB_PATH = _env_str(
 )
 
 _lib = None
-_lib_failed = False  # negative cache: don't re-make per failed load
+_lib_failed = False  # negative cache: don't retry a failed dlopen
 _lib_lock = threading.Lock()
-# Build-failure forensics: when `make -C native` fails we fall back to
-# a prebuilt .so (or to None), but the failure must be VISIBLE — a
-# silent pure-Python fallback let benches report fallback numbers as
-# native.  The make error tail is kept here for build_error() and the
-# one-shot warning below; obs gauges surface it to scrapes.
+# The make error tail, kept for build_error() and re-raised by every
+# later load: the chip tool copies the tree as it stands on disk, so a
+# library the sources did not just produce is a wrong program.
 _build_error: str | None = None
+
+
+class NativeBuildError(RuntimeError):
+    """`make -C native` failed; the message carries the error tail."""
 
 
 def build_error() -> str | None:
@@ -57,49 +62,38 @@ def build_error() -> str | None:
 _make_attempted = False
 
 
-def _run_make(lib_path: str) -> None:
-    """Invoke make; record + warn ONCE on failure instead of silently
-    swallowing it (the prebuilt-.so / pure-Python fallback still
-    engages, but now visibly).  One `make` covers both libraries
-    (Makefile `all:`), so the runtime and fastpath loaders share a
-    single attempt — and a failing build warns once, not per caller."""
+def _run_make() -> None:
+    """Invoke make once per process and raise NativeBuildError on
+    failure — then and on every later call.  One `make` covers both
+    libraries (Makefile `all:`), so the runtime and fastpath loaders
+    share a single attempt; the Makefile's dependency tracking makes
+    it a no-op when the libraries are fresh."""
     global _build_error, _make_attempted
-    if _make_attempted:
-        return
-    _make_attempted = True
-    # Build-failure forensics name the sanitizer flavor attempted: a
-    # failing `make asan` (no compiler-rt, say) must never read as a
-    # failing release build — and vice versa.
-    flavor = f"sanitizer={_SANITIZE or 'none'}"
-    try:
-        subprocess.run(
-            ["make", "-C", _NATIVE_DIR, _MAKE_TARGET], check=True,
-            capture_output=True, timeout=120,
-        )
-    except subprocess.CalledProcessError as exc:
-        tail = (exc.stderr or exc.stdout or b"")[-800:].decode(
-            "utf-8", "replace"
-        )
-        _build_error = (
-            f"make -C native {_MAKE_TARGET} failed ({flavor}, "
-            f"rc={exc.returncode}): {tail}"
-        )
-    except (OSError, subprocess.SubprocessError) as exc:
-        _build_error = (
-            f"make -C native {_MAKE_TARGET} failed ({flavor}): {exc!r}"
-        )
+    if not _make_attempted:
+        _make_attempted = True
+        # The error names the sanitizer flavor attempted: a failing
+        # `make asan` (no compiler-rt, say) must never read as a
+        # failing release build — and vice versa.
+        flavor = f"sanitizer={_SANITIZE or 'none'}"
+        try:
+            subprocess.run(
+                ["make", "-C", _NATIVE_DIR, _MAKE_TARGET], check=True,
+                capture_output=True, timeout=300,
+            )
+        except subprocess.CalledProcessError as exc:
+            tail = (exc.stderr or exc.stdout or b"")[-800:].decode(
+                "utf-8", "replace"
+            )
+            _build_error = (
+                f"make -C native {_MAKE_TARGET} failed ({flavor}, "
+                f"rc={exc.returncode}): {tail}"
+            )
+        except (OSError, subprocess.SubprocessError) as exc:
+            _build_error = (
+                f"make -C native {_MAKE_TARGET} failed ({flavor}): {exc!r}"
+            )
     if _build_error is not None:
-        import warnings
-
-        fallback = (
-            "falling back to the prebuilt library"
-            if os.path.exists(lib_path)
-            else "no prebuilt library — pure-Python fallback"
-        )
-        warnings.warn(
-            f"native build failed ({fallback}): {_build_error}",
-            RuntimeWarning, stacklevel=3,
-        )
+        raise NativeBuildError(_build_error)
 
 
 class _Event(ctypes.Structure):
@@ -121,12 +115,10 @@ def _load():
             return _lib
         if _lib_failed:
             return None
+        # Always invoke make: a no-op when the library is fresh, a
+        # rebuild when a source changed, NativeBuildError otherwise.
+        _run_make()
         _lib_failed = True  # cleared on success below
-        # Always invoke make: the Makefile's dependency tracking makes
-        # this a no-op when the library is fresh, and it REBUILDS a
-        # stale prebuilt .so whose symbols would otherwise fail the
-        # argtypes registration below with an AttributeError.
-        _run_make(_LIB_PATH)
         if not os.path.exists(_LIB_PATH):
             return None
         try:

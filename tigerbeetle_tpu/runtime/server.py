@@ -305,24 +305,16 @@ class ReplicaServer:
         self._c_verify_bytes = self.registry.counter(
             "server.verify_body_bytes"
         )
-        # Native availability is pinned at startup (the loader caches);
-        # a build failure is VISIBLE here and in the warning
-        # runtime/native.py emits — benches must not pass fallback
-        # numbers off as native.
+        # Native availability is pinned at startup (the loader caches).
+        # A failed build raised before this point; the gauge is 1 only
+        # where the pure-Python arm was asked for (TB_FASTPATH_DISABLE).
         from tigerbeetle_tpu.runtime import fastpath as fastpath_mod
-        from tigerbeetle_tpu.runtime import native as native_mod
 
         self._fastpath = fastpath_mod
         fp_unavailable = 0 if fastpath_mod.batch_verify_available() else 1
         self.registry.gauge_fn(
             "fastpath.native_unavailable", lambda: fp_unavailable
         )
-        if fp_unavailable and native_mod.build_error():
-            print(
-                "TB_WARN fastpath native unavailable: "
-                + native_mod.build_error(),
-                flush=True,
-            )
         # Hash-once commit path (round 23): which SHA-256 engine serves
         # the hot path (scalar fallback warned once + gauged so no
         # bench can mistake a 225 MB/s run for a SHA-NI run), plus the
@@ -675,6 +667,13 @@ class ReplicaServer:
             self.replica.on_requests_batch(req_hdrs, req_bodies)
         return msgs
 
+    def device_report(self) -> dict | None:
+        """Platform, device kind/count/ids, engine and engine state of
+        the state machine (None for the dict-backed CPU engine, which
+        holds no device)."""
+        report = getattr(self.replica.sm, "device_report", None)
+        return report() if report is not None else None
+
     def _send_stats_reply(self, conn: int, header) -> None:
         # Admin scrape (obs/scrape.py): answered from the registry
         # snapshot right here — read-only, sessionless, and never
@@ -687,6 +686,7 @@ class ReplicaServer:
         snap["anatomy.exemplars"] = (
             self.replica.anatomy.exemplar_snapshot()
         )
+        snap["device"] = self.device_report()
         reply, body = stats_reply(snap, header)
         self.bus.native.send(conn, reply.tobytes() + body)
 
