@@ -44,9 +44,12 @@ def main():
         h.submit(op, body)
     sm.sync()
     eng0 = sm._dev
-    eng0.stat_t_h2d = eng0.stat_t_dispatch = 0.0
-    eng0.stat_t_fetch = eng0.stat_t_finish = 0.0
     eng0.stat_fetches = 0
+    split = ("launch_us", "dispatch_us", "commit.update_us",
+             "link.fetch_wait_us", "link.fetch_copy_us", "finish_us")
+    # The engine's leaf stages are histograms, never reset: read the
+    # sums as a window.
+    before = {k: eng0.metrics.histogram(k).total for k in split}
 
     t0 = time.perf_counter()
     futs = [h.submit_async(op, body) for op, body in timed]
@@ -62,10 +65,10 @@ def main():
         f"{N/dt:,.0f} ev/s  ({dt:.2f}s, failed={failed}, "
         f"fetches={eng.stat_fetches}, semantic={eng.stat_semantic_events})"
     )
-    print(
-        f"  split: h2d={eng.stat_t_h2d:.2f}s dispatch={eng.stat_t_dispatch:.2f}s "
-        f"fetch={eng.stat_t_fetch:.2f}s finish={eng.stat_t_finish:.2f}s"
-    )
+    print("  split: " + " ".join(
+        f"{k}={(eng.metrics.histogram(k).total - before[k]) / 1e6:.2f}s"
+        for k in split
+    ))
 
 
 main()

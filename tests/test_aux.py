@@ -93,7 +93,7 @@ def test_server_writes_trace(tmp_path):
     doc = json.loads(open(trace).read())
     names = {e["name"] for e in doc["traceEvents"]}
     assert "state_machine_commit" in names
-    assert "journal_write" in names
+    assert "vsr.journal.write" in names
 
 
 def test_statsd_lines():

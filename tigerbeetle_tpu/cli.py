@@ -158,14 +158,10 @@ def cmd_start(args: list[str]) -> None:
         f"device={json.dumps(server.device_report())}",
         flush=True,
     )
-    # Graceful shutdown on SIGTERM/SIGINT: flush the AOF and write the
-    # trace file (close() is the only writer of --trace output).
-    import signal
-
-    def _stop(signum, frame):
-        raise KeyboardInterrupt
-
-    signal.signal(signal.SIGTERM, _stop)
+    # SIGTERM is serve_forever's (runtime/server.py
+    # install_flight_handlers: flight record and trace file, then death
+    # by the signal).  SIGINT and crashes unwind through close(), which
+    # flushes the AOF and writes the trace file too.
     try:
         server.serve_forever()
     except KeyboardInterrupt:

@@ -82,6 +82,7 @@ class Histogram:
 
     __slots__ = ("name", "_v", "counts", "count", "total", "max",
                  "unit_scale")
+    live = True  # the TB_METRICS=0 stand-in says False: skip the clock
 
     def __init__(self, name: str, vcell: list, unit_scale: int = 1) -> None:
         self.name = name
@@ -134,6 +135,19 @@ class Histogram:
         if value > self.max:
             self.max = value
         self._v[0] += 1
+
+    def observe_split(self, total, n: int) -> None:
+        """`n` samples of `total / n` each: a timed run that produced
+        `n` units (the prepares of one drain).  `n == 0` adds the time
+        to the sum alone, so that the sum stays the time spent."""
+        if n == 1:
+            self.observe(total)
+        elif n:
+            for _ in range(n):
+                self.observe(total / n)
+        else:
+            self.total += total
+            self._v[0] += 1
 
     def time(self) -> "_Timer":
         """Context manager: observe the elapsed µs of the with-block."""
@@ -214,8 +228,12 @@ class _NoopHistogram:
     max = 0.0
     counts: dict = {}
     unit_scale = 1
+    live = False
 
     def observe(self, value) -> None:
+        pass
+
+    def observe_split(self, total, n: int) -> None:
         pass
 
     def time(self) -> _NoopTimer:

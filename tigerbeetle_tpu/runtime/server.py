@@ -244,6 +244,15 @@ class ReplicaServer:
             "json" if trace_path else "none", process_id=replica_index
         )
         tracer.flight = self.flight
+        self.tracer = tracer
+        if getattr(self.replica.sm, "engine", None) == "device":
+            # The process has JAX by now: leaf stages also land in the
+            # profiler's trace (inert while no profiler session runs),
+            # on the device trace's clock.  vsr/, lsm/ and utils/ see
+            # only the injected callable.
+            import jax
+
+            tracer.annotate = jax.profiler.TraceAnnotation
         self.replica.set_tracer(tracer)
         self.replica.anatomy.flight = self.flight
         # Unified registry tree (obs/registry.py): the replica's and
@@ -273,10 +282,28 @@ class ReplicaServer:
         self.registry.gauge_fn(
             "server.queue_depth", lambda: len(self.replica.request_queue)
         )
-        # Drain-loop instruments: messages per drain, wire decode time
-        # per message, drains that hit the round bound.
+        # Monotonic, in the stages' unit: the base of the loop's shares
+        # (poll_wait_us.sum over a window's uptime is the share of it
+        # the one server thread had nothing to do).
+        t_up = time.monotonic_ns()
+        self.registry.gauge_fn(
+            "server.uptime_us", lambda: (time.monotonic_ns() - t_up) // 1000
+        )
+        # Drain-loop instruments: messages per drain, drains that hit
+        # the round bound, and the loop's own leaf stages (the
+        # replica's and the state machine's tile the rest of it).
+        from tigerbeetle_tpu.utils.tracer import Stage
+
         self._h_drain = self.registry.histogram("server.drain_msgs")
-        self._h_decode = self.registry.histogram("server.decode_us")
+        self._st_poll_wait = Stage(
+            self.registry.histogram("server.poll_wait_us"), "server.poll_wait"
+        )
+        self._st_ingress = Stage(
+            self.registry.histogram("server.ingress_us"), "server.ingress"
+        )
+        self._st_tick = Stage(
+            self.replica.metrics.histogram("tick_us"), "vsr.tick"
+        )
         self._c_drains = self.registry.counter("server.drains")
         self._c_drain_rounds = self.registry.counter("server.drain_rounds")
         # Columnar ingest fast path (round 14): TB_FASTPATH_DECODE=1
@@ -426,17 +453,25 @@ class ReplicaServer:
         rounds = 0
         drained = 0
         while True:
-            t_poll = timeout_ms if rounds == 0 else 0
             rounds += 1
+            # The first look at the bus may block: the thread has
+            # nothing to do (server.poll_wait).  The later ones, of zero
+            # timeout, only fetch what has come in since: ingress work.
+            with self.tracer.stage(
+                self._st_poll_wait if rounds == 1 else self._st_ingress
+            ):
+                t_poll = timeout_ms if rounds == 1 else 0
+                if self._fastpath_decode:
+                    batch = self.bus.native.poll_drain(
+                        t_poll, self._drain_batch_max
+                    )
+                else:
+                    events = self.bus.native.poll(t_poll)
             if self._fastpath_decode:
-                batch = self.bus.native.poll_drain(
-                    t_poll, self._drain_batch_max
-                )
                 got = batch[0] > 0
                 if got:
                     drained += self._dispatch_drain(*batch)
             else:
-                events = self.bus.native.poll(t_poll)
                 got = bool(events)
                 for ev_type, conn, payload in events:
                     if ev_type == EV_CLOSED:
@@ -469,8 +504,11 @@ class ReplicaServer:
             # error bounds reflect event-loop stalls.
             self.replica.monotonic_external = True
             self.replica.monotonic = now
-            self.replica.tick()
-            self.bus.connect_peers(self.replica.cluster, self.replica.view)
+            with self.tracer.stage(self._st_tick):
+                self.replica.tick()
+                self.bus.connect_peers(
+                    self.replica.cluster, self.replica.view
+                )
             if now - self._last_stats >= 100 * TICK_NS:  # ~1s cadence
                 self._last_stats = now
                 self._print_stats()
@@ -525,58 +563,44 @@ class ReplicaServer:
         (pre-verified), client requests collect into one columnar
         batch handed to the replica at the end of the round.  Bodies
         stay zero-copy views of the drain arena until a retention
-        point (queue/prepare) forces the single necessary copy."""
+        point (queue/prepare) forces the single necessary copy.
+
+        The server.ingress stage covers the round up to that hand-over
+        (what the walk dispatches inline has stages of its own, which
+        suspend it)."""
+        with self.tracer.stage(self._st_ingress) as run:
+            arrived = run.t0
+            msgs, req_hdrs, req_bodies = self._ingest_drain(
+                n, ev_types, conns, offsets, lens, arena
+            )
+        if req_hdrs:
+            self.replica.on_requests_batch(req_hdrs, req_bodies, arrived)
+        return msgs
+
+    def _ingest_drain(self, n, ev_types, conns, offsets, lens,
+                      arena) -> tuple:
+        """-> (messages taken, client request headers, their bodies)"""
         import numpy as np
 
         is_msg = (ev_types[:n] == EV_MESSAGE) & (lens[:n] > 0)
         midx = np.nonzero(is_msg)[0]
         hdrs = ok = None
         if len(midx):
-            t0 = time.perf_counter_ns()
-            moffs = offsets[midx]
-            mlens = lens[midx]
-            ok, hdrs, native, bytes_hashed = self._fastpath.verify_and_gather(
-                arena, moffs, mlens
+            # The verify and the gather alone (the stage around them
+            # also takes in the walk below), on the tracer's clock.
+            t0 = self.tracer.stamp(self._h_decode_ev)
+            ok, hdrs, mlens = self._verify_drain(
+                arena, offsets[midx], lens[midx]
             )
-            (self._c_fp_hits if native else self._c_fp_fallbacks).inc()
-            # The verify pass is the ingress hash tier.  The replica's
-            # hash.bytes_hashed tracks COMMIT-PATH body bytes only
-            # (request + prepare frames that verified — the bodies
-            # whose digests the reuse seams may consume), so the smoke
-            # ratio against committed_body_bytes is exact; protocol
-            # bodies (ping clock advertisements etc.) are control-plane
-            # noise and land in server.verify_body_bytes, the raw
-            # engine total.  bytes_hashed is None only on the
-            # stale-.so corner — skip, never guess.
-            if bytes_hashed is not None:
-                self._c_verify_bytes.inc(bytes_hashed)
-                cmds = hdrs["command"]
-                ops = hdrs["operation"]
-                # Sessionless admin queries (stats / state_root) are
-                # request frames that never commit — excluded, or a
-                # scrape-polling client would inflate the numerator.
-                rel = np.asarray(ok, bool) & (
-                    (
-                        (cmds == int(Command.request))
-                        & (ops != int(wire.VsrOperation.stats))
-                        & (ops != int(wire.VsrOperation.state_root))
-                    )
-                    | (cmds == int(Command.prepare))
-                )
-                rel_bytes = (
-                    int(mlens[rel].sum()) - HEADER_SIZE * int(rel.sum())
-                )
-                if rel_bytes > 0:
-                    self.replica._c_hash_bytes.inc(rel_bytes)
             # Amortized decode cost per 128-byte event record, sampled
             # only for rounds that actually carry event bodies —
             # protocol-only rounds (heartbeats, prepare_oks) would
             # otherwise report the fixed per-drain setup cost as a
             # bogus "per event" number.
             n_events = (int(mlens.sum()) - HEADER_SIZE * len(midx)) // 128
-            if n_events > 0:
+            if n_events > 0 and t0 is not None:
                 self._h_decode_ev.observe(
-                    (time.perf_counter_ns() - t0) / 1e3 / n_events
+                    (self.tracer.clock() - t0) / 1e3 / n_events
                 )
         mv = memoryview(arena)
         msgs = 0
@@ -663,9 +687,47 @@ class ReplicaServer:
                     verified=True,
                 )
         flush_run()
-        if req_hdrs:
-            self.replica.on_requests_batch(req_hdrs, req_bodies)
-        return msgs
+        return msgs, req_hdrs, req_bodies
+
+    def _verify_drain(self, arena, moffs, mlens):
+        """One batch checksum pass and one header gather over a
+        drain's framed messages.  -> (ok, headers, lengths)"""
+        import numpy as np
+
+        ok, hdrs, native, bytes_hashed = self._fastpath.verify_and_gather(
+            arena, moffs, mlens
+        )
+        (self._c_fp_hits if native else self._c_fp_fallbacks).inc()
+        # The verify pass is the ingress hash tier.  The replica's
+        # hash.bytes_hashed tracks COMMIT-PATH body bytes only
+        # (request + prepare frames that verified — the bodies
+        # whose digests the reuse seams may consume), so the smoke
+        # ratio against committed_body_bytes is exact; protocol
+        # bodies (ping clock advertisements etc.) are control-plane
+        # noise and land in server.verify_body_bytes, the raw
+        # engine total.  bytes_hashed is None only on the
+        # stale-.so corner — skip, never guess.
+        if bytes_hashed is not None:
+            self._c_verify_bytes.inc(bytes_hashed)
+            cmds = hdrs["command"]
+            ops = hdrs["operation"]
+            # Sessionless admin queries (stats / state_root) are
+            # request frames that never commit — excluded, or a
+            # scrape-polling client would inflate the numerator.
+            rel = np.asarray(ok, bool) & (
+                (
+                    (cmds == int(Command.request))
+                    & (ops != int(wire.VsrOperation.stats))
+                    & (ops != int(wire.VsrOperation.state_root))
+                )
+                | (cmds == int(Command.prepare))
+            )
+            rel_bytes = (
+                int(mlens[rel].sum()) - HEADER_SIZE * int(rel.sum())
+            )
+            if rel_bytes > 0:
+                self.replica._c_hash_bytes.inc(rel_bytes)
+        return ok, hdrs, mlens
 
     def device_report(self) -> dict | None:
         """Platform, device kind/count/ids, engine and engine state of
@@ -730,7 +792,6 @@ class ReplicaServer:
         body = payload[HEADER_SIZE:]
         ok = wire.verify_header(header, body)
         decode_us = (time.perf_counter_ns() - t0) / 1e3
-        self._h_decode.observe(decode_us)
         n_events = len(body) // 128
         if n_events > 0:
             self._h_decode_ev.observe(decode_us / n_events)
@@ -809,7 +870,10 @@ class ReplicaServer:
             self.flight.note(f"shed.t{tenant}")
 
     def install_flight_handlers(self) -> None:
-        """Dump the flight ring on SIGTERM, then die with the default
+        """THE SIGTERM handler of a serving process: dump the flight
+        ring and, where tracing is on (--trace / TB_TRACE=json), the
+        tracer's file beside it — spans the signal found open are
+        closed at now and marked — then die with the default
         disposition (exit code intact for supervisors).  Main-thread
         only — in-process test servers (threaded loops) skip it."""
         import signal
@@ -817,6 +881,8 @@ class ReplicaServer:
         def on_sigterm(signum, frame):
             try:
                 self.flight.write(self._flight_path, reason="sigterm")
+                if self._trace_path:
+                    self.tracer.write(self._trace_path)
             finally:
                 signal.signal(signum, signal.SIG_DFL)
                 os.kill(os.getpid(), signum)
@@ -857,7 +923,7 @@ class ReplicaServer:
         if self.replica.aof is not None:
             self.replica.aof.close()
         if self._trace_path:
-            self.replica.tracer.write(self._trace_path)
+            self.tracer.write(self._trace_path)
         self.bus.native.close()
         self.storage.close()
 

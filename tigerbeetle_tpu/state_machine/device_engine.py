@@ -511,12 +511,27 @@ class DeviceEngine:
             "stat_wave_window_bytes_peak": _c("wave.window_bytes_peak"),
             "stat_wave_window_padded_peak": _c("wave.window_padded_peak"),
             "stat_wave_sharded": _c("wave.sharded"),
-            # Wall-time split (seconds) for perf forensics.
-            "stat_t_h2d": _c("t.h2d_s"),
-            "stat_t_dispatch": _c("t.dispatch_s"),
-            "stat_t_fetch": _c("t.fetch_s"),
-            "stat_t_finish": _c("t.finish_s"),
         }
+        # The engine's leaf stages (utils/tracer.py), in the order a
+        # window passes through them; scraped as sm.dev.<name>_us where
+        # the owning machine scopes this registry under "dev".  The
+        # link.<stage>_us histograms below time single crossings and
+        # enclose parts of these.
+        _h = self.metrics.histogram
+        self._st_launch = tracer_mod.Stage(_h("launch_us"), "sm.dev.launch")
+        self._st_dispatch = tracer_mod.Stage(
+            _h("dispatch_us"), "sm.dev.dispatch"
+        )
+        self._st_commit_update = tracer_mod.Stage(
+            _h("commit.update_us"), "sm.dev.commit.update"
+        )
+        self._st_fetch_wait = tracer_mod.Stage(
+            _h("link.fetch_wait_us"), "sm.dev.link.fetch_wait"
+        )
+        self._st_fetch_copy = tracer_mod.Stage(
+            _h("link.fetch_copy_us"), "sm.dev.link.fetch_copy"
+        )
+        self._st_finish = tracer_mod.Stage(_h("finish_us"), "sm.dev.finish")
         # Per-stage crossing-latency histograms, hoisted so _retry
         # pays one dict lookup per crossing (no string building; the
         # shared no-op instances when TB_METRICS=0).
@@ -531,14 +546,15 @@ class DeviceEngine:
         # instead of re-deriving both from guesses.
         self.metrics.gauge_fn("scrub.every", lambda: self._scrub_every)
         self.metrics.gauge_fn("probe.every", lambda: _PROBE_EVERY)
-        self._h_scrub_cost = self.metrics.histogram("scrub.cost_us")
+        self._st_scrub = tracer_mod.Stage(
+            self.metrics.histogram("scrub.cost_us"), "sm.dev.scrub.cost"
+        )
         # Split scrub costs: the 16-byte digest compare vs the
         # full-fetch localization fallback — the next chip session
         # reads both (and the per-step digest-update overhead) off one
         # scrape (ROADMAP "scrub/probe cadence tuning").
         self._h_scrub_cheap = self.metrics.histogram("scrub.cheap_us")
         self._h_scrub_fallback = self.metrics.histogram("scrub.fallback_us")
-        self._h_commit_update = self.metrics.histogram("commit.update_us")
         # Multi-device: the authoritative tables shard ROW-WISE across
         # every visible device (NamedSharding over a 1-D "shard" mesh);
         # the semantic kernels then run SPMD with XLA-inserted
@@ -641,10 +657,6 @@ class DeviceEngine:
     stat_scrub_fallback = obs_stat_property("stat_scrub_fallback")
     stat_full_fetches = obs_stat_property("stat_full_fetches")
     stat_commit_repairs = obs_stat_property("stat_commit_repairs")
-    stat_t_h2d = obs_stat_property("stat_t_h2d")
-    stat_t_dispatch = obs_stat_property("stat_t_dispatch")
-    stat_t_fetch = obs_stat_property("stat_t_fetch")
-    stat_t_finish = obs_stat_property("stat_t_finish")
 
     # ------------------------------------------------------------------
     # Link crossings: bounded retry + transient/fatal classification.
@@ -1313,7 +1325,17 @@ class DeviceEngine:
         overhead per dispatch vs ~0.8 ms device compute)."""
         if not recs:
             return
-        t0 = _time.perf_counter()
+        with self.tracer.stage(self._st_launch):
+            units, dev_bufs, dev_solo, offsets = self._upload_window(recs)
+        with self.tracer.stage(self._st_dispatch):
+            self._dispatch_units(units, dev_bufs, dev_solo, offsets)
+        # Absorb the whole window's touched rows into the on-device
+        # commitment: one extra dispatch per launch.
+        self._commit_absorb(recs)
+
+    def _upload_window(self, recs: list[_InFlight]):
+        """The sm.dev.launch stage: pack the window's inputs into as
+        few buffers as it takes, upload, and block until they land."""
         units = self._plan_chunks(recs)
         # One (tier, B, C) buffer + (tier,) ns/tsb per input spec; scan
         # chunks claim contiguous row ranges in plan order.  The tier
@@ -1368,8 +1390,11 @@ class DeviceEngine:
             ),
             "h2d",
         )
-        t1 = _time.perf_counter()
-        self.stat_t_h2d += t1 - t0
+        return units, dev_bufs, dev_solo, offsets
+
+    def _dispatch_units(self, units, dev_bufs, dev_solo, offsets) -> None:
+        """The sm.dev.dispatch stage: the window's kernels, back to
+        back, no transfer between them."""
         for i, (ukind, urecs) in enumerate(units):
             if ukind == "meta":
                 slots, flags, ledger = urecs[0].meta_args
@@ -1412,13 +1437,6 @@ class DeviceEngine:
             for g, rec in enumerate(urecs):
                 rec.ring_at = (self._ring_at + g) % _RING
             self._ring_at = (self._ring_at + len(urecs)) % _RING
-        self.stat_t_dispatch += _time.perf_counter() - t1
-        # Absorb the whole window's touched rows into the on-device
-        # commitment: one extra dispatch per launch (commit.update_us).
-        if self._commit_enabled:
-            touched = self._collect_touched(recs)
-            if touched is not None:
-                self._commit_update(touched)
 
     def _dispatch(self, rec: _InFlight) -> None:
         """Immediate single-batch dispatch (fallback re-dispatch path)."""
@@ -1572,19 +1590,29 @@ class DeviceEngine:
         """Ring snapshot + lookup-row pulls for a launched window; the
         fetch drains the device stream (idle on return)."""
         ring_np = None
-        t0 = _time.perf_counter()
         if any(r.kind in _SEMANTIC_KINDS for r in recs):
             self.stat_fetches += 1
             # THE burst fetch.
-            ring_np = self._retry(lambda: self.link.fetch(self.ring), "fetch")
+            ring_np = self._fetch(self.ring)
         for rec in recs:
             if rec.kind in ("lookup", "waves", "spec") and rec.handle is not None:
-                rec.rows = self._retry(
-                    lambda h=rec.handle: self.link.fetch(h), "fetch"
-                )
+                rec.rows = self._fetch(rec.handle)
                 rec.handle = None
-        self.stat_t_fetch += _time.perf_counter() - t0
         return ring_np
+
+    def _fetch(self, array) -> np.ndarray:
+        """One fetch crossing of a launched window, in two leaves: the
+        exposed wait for the kernels that produce `array` (the copy
+        would wait for them anyway), then the d2h copy alone.  The
+        link sees one crossing, and link.fetch_us encloses both."""
+
+        def cross():
+            with self.tracer.stage(self._st_fetch_wait):
+                jax.block_until_ready(array)
+            with self.tracer.stage(self._st_fetch_copy):
+                return self.link.fetch(array)
+
+        return self._retry(cross, "fetch")
 
     def _window_clean(self, recs, ring_np) -> bool:
         for rec in recs:
@@ -1596,7 +1624,10 @@ class DeviceEngine:
         return True
 
     def _resolve_clean(self, recs, ring_np) -> None:
-        t0 = _time.perf_counter()
+        with self.tracer.stage(self._st_finish):
+            self._resolve_clean_impl(recs, ring_np)
+
+    def _resolve_clean_impl(self, recs, ring_np) -> None:
         for rec in recs:
             if rec.kind == "meta":
                 continue
@@ -1612,7 +1643,6 @@ class DeviceEngine:
             self.stat_semantic_events += rec.n
             rec.future.resolve(rec.finish(s))
             self._release_bound(rec)
-        self.stat_t_finish += _time.perf_counter() - t0
 
     def _rotate(self) -> None:
         """Window boundary: fetch the launched window's ring, and —
@@ -1715,10 +1745,7 @@ class DeviceEngine:
                     self._dispatch(rec)
             # The re-dispatched suffix mutated the rebuilt table: fold
             # its touched rows back into the commitment.
-            if self._commit_enabled and covered:
-                touched = self._collect_touched(covered)
-                if touched is not None:
-                    self._commit_update(touched)
+            self._commit_absorb(covered)
             ring_np = None
 
     def _mirror_table_np(self) -> np.ndarray:
@@ -1887,6 +1914,20 @@ class DeviceEngine:
         `slots` index the DEVICE table (hot slots under tiering)."""
         if not self._commit_enabled or self.dev_row_hash is None:
             return
+        with self.tracer.stage(self._st_commit_update):
+            self._commit_update_rows(slots)
+
+    def _commit_absorb(self, recs) -> None:
+        """_commit_update over every row `recs` can have modified; the
+        stage covers collecting them too."""
+        if not self._commit_enabled or self.dev_row_hash is None:
+            return
+        with self.tracer.stage(self._st_commit_update):
+            touched = self._collect_touched(recs)
+            if touched is not None:
+                self._commit_update_rows(touched)
+
+    def _commit_update_rows(self, slots) -> None:
         slots = np.unique(np.asarray(slots, np.int64))
         slots = slots[(slots >= 0) & (slots < self.balances.shape[0])]
         if len(slots) == 0:
@@ -1902,12 +1943,11 @@ class DeviceEngine:
                 padded >= 0, self.hot.logical_of[np.maximum(padded, 0)], 0
             )
         self.stat_commit_updates += 1
-        with self._h_commit_update.time():
-            self.dev_row_hash, self.dev_digest = self._run(
-                fns["update"], self.balances, self.meta,
-                self.dev_row_hash, self.dev_digest,
-                jnp.asarray(padded), jnp.asarray(rows),
-            )
+        self.dev_row_hash, self.dev_digest = self._run(
+            fns["update"], self.balances, self.meta,
+            self.dev_row_hash, self.dev_digest,
+            jnp.asarray(padded), jnp.asarray(rows),
+        )
 
     def _collect_touched(self, recs) -> np.ndarray | None:
         """Union of balance rows a record list can have modified."""
@@ -2171,7 +2211,7 @@ class DeviceEngine:
             and self.dev_digest is not None
             and self.mirror.commitment is not None
         )
-        with self._h_scrub_cost.time():
+        with self.tracer.stage(self._st_scrub):
             if cheap:
                 self.stat_scrub_cheap += 1
                 with self._h_scrub_cheap.time():

@@ -1031,13 +1031,26 @@ def _checksum(table):
 
 import functools as _ft
 
+def _jit_as(name: str, fn):
+    """jax.jit(fn) under a program name of its own: a profiler trace
+    and a compile log call the program `jit_<name>`, where a closure
+    would be `jit_run` and a functools.partial `jit__unknown`.  What
+    is compiled is the same."""
+
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named)
+
+
 orderfree = jax.jit(_orderfree)
-orderfree_lo = jax.jit(_ft.partial(_orderfree, lo_only=True))
+orderfree_lo = _jit_as("orderfree_lo", _ft.partial(_orderfree, lo_only=True))
 orderfree_tight = jax.jit(_orderfree_tight)
 linked = jax.jit(_linked)
-linked_small = jax.jit(_ft.partial(_linked, small=True))
+linked_small = _jit_as("linked_small", _ft.partial(_linked, small=True))
 two_phase = jax.jit(_two_phase)
-two_phase_lo = jax.jit(_ft.partial(_two_phase, lo_only=True))
+two_phase_lo = _jit_as("two_phase_lo", _ft.partial(_two_phase, lo_only=True))
 
 
 # Scanned dispatch: G same-kind batches per device LAUNCH.  The link
@@ -1049,7 +1062,7 @@ two_phase_lo = jax.jit(_ft.partial(_two_phase, lo_only=True))
 # rows are addressed (ring_at0 + g) % ring_rows per step, so chunks
 # may wrap the ring freely.
 
-def _scan_of(fn, G):
+def _scan_of(kind, fn, G):
     def run(table, meta, ring, ring_at0, stack, ns, tsb):
         R = ring.shape[0]
 
@@ -1067,7 +1080,7 @@ def _scan_of(fn, G):
         )
         return table, ring
 
-    return jax.jit(run)
+    return _jit_as(f"scan_{kind}_g{G}", run)
 
 
 _BASE_FNS = {
@@ -1113,7 +1126,7 @@ def _scan_sizes() -> tuple[int, ...]:
 SCAN_SIZES = _scan_sizes()
 # kind -> {G: jitted scan}; compiled lazily per (kind, G) actually used.
 scan_kernels = {
-    kind: {G: _scan_of(fn, G) for G in SCAN_SIZES}
+    kind: {G: _scan_of(kind, fn, G) for G in SCAN_SIZES}
     for kind, fn in _BASE_FNS.items()
 }
 
@@ -1125,7 +1138,7 @@ scan_kernels = {
 # measured in r5 (not re-measured since) every h2d after the first
 # kernel paid a large FIXED cost, so transfer COUNT was what mattered.
 
-def _scan_win_of(fn, G):
+def _scan_win_of(kind, fn, G):
     def run(table, meta, ring, ring_at0, big, off, ns_all, tsb_all):
         R = ring.shape[0]
 
@@ -1146,16 +1159,16 @@ def _scan_win_of(fn, G):
         )
         return table, ring
 
-    return jax.jit(run)
+    return _jit_as(f"scan_win_{kind}_g{G}", run)
 
 
 scan_win_kernels = {
-    kind: {G: _scan_win_of(fn, G) for G in SCAN_SIZES}
+    kind: {G: _scan_win_of(kind, fn, G) for G in SCAN_SIZES}
     for kind, fn in _BASE_FNS.items()
 }
 
 
-def _staged(fn, ncols):
+def _staged(kind, fn, ncols):
     """Staged variant: the batch is a slice of a device-resident
     superbatch (one h2d covers many batches — transfers issued while
     the stream is busy cost ~25 ms each on this link, so they are
@@ -1165,14 +1178,18 @@ def _staged(fn, ncols):
         pk = jax.lax.dynamic_slice(super_pk, (g * B, 0), (B, ncols))
         return fn(table, meta, ring, ring_at, pk, n, ts_base)
 
-    return jax.jit(run)
+    return _jit_as(f"staged_{kind}", run)
 
 
-orderfree_staged = _staged(_orderfree, N_COLS)
-orderfree_lo_staged = _staged(_ft.partial(_orderfree, lo_only=True), N_COLS)
-linked_staged = _staged(_linked, N_COLS)
-two_phase_staged = _staged(_two_phase, N_COLS_TP)
-two_phase_lo_staged = _staged(_ft.partial(_two_phase, lo_only=True), N_COLS_TP)
+orderfree_staged = _staged("orderfree", _orderfree, N_COLS)
+orderfree_lo_staged = _staged(
+    "orderfree_lo", _ft.partial(_orderfree, lo_only=True), N_COLS
+)
+linked_staged = _staged("linked", _linked, N_COLS)
+two_phase_staged = _staged("two_phase", _two_phase, N_COLS_TP)
+two_phase_lo_staged = _staged(
+    "two_phase_lo", _ft.partial(_two_phase, lo_only=True), N_COLS_TP
+)
 lookup = jax.jit(_lookup)
 apply_deltas = jax.jit(_apply_deltas)
 meta_update = jax.jit(_meta_update)

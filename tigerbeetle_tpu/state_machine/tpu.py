@@ -24,6 +24,7 @@ post-processing ~ the groove inserts the reference does inline.
 
 from __future__ import annotations
 
+import functools
 import time as _time
 
 import numpy as np
@@ -408,6 +409,18 @@ class TpuStateMachine:
         # Per-batch wave plan wall time (the cumulative counter above
         # hides the tail; the histogram is scrapeable).
         self._h_dev_wave_plan = self.metrics.histogram("dev_wave.plan_us")
+        # Stage tracer (utils/tracer.py): NULL until the owning replica
+        # shares its own (set_tracer); engines this machine makes,
+        # restores included, get the same one.
+        from tigerbeetle_tpu.utils import tracer as tracer_mod
+
+        self.tracer = tracer_mod.NULL
+        # sm.plan: a create_transfers batch from the commit's entry to
+        # the engine's submit (decode, id and account directory joins,
+        # routing, packing).
+        self._st_plan = tracer_mod.Stage(
+            self.metrics.histogram("plan_us"), "sm.plan"
+        )
         # Per-request anatomy hook (obs/anatomy.py): the owning
         # Replica shares its recorder and stamps the current prepare's
         # trace id before each commit, so commit_async can attribute
@@ -446,6 +459,19 @@ class TpuStateMachine:
             self._dev = DeviceEngine(
                 account_capacity, self._mirror, link=device_link,
                 metrics=self.metrics.scope("dev"),
+            )
+            # Compiles of this process (device.py counts them from
+            # JAX's own events): a window in which the count moves
+            # compiled on the served path.
+            from tigerbeetle_tpu import device as device_mod
+
+            self.metrics.gauge_fn(
+                "dev.compile.count",
+                lambda: device_mod.compile_stats()["count"],
+            )
+            self.metrics.gauge_fn(
+                "dev.compile.seconds",
+                lambda: device_mod.compile_stats()["seconds"],
             )
             # Speculative-execution counters live on the MACHINE
             # registry (dev_wave.spec.*, next to the dev_wave.*
@@ -542,6 +568,11 @@ class TpuStateMachine:
     stat_dev_wave_steps = obs_stat_property("stat_dev_wave_steps")
     stat_dev_wave_events = obs_stat_property("stat_dev_wave_events")
     stat_dev_wave_plan_s = obs_stat_property("stat_dev_wave_plan_s")
+
+    def set_tracer(self, tracer) -> None:
+        self.tracer = tracer
+        if hasattr(self._dev, "tracer"):
+            self._dev.tracer = tracer
 
     def device_report(self) -> dict:
         """What this machine runs on and how its engine fares — the
@@ -1337,7 +1368,21 @@ class TpuStateMachine:
         the device computes result codes (VERDICT r3 #1).  Falls back
         to the (drained) host path for shapes outside the kernels'
         classes — the same residual classes the r3 fast paths punted.
+
+        The sm.plan stage is the planning alone: it ends where the
+        engine's submit, or the host path, takes over.
         """
+        with self.tracer.stage(self._st_plan):
+            submit = self._plan_create_transfers_device(
+                timestamp, input_bytes
+            )
+        return submit()
+
+    def _plan_create_transfers_device(self, timestamp: int,
+                                      input_bytes: bytes):
+        """Decode, directory joins, routing and packing.  -> what
+        resolves the batch, to be called: the engine's submit with its
+        arguments bound, or the host path."""
         from tigerbeetle_tpu.state_machine import device_kernels as dk
         from tigerbeetle_tpu.state_machine.device_engine import ReplyFuture
 
@@ -1370,10 +1415,10 @@ class TpuStateMachine:
         # path (bit-identical replies) until commit_async's lifecycle
         # tick re-promotes it through the checksum handshake.
         if self._dev.state is not types.EngineState.healthy:
-            return host_path()
+            return host_path
 
         if n == 0 or n > dk.B:
-            return host_path()
+            return host_path
 
         # Forced-optimistic routing (TB_WAVES_SPECULATE=force): every
         # window batch — including shapes the semantic kernels could
@@ -1381,7 +1426,7 @@ class TpuStateMachine:
         # differential-fuzz / bench arm that maximizes coverage of the
         # validate-and-residue machinery.
         if waves.spec_mode() == "force":
-            return host_path()
+            return host_path
 
         id_lo = np.asarray(events["id_lo"])
         id_hi = np.asarray(events["id_hi"])
@@ -1414,7 +1459,7 @@ class TpuStateMachine:
             )
             ids_unique = len(np.unique(mix)) == n
         if not ids_unique or has_bal:
-            return host_path()
+            return host_path
 
         # In-flight hazards: this batch's ids (duplicate checks) and —
         # for pv batches — its pending references must not collide
@@ -1437,7 +1482,7 @@ class TpuStateMachine:
 
         e_found, _e_row = self._tdir.lookup(id_lo, id_hi)
         if e_found.any():
-            return host_path()
+            return host_path
 
         # Account joins (slots + flags for routing).
         dr_lo = np.asarray(events["debit_account_id_lo"])
@@ -1473,26 +1518,23 @@ class TpuStateMachine:
             input_bytes=input_bytes,
         )
 
-        # Each submit path returns None when the batch cannot run on
-        # device — under tiering, a touched-account set the hot window
-        # cannot hold (tier_prefetch declined) — and the exact host
-        # path takes over.
+        # Each packer returns the engine's submit, bound, or None when
+        # the batch cannot run on device — under tiering, a
+        # touched-account set the hot window cannot hold (tier_prefetch
+        # declined) — and the exact host path takes over.
+        submit = None
         if not (has_linked or has_pv) and not touch_limit_hist:
-            fut = self._submit_device_orderfree(**common)
-            return fut if fut is not None else host_path()
-        if (
+            submit = self._submit_device_orderfree(**common)
+        elif (
             has_linked
             and not (has_pending or has_pv)
             and not touch_hist
             and not amount_hi.any()
         ):
-            fut = self._submit_device_linked(**common)
-            return fut if fut is not None else host_path()
-        if has_pv and not has_linked and not timeout.any() and not touch_limit_hist:
-            fut = self._submit_device_two_phase(**common)
-            if fut is not None:
-                return fut
-        return host_path()
+            submit = self._submit_device_linked(**common)
+        elif has_pv and not has_linked and not timeout.any() and not touch_limit_hist:
+            submit = self._submit_device_two_phase(**common)
+        return submit if submit is not None else host_path
 
     def _device_pack_base(
         self, n, events, id_lo, id_hi, dr_lo, dr_hi, cr_lo, cr_hi,
@@ -1832,8 +1874,8 @@ class TpuStateMachine:
             kind = "orderfree_tight"
         else:
             kind = "orderfree" if has_hi else "orderfree_lo"
-        return self._dev.submit(
-            kind, pk, n, ts_base, finish,
+        return functools.partial(
+            self._dev.submit, kind, pk, n, ts_base, finish,
             self._device_fallback(timestamp, input_bytes),
             id_keys=keys_sorted,
             bound=_amount_bound_total(amount_lo, amount_hi),
@@ -1892,8 +1934,8 @@ class TpuStateMachine:
             if int(amount_lo.sum(dtype=np.uint64)) < (1 << 31)
             else "linked"
         )
-        return self._dev.submit(
-            kind, pk, n, ts_base, finish,
+        return functools.partial(
+            self._dev.submit, kind, pk, n, ts_base, finish,
             self._device_fallback(timestamp, input_bytes),
             id_keys=keys_sorted,
             bound=_amount_bound_total(amount_lo, amount_hi),
@@ -2091,8 +2133,8 @@ class TpuStateMachine:
         bound = 2 * _amount_bound_total(
             amount_lo, amount_hi
         ) + _amount_bound_total(p_amt_lo, p_amt_hi)
-        return self._dev.submit(
-            kind, pk, n, ts_base, finish,
+        return functools.partial(
+            self._dev.submit, kind, pk, n, ts_base, finish,
             self._device_fallback(timestamp, input_bytes),
             id_keys=keys_sorted,
             bound=bound,
@@ -4129,6 +4171,7 @@ def _tpu_restore(self, data: bytes) -> None:
             cap, self._mirror, link=self._device_link,
             metrics=self.metrics.scope("dev"),
         )
+        self._dev.tracer = self.tracer
         # Re-bind the machine-registry dev_wave.spec.* handles — the
         # counters are process-lifetime cumulative across restores.
         self._dev.spec_stats = make_spec_stats(self.metrics)

@@ -98,8 +98,22 @@ class Journal:
         self.metrics = registry
         self._c_writes = registry.counter("journal.writes")
         self._c_sync_batches = registry.counter("journal.sync_batches")
-        self._h_write = registry.histogram("journal.write_us")
-        self._h_sync = registry.histogram("journal.sync_us")
+        from tigerbeetle_tpu.utils.tracer import Stage
+
+        self._st_write = Stage(
+            registry.histogram("journal.write_us"), "vsr.journal.write"
+        )
+        # Enclosed by the covering sync's leaf (vsr.gc.sync): its
+        # fdatasync on the loop's thread, no annotation of its own.
+        self._st_sync = Stage(
+            registry.histogram("journal.sync_us"), "vsr.journal.sync",
+            leaf=False,
+        )
+        # The same fdatasync on the WAL worker (the leading-edge sync
+        # of a drain): a leaf of that thread, out of the loop's sums.
+        self._st_sync_worker = Stage(
+            self._st_sync.hist, "vsr.journal.sync", tid=1
+        )
 
     # ------------------------------------------------------------------
 
@@ -125,9 +139,7 @@ class Journal:
         slot = self.slot_for_op(op)
 
         self._c_writes.inc()
-        with self.tracer.span(
-            "journal_write", op=op, bytes=len(body)
-        ), self._h_write.time():
+        with self.tracer.stage(self._st_write, op=op, bytes=len(body)):
             if self._native_frame:
                 # C builds the padded prepare, updates headers[slot]
                 # in place, and builds the redundant sector — Python
@@ -184,9 +196,7 @@ class Journal:
         assert int(header["size"]) == HEADER_SIZE + body_len
         op = int(header["op"])
         self._c_writes.inc()
-        with self.tracer.span(
-            "journal_write", op=op, bytes=body_len
-        ), self._h_write.time():
+        with self.tracer.stage(self._st_write, op=op, bytes=body_len):
             self.storage.write(self.layout.prepare_slot_offset(slot), wal_view)
             self.storage.write(
                 self.layout.wal_headers_offset + sector_index * SECTOR_SIZE,
@@ -207,7 +217,7 @@ class Journal:
         self.unsynced_writes = 0
         self._c_sync_batches.inc()
         try:
-            with self._h_sync.time():
+            with self.tracer.stage(self._st_sync):
                 self.storage.sync_wal()
         except BaseException:
             # The covering sync did not complete: everything it would
@@ -215,6 +225,13 @@ class Journal:
             self.unsynced_writes += 1
             raise
         return True
+
+    def sync_wal_on_worker(self) -> None:
+        """What the WAL worker runs for the leading-edge sync of a
+        drain (vsr/multi.py _journal_write); the caller keeps the
+        deferred-write accounting."""
+        with self.tracer.stage(self._st_sync_worker):
+            self.storage.sync_wal()
 
     def header_sector_intact(self, slot: int) -> bool:
         """Does the DISK redundant-header sector for `slot` match the

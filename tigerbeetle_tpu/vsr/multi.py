@@ -151,7 +151,11 @@ class VsrReplica(Replica):
 
         self.pipeline: dict[int, PipelineEntry] = {}
         self.request_queue: list[tuple[np.ndarray, bytes]] = []
-        self._queued_keys: set[tuple[int, int]] = set()
+        # (client, request) of every queued request -> when it came
+        # in (the tracer's clock; None with metrics off): the stamp
+        # behind vsr.request_wait_us, kept with the queue entry and
+        # never on the wire.
+        self._queued_keys: dict[tuple[int, int], int | None] = {}
         # Admission control (runtime/server.py sets both): bound on
         # the request queue — None = unbounded (sim clusters) — and
         # an owner callback fired per shed (counters, flight ring).
@@ -331,7 +335,11 @@ class VsrReplica(Replica):
             "prepares_written"
         )
         self._stats["stat_gc_flushes"] = self.metrics.counter("gc_flushes")
-        self._h_gc_sync = self.metrics.histogram("gc.sync_us")
+        from tigerbeetle_tpu.utils.tracer import Stage
+
+        self._st_gc_sync = Stage(
+            self.metrics.histogram("gc.sync_us"), "vsr.gc.sync"
+        )
         self._c_gc_deferred_acks = self.metrics.counter("gc.deferred_acks")
 
         # Native commit pipeline (round 20): per-prepare header
@@ -361,10 +369,26 @@ class VsrReplica(Replica):
         # unit_scale=16 widens the sub-µs floor (1/16-µs buckets below
         # 1 µs) so the native drain's amortized per-prepare cost stays
         # resolvable instead of collapsing into bucket 0.
-        self._h_prepare_us = self.metrics.histogram("prepare_us",
-                                                    unit_scale=16)
         self._h_prepare_ok_us = self.metrics.histogram("prepare_ok_us",
                                                        unit_scale=16)
+        # vsr.prepare: the primary's queue drain and prepare build, one
+        # sample a prepare (a drain's run shares its time among the
+        # prepares it built; the drain's own collect loop adds to the
+        # sum alone).  The journal writes inside it are their own leaf.
+        self._st_prepare = Stage(
+            self.metrics.histogram("prepare_us", unit_scale=16),
+            "vsr.prepare",
+        )
+        self._st_admit = Stage(
+            self.metrics.histogram("admit_us"), "vsr.admit"
+        )
+        self._st_reply_send = Stage(
+            self.metrics.histogram("reply_send_us"), "vsr.reply_send"
+        )
+        # From a request's arrival (its drain's decode, or its enqueue
+        # on the per-message path) to its leaving the queue for the
+        # prepare that carries it.
+        self._h_request_wait = self.metrics.histogram("request_wait_us")
 
         # C-resident drain loop (round 22): whole prepare/ack runs
         # cross into native/tb_pipeline.cpp as ONE call per batch seam
@@ -731,7 +755,7 @@ class VsrReplica(Replica):
             return
         self._primary_prepare(header, body)
 
-    def on_requests_batch(self, headers, bodies) -> None:
+    def on_requests_batch(self, headers, bodies, arrived=None) -> None:
         """Columnar request intake (runtime/server.py fast drain): one
         drain's worth of client requests, headers pre-verified and
         decoded in a single batch pass.  Request-level semantics are
@@ -741,9 +765,18 @@ class VsrReplica(Replica):
         tail, and running it per request was O(drain x pipeline)), and
         fresh requests funnel through the queue so one drain drains
         into few multiplexed prepares instead of re-entering the
-        prepare path per message."""
+        prepare path per message.  `arrived`: the tracer's clock when
+        the drain that carried them was decoded (request_wait_us)."""
         if self.status != "normal":
             return
+        with self.tracer.stage(self._st_admit):
+            admitted = self._admit_requests(headers, bodies, arrived)
+        if admitted:
+            self._drain_request_queue()
+
+    def _admit_requests(self, headers, bodies, arrived) -> bool:
+        """-> whether this replica is the primary (the queue may hold
+        something to prepare)."""
         # The drain verified checksums, not addressing: a frame for a
         # DIFFERENT cluster must be dropped exactly as on_message
         # drops it (cross-cluster isolation; the legacy arm's behavior).
@@ -757,7 +790,7 @@ class VsrReplica(Replica):
         if not self.is_primary:
             for i, h in enumerate(headers):
                 self.bus.send(self.primary_index(), h, bytes(bodies[i]))
-            return
+            return False
         inflight = self._inflight_requests()
         undecidable = inflight is UNDECIDABLE
         # Per-drain dedupe pre-pass (r22): classify the common case —
@@ -816,14 +849,14 @@ class VsrReplica(Replica):
                 # the call entirely — draining would no-op after an
                 # O(pipeline + tail) in-flight rescan per shed.
                 self._drain_request_queue()
-            self._enqueue_request(h, body)
+            self._enqueue_request(h, body, arrived=arrived)
             if not undecidable and verdict is None:
                 key = (wire.u128(h, "client"), int(h["request"]))
                 # Only if actually queued (not shed): a shed duplicate
                 # later in the batch must shed again, not "drop".
                 if key[0] and key in self._queued_keys:
                     inflight.add(key)
-        self._drain_request_queue()
+        return True
 
     def _admit_prepass(self, headers, inflight) -> list[bool]:
         """Vectorized fast/slow classification for one drain's request
@@ -1021,7 +1054,8 @@ class VsrReplica(Replica):
                 self._on_prepare(h, bytes(rest_b[i]))
 
     def _enqueue_request(self, header: np.ndarray, body: bytes,
-                         readmit: bool = False) -> None:
+                         readmit: bool = False,
+                         arrived: int | None = None) -> None:
         """Queue a request exactly once: broadcast retransmissions of
         the same (client, request) must not pile up (a batched drain
         would execute every copy).
@@ -1062,7 +1096,9 @@ class VsrReplica(Replica):
             ):
                 self._shed_request(header, tenant)
                 return
-        self._queued_keys.add(key)
+        if arrived is None:
+            arrived = self.tracer.stamp(self._h_request_wait)
+        self._queued_keys[key] = arrived
         self.anatomy.stage_h(header, "queued")
         self.request_queue.append((header, body))
         if self.qos is not None:
@@ -1136,9 +1172,13 @@ class VsrReplica(Replica):
             else:
                 self._tenant_depth.pop(self._last_pop_tenant, None)
         h, b = self.request_queue.pop(idx)
-        self._queued_keys.discard(
-            (wire.u128(h, "client"), int(h["request"]))
+        arrived = self._queued_keys.pop(
+            (wire.u128(h, "client"), int(h["request"])), None
         )
+        if arrived is not None:
+            self._h_request_wait.observe(
+                (self.tracer.clock() - arrived) / 1e3
+            )
         if self.qos is not None and not self.request_queue:
             # Queue drained: the overload episode (if any) is over;
             # the next pops are FIFO again until the next shed.
@@ -1322,6 +1362,15 @@ class VsrReplica(Replica):
         self, request: np.ndarray, body: bytes,
         subs: list[tuple[int, int, int]] | None = None,
     ) -> None:
+        with self.tracer.stage(self._st_prepare):
+            prepare = self._build_prepare(request, body, subs)
+        self._replicate(prepare, body)
+        self._maybe_commit_pipeline()
+
+    def _build_prepare(self, request: np.ndarray, body: bytes,
+                       subs) -> np.ndarray:
+        """One prepare from header build to pipeline entry (the
+        vsr.prepare stage; the WAL write inside is its own leaf)."""
         operation = int(request["operation"])
         self._advance_prepare_timestamp()
         if operation >= constants.VSR_OPERATIONS_RESERVED:
@@ -1330,12 +1379,6 @@ class VsrReplica(Replica):
         timestamp = self.sm.prepare_timestamp
 
         op = self.op + 1
-        # The instrument times exactly the spans the native pipeline
-        # replaces — header build + checksum stamping here, pipeline
-        # bookkeeping below — NOT sm.prepare / WAL write / replicate
-        # (body-proportional or I/O work both arms share; including it
-        # buried the arm delta under disk + scheduler noise).
-        t0 = time.perf_counter_ns()
         if self._np is not None:
             # Native arm: one C call builds + checksums the prepare
             # header (client/request/operation/trace copied from the
@@ -1380,7 +1423,6 @@ class VsrReplica(Replica):
             else:
                 wire.finalize_header(prepare, body)
                 self._c_hash_bytes.inc(len(body))
-        build_ns = time.perf_counter_ns() - t0
         self.anatomy.stage_h(prepare, "prepare")
 
         self._journal_write(prepare, body)
@@ -1391,17 +1433,13 @@ class VsrReplica(Replica):
         # prepare supersedes it (a matching stale fill would otherwise
         # overwrite this slot — seed 460991023).
         self._repair_wanted.pop(op, None)
-        t1 = time.perf_counter_ns()
         synced = not self._gc_enabled
         self.pipeline[op] = PipelineEntry(
             prepare, body, {self.replica}, subs, synced=synced,
         )
         if self._np is not None:
             self._np.note_prepare(prepare, synced, self.replica)
-        build_ns += time.perf_counter_ns() - t1
-        self._h_prepare_us.observe(build_ns / 1000.0)
-        self._replicate(prepare, body)
-        self._maybe_commit_pipeline()
+        return prepare
 
     def _primary_prepare_plan(
         self,
@@ -1433,6 +1471,12 @@ class VsrReplica(Replica):
                     self._primary_prepare(head, pbody)
             return
 
+        with self.tracer.stage(self._st_prepare) as run:
+            run.split(len(plan))
+            self._build_prepares_native(plan)
+        self._maybe_commit_pipeline()
+
+    def _build_prepares_native(self, plan: list) -> None:
         from tigerbeetle_tpu.constants import SECTOR_SIZE
         from tigerbeetle_tpu.runtime import fastpath as _fastpath
         from tigerbeetle_tpu.vsr.journal import HEADERS_PER_SECTOR
@@ -1459,7 +1503,6 @@ class VsrReplica(Replica):
             bodies.append(pbody)
 
         op0 = self.op + 1
-        t0 = time.perf_counter_ns()
         built = _fastpath.build_prepares(
             self._np, req_hdrs, bodies, timestamps, contexts,
             cluster=self.cluster, view=self.view, op0=op0,
@@ -1471,7 +1514,6 @@ class VsrReplica(Replica):
             sector_size=SECTOR_SIZE,
             reuse=self._hash_reuse,
         )
-        build_ns = time.perf_counter_ns() - t0
         if self._hash_reuse:
             self._c_hash_reuse.inc(k)
         else:
@@ -1492,7 +1534,6 @@ class VsrReplica(Replica):
         wal_arena, wal_off, wal_len, slots, sector_arena, sector_index = (
             frames
         )
-        per_prepare_us = build_ns / k / 1000.0
         wal_mv = memoryview(wal_arena)
         sector_mv = memoryview(sector_arena)
         for i in range(k):
@@ -1518,9 +1559,7 @@ class VsrReplica(Replica):
                 prepare, bodies[i], {self.replica}, plan[i][2],
                 synced=False,
             )
-            self._h_prepare_us.observe(per_prepare_us)
             self._replicate(prepare, bodies[i])
-        self._maybe_commit_pipeline()
 
     def _replicate(self, prepare: np.ndarray, body: bytes) -> None:
         """Ring forwarding: send to successor only (reference:
@@ -1645,22 +1684,24 @@ class VsrReplica(Replica):
             self.commit_parent = wire.u128(entry.header, "checksum")
             self.commit_max = max(self.commit_max, op)
             client = wire.u128(entry.header, "client")
-            if entry.subs:
-                # Batched prepare: forward each sub-request's OWN
-                # reply, captured at commit — re-reading the session's
-                # stored reply here would send the batch's LAST reply
-                # to every sub when one client multiplexed several
-                # requests into the batch (open-loop sessions).
-                batch_replies, self._batch_replies = (
-                    self._batch_replies, []
-                )
-                for sub_client, rh_bytes, piece in batch_replies:
-                    self._gc_send_client(
-                        sub_client,
-                        wire.header_from_bytes(rh_bytes), piece,
+            with self.tracer.stage(self._st_reply_send):
+                if entry.subs:
+                    # Batched prepare: forward each sub-request's OWN
+                    # reply, captured at commit — re-reading the
+                    # session's stored reply here would send the
+                    # batch's LAST reply to every sub when one client
+                    # multiplexed several requests into the batch
+                    # (open-loop sessions).
+                    batch_replies, self._batch_replies = (
+                        self._batch_replies, []
                     )
-            elif client:
-                self._send_reply(entry.header, reply_body)
+                    for sub_client, rh_bytes, piece in batch_replies:
+                        self._gc_send_client(
+                            sub_client,
+                            wire.header_from_bytes(rh_bytes), piece,
+                        )
+                elif client:
+                    self._send_reply(entry.header, reply_body)
             # The request's timeline closes at reply: e2e into the
             # anatomy histogram, tail exemplars retained.
             self.anatomy.finish_h(entry.header, "reply")
@@ -1700,6 +1741,16 @@ class VsrReplica(Replica):
             return
         if self._anchor_pending:
             return  # canonical head checksum still being repaired
+        if not self.request_queue:
+            return
+        # The drain's own work (pops, the at-most-once gate, coalescing)
+        # adds to vsr.prepare's sum without a sample: the prepares it
+        # builds bring theirs, and suspend this run while they do.
+        with self.tracer.stage(self._st_prepare) as run:
+            run.split(0)
+            self._drain_request_queue_impl()
+
+    def _drain_request_queue_impl(self) -> None:
         requeue: list[tuple[np.ndarray, bytes]] = []
         # ONE in-flight scan per drain, updated incrementally as
         # prepares land (the scan walks the pipeline + uncommitted
@@ -1708,11 +1759,7 @@ class VsrReplica(Replica):
         # ingest path is built to avoid).  Committed-then-stale keys
         # are harmless: the session-table check runs first in
         # _request_dedupe and already answers for them.
-        inflight = (
-            self._inflight_requests(include_queue=False)
-            if self.request_queue
-            else None
-        )
+        inflight = self._inflight_requests(include_queue=False)
         # Drain plan (r22): with group commit on, a new prepare CANNOT
         # commit mid-drain (entries start unsynced until the covering
         # flush), so the drain first COLLECTS the whole run and then
@@ -1931,7 +1978,7 @@ class VsrReplica(Replica):
         if self._wal_sync_worker is not None and self._gc_sync_job is None:
             self._gc_sync_cover = self.journal.unsynced_writes
             self._gc_sync_job = self._wal_sync_worker.submit(
-                self.storage.sync_wal
+                self.journal.sync_wal_on_worker
             )
 
     def _journal_write_framed(
@@ -1956,7 +2003,7 @@ class VsrReplica(Replica):
         if self._wal_sync_worker is not None and self._gc_sync_job is None:
             self._gc_sync_cover = self.journal.unsynced_writes
             self._gc_sync_job = self._wal_sync_worker.submit(
-                self.storage.sync_wal
+                self.journal.sync_wal_on_worker
             )
 
     def _gc_defer(self) -> bool:
@@ -1983,9 +2030,9 @@ class VsrReplica(Replica):
     def _gc_covering_sync(self) -> None:
         """Make every deferred WAL write durable NOW (acks stay
         buffered — flush_group_commit releases them)."""
-        with self.tracer.span(
-            "gc_covering_sync", deferred=self.journal.unsynced_writes
-        ), self._h_gc_sync.time():
+        with self.tracer.stage(
+            self._st_gc_sync, deferred=self.journal.unsynced_writes
+        ):
             job, self._gc_sync_job = self._gc_sync_job, None
             if job is not None:
                 job.result()
@@ -2016,32 +2063,8 @@ class VsrReplica(Replica):
             self._gc_covering_sync()
             self.stat_gc_flushes += 1
         if self._gc_pending:
-            pending, self._gc_pending = self._gc_pending, []
-            # Scatter-gather release (r22): a backup drain typically
-            # defers a whole run of prepare_oks to ONE destination (the
-            # primary) — batch those into a single vectored bus call
-            # when the transport supports it.  Mixed destinations or
-            # client replies keep the in-order per-frame loop.
-            send_frames = getattr(self.bus, "send_frames", None)
-            if (
-                self._drain_native
-                and send_frames is not None
-                and len(pending) > 1
-                and all(
-                    kind == "replica" and dst == pending[0][1]
-                    for kind, dst, _h, _b in pending
-                )
-            ):
-                send_frames(
-                    pending[0][1],
-                    [(header, body) for _k, _d, header, body in pending],
-                )
-            else:
-                for kind, dst, header, body in pending:
-                    if kind == "client":
-                        self.bus.send_client(dst, header, body)
-                    else:
-                        self.bus.send(dst, header, body)
+            with self.tracer.stage(self._st_reply_send):
+                self._gc_release()
         # The covering sync makes our self-votes count: commit any
         # pipeline entries that were waiting on it (their replies go
         # out directly — nothing is deferred any more).
@@ -2053,6 +2076,36 @@ class VsrReplica(Replica):
             if self._np is not None:
                 self._np.mark_all_synced()
             self._maybe_commit_pipeline()
+
+    def _gc_release(self) -> None:
+        """The acks the covering sync gated (prepare_ok, client
+        replies, evictions) go out, in order."""
+        pending, self._gc_pending = self._gc_pending, []
+        # Scatter-gather release (r22): a backup drain typically
+        # defers a whole run of prepare_oks to ONE destination (the
+        # primary) — batch those into a single vectored bus call
+        # when the transport supports it.  Mixed destinations or
+        # client replies keep the in-order per-frame loop.
+        send_frames = getattr(self.bus, "send_frames", None)
+        if (
+            self._drain_native
+            and send_frames is not None
+            and len(pending) > 1
+            and all(
+                kind == "replica" and dst == pending[0][1]
+                for kind, dst, _h, _b in pending
+            )
+        ):
+            send_frames(
+                pending[0][1],
+                [(header, body) for _k, _d, header, body in pending],
+            )
+        else:
+            for kind, dst, header, body in pending:
+                if kind == "client":
+                    self.bus.send_client(dst, header, body)
+                else:
+                    self.bus.send(dst, header, body)
 
     def _aof_barrier(self) -> None:
         # The AOF must never record an op a crash could erase from the

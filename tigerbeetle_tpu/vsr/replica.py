@@ -107,8 +107,34 @@ class Replica:
             "stat_ckpt_sync": self.metrics.counter("ckpt.sync"),
         }
         self._c_commits = self.metrics.counter("commits")
-        self._h_commit = self.metrics.histogram("commit_us")
+        # Client requests carried by committed prepares (a coalesced
+        # prepare carries several): requests_committed / commits is
+        # how well the drain coalesces.
+        self._c_requests_committed = self.metrics.counter(
+            "requests_committed"
+        )
         self._h_request = self.metrics.histogram("request_us")
+        # Stages (utils/tracer.py): names are the scrape keys less
+        # `_us`, under the "vsr." the owning server attaches this
+        # registry at.  `vsr.commit` encloses the four commit leaves
+        # and the state machine's own (`sm.plan`, `sm.dev.*`).
+        from tigerbeetle_tpu.utils.tracer import Stage
+
+        hist = self.metrics.histogram
+        self._st_commit = Stage(hist("commit_us"), "vsr.commit", leaf=False)
+        self._st_prefetch = Stage(
+            hist("commit.prefetch_us"), "vsr.commit.prefetch"
+        )
+        self._st_reply = Stage(hist("commit.reply_us"), "vsr.commit.reply")
+        self._st_beat = Stage(hist("commit.beat_us"), "vsr.commit.beat")
+        self._st_ckpt_freeze = Stage(
+            hist("ckpt.freeze_us"), "vsr.ckpt.freeze"
+        )
+        # On the checkpoint worker: annotated on its own thread, out of
+        # the loop's sums.
+        self._st_ckpt_finalize = Stage(
+            hist("ckpt.finalize_us"), "vsr.ckpt.finalize", tid=2
+        )
         # Hash-once commit path (round 23).  hash.bytes_hashed counts
         # BODY bytes actually SHA-256'd on this replica (ingress
         # verify, build rehashes under TB_HASH_REUSE=0, and the
@@ -138,8 +164,6 @@ class Replica:
         # server re-points this at its own `server.reply_encode_us`
         # histogram so the drain-loop instruments sit together.
         self.h_reply_encode = self.metrics.histogram("reply_encode_us")
-        self._h_ckpt_freeze = self.metrics.histogram("ckpt.freeze_us")
-        self._h_ckpt_finalize = self.metrics.histogram("ckpt.finalize_us")
         self.metrics.gauge_fn("commit_min", lambda: self.commit_min)
         # Per-request anatomy (obs/anatomy.py): stage timelines for
         # sampled requests, keyed by the wire trace context.  Enabled
@@ -486,9 +510,8 @@ class Replica:
         lifecycle)."""
         self.tracer = tracer
         self.journal.tracer = tracer
-        dev = getattr(self.sm, "_dev", None)
-        if dev is not None and hasattr(dev, "tracer"):
-            dev.tracer = tracer
+        if hasattr(self.sm, "set_tracer"):
+            self.sm.set_tracer(tracer)
 
     def _commit_prepare(self, header: np.ndarray, body: bytes,
                         replay: bool = False) -> bytes:
@@ -497,11 +520,10 @@ class Replica:
         in the `commit` span + commit_us histogram so per-op commit
         latency is scrapeable (bench sources its commit percentiles
         from this, not from re-derived timings)."""
-        with self.tracer.span(
-            "commit", op=int(header["op"])
-        ), self._h_commit.time():
+        with self.tracer.stage(self._st_commit, op=int(header["op"])):
             reply = self._commit_prepare_impl(header, body, replay)
         self._c_commits.inc()
+        self._c_requests_committed.inc(wire.u128(header, "context") or 1)
         # Ratio denominator for the hash-once contract: every body
         # byte this replica commits.  The TCP smoke asserts
         # bytes_hashed / committed_body_bytes <= 1.0 per role with
@@ -600,8 +622,8 @@ class Replica:
                 # Logically-batched prepare: commit the combined event
                 # batch once, then demux + store each sub-request's
                 # reply slice (state_machine/demuxer.py).
-                events, subs = demuxer.decode_trailer(body, n_subs)
-                with self.tracer.span("state_machine_prefetch"):
+                with self.tracer.stage(self._st_prefetch):
+                    events, subs = demuxer.decode_trailer(body, n_subs)
                     self.sm.prefetch(
                         sm_op, events, prefetch_timestamp=timestamp
                     )
@@ -611,52 +633,14 @@ class Replica:
                     reply = self.sm.commit(
                         client, op, timestamp, sm_op, events
                     )
-                dm = demuxer.Demuxer(sm_op, reply)
-                offset = 0
-                pieces = []
-                for _sub_client, _sub_request, count in subs:
-                    pieces.append(dm.decode(offset, count))
-                    offset += count
-                # Per-sub replies captured AT commit: a session stores
-                # only its LATEST reply, so when one batch multiplexes
-                # several requests of the SAME client (open-loop
-                # sessions keep many in flight), sending the stored
-                # reply N times would answer every sub with the last
-                # request's bytes — earlier subs would never resolve.
-                # The pipeline sends these captured pairs instead.
-                #
-                # Coalesced encode (columnar ingest, round 14): ALL sub
-                # reply headers are built in one vectorized pass and
-                # checksummed in one batch finalize — replacing per-sub
-                # make_header + 2 hashlib calls — then scattered to
-                # sessions in sub order (bit-identical bytes to the
-                # old per-sub path).
-                with self.h_reply_encode.time():
-                    rhdrs = self._encode_sub_replies(header, subs, pieces)
-                self._batch_replies = []
-                for i, (sub_client, sub_request, _count) in enumerate(subs):
-                    if not sub_client:
-                        continue
-                    entry = self.sessions.get(sub_client)
-                    if entry is None:  # un-registered (tests drive raw)
-                        continue
-                    piece = pieces[i]
-                    entry.request = sub_request
-                    entry.reply_header = rhdrs[i].tobytes()
-                    msg = entry.reply_header + piece
-                    self.storage.write(
-                        self.storage.layout.reply_slot_offset(entry.slot),
-                        msg.ljust(_sectors(len(msg)), b"\x00"),
-                    )
-                    self._batch_replies.append(
-                        (sub_client, entry.reply_header, piece)
-                    )
+                with self.tracer.stage(self._st_reply):
+                    self._store_sub_replies(header, sm_op, reply, subs)
+                    if self.hash_log is not None and not replay:
+                        self.hash_log.record(op, header.tobytes(), reply)
                 self._compact_beat()
                 self.commit_min = op
-                if self.hash_log is not None and not replay:
-                    self.hash_log.record(op, header.tobytes(), reply)
                 return reply
-            with self.tracer.span("state_machine_prefetch"):
+            with self.tracer.stage(self._st_prefetch):
                 self.sm.prefetch(sm_op, body, prefetch_timestamp=timestamp)
             with self.tracer.span(
                 "state_machine_commit", op=op, bytes=len(body)
@@ -665,14 +649,61 @@ class Replica:
 
         self._compact_beat()
         self.commit_min = op
-        # Replayed commits are not recorded: a recovered WAL tail may
-        # include speculative ops that never reached quorum and are
-        # later superseded (two-step repair corrects the state).
-        if self.hash_log is not None and not replay:
-            self.hash_log.record(op, header.tobytes(), reply)
-        if client and operation != int(VsrOperation.register):
-            self._store_reply(header, reply)
+        with self.tracer.stage(self._st_reply):
+            # Replayed commits are not recorded: a recovered WAL tail
+            # may include speculative ops that never reached quorum
+            # and are later superseded (two-step repair corrects the
+            # state).
+            if self.hash_log is not None and not replay:
+                self.hash_log.record(op, header.tobytes(), reply)
+            if client and operation != int(VsrOperation.register):
+                self._store_reply(header, reply)
         return reply
+
+    def _store_sub_replies(self, header: np.ndarray, sm_op, reply: bytes,
+                           subs) -> None:
+        """Demux a coalesced prepare's reply and store each
+        sub-request's slice (state_machine/demuxer.py)."""
+        dm = demuxer.Demuxer(sm_op, reply)
+        offset = 0
+        pieces = []
+        for _sub_client, _sub_request, count in subs:
+            pieces.append(dm.decode(offset, count))
+            offset += count
+        # Per-sub replies captured AT commit: a session stores
+        # only its LATEST reply, so when one batch multiplexes
+        # several requests of the SAME client (open-loop
+        # sessions keep many in flight), sending the stored
+        # reply N times would answer every sub with the last
+        # request's bytes — earlier subs would never resolve.
+        # The pipeline sends these captured pairs instead.
+        #
+        # Coalesced encode (columnar ingest, round 14): ALL sub
+        # reply headers are built in one vectorized pass and
+        # checksummed in one batch finalize — replacing per-sub
+        # make_header + 2 hashlib calls — then scattered to
+        # sessions in sub order (bit-identical bytes to the
+        # old per-sub path).
+        with self.h_reply_encode.time():
+            rhdrs = self._encode_sub_replies(header, subs, pieces)
+        self._batch_replies = []
+        for i, (sub_client, sub_request, _count) in enumerate(subs):
+            if not sub_client:
+                continue
+            entry = self.sessions.get(sub_client)
+            if entry is None:  # un-registered (tests drive raw)
+                continue
+            piece = pieces[i]
+            entry.request = sub_request
+            entry.reply_header = rhdrs[i].tobytes()
+            msg = entry.reply_header + piece
+            self.storage.write(
+                self.storage.layout.reply_slot_offset(entry.slot),
+                msg.ljust(_sectors(len(msg)), b"\x00"),
+            )
+            self._batch_replies.append(
+                (sub_client, entry.reply_header, piece)
+            )
 
     # ------------------------------------------------------------------
     # Reconfiguration (reference: src/vsr.zig:273-311).
@@ -774,6 +805,10 @@ class Replica:
         on a whole interval's worth."""
         if self.forest is None:
             return
+        with self.tracer.stage(self._st_beat):
+            self._compact_beat_impl()
+
+    def _compact_beat_impl(self) -> None:
         # Spill/compaction beats keep running through an async flip
         # window: allocation is safe because the FreeSet quarantines
         # the frozen checkpoint's released blocks from reuse until the
@@ -924,9 +959,7 @@ class Replica:
         if self.op > base:
             self._ckpt_interval_observed = self.op - base
         with self.tracer.span("checkpoint", op=self.commit_min):
-            with self.tracer.span(
-                "ckpt_freeze", op=self.commit_min
-            ), self._h_ckpt_freeze.time():
+            with self.tracer.stage(self._st_ckpt_freeze, op=self.commit_min):
                 args = self._checkpoint_freeze()
             self._ckpt_last_op = self.commit_min
             if self._ckpt_worker is not None:
@@ -1010,7 +1043,7 @@ class Replica:
                              members, state_root) -> None:
         """Disk half (checkpoint worker in async mode): everything the
         new superblock references must be durable before the flip."""
-        with self._h_ckpt_finalize.time():
+        with self.tracer.stage(self._st_ckpt_finalize):
             self._checkpoint_finalize_impl(
                 commit_min, head_checksum, offset, size, blob_checksum,
                 view, epoch, members, state_root,
