@@ -70,10 +70,11 @@ def replay_pipelined(h_d, h_c, ops):
     return replies_d
 
 
-@pytest.mark.parametrize("stage", ["h2d", "dispatch", "fetch"])
+@pytest.mark.parametrize("stage", ["h2d", "dispatch", "fetch_start", "fetch"])
 def test_demote_at_every_stage_resolves_inflight(stage):
-    """Fatal loss at each pipeline stage (pre-upload, mid-dispatch, at
-    ring fetch): every in-flight future resolves bit-identically via
+    """Fatal loss at each pipeline stage (pre-upload, mid-dispatch, as a
+    summary's copy home starts, at its fetch): every in-flight future
+    resolves bit-identically via
     host replay, and the engine lands in degraded mode."""
     h_d, h_c, link = mk_chaos_pair()
     ops = simple_ops()

@@ -139,11 +139,8 @@ def test_cross_batch_duplicate_id_hazard():
     replay_both(h_d, h_c, ops)
 
 
-def test_fallback_overflow_orderfree():
-    """Amounts near 2^128 trip the admission check -> exact host
-    fallback, still bit-identical to the oracle."""
+def _overflow_ops():
     big = (1 << 127) + 5
-    h_d, h_c = mk_pair()
     ops = [(Operation.create_accounts, accounts([1, 2, 3]))]
     # Two debits of ~2^127 on the same account: the second overflows
     # debits_posted, so total-sum admission must refuse the batch.
@@ -171,7 +168,14 @@ def test_fallback_overflow_orderfree():
         )
     )
     ops.append((Operation.lookup_accounts, hz.ids_bytes([1, 2, 3])))
-    replay_both(h_d, h_c, ops)
+    return ops
+
+
+def test_fallback_overflow_orderfree():
+    """Amounts near 2^128 trip the admission check -> exact host
+    fallback, still bit-identical to the oracle."""
+    h_d, h_c = mk_pair()
+    replay_both(h_d, h_c, _overflow_ops())
     assert h_d.sm._dev.stat_fallback_batches >= 1
 
 
@@ -271,24 +275,25 @@ def test_recovery_with_pending_window_stays_ordered(monkeypatch):
     h_d.sm.verify_device_mirror()
 
 
-def test_fallback_cap_exceeded():
-    """More failures than the summary cap -> host re-execution with
-    full failure list."""
-    h_d, h_c = mk_pair()
+def _cap_ops():
     ops = [(Operation.create_accounts, accounts([1, 2]))]
     rows = [
         dict(id=100 + i, debit_account_id=1, credit_account_id=1, amount=1)
         for i in range(100)  # accounts_must_be_different x100 > cap 60
     ]
     ops.append((Operation.create_transfers, transfers(rows)))
-    replay_both(h_d, h_c, ops)
+    return ops
+
+
+def test_fallback_cap_exceeded():
+    """More failures than the summary cap -> host re-execution with
+    full failure list."""
+    h_d, h_c = mk_pair()
+    replay_both(h_d, h_c, _cap_ops())
     assert h_d.sm._dev.stat_fallback_batches >= 1
 
 
-def test_linked_precondition_fallback():
-    """Limit accounts with u128-scale balances exceed the fixpoint's
-    u64-safety precondition -> device flags, host decides."""
-    h_d, h_c = mk_pair()
+def _precond_ops():
     huge = 1 << 62
     ops = [
         (
@@ -320,7 +325,43 @@ def test_linked_precondition_fallback():
         )
     )
     ops.append((Operation.lookup_accounts, hz.ids_bytes([1, 2, 3])))
-    replay_both(h_d, h_c, ops)
+    return ops
+
+
+def test_linked_precondition_fallback():
+    """Limit accounts with u128-scale balances exceed the fixpoint's
+    u64-safety precondition -> device flags, host decides."""
+    h_d, h_c = mk_pair()
+    replay_both(h_d, h_c, _precond_ops())
+
+
+@pytest.mark.parametrize("flag,ops_of", [
+    ("FLAG_OVERFLOW", _overflow_ops),
+    ("FLAG_CAP", _cap_ops),
+    ("FLAG_PRECOND", _precond_ops),
+])
+def test_a_flagged_window_recovers_from_its_own_rows(monkeypatch, flag, ops_of):
+    """The flag reaches the host in the window's own fetched row (an
+    output of the kernel: no ring to index), and that row is what
+    sends the window through _resolve_recovery."""
+    from tigerbeetle_tpu.state_machine import device_engine as de
+    from tigerbeetle_tpu.state_machine import device_kernels as dk
+
+    seen = []
+    real = de.DeviceEngine._resolve_recovery
+
+    def spy(self, covered):
+        for rec in covered:
+            if rec.kind in de._SEMANTIC_KINDS:
+                assert rec.out.rows.shape[1:] == (dk.SUMMARY_WORDS,)
+                seen.append(int(rec.out.rows[rec.row][1]))
+        return real(self, covered)
+
+    monkeypatch.setattr(de.DeviceEngine, "_resolve_recovery", spy)
+    h_d, h_c = mk_pair()
+    replay_both(h_d, h_c, ops_of())
+    assert any(flags & getattr(dk, flag) for flags in seen), seen
+    assert h_d.sm._dev.stat_fallback_batches >= 1
 
 
 def test_linked_fixpoint_multi_iteration():
@@ -960,3 +1001,162 @@ def test_resource_exhausted_is_not_retried():
     with pytest.raises(de.DeviceLostError):
         eng._retry(oom, "dispatch")
     assert len(calls) == 1 and eng.stat_retries == 0
+
+
+# ----------------------------------------------------------------------
+# A window crosses the link once each way (PR 27).
+
+import jax  # noqa: E402
+
+from tigerbeetle_tpu.state_machine.device_engine import DeviceLink  # noqa: E402
+
+
+class _RecordingLink(DeviceLink):
+    """DeviceLink that writes down every crossing, in order."""
+
+    def __init__(self) -> None:
+        self.log = []
+
+    def device_put(self, array, sharding=None):
+        self.log.append(("put", tuple(array.shape)))
+        return super().device_put(array, sharding)
+
+    def dispatch(self, fn, *args):
+        name = getattr(fn, "__name__", None) or fn.__wrapped__.__name__
+        # Every argument is already on the device: no Python scalar, no
+        # numpy array, nothing the call itself would have to upload.
+        flat = jax.tree_util.tree_leaves(args)
+        assert all(isinstance(a, jax.Array) for a in flat), (name, args)
+        self.log.append(("dispatch", name, len(args)))
+        return super().dispatch(fn, *args)
+
+    def copy_to_host_async(self, array):
+        self.log.append(("copy_start", tuple(array.shape)))
+        super().copy_to_host_async(array)
+
+    def fetch(self, array):
+        self.log.append(("fetch", array.nbytes))
+        return super().fetch(array)
+
+
+def _sealed_batch(dk, kind, n):
+    """A packed batch of `n` events that touch rows 0 and 1 (their
+    ladder verdicts do not matter here, only how they cross)."""
+    ncols, dtype = dk.PK_SPEC[kind]
+    pk = np.zeros((dk.ROWS, ncols), dtype)
+    if kind == "orderfree_tight":
+        pk[:n, 1], pk[:n, 2] = 1, 2
+    else:
+        pk[:n, dk.COL_SLOTS] = 1 | (2 << 32)
+    return pk
+
+
+_ALL_KINDS = (
+    "orderfree", "orderfree_lo", "orderfree_tight", "linked",
+    "linked_small", "two_phase", "two_phase_lo",
+)
+
+
+@pytest.mark.parametrize(
+    "kind,G",
+    [(k, 1) for k in _ALL_KINDS]
+    + [("orderfree_tight", 4), ("linked_small", 4), ("two_phase_lo", 4)],
+)
+def test_a_window_crosses_the_link_once_each_way(monkeypatch, kind, G):
+    """Per dispatch unit: ONE upload (the scalars ride in it), the
+    kernel's dispatch on device arrays alone, one started copy of the
+    unit's own summary rows, then the digest's two uploads and its
+    dispatch; at the rotation one fetch of 512 bytes a record."""
+    from tigerbeetle_tpu.state_machine import device_engine as de
+    from tigerbeetle_tpu.state_machine import device_kernels as dk
+
+    monkeypatch.setattr(de, "_WINDOW", G)
+    link = _RecordingLink()
+    eng = de.DeviceEngine(64, BalanceMirror(64), link=link)
+    link.log.clear()
+    puts0, bytes0 = eng.stat_puts, eng.stat_fetch_bytes
+    got = []
+    ts_base = (7 << 32) | 9           # both halves of the tight format
+    futs = [
+        eng.submit(
+            kind, _sealed_batch(dk, kind, n=2 + g), 2 + g, ts_base,
+            lambda s, g=g: got.append((g, s["n_active"])) or b"ok",
+            lambda: b"host",
+        )
+        for g in range(G)
+    ]
+    ncols, _dtype = dk.PK_SPEC[kind]
+    up = (dk.ROWS, ncols) if G == 1 else (G, dk.ROWS, ncols)
+    down = (dk.SUMMARY_WORDS,) if G == 1 else (G, dk.SUMMARY_WORDS)
+    # The window filled at the last submit: it is launched, not fetched.
+    program = link.log[1][1]
+    assert kind in program and (G == 1 or f"scan_{kind}_g{G}" == program)
+    launched = [
+        ("put", up),
+        ("dispatch", program, 3),
+        ("copy_start", down),
+        ("dispatch", "_update", 6),
+    ]
+    assert link.log == launched
+    eng.drain()
+    assert link.log == launched + [("fetch", 512 * G)]
+    assert [f.result() for f in futs] == [b"ok"] * G
+    # n crossed inside the buffer, record by record, in order.
+    assert got == [(g, 2 + g) for g in range(G)]
+    assert eng.stat_puts - puts0 == 1 + 2
+    assert eng.stat_fetch_bytes - bytes0 == 512 * G
+    assert eng.stat_fallback_batches == 0 and eng.stat_demotions == 0
+
+
+def test_the_scalars_cross_in_the_buffers_last_row():
+    """seal_scalars on the host, _split_scalars on the device: n and a
+    ts_base wider than 32 bits, through both packed formats."""
+    import jax
+
+    from tigerbeetle_tpu.state_machine import device_kernels as dk
+
+    ts_base = (0x1234 << 32) | 0x89ABCDEF
+    for kind in ("orderfree", "orderfree_tight"):
+        ncols, dtype = dk.PK_SPEC[kind]
+        pk = dk.seal_scalars(np.ones((dk.ROWS, ncols), dtype), 37, ts_base)
+        assert (pk[: dk.B] == 1).all()
+        rows, n, ts = jax.jit(dk._split_scalars)(pk)
+        assert rows.shape == (dk.B, ncols)
+        assert (int(n), int(ts)) == (37, ts_base)
+    with pytest.raises(AssertionError):
+        dk.seal_scalars(np.zeros((dk.B, 6), np.uint64), 1, 1)
+
+
+@pytest.mark.parametrize("stage", ["h2d", "fetch_start", "fetch"])
+def test_a_fault_at_each_crossing_of_a_prepare_demotes_once(stage):
+    """A fatal fault at the upload, at the copy's start, and at its
+    wait: the reply comes bit-identical from the host fallback, and the
+    demotion is counted once."""
+    from tigerbeetle_tpu.testing.chaos import ChaosLink
+    from tigerbeetle_tpu.types import EngineState
+
+    link = ChaosLink(seed=27)
+    sm_d = TpuStateMachine(
+        engine="device", account_capacity=1 << 12, device_link=link
+    )
+    h_d, h_c = hz.SingleNodeHarness(sm_d), hz.SingleNodeHarness(CpuStateMachine())
+    setup = (Operation.create_accounts, accounts([1, 2, 3]))
+    assert h_d.submit(*setup) == h_c.submit(*setup)
+    dev = sm_d._dev
+    assert dev.state is EngineState.healthy and dev.stat_demotions == 0
+    link.fail_next(stage=stage, kind="fatal")
+    batch = (
+        Operation.create_transfers,
+        transfers(
+            [
+                dict(id=10, debit_account_id=1, credit_account_id=2, amount=5),
+                dict(id=11, debit_account_id=2, credit_account_id=2, amount=1),
+                dict(id=12, debit_account_id=3, credit_account_id=1, amount=7),
+            ]
+        ),
+    )
+    assert h_d.submit(*batch) == h_c.submit(*batch)
+    assert link.stat_fatal == 1 and dev.stat_demotions == 1
+    assert dev.stat_degraded_events == 3 and not dev.has_inflight()
+    look = (Operation.lookup_accounts, hz.ids_bytes([1, 2, 3]))
+    assert h_d.submit(*look) == h_c.submit(*look)

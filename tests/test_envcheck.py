@@ -6,9 +6,6 @@ import pytest
 
 from tigerbeetle_tpu import envcheck
 from tigerbeetle_tpu.state_machine import waves
-from tigerbeetle_tpu.state_machine.device_engine import (
-    _validate_window_ring,
-)
 
 
 def test_env_int_rejects_garbage(monkeypatch):
@@ -18,11 +15,11 @@ def test_env_int_rejects_garbage(monkeypatch):
 
 
 def test_env_int_bounds(monkeypatch):
-    monkeypatch.setenv("TB_DEV_RING", "0")
-    with pytest.raises(envcheck.EnvVarError, match="must be >= 2"):
-        envcheck.env_int("TB_DEV_RING", 256, minimum=2)
-    monkeypatch.setenv("TB_DEV_RING", "512")
-    assert envcheck.env_int("TB_DEV_RING", 256, minimum=2) == 512
+    monkeypatch.setenv("TB_DEV_PROBE_EVERY", "0")
+    with pytest.raises(envcheck.EnvVarError, match="must be >= 1"):
+        envcheck.env_int("TB_DEV_PROBE_EVERY", 8, minimum=1)
+    monkeypatch.setenv("TB_DEV_PROBE_EVERY", "512")
+    assert envcheck.env_int("TB_DEV_PROBE_EVERY", 8, minimum=1) == 512
 
 
 def test_env_int_default_when_unset(monkeypatch):
@@ -144,16 +141,6 @@ def test_tb_drain_batch_constraint_named(monkeypatch):
     assert envcheck.drain_batch_max() == 64
     monkeypatch.delenv("TB_DRAIN_BATCH")
     assert envcheck.drain_batch_max() == 4096
-
-
-def test_window_ring_constraint_named():
-    with pytest.raises(envcheck.EnvVarError) as err:
-        _validate_window_ring(200, 256)
-    message = str(err.value)
-    assert "TB_DEV_WINDOW" in message
-    assert "TB_DEV_RING" in message
-    assert "2*TB_DEV_WINDOW" in message
-    _validate_window_ring(128, 256)  # boundary is legal
 
 
 def test_tb_waves_mode_validated(monkeypatch):

@@ -340,6 +340,16 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     assert snap["vsr.request_wait_us.count"] >= 13
     inside = sum(snap[k + "_us.sum"] for k in COMMIT_LEAVES)
     assert 0.8 * snap["vsr.commit_us.sum"] < inside <= snap["vsr.commit_us.sum"]
+    # A prepare crosses the link once each way: its one fetch has a
+    # sample in both leaves and brings home its own 512-byte row; up go
+    # the packed buffer and the digest's two arrays.
+    fetched = snap["sm.dev.fetches"]
+    assert fetched == 12
+    assert snap["sm.dev.link.fetch_wait_us.count"] == fetched
+    assert snap["sm.dev.link.fetch_copy_us.count"] == fetched
+    assert snap["sm.dev.link.fetch_bytes"] == 512 * fetched
+    assert snap["sm.dev.link.fetch_start_us.count"] == fetched
+    assert 3 * fetched <= snap["sm.dev.link.puts"] < 4 * fetched + 8
 
     doc = json.load(open(tmp_path / "trace.json"))
     leaves = stage_names()

@@ -25,7 +25,6 @@ import pytest
 
 A = 1 << 16       # cli.CACHE_DEFAULT: the served table
 B = 8192          # production event bucket
-WINDOW = 96       # TB_DEV_WINDOW default
 KINDS = ("orderfree_tight", "linked_small", "two_phase_lo")
 
 
@@ -102,41 +101,41 @@ def _s(shape, dtype):
 def _tables(dk):
     import jax.numpy as jnp
 
-    return (
-        _s((A, 8), jnp.uint64), _s((A, 2), jnp.uint32),
-        _s((256, dk.SUMMARY_WORDS), jnp.uint64),
-    )
+    return _s((A, 8), jnp.uint64), _s((A, 2), jnp.uint32)
 
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_base_kernel_compiles_for_v5e(one_chip, dk, kind):
     """One batch per launch: the lone-request dispatch."""
+    import jax
     import jax.numpy as jnp
 
     ncols, dtype = dk.PK_SPEC[kind]
     fn = {"orderfree_tight": dk.orderfree_tight,
           "linked_small": dk.linked_small,
           "two_phase_lo": dk.two_phase_lo}[kind]
-    # Scalars as x64 makes the engine's Python ints: ring_at and n
-    # int64, ts_base uint64.
-    _compile(
-        fn, one_chip, *_tables(dk), _s((), jnp.int64),
-        _s((B, ncols), dtype), _s((), jnp.int64), _s((), jnp.uint64),
-    )
+    # The link's contract: the scalars ride in the buffer's last row,
+    # and the summary row comes back as an output of its own.
+    args = (*_tables(dk), _s((dk.ROWS, ncols), dtype))
+    _compile(fn, one_chip, *args)
+    table, row = jax.eval_shape(fn, *args)
+    assert table.shape == (A, 8)
+    assert (row.shape, row.dtype) == ((dk.SUMMARY_WORDS,), jnp.uint64)
 
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_window_scan_compiles_for_v5e(one_chip, dk, kind):
-    """Sixteen batches per launch out of a 96-batch window buffer."""
+    """Sixteen batches per launch out of one uploaded stack; their
+    summary rows come back as one (16, SUMMARY_WORDS) output."""
+    import jax
     import jax.numpy as jnp
 
     ncols, dtype = dk.PK_SPEC[kind]
-    _compile(
-        dk.scan_win_kernels[kind][16], one_chip, *_tables(dk),
-        _s((), jnp.int64), _s((WINDOW, B, ncols), dtype),
-        _s((), jnp.int64), _s((WINDOW,), jnp.int64),
-        _s((WINDOW,), jnp.uint64),
-    )
+    fn = dk.scan_kernels[kind][16]
+    args = (*_tables(dk), _s((16, dk.ROWS, ncols), dtype))
+    _compile(fn, one_chip, *args)
+    _table, rows = jax.eval_shape(fn, *args)
+    assert (rows.shape, rows.dtype) == ((16, dk.SUMMARY_WORDS), jnp.uint64)
 
 
 def test_speculative_wave_executor_compiles_for_v5e(one_chip):

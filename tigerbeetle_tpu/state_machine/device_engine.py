@@ -3,30 +3,35 @@
 Owns the authoritative HBM balance table + account-meta table and a
 stream of semantic-kernel dispatches (device_kernels.py).  The host
 submits packed batches and gets back *reply futures*; result codes are
-computed on device, ride the failure-sparse summary ring, and
-materialize once per execution window.
+computed on device, come home as failure-sparse summary rows (512
+bytes a batch, an output of the kernel itself), and materialize once
+per execution window.
 
-Execution model (r5: phase-separated windows)
----------------------------------------------
-The shape was dictated by a host<->device link measured in an earlier
-round and not re-measured since: a d2h fetch cost ~105 ms regardless
-of size, and any h2d issued while kernels were in flight stalled the
-stream for tens of milliseconds (experiments/stage_sweep.py).  Whether
-a local chip's link still asks for this is ROADMAP D2's question, not
-this module's.  So the engine never touches the link while the device
-is busy:
+Execution model: a window crosses the link once each way
+---------------------------------------------------------
+Measured on the v5e (PERF.md sections 6 and 7.8, PR 27's probe): an
+upload costs the host ~0.25 ms (160 KB or a scalar alike), a kernel's
+dispatch ~0.3 ms, a 512-byte d2h 0.35-0.5 ms when it starts at the
+fetch and 0.03 ms when it started at dispatch and the host had other
+work meanwhile; the chip runs a padded batch in ~1.9 ms.  The host's
+crossings bounded a prepare, not the kernel, so a window's schedule
+is:
 
   submit()  appends the packed batch to a host-side window; NOTHING
-            is dispatched until the window fills (TB_DEV_WINDOW).
-  rotate    at the window boundary: (1) fetch the summary ring for the
-            PREVIOUS window — the fetch drains the stream, leaving the
-            device idle; (2) while idle, upload the new window's
-            superbatches in one h2d per column layout and pull any
-            lookup-gather handles; (3) dispatch every kernel of the new
-            window back-to-back — zero in-stream transfers; (4) only
-            then run the previous window's host bookkeeping (finish
-            callbacks), overlapped with the device crunching the new
-            window.
+            is dispatched until the window fills (TB_DEV_WINDOW) or
+            is drained (the served path drains every prepare).
+  launch    per dispatch unit (one batch, or a same-kind scan chunk):
+            ONE upload that carries the batch's scalars in its last
+            row, the dispatch behind it (the runtime orders the h2d
+            before the kernel by data flow: nothing is waited for),
+            and the start of the copy home of the unit's own summary
+            rows (512 bytes a batch).  Then the digest's host work,
+            under the kernels and the copies.
+  rotate    at the window boundary: (1) wait for the PREVIOUS
+            window's summary copies; (2) launch the new window;
+            (3) only then run the previous window's host bookkeeping
+            (finish callbacks), overlapped with the device crunching
+            the new window.
 
 A batch whose summary carries a fallback flag (balance overflow in
 play, failure-cap exceeded, precondition violated) triggers exact
@@ -60,19 +65,6 @@ from tigerbeetle_tpu.types import EngineState
 from tigerbeetle_tpu.utils import tracer as tracer_mod
 
 _WINDOW = envcheck.env_int("TB_DEV_WINDOW", 96, minimum=1)
-_RING = envcheck.env_int("TB_DEV_RING", 256, minimum=2)
-
-
-def _validate_window_ring(window: int, ring: int) -> None:
-    if 2 * window > ring:
-        raise envcheck.EnvVarError(
-            f"TB_DEV_WINDOW={window} / TB_DEV_RING={ring} invalid: the "
-            "summary ring must hold two windows (2*TB_DEV_WINDOW <= "
-            "TB_DEV_RING)"
-        )
-
-
-_validate_window_ring(_WINDOW, _RING)
 
 # Link-robustness knobs: bounded retry with exponential backoff on
 # every link crossing, a health-probe cadence for re-promotion out of
@@ -102,9 +94,8 @@ _SCRUB_EVERY = envcheck.env_int("TB_DEV_SCRUB_EVERY", _PROBE_EVERY, minimum=0)
 _SCRUB_EVERY_LEGACY = 256
 # Maximum deterministic per-engine offset applied to the scrub cadence
 # so every engine's TB_DEV_SCRUB_EVERY-th fetch doesn't land on the
-# same ring rotation (each scrub costs one checksum fetch — ~105 ms on
-# the link measured in an earlier round, not re-measured).  -1 = auto
-# (an eighth of the cadence).
+# same window rotation (each scrub costs one dispatch and one 32-byte
+# fetch).  -1 = auto (an eighth of the cadence).
 _SCRUB_JITTER = envcheck.env_int("TB_DEV_SCRUB_JITTER", -1, minimum=-1)
 
 
@@ -224,8 +215,9 @@ class DeviceLink:
     goes through this object so the chaos harness (testing/chaos.py)
     can interpose a seeded fault-injecting shim, and so retry/
     classification lives in exactly one place (DeviceEngine._retry).
-    Stages: "h2d" (uploads), "dispatch" (kernel launches), "fetch"
-    (d2h reads), "probe" (health check).
+    Stages: "h2d" (uploads), "dispatch" (kernel launches),
+    "fetch_start" (a d2h copy started, not waited for), "fetch" (d2h
+    reads), "probe" (health check).
     """
 
     def device_put(self, array, sharding=None):
@@ -235,6 +227,11 @@ class DeviceLink:
 
     def block_until_ready(self, arrays):
         return jax.block_until_ready(arrays)
+
+    def copy_to_host_async(self, array) -> None:
+        """Start the d2h copy of `array` behind the program that
+        produces it; fetch() then finds the bytes on the host."""
+        array.copy_to_host_async()
 
     def fetch(self, array) -> np.ndarray:
         return np.asarray(array)
@@ -295,12 +292,12 @@ class _InFlight:
 
     __slots__ = (
         "kind", "pk", "n", "ts_base", "finish", "fallback", "future",
-        "ring_at", "id_keys", "handle", "slots", "rows", "meta_args",
+        "out", "row", "id_keys", "handle", "slots", "rows", "meta_args",
         "wave_args", "bound", "touched", "hot_slots",
     )
 
     def __init__(self, kind, future, finish, *, pk=None, n=0, ts_base=0,
-                 fallback=None, ring_at=-1, id_keys=None, handle=None,
+                 fallback=None, id_keys=None, handle=None,
                  slots=None, meta_args=None, wave_args=None, bound=0,
                  hot_slots=None):
         self.kind = kind
@@ -310,7 +307,10 @@ class _InFlight:
         self.finish = finish
         self.fallback = fallback
         self.future = future
-        self.ring_at = ring_at
+        # A semantic batch's summary: row `row` of its dispatch
+        # unit's output (_UnitOut), set at dispatch.
+        self.out = None
+        self.row = 0
         self.id_keys = id_keys  # sorted u128-packed ids (hazard probes)
         self.handle = handle    # lookup gather / wave packed-output handle
         self.slots = slots      # lookup slots, LOGICAL (host replay reads
@@ -329,6 +329,18 @@ class _InFlight:
         # still contribute (wave admission's in-flight term); released
         # when the record's bookkeeping lands on the mirror.
         self.bound = bound
+
+
+class _UnitOut:
+    """One dispatch unit's summary output: the device handle from
+    dispatch to the window's fetch, then its (G, SUMMARY_WORDS) numpy
+    rows (G = 1 for a solo batch)."""
+
+    __slots__ = ("handle", "rows")
+
+    def __init__(self, handle) -> None:
+        self.handle = handle
+        self.rows = None
 
 
 # Speculative-execution forensics (ISSUE r18): counters named
@@ -450,7 +462,7 @@ class DeviceEngine:
         # Healthy-mode scrub cadence, jittered by a deterministic
         # per-engine offset (seeded) so a fleet of engines sharing the
         # link doesn't scrub on the same fetch ordinal — and so the
-        # scrub's own fetch doesn't ride the identical ring
+        # scrub's own fetch doesn't ride the identical window
         # rotation every cycle.  The offset only ADVANCES the first
         # scrub; the steady-state period stays TB_DEV_SCRUB_EVERY.
         global _ENGINE_SEQ
@@ -490,6 +502,11 @@ class DeviceEngine:
             "stat_semantic_events": _c("semantic_events"),
             "stat_fallback_batches": _c("fallback_batches"),
             "stat_fetches": _c("fetches"),
+            # How a window crossed: arrays the engine uploaded (every
+            # link.device_put and every host array handed to a
+            # program), and bytes _fetch brought home.
+            "stat_puts": _c("link.puts"),
+            "stat_fetch_bytes": _c("link.fetch_bytes"),
             # Degraded-mode lifecycle (bench engine_health reports).
             "stat_demotions": _c("demotions"),
             "stat_repromotions": _c("repromotions"),
@@ -537,7 +554,7 @@ class DeviceEngine:
         # shared no-op instances when TB_METRICS=0).
         self._link_hists = {
             stage: self.metrics.histogram(f"link.{stage}_us")
-            for stage in ("h2d", "dispatch", "fetch", "probe")
+            for stage in ("h2d", "dispatch", "fetch_start", "fetch", "probe")
         }
         # Cadence first-guesses as pull gauges + measured per-scrub
         # cost (ROADMAP "scrub/probe cadence tuning" carry-over): the
@@ -573,8 +590,6 @@ class DeviceEngine:
                 make_row_mesh(devices), P("shard", None)
             )
         self._meta_host = np.zeros((capacity, 2), np.uint32)
-        self.ring = jnp.zeros((_RING, dk.SUMMARY_WORDS), jnp.uint64)
-        self._ring_at = 0
         # Incremental state commitment (commitment.py): a device-side
         # (capacity, 2) per-row-hash array + (2,) u64 fold, updated
         # from just the rows each launch touched, with a bit-identical
@@ -639,6 +654,8 @@ class DeviceEngine:
     stat_semantic_events = obs_stat_property("stat_semantic_events")
     stat_fallback_batches = obs_stat_property("stat_fallback_batches")
     stat_fetches = obs_stat_property("stat_fetches")
+    stat_puts = obs_stat_property("stat_puts")
+    stat_fetch_bytes = obs_stat_property("stat_fetch_bytes")
     stat_demotions = obs_stat_property("stat_demotions")
     stat_repromotions = obs_stat_property("stat_repromotions")
     stat_probe_failures = obs_stat_property("stat_probe_failures")
@@ -692,25 +709,28 @@ class DeviceEngine:
                 delay_s = min(delay_s * 2, _BACKOFF_CAP_MS / 1e3)
 
     def _put(self, array):
+        self._stats["stat_puts"].inc()
         return self._retry(lambda: self.link.device_put(array), "h2d")
+
+    def _arg(self, array):
+        """A host array handed to a program as an argument: an upload
+        of its own, counted with the puts."""
+        self._stats["stat_puts"].inc()
+        return jnp.asarray(array)
 
     def _run(self, fn, *args):
         return self._retry(lambda: self.link.dispatch(fn, *args), "dispatch")
 
     def _place(self, table):
-        if self.sharding is None:
-            sharding = None
-        else:
-            sharding = self.sharding
+        self._stats["stat_puts"].inc()
         return self._retry(
-            lambda: self.link.device_put(table, sharding), "h2d"
+            lambda: self.link.device_put(table, self.sharding), "h2d"
         )
 
     def prewarm(self, kinds) -> None:
-        """Pay the one-time per-process costs OFF the hot path: the
-        runtime may set up a transfer plan per h2d SHAPE (~1 s each on
-        the link measured in an earlier round) and XLA compiles each
-        scan kernel on first call.
+        """Pay the one-time per-process costs OFF the hot path: XLA
+        compiles each kernel on first call, and the first upload of
+        a shape may set up its transfer.
         Callers that know their workload (bench configs) name the
         kinds; engine construction happens during untimed setup.
 
@@ -780,38 +800,21 @@ class DeviceEngine:
         kinds = [k for k in kinds if k in _KERNELS]
         if not kinds:
             return
-        tiers = sorted({self._tier(1), self._tier(self.window)})
-        for ncols, dtype in {dk.PK_SPEC[k] for k in kinds}:
-            self._put(np.zeros((dk.B, ncols), dtype))
-            for W in tiers:
-                self._put(np.zeros((W, dk.B, ncols), dtype))
-        # The per-window ns/tsb arrays transfer from host at launch —
-        # their transfer plans need warming like the buffers'.
-        for W in tiers:
-            self._put(np.zeros(W, np.int64))
-            self._put(np.zeros(W, np.uint64))
+        scans = [G for G in dk.SCAN_SIZES if G <= self.window]
         table = jnp.zeros_like(self.balances)
         meta = jnp.zeros_like(self.meta)
-        ring = jnp.zeros_like(self.ring)
         outs = []
         for k in kinds:
             ncols, dtype = dk.PK_SPEC[k]
-            pk = jnp.zeros((dk.B, ncols), dtype)
-            outs.append(
-                _KERNELS[k](table, meta, ring, 0, pk, 0, jnp.uint64(1))
-            )
-            for W in tiers:
-                big = jnp.zeros((W, dk.B, ncols), dtype)
-                ns = jnp.zeros(W, jnp.int64)
-                tsb = jnp.zeros(W, jnp.uint64)
-                for G in dk.SCAN_SIZES:
-                    if G > W:
-                        continue
-                    outs.append(
-                        dk.scan_win_kernels[k][G](
-                            table, meta, ring, 0, big, 0, ns, tsb
-                        )
-                    )
+            # An all-zero buffer is a batch of n = 0: nothing applies.
+            pk = self._put(np.zeros((dk.ROWS, ncols), dtype))
+            outs.append(_KERNELS[k](table, meta, pk))
+            for G in scans:
+                stack = self._put(np.zeros((G, dk.ROWS, ncols), dtype))
+                outs.append(dk.scan_kernels[k][G](table, meta, stack))
+        for out in outs:
+            # The copy home of each output shape, as a launch makes it.
+            self.link.copy_to_host_async(out[1])
         self._retry(lambda: self.link.block_until_ready(outs), "h2d")
 
     # ------------------------------------------------------------------
@@ -1027,6 +1030,7 @@ class DeviceEngine:
         In degraded mode the batch never touches the link: it resolves
         immediately through the exact host path (bit-identical reply).
         """
+        dk.seal_scalars(pk, n, ts_base)
         return self._submit_record(
             n, fallback,
             lambda fut: _InFlight(
@@ -1266,17 +1270,17 @@ class DeviceEngine:
         pad = ((len(slots) + 255) & ~255) or 256
         sl = np.full(pad, -1, np.int64)
         sl[: len(slots)] = slots
-        return self._run(dk.lookup, self.balances, jnp.asarray(sl))
+        return self._run(dk.lookup, self.balances, self._arg(sl))
 
     # ------------------------------------------------------------------
-    # Window launch: one h2d per column layout (device idle at call
-    # time), then back-to-back dispatches with no in-stream transfers.
+    # Window launch: per dispatch unit one upload, the dispatch behind
+    # it, and the start of its summary's copy home.
 
     def _plan_chunks(self, recs):
         """Group records into dispatch units: maximal same-kind
         semantic runs split into scan chunks (largest SCAN_SIZES
-        first, exact decomposition — no padding, no wasted ring
-        rows), with meta/lookup records as unit boundaries."""
+        first, exact decomposition — no padding), with meta/lookup
+        records as unit boundaries."""
         units = []
         run = []
         for rec in recs:
@@ -1296,10 +1300,6 @@ class DeviceEngine:
             units.extend(self._split_run(run))
         return units
 
-    def _tier(self, rows: int) -> int:
-        small = max(1, self.window // 3)
-        return small if rows <= small else self.window
-
     @staticmethod
     def _split_run(run):
         out = []
@@ -1313,140 +1313,106 @@ class DeviceEngine:
         return out
 
     def _launch(self, recs: list[_InFlight]) -> None:
-        """Upload the window's inputs in as FEW transfers as possible
-        (on the link measured in r5, not re-measured since, every h2d
-        after the first kernel ran paid a large fixed cost — transfer
-        count dominated),
-        block until they land (an in-flight transfer behind queued
-        kernels crawls at the serialized in-stream rate), then
-        dispatch back-to-back with zero in-stream transfers.
-        Same-kind runs go G batches per LAUNCH via lax.scan reading
-        from a per-spec window buffer at a row offset (~10 ms launch
-        overhead per dispatch vs ~0.8 ms device compute)."""
+        """A window crosses the link once each way per dispatch unit:
+        its inputs go up in ONE buffer that carries the scalars too
+        (an upload or a Python-scalar argument costs ~0.25 ms each on
+        the v5e, and jnp.uint64(ts_base) ran a 0.37 ms program of its
+        own: tests/benchmarks/data/prepare-40ms.json), nothing is
+        waited for before the dispatch (the runtime orders the h2d
+        before the kernel by data flow; the block that stood here
+        cost 0.5 ms a prepare: PERF.md section 6, PR 27), and each
+        unit's summary rows start their copy home as soon as it is
+        dispatched — so the digest's host work below runs under the
+        kernels and the copies, not in front of them.  Same-kind runs
+        go G batches per dispatch via lax.scan (a dispatch costs
+        0.3-0.7 ms of host time a kernel)."""
         if not recs:
             return
         with self.tracer.stage(self._st_launch):
-            units, dev_bufs, dev_solo, offsets = self._upload_window(recs)
+            units = self._upload_window(recs)
         with self.tracer.stage(self._st_dispatch):
-            self._dispatch_units(units, dev_bufs, dev_solo, offsets)
+            self._dispatch_units(units)
         # Absorb the whole window's touched rows into the on-device
         # commitment: one extra dispatch per launch.
         self._commit_absorb(recs)
 
     def _upload_window(self, recs: list[_InFlight]):
-        """The sm.dev.launch stage: pack the window's inputs into as
-        few buffers as it takes, upload, and block until they land."""
-        units = self._plan_chunks(recs)
-        # One (tier, B, C) buffer + (tier,) ns/tsb per input spec; scan
-        # chunks claim contiguous row ranges in plan order.  The tier
-        # (buffer row count) rounds the spec's claimed rows up to
-        # window/3 or window, so a minority spec in a mixed window does
-        # not ship a full window of padding (the link is bytes-bound).
-        rows_of: dict[tuple, int] = {}
-        for ukind, urecs in units:
-            if ukind == "scan":
-                spec = dk.PK_SPEC[urecs[0].kind]
-                rows_of[spec] = rows_of.get(spec, 0) + len(urecs)
-        bufs: dict[tuple, list] = {}  # spec -> [big, ns, tsb, cursor]
-        offsets: dict[int, int] = {}
-        for i, (ukind, urecs) in enumerate(units):
-            if ukind != "scan":
-                continue
-            spec = dk.PK_SPEC[urecs[0].kind]
-            if spec not in bufs:
-                ncols, dtype = spec
-                tier = self._tier(rows_of[spec])
-                bufs[spec] = [
-                    np.zeros((tier, dk.B, ncols), dtype),
-                    np.zeros(tier, np.int64),
-                    np.zeros(tier, np.uint64),
-                    0,
-                ]
-            big, ns, tsb, cur = bufs[spec]
-            for g, rec in enumerate(urecs):
-                big[cur + g] = rec.pk
-                ns[cur + g] = rec.n
-                tsb[cur + g] = rec.ts_base
-            offsets[i] = cur
-            bufs[spec][3] = cur + len(urecs)
-        dev_bufs = {
-            spec: (
-                self._put(big),
-                self._put(ns),
-                self._put(tsb),
-            )
-            for spec, (big, ns, tsb, _cur) in bufs.items()
-        }
-        dev_solo = {
-            i: self._put(urecs[0].pk)
-            for i, (ukind, urecs) in enumerate(units)
-            if ukind == "solo"
-        }
-        # ONE blocking sync (each blocking call cost a ~100 ms round
-        # trip on the link measured in an earlier round).
-        self._retry(
-            lambda: self.link.block_until_ready(
-                [list(dev_bufs.values()), list(dev_solo.values())]
-            ),
-            "h2d",
-        )
-        return units, dev_bufs, dev_solo, offsets
-
-    def _dispatch_units(self, units, dev_bufs, dev_solo, offsets) -> None:
-        """The sm.dev.dispatch stage: the window's kernels, back to
-        back, no transfer between them."""
-        for i, (ukind, urecs) in enumerate(units):
-            if ukind == "meta":
-                slots, flags, ledger = urecs[0].meta_args
-                self.meta = self._run(
-                    dk.meta_update,
-                    self.meta, jnp.asarray(slots), jnp.asarray(flags),
-                    jnp.asarray(ledger),
-                )
-                continue
-            if ukind == "lookup":
-                rec0 = urecs[0]
-                urecs[0].handle = self._gather(
-                    rec0.hot_slots if rec0.hot_slots is not None
-                    else rec0.slots
-                )
-                continue
-            if ukind == "waves":
-                self._exec_waves(urecs[0])
-                continue
-            if ukind == "spec":
-                self._exec_spec(urecs[0])
-                continue
+        """The sm.dev.launch stage: one upload per semantic dispatch
+        unit — the batch's sealed buffer, or a scan chunk's stack of
+        them.  Not waited for: the dispatch that reads a buffer
+        is ordered behind its h2d by the runtime.
+        -> [(unit kind, records, uploaded buffer or None)]"""
+        units = []
+        for ukind, urecs in self._plan_chunks(recs):
+            dev_pk = None
             if ukind == "solo":
-                rec = urecs[0]
-                self.balances, self.ring = self._run(
-                    _KERNELS[rec.kind],
-                    self.balances, self.meta, self.ring, self._ring_at,
-                    dev_solo[i], rec.n, jnp.uint64(rec.ts_base),
+                dev_pk = self._put(urecs[0].pk)
+            elif ukind == "scan":
+                dev_pk = self._put(np.stack([r.pk for r in urecs]))
+            units.append((ukind, urecs, dev_pk))
+        return units
+
+    def _dispatch_units(self, units) -> None:
+        """The sm.dev.dispatch stage: the window's programs, back to
+        back, every argument already a device array; each unit's
+        output starts its copy home behind its own dispatch."""
+        for ukind, urecs, dev_pk in units:
+            kind = urecs[0].kind
+            if ukind == "solo":
+                self._run_semantic(_KERNELS[kind], dev_pk, urecs)
+            elif ukind == "scan":
+                self._run_semantic(
+                    dk.scan_kernels[kind][len(urecs)], dev_pk, urecs
                 )
-                rec.ring_at = self._ring_at
-                self._ring_at = (self._ring_at + 1) % _RING
-                continue
-            big, ns, tsb = dev_bufs[dk.PK_SPEC[urecs[0].kind]]
-            scan_fn = dk.scan_win_kernels[urecs[0].kind][len(urecs)]
-            self.balances, self.ring = self._run(
-                scan_fn,
-                self.balances, self.meta, self.ring, self._ring_at,
-                big, offsets[i], ns, tsb,
+            else:
+                self._dispatch_aux(urecs[0])
+
+    def _run_semantic(self, fn, dev_pk, urecs) -> None:
+        self.balances, rows = self._run(
+            fn, self.balances, self.meta, dev_pk
+        )
+        out = _UnitOut(rows)
+        for g, rec in enumerate(urecs):
+            rec.out = out
+            rec.row = g
+        self._start_fetch(rows)
+
+    def _dispatch_aux(self, rec: _InFlight) -> None:
+        """Dispatch one non-semantic record (meta update, lookup
+        gather, wave or speculative batch)."""
+        if rec.kind == "meta":
+            slots, flags, ledger = rec.meta_args
+            self.meta = self._run(
+                dk.meta_update,
+                self.meta, self._arg(slots), self._arg(flags),
+                self._arg(ledger),
             )
-            for g, rec in enumerate(urecs):
-                rec.ring_at = (self._ring_at + g) % _RING
-            self._ring_at = (self._ring_at + len(urecs)) % _RING
+            return
+        if rec.kind == "lookup":
+            rec.handle = self._gather(
+                rec.hot_slots if rec.hot_slots is not None else rec.slots
+            )
+        elif rec.kind == "waves":
+            self._exec_waves(rec)
+        else:
+            self._exec_spec(rec)
+        self._start_fetch(rec.handle)
 
     def _dispatch(self, rec: _InFlight) -> None:
-        """Immediate single-batch dispatch (fallback re-dispatch path)."""
-        self.balances, self.ring = self._run(
-            _KERNELS[rec.kind],
-            self.balances, self.meta, self.ring, self._ring_at,
-            jnp.asarray(rec.pk), rec.n, jnp.uint64(rec.ts_base),
+        """Immediate single-record dispatch (recovery re-dispatch)."""
+        if rec.kind in _SEMANTIC_KINDS:
+            self._run_semantic(
+                _KERNELS[rec.kind], self._put(rec.pk), [rec]
+            )
+        else:
+            self._dispatch_aux(rec)
+
+    def _start_fetch(self, array) -> None:
+        """Start `array`'s copy home behind the program just
+        dispatched; _fetch waits for it at the window's rotation."""
+        self._retry(
+            lambda: self.link.copy_to_host_async(array), "fetch_start"
         )
-        rec.ring_at = self._ring_at
-        self._ring_at = (self._ring_at + 1) % _RING
 
     def _exec_waves(self, rec: _InFlight) -> None:
         """Execute a wave record's plan against the authoritative
@@ -1586,25 +1552,32 @@ class DeviceEngine:
     # ------------------------------------------------------------------
     # Rotation + materialization.
 
-    def _fetch_ring(self, recs):
-        """Ring snapshot + lookup-row pulls for a launched window; the
-        fetch drains the device stream (idle on return)."""
-        ring_np = None
+    def _fetch_window(self, recs) -> None:
+        """Bring a launched window's outputs home: each dispatch
+        unit's summary rows (512 bytes a batch) and each lookup/wave
+        handle.  Their copies started at dispatch; this waits."""
         if any(r.kind in _SEMANTIC_KINDS for r in recs):
             self.stat_fetches += 1
-            # THE burst fetch.
-            ring_np = self._fetch(self.ring)
         for rec in recs:
-            if rec.kind in ("lookup", "waves", "spec") and rec.handle is not None:
+            if rec.kind in _SEMANTIC_KINDS:
+                out = rec.out
+                if out.rows is None:
+                    out.rows = self._fetch(out.handle).reshape(
+                        -1, dk.SUMMARY_WORDS
+                    )
+                    out.handle = None
+            elif rec.kind in ("lookup", "waves", "spec") and (
+                rec.handle is not None
+            ):
                 rec.rows = self._fetch(rec.handle)
                 rec.handle = None
-        return ring_np
 
     def _fetch(self, array) -> np.ndarray:
         """One fetch crossing of a launched window, in two leaves: the
-        exposed wait for the kernels that produce `array` (the copy
-        would wait for them anyway), then the d2h copy alone.  The
-        link sees one crossing, and link.fetch_us encloses both."""
+        exposed wait for the program that produces `array`, then what
+        is left of its copy home (started at dispatch) and the numpy
+        view.  The link sees one crossing, and link.fetch_us encloses
+        both."""
 
         def cross():
             with self.tracer.stage(self._st_fetch_wait):
@@ -1612,22 +1585,25 @@ class DeviceEngine:
             with self.tracer.stage(self._st_fetch_copy):
                 return self.link.fetch(array)
 
-        return self._retry(cross, "fetch")
+        got = self._retry(cross, "fetch")
+        self._stats["stat_fetch_bytes"].inc(got.nbytes)
+        return got
 
-    def _window_clean(self, recs, ring_np) -> bool:
+    @staticmethod
+    def _window_clean(recs) -> bool:
         for rec in recs:
             if rec.kind not in _SEMANTIC_KINDS:
                 continue
-            s = ring_np[rec.ring_at]
-            if int(s[1]) & (dk.FLAG_OVERFLOW | dk.FLAG_CAP | dk.FLAG_PRECOND):
+            flags = int(rec.out.rows[rec.row][1])
+            if flags & (dk.FLAG_OVERFLOW | dk.FLAG_CAP | dk.FLAG_PRECOND):
                 return False
         return True
 
-    def _resolve_clean(self, recs, ring_np) -> None:
+    def _resolve_clean(self, recs) -> None:
         with self.tracer.stage(self._st_finish):
-            self._resolve_clean_impl(recs, ring_np)
+            self._resolve_clean_impl(recs)
 
-    def _resolve_clean_impl(self, recs, ring_np) -> None:
+    def _resolve_clean_impl(self, recs) -> None:
         for rec in recs:
             if rec.kind == "meta":
                 continue
@@ -1639,13 +1615,13 @@ class DeviceEngine:
                 rec.future.resolve(rec.finish(rec.rows))
                 self._release_bound(rec)
                 continue
-            s = dk.unpack_summary(ring_np[rec.ring_at])
+            s = dk.unpack_summary(rec.out.rows[rec.row])
             self.stat_semantic_events += rec.n
             rec.future.resolve(rec.finish(s))
             self._release_bound(rec)
 
     def _rotate(self) -> None:
-        """Window boundary: fetch the launched window's ring, and —
+        """Window boundary: fetch the launched window's outputs, and —
         when it is clean — launch the pending window while the host
         still holds the fetched results, then finish the old window's
         bookkeeping overlapped with the new window's device work.
@@ -1656,14 +1632,15 @@ class DeviceEngine:
         every unresolved record still in the stream lists, in order.
         """
         prev = self._launched
-        ring_np = self._fetch_ring(prev) if prev else None
-        if prev and (ring_np is None or self._window_clean(prev, ring_np)):
+        if prev:
+            self._fetch_window(prev)
+        if prev and self._window_clean(prev):
             nxt = self._pending
             self._launch(nxt)  # may raise: prev + nxt stay tracked
             self._launched = nxt
             self._pending = []
             self._pending_semantic = 0
-            self._resolve_clean(prev, ring_np)  # host-only, cannot lose
+            self._resolve_clean(prev)  # host-only, cannot lose
             return
         if prev:
             # Fallback in the window: serial exact recovery first.
@@ -1675,7 +1652,7 @@ class DeviceEngine:
             # clears.
             self._launched = []
             self._recovering = prev
-            self._resolve_recovery(prev, ring_np)
+            self._resolve_recovery(prev)
             self._recovering = []
         self._launched = []
         nxt = self._pending
@@ -1684,13 +1661,12 @@ class DeviceEngine:
         self._pending = []
         self._pending_semantic = 0
 
-    def _resolve_recovery(self, covered, ring_np) -> None:
-        """Exact recovery: resolve in order until the flagged batch,
-        host re-execute it (mirror becomes current), rebuild the device
-        table, re-dispatch everything after it, repeat until done."""
+    def _resolve_recovery(self, covered) -> None:
+        """Exact recovery, from the window's own fetched rows: resolve
+        in order until the flagged batch, host re-execute it (mirror
+        becomes current), rebuild the device table, re-dispatch
+        everything after it, fetch those, repeat until done."""
         while covered:
-            if ring_np is None:
-                ring_np = self._fetch_ring(covered)
             failed_at = None
             for i, rec in enumerate(covered):
                 if rec.kind == "meta":
@@ -1707,7 +1683,7 @@ class DeviceEngine:
                     rec.future.resolve(rec.finish(rec.rows))
                     self._release_bound(rec)
                     continue
-                s = dk.unpack_summary(ring_np[rec.ring_at])
+                s = dk.unpack_summary(rec.out.rows[rec.row])
                 if s["overflow"] or s["cap_exceeded"] or s["precond"]:
                     failed_at = i
                     self.stat_fallback_batches += 1
@@ -1725,28 +1701,11 @@ class DeviceEngine:
             self._upload_from_mirror()
             covered = covered[failed_at + 1 :]
             for rec in covered:
-                if rec.kind == "meta":
-                    slots, flags, ledger = rec.meta_args
-                    self.meta = self._run(
-                        dk.meta_update,
-                        self.meta, jnp.asarray(slots), jnp.asarray(flags),
-                        jnp.asarray(ledger),
-                    )
-                elif rec.kind == "lookup":
-                    rec.handle = self._gather(
-                        rec.hot_slots if rec.hot_slots is not None
-                        else rec.slots
-                    )
-                elif rec.kind == "waves":
-                    self._exec_waves(rec)
-                elif rec.kind == "spec":
-                    self._exec_spec(rec)
-                else:
-                    self._dispatch(rec)
+                self._dispatch(rec)
             # The re-dispatched suffix mutated the rebuilt table: fold
             # its touched rows back into the commitment.
             self._commit_absorb(covered)
-            ring_np = None
+            self._fetch_window(covered)
 
     def _mirror_table_np(self) -> np.ndarray:
         """Device-layout (capacity, 8) snapshot of the host mirror."""
@@ -1946,7 +1905,7 @@ class DeviceEngine:
         self.dev_row_hash, self.dev_digest = self._run(
             fns["update"], self.balances, self.meta,
             self.dev_row_hash, self.dev_digest,
-            jnp.asarray(padded), jnp.asarray(rows),
+            self._arg(padded), self._arg(rows),
         )
 
     def _collect_touched(self, recs) -> np.ndarray | None:
@@ -2123,7 +2082,7 @@ class DeviceEngine:
         operation by the state machine (tpu.commit_async): in degraded
         mode, a health probe + re-promotion attempt every _PROBE_EVERY
         operations; while healthy, the checksum scrub every
-        _SCRUB_EVERY ring fetches."""
+        _SCRUB_EVERY window fetches."""
         if self.state is EngineState.degraded:
             self._degraded_submits += 1
             if self._degraded_submits >= _PROBE_EVERY:
@@ -2154,8 +2113,6 @@ class DeviceEngine:
         try:
             self._retry(self.link.probe, "probe")
             self._heal_from_mirror()  # meta first, commitment rebuilt
-            self.ring = jnp.zeros((_RING, dk.SUMMARY_WORDS), jnp.uint64)
-            self._ring_at = 0
             if self._commit_enabled and self.mirror.commitment is not None:
                 # Cheap handshake: the device's freshly-rebuilt 16-byte
                 # root vs the incrementally-maintained host twin — no
@@ -2360,7 +2317,7 @@ class DeviceEngine:
             packed[3, :take] = d_hi[at : at + take]
             packed[3, take:] = 0
             self.balances = self._run(
-                dk.apply_deltas, self.balances, jnp.asarray(packed)
+                dk.apply_deltas, self.balances, self._arg(packed)
             )
             at += take
         # Flushed deltas must land before any later queued meta/lookup

@@ -15,17 +15,18 @@ reference: src/state_machine.zig:1220-1306 (execute loop),
 vectorized host resolvers (resolve.py) implement; differential fuzz in
 tests/test_device_engine.py pins all three kernels to the CPU oracle.
 
-Link constraints (measured on the link of an earlier round, not
-re-measured since — ROADMAP D2 decides what follows from a local
-chip's): a device->host fetch cost ~105 ms at ~15 MB/s, serialized.
-Per-event result readback was impossible at millions of events/s, so
-each kernel
-writes a fixed-size FAILURE-SPARSE summary row (60 failure slots +
-status flags) into a device ring; the host fetches the ring once per
-burst.  Batches whose failures exceed the cap — or that hit an
-overflow/precondition edge — raise a flag and are re-executed exactly
-on the host engine (the fallback path), so the sparse encoding never
-loses information.
+Link contract (v5e, PERF.md sections 6 and 7.8): a d2h of 4 KB costs
+~0.5 ms and of 512 KB ~2.4 ms, every separate upload or Python-scalar
+argument ~0.25 ms of host time, a kernel's dispatch ~0.3 ms.  So a
+batch crosses ONCE each way.  Up: its scalars (n, ts_base) ride in
+the packed buffer's last row (SCALAR_ROW) — no scalar argument, no
+convert program.  Down: each kernel returns, beside the new table, a
+fixed-size FAILURE-SPARSE summary row (60 failure slots + status
+flags, 512 bytes) as an output of its own; the engine starts its copy
+home at dispatch and fetches nothing else.  Batches whose failures
+exceed the cap — or that hit an overflow/precondition edge — raise a
+flag and are re-executed exactly on the host engine (the fallback
+path), so the sparse encoding never loses information.
 
 Input marshaling split (who computes what): the host packs raw event
 columns and *stateless byte predicates* (id == 0, id == maxInt,
@@ -64,6 +65,10 @@ if B & (B - 1) != 0:
         f"TB_DEV_B={B} invalid: must be a power of 2 <= 8192"
     )
 assert 4 * B * 255 < (1 << 24), "TB_DEV_B too large for exact f32 sums"
+# A packed input is (ROWS, ncols): B event rows, then one row that
+# carries the batch's scalars up with them (seal_scalars below).
+SCALAR_ROW = B
+ROWS = B + 1
 SUMMARY_WORDS = 64
 FAIL_CAP = SUMMARY_WORDS - 4   # failure entries per batch summary
 
@@ -352,11 +357,24 @@ def _summary(results, active, flags_word, last_applied):
     return jnp.concatenate([head, entries])
 
 
+def _split_scalars(pkx):
+    """(pk, n, ts_base) of an uploaded (ROWS, ncols) buffer: the event
+    rows, and the scalars seal_scalars wrote into row SCALAR_ROW."""
+    tail = pkx[SCALAR_ROW]
+    if pkx.dtype == jnp.uint32:
+        ts_base = tail[1].astype(jnp.uint64) | (
+            tail[2].astype(jnp.uint64) << jnp.uint64(32)
+        )
+    else:
+        ts_base = tail[1]
+    return pkx[:B], tail[0].astype(jnp.int64), ts_base
+
+
 # ---------------------------------------------------------------------------
 # Order-free kernel.
 
 
-def _orderfree(table, meta, ring, ring_at, pk, n, ts_base, lo_only=False):
+def _orderfree(table, meta, pkx, lo_only=False):
     """Order-independent batch: full static ladder + overflow admission
     + scatter apply + result codes, all on device.
 
@@ -369,16 +387,13 @@ def _orderfree(table, meta, ring, ring_at, pk, n, ts_base, lo_only=False):
     src/state_machine.zig:1531-1545) — and overflows_timeout, which is
     order-independent and computed per event here.
     """
-    return _orderfree_core(
-        table, meta, ring, ring_at, _unpack(pk), n, ts_base, lo_only
-    )
+    pk, n, ts_base = _split_scalars(pkx)
+    return _orderfree_core(table, meta, _unpack(pk), n, ts_base, lo_only)
 
 
-# Tight 20-byte/event format for the dominant order-free class: on
-# the link measured in r5 (not re-measured since) h2d bandwidth fell to
-# ~30 MB/s once any kernel had run in the process, so INPUT BYTES were
-# the device engine's throughput ceiling — 5xu32 instead of 6xu64 is
-# 2.4x fewer of them.
+# Tight 20-byte/event format for the dominant order-free class:
+# 5xu32 instead of 6xu64 is 2.4x fewer input bytes to upload (an h2d
+# of 400 KB costs 1.03 ms on the v5e, PERF.md section 7.8).
 # Host gating (exact facts, not predictions — no device re-check
 # needed): every amount_hi == 0, amount_lo < 2^32, timeout == 0.
 # Word 0 packs the predicate bits (low 18), the 6 transfer-flag bits,
@@ -390,7 +405,8 @@ TIGHT_RESERVED_BIT = 1 << 25
 N_COLS_TIGHT = 5
 
 
-def _orderfree_tight(table, meta, ring, ring_at, pk32, n, ts_base):
+def _orderfree_tight(table, meta, pkx):
+    pk32, n, ts_base = _split_scalars(pkx)
     w0 = pk32[:, 0]
     zero64 = jnp.zeros(B, jnp.uint64)
     # The reserved-flag predicate rides flag bit 6: the ladder's
@@ -413,12 +429,10 @@ def _orderfree_tight(table, meta, ring, ring_at, pk32, n, ts_base):
         "timeout": zero64,
         "p_tgt": jnp.full(B, -1, jnp.int64),
     }
-    return _orderfree_core(
-        table, meta, ring, ring_at, ev, n, ts_base, lo_only=True
-    )
+    return _orderfree_core(table, meta, ev, n, ts_base, lo_only=True)
 
 
-def _orderfree_core(table, meta, ring, ring_at, ev, n, ts_base, lo_only):
+def _orderfree_core(table, meta, ev, n, ts_base, lo_only):
     A = table.shape[0]
     iota = jnp.arange(B, dtype=jnp.int64)
     active = iota < n
@@ -446,16 +460,14 @@ def _orderfree_core(table, meta, ring, ring_at, ev, n, ts_base, lo_only):
     applied_idx = jnp.where(ok, iota, -1)
     last_applied = applied_idx.max()
     flags_word = jnp.where(ov, jnp.uint64(FLAG_OVERFLOW), jnp.uint64(0))
-    s = _summary(r, active, flags_word, last_applied)
-    ring = jax.lax.dynamic_update_slice(ring, s[None, :], (ring_at, 0))
-    return new_table, ring
+    return new_table, _summary(r, active, flags_word, last_applied)
 
 
 # ---------------------------------------------------------------------------
 # Linked-chain kernel (port of resolve.linked_resolve to device).
 
 
-def _linked(table, meta, ring, ring_at, pk, n, ts_base, small=False):
+def _linked(table, meta, pkx, small=False):
     """Linked-chain batch of plain posted transfers; limit-flag
     accounts allowed.  Jacobi fixpoint over per-account segmented
     prefix sums converges to the exact sequential verdicts (see
@@ -468,6 +480,7 @@ def _linked(table, meta, ring, ring_at, pk, n, ts_base, small=False):
     pieces (the fixpoint's dominant per-iteration cost).  The device
     still verifies the bound and raises the precondition flag (exact
     host fallback) if the router's pick was wrong."""
+    pk, n, _ts_base = _split_scalars(pkx)
     ev = _unpack(pk)
     A = table.shape[0]
     iota = jnp.arange(B, dtype=jnp.int64)
@@ -615,8 +628,9 @@ def _linked(table, meta, ring, ring_at, pk, n, ts_base, small=False):
     def excl_prefix(v):
         # Exact u64 inclusive cumsum.  A direct u64 cumsum lowers to a
         # variadic (u32, u32) reduce-window that blows XLA:TPU's
-        # scoped vmem inside while_loop bodies — see
-        # experiments/tpu_compile_check.py.  small: the verified
+        # scoped vmem inside while_loop bodies
+        # (tests/test_tpu_compile.py compiles this for the v5e).
+        # small: the verified
         # < 2^31 total makes one i32 cumsum exact.  General: four
         # 16-bit-piece i32 cumsums (totals < 2^61 by the
         # precondition; piece sums < M * 2^16 < 2^31).
@@ -743,22 +757,21 @@ def _linked(table, meta, ring, ring_at, pk, n, ts_base, small=False):
         )
         | (iters.astype(jnp.uint64) << jnp.uint64(ITERS_SHIFT))
     )
-    s = _summary(results, active, flags_word, last_applied)
-    ring = jax.lax.dynamic_update_slice(ring, s[None, :], (ring_at, 0))
-    return new_table, ring
+    return new_table, _summary(results, active, flags_word, last_applied)
 
 
 # ---------------------------------------------------------------------------
 # Two-phase kernel (port of resolve.two_phase_resolve to device).
 
 
-def _two_phase(table, meta, ring, ring_at, pk, n, ts_base, lo_only=False):
+def _two_phase(table, meta, pkx, lo_only=False):
     """Pending-create + post/void batch with balance-independent
     verdicts (router preconditions: no linked/balancing, all timeouts
     zero, no limit/history accounts, unique fresh ids).  Closed-form:
     vectorized ladder + first-wins winner reduction, then scatter
     apply of adds and releases (reference:
     src/state_machine.zig:1608-1741)."""
+    pk, n, _ts_base = _split_scalars(pkx)
     ev = _unpack(pk)
     A = table.shape[0]
     iota = jnp.arange(B, dtype=jnp.int64)
@@ -969,9 +982,7 @@ def _two_phase(table, meta, ring, ring_at, pk, n, ts_base, lo_only=False):
 
     last_applied = jnp.where(ok, iota, -1).max()
     flags_word = jnp.where(fallback, jnp.uint64(FLAG_OVERFLOW), jnp.uint64(0))
-    s = _summary(code, active, flags_word, last_applied)
-    ring = jax.lax.dynamic_update_slice(ring, s[None, :], (ring_at, 0))
-    return new_table, ring
+    return new_table, _summary(code, active, flags_word, last_applied)
 
 
 # ---------------------------------------------------------------------------
@@ -1053,32 +1064,18 @@ two_phase = jax.jit(_two_phase)
 two_phase_lo = _jit_as("two_phase_lo", _ft.partial(_two_phase, lo_only=True))
 
 
-# Scanned dispatch: G same-kind batches per device LAUNCH.  The link
-# measured in an earlier round (not re-measured since) charged ~10 ms
-# of launch overhead per dispatch even with resident inputs
-# (experiments/scan_resident_probe.py: solo 11 ms/batch vs scan-16
-# 2.0 ms/batch; the op-level trace put actual device compute at
-# ~0.8 ms) — lax.scan amortizes that overhead over the chunk.  Ring
-# rows are addressed (ring_at0 + g) % ring_rows per step, so chunks
-# may wrap the ring freely.
+# Scanned dispatch: G same-kind batches per device LAUNCH, read from
+# one uploaded (G, ROWS, ncols) stack; lax.scan spreads an upload's
+# and a dispatch's fixed host cost (~0.25 and 0.3-0.7 ms on the v5e:
+# PERF.md section 6, PR 27) over the chunk.  The G summary rows come back
+# as ONE (G, SUMMARY_WORDS) output, in record order.
 
 def _scan_of(kind, fn, G):
-    def run(table, meta, ring, ring_at0, stack, ns, tsb):
-        R = ring.shape[0]
+    def run(table, meta, stack):
+        def step(table, pkx):
+            return fn(table, meta, pkx)
 
-        def step(carry, xs):
-            table, ring = carry
-            g, nn, t = xs
-            table, ring = fn(
-                table, meta, ring, (ring_at0 + g) % R, stack[g], nn, t
-            )
-            return (table, ring), None
-
-        (table, ring), _ = jax.lax.scan(
-            step, (table, ring),
-            (jnp.arange(G), ns, tsb),
-        )
-        return table, ring
+        return jax.lax.scan(step, table, stack)
 
     return _jit_as(f"scan_{kind}_g{G}", run)
 
@@ -1104,10 +1101,8 @@ PK_SPEC = {
     "two_phase_lo": (N_COLS_TP, np.uint64),
 }
 # Batches per scan launch, largest first (exact decomposition in the
-# engine's chunk planner).  Larger tiers amortize the per-launch
-# overhead (~10 ms on the link measured in an earlier round) over
-# more batches; lax.scan compile time is length-independent, so the
-# only cost of a big tier is its staged input buffer.
+# engine's chunk planner).  lax.scan compile time is
+# length-independent, so the only cost of a big tier is its stack.
 def _scan_sizes() -> tuple[int, ...]:
     from tigerbeetle_tpu import envcheck
 
@@ -1131,65 +1126,6 @@ scan_kernels = {
 }
 
 
-# Window-buffer scans: the G-batch chunk reads its inputs from a
-# window-sized device buffer at a traced row offset, so the engine
-# uploads ONE (W, B, C) buffer (+ one ns and one tsb array) per input
-# spec per window instead of one stack per chunk — on the link
-# measured in r5 (not re-measured since) every h2d after the first
-# kernel paid a large FIXED cost, so transfer COUNT was what mattered.
-
-def _scan_win_of(kind, fn, G):
-    def run(table, meta, ring, ring_at0, big, off, ns_all, tsb_all):
-        R = ring.shape[0]
-
-        def step(carry, g):
-            table, ring = carry
-            pk = jax.lax.dynamic_slice(
-                big, (off + g, 0, 0), (1,) + big.shape[1:]
-            )[0]
-            nn = jax.lax.dynamic_slice(ns_all, (off + g,), (1,))[0]
-            tb = jax.lax.dynamic_slice(tsb_all, (off + g,), (1,))[0]
-            table, ring = fn(
-                table, meta, ring, (ring_at0 + g) % R, pk, nn, tb
-            )
-            return (table, ring), None
-
-        (table, ring), _ = jax.lax.scan(
-            step, (table, ring), jnp.arange(G)
-        )
-        return table, ring
-
-    return _jit_as(f"scan_win_{kind}_g{G}", run)
-
-
-scan_win_kernels = {
-    kind: {G: _scan_win_of(kind, fn, G) for G in SCAN_SIZES}
-    for kind, fn in _BASE_FNS.items()
-}
-
-
-def _staged(kind, fn, ncols):
-    """Staged variant: the batch is a slice of a device-resident
-    superbatch (one h2d covers many batches — transfers issued while
-    the stream is busy cost ~25 ms each on this link, so they are
-    amortized across a stage; see experiments/staged_probe.py)."""
-
-    def run(table, meta, ring, ring_at, super_pk, g, n, ts_base):
-        pk = jax.lax.dynamic_slice(super_pk, (g * B, 0), (B, ncols))
-        return fn(table, meta, ring, ring_at, pk, n, ts_base)
-
-    return _jit_as(f"staged_{kind}", run)
-
-
-orderfree_staged = _staged("orderfree", _orderfree, N_COLS)
-orderfree_lo_staged = _staged(
-    "orderfree_lo", _ft.partial(_orderfree, lo_only=True), N_COLS
-)
-linked_staged = _staged("linked", _linked, N_COLS)
-two_phase_staged = _staged("two_phase", _two_phase, N_COLS_TP)
-two_phase_lo_staged = _staged(
-    "two_phase_lo", _ft.partial(_two_phase, lo_only=True), N_COLS_TP
-)
 lookup = jax.jit(_lookup)
 apply_deltas = jax.jit(_apply_deltas)
 meta_update = jax.jit(_meta_update)
@@ -1230,11 +1166,11 @@ def pack_base(
     dr_slot, cr_slot, e_found, p_found=None, p_tgt=None,
     n_cols: int = N_COLS,
 ):
-    """Build the packed (B, n_cols) u64 input matrix on the host.
+    """Build the packed (ROWS, n_cols) u64 input matrix on the host.
 
     Everything here is wire decoding, stateless byte predicates, and
     join results — no result-code decisions (those live on device)."""
-    pk = np.zeros((B, n_cols), np.uint64)
+    pk = np.zeros((ROWS, n_cols), np.uint64)
     bits = _predicate_bits(
         np.uint64, n, id_lo, id_hi, dr_lo, dr_hi, cr_lo, cr_hi,
         pend_lo, pend_hi, ts_nonzero,
@@ -1276,11 +1212,11 @@ def pack_tight(
     n, id_lo, id_hi, dr_lo, dr_hi, cr_lo, cr_hi, pend_lo, pend_hi,
     amount_lo, flags, ledger, code, ts_nonzero, dr_slot, cr_slot,
 ):
-    """Tight (B, 5) u32 order-free input (see _orderfree_tight).
+    """Tight (ROWS, 5) u32 order-free input (see _orderfree_tight).
 
     Caller-guaranteed facts: amount_hi == 0, amount_lo < 2^32,
     timeout == 0 for every event."""
-    pk = np.zeros((B, N_COLS_TIGHT), np.uint32)
+    pk = np.zeros((ROWS, N_COLS_TIGHT), np.uint32)
     bits = _predicate_bits(
         np.uint32, n, id_lo, id_hi, dr_lo, dr_hi, cr_lo, cr_hi,
         pend_lo, pend_hi, ts_nonzero,
@@ -1334,6 +1270,22 @@ def pack_two_phase_ext(
         (tgt_ev.astype(np.int64) + 1).astype(np.uint64)
         | (dstat_init_ev.astype(np.uint64) << np.uint64(32))
     )
+    return pk
+
+
+def seal_scalars(pk: np.ndarray, n: int, ts_base: int) -> np.ndarray:
+    """Write a batch's scalars into its packed buffer's last row (in
+    place), so they cross the link inside the one upload: word 0 is
+    n, then ts_base as one u64 or as two u32 halves, low first
+    (_split_scalars is the device-side inverse)."""
+    assert pk.shape[0] == ROWS, pk.shape
+    tail = pk[SCALAR_ROW]
+    tail[0] = n
+    if pk.dtype == np.uint32:
+        tail[1] = ts_base & 0xFFFFFFFF
+        tail[2] = ts_base >> 32
+    else:
+        tail[1] = ts_base
     return pk
 
 

@@ -2,7 +2,8 @@
 
 The device-authoritative engine funnels every host<->device crossing
 through one seam (device_engine.DeviceLink: "h2d" uploads, "dispatch"
-kernel launches, "fetch" d2h reads, "probe" health checks).  ChaosLink
+kernel launches, "fetch_start" d2h copies started at dispatch, "fetch"
+d2h reads, "probe" health checks).  ChaosLink
 interposes on that seam with a SEEDED fault plan, so CPU-only tests can
 drive the full degraded-mode lifecycle — transient-retry, fatal loss,
 demote, serve-degraded, re-promote + checksum handshake — with no TPU
@@ -29,7 +30,7 @@ from tigerbeetle_tpu.state_machine.device_engine import (
     TransientLinkError,
 )
 
-STAGES = ("h2d", "dispatch", "fetch", "probe")
+STAGES = ("h2d", "dispatch", "fetch_start", "fetch", "probe")
 
 
 class ChaosLink(DeviceLink):
@@ -146,6 +147,10 @@ class ChaosLink(DeviceLink):
     def block_until_ready(self, arrays):
         self._cross("h2d")
         return super().block_until_ready(arrays)
+
+    def copy_to_host_async(self, array) -> None:
+        self._cross("fetch_start")
+        super().copy_to_host_async(array)
 
     def fetch(self, array) -> np.ndarray:
         self._cross("fetch")
