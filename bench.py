@@ -569,11 +569,7 @@ def _run_durable_once(n_events: int, ckpt_async: bool = True) -> dict:
     conf = __import__(
         "tigerbeetle_tpu.constants", fromlist=["PRODUCTION"]
     ).PRODUCTION
-    forest_blocks = 1 << 14  # 16k x 64KiB = 1 GiB block region
-    layout = ZoneLayout(
-        config=conf,
-        grid_size=2 * vsr_replica.SNAPSHOT_SPAN + (forest_blocks << 16),
-    )
+    layout = ZoneLayout(config=conf)
     tmp = tempfile.mkdtemp(prefix="tb_bench_durable_")
     path = os.path.join(tmp, "0_0.tigerbeetle")
     env_before = os.environ.get("TB_CKPT_ASYNC")
@@ -588,9 +584,7 @@ def _run_durable_once(n_events: int, ckpt_async: bool = True) -> dict:
             conf, account_capacity=1 << 12,
             transfer_capacity=n_events + 2 * BATCH + 1024,
         )
-        r = vsr_replica.Replica(
-            storage, 0xB, sm, forest_block_count=forest_blocks
-        )
+        r = vsr_replica.Replica(storage, 0xB, sm)
         r.open()
 
         setup, timed, _sizing = gen_simple(n_events)
@@ -903,7 +897,7 @@ def _run_replicated_once(n_events: int, group_commit: bool = True,
             "from tigerbeetle_tpu.runtime.server import ReplicaServer\n"
             "from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine\n"
             "s = ReplicaServer({path!r}, addresses={addrs!r}.split(','),\n"
-            "    replica_index={i}, grid_size=1 << 30,\n"
+            "    replica_index={i},\n"
             "    state_machine_factory=lambda: TpuStateMachine(\n"
             "        account_capacity=1 << 12,\n"
             "        transfer_capacity={cap}))\n"
@@ -1459,7 +1453,7 @@ def run_open_loop() -> dict:
             "from tigerbeetle_tpu.runtime.server import ReplicaServer\n"
             "from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine\n"
             "s = ReplicaServer({path!r}, addresses={addrs!r}.split(','),\n"
-            "    replica_index={i}, grid_size=1 << 30,\n"
+            "    replica_index={i},\n"
             "    state_machine_factory=lambda: TpuStateMachine(\n"
             "        account_capacity=1 << 12,\n"
             "        transfer_capacity=1 << 22))\n"
@@ -1898,7 +1892,7 @@ def run_read_scale() -> dict:
             "from tigerbeetle_tpu.runtime.server import ReplicaServer\n"
             "from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine\n"
             "s = ReplicaServer({path!r}, addresses={addrs!r}.split(','),\n"
-            "    replica_index={i}, grid_size=1 << 30,\n"
+            "    replica_index={i},\n"
             "    aof_path={aof!r} if {i} == 0 else None,\n"
             "    state_machine_factory=lambda: TpuStateMachine(\n"
             "        account_capacity=1 << 12,\n"
@@ -2293,7 +2287,7 @@ def run_qos_suite() -> dict:
             "from tigerbeetle_tpu.runtime.server import ReplicaServer\n"
             "from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine\n"
             "s = ReplicaServer({path!r}, addresses=['127.0.0.1:{port}'],\n"
-            "    replica_index=0, grid_size=1 << 30,\n"
+            "    replica_index=0,\n"
             "    state_machine_factory=lambda: TpuStateMachine(\n"
             "        account_capacity=1 << 12,\n"
             "        transfer_capacity=1 << 22))\n"
@@ -3039,7 +3033,7 @@ def _run_sharded_once(n_shards: int) -> dict:
                 "from tigerbeetle_tpu.runtime.server import ReplicaServer\n"
                 "from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine\n"
                 "s = ReplicaServer({path!r}, addresses=[{addr!r}],\n"
-                "    replica_index=0, grid_size=1 << 30,\n"
+                "    replica_index=0,\n"
                 "    state_machine_factory=lambda: TpuStateMachine(\n"
                 "        account_capacity=1 << 12,\n"
                 "        transfer_capacity={cap}))\n"

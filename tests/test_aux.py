@@ -115,7 +115,7 @@ def test_statsd_lines():
 
 def test_aof_records_and_replays(tmp_path):
     path = str(tmp_path / "log.aof")
-    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 20))
+    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN))
     vsr_replica.format(storage, 5)
     r = vsr_replica.Replica(
         storage, 5, CpuStateMachine(cfg.TEST_MIN), aof=aof_mod.AOF(path)
@@ -143,7 +143,7 @@ def test_aof_records_and_replays(tmp_path):
 
 
 def test_grid_scrubber_finds_corruption():
-    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 22))
+    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN))
     grid = Grid(storage, block_size=4096, block_count=64)
     fs = grid.free_set
     res = fs.reserve(8)
@@ -167,7 +167,7 @@ def test_grid_scrubber_tour_semantics():
     walks a STABLE snapshot paced across cycle_ticks, skips blocks
     freed mid-tour instead of flagging their stale frames, and picks
     up new allocations on the next tour."""
-    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 22))
+    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN))
     grid = Grid(storage, block_size=4096, block_count=64)
     fs = grid.free_set
     res = fs.reserve(16)

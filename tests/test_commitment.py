@@ -679,7 +679,7 @@ def test_commitment_disabled_engine_uses_legacy_scrub(monkeypatch):
 def _layout():
     from tigerbeetle_tpu.vsr.storage import ZoneLayout
 
-    return ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 20)
+    return ZoneLayout(config=cfg.TEST_MIN)
 
 
 def test_checkpoint_state_root_roundtrip():

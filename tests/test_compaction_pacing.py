@@ -16,7 +16,7 @@ from tigerbeetle_tpu import constants as cfg
 
 
 def make_tree(memtable_max=64, value_size=8):
-    layout = ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 22)
+    layout = ZoneLayout(config=cfg.TEST_MIN)
     storage = MemoryStorage(layout)
     grid = Grid(storage, block_size=1 << 12, block_count=1 << 10)
     return Tree(grid, "t", value_size=value_size, memtable_max=memtable_max)
@@ -110,7 +110,7 @@ def test_tombstones_drop_only_at_last_level():
 def _forest_fixture():
     from tigerbeetle_tpu.lsm.forest import Forest
 
-    layout = ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 22)
+    layout = ZoneLayout(config=cfg.TEST_MIN)
     storage = MemoryStorage(layout)
     forest = Forest(storage, block_size=1 << 12, block_count=1 << 10,
                     memtable_max=64)

@@ -63,7 +63,7 @@ def test_tb_ckpt_async_disables_worker(monkeypatch, tmp_path):
     from tigerbeetle_tpu.vsr import replica as vsr_replica
     from tigerbeetle_tpu.vsr.storage import FileStorage, ZoneLayout
 
-    layout = ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 20)
+    layout = ZoneLayout(config=cfg.TEST_MIN)
     path = str(tmp_path / "data.tb")
     storage = FileStorage(path, layout, create=True)
     vsr_replica.format(storage, 5)

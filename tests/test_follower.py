@@ -152,7 +152,7 @@ def _fresh_replica(storage, path):
 
 def test_aof_repair_truncates_torn_tail(tmp_path):
     path = str(tmp_path / "log.aof")
-    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 20))
+    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN))
     vsr_replica.format(storage, CLUSTER)
     r = _fresh_replica(storage, path)
     r.on_request(types.Operation.create_accounts,
@@ -181,7 +181,7 @@ def test_recovery_gap_fill_restores_stream(tmp_path):
     the ops: recovery replay re-appends exactly the missing records,
     so a replay of the AOF reaches the identical state."""
     path = str(tmp_path / "log.aof")
-    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 20))
+    storage = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN))
     vsr_replica.format(storage, CLUSTER)
     r = _fresh_replica(storage, path)
     r.on_request(types.Operation.create_accounts,
@@ -225,7 +225,7 @@ class _Primary:
 
         self.aof = SimAof()
         self.storage = MemoryStorage(
-            ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 20)
+            ZoneLayout(config=cfg.TEST_MIN)
         )
         vsr_replica.format(self.storage, CLUSTER)
         self.replica = vsr_replica.Replica(

@@ -14,7 +14,7 @@ from tigerbeetle_tpu.vsr.storage import MemoryStorage, ZoneLayout
 
 
 def storage():
-    return MemoryStorage(ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 22))
+    return MemoryStorage(ZoneLayout(config=cfg.TEST_MIN))
 
 
 def grid(block_size=4096, block_count=1 << 10):

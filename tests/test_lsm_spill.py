@@ -39,7 +39,7 @@ CONF = cfg.Config(
 
 
 def layout():
-    return ZoneLayout(config=CONF, grid_size=1 << 20)
+    return ZoneLayout(config=CONF)
 
 
 def make_tpu_replica(storage):

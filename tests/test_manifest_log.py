@@ -11,9 +11,9 @@ from tigerbeetle_tpu.vsr.storage import MemoryStorage, ZoneLayout
 
 def make_forest(storage=None):
     storage = storage or MemoryStorage(
-        ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 20)
+        ZoneLayout(config=cfg.TEST_MIN)
     )
-    f = Forest(storage, memtable_max=64)
+    f = Forest(storage, block_count=1 << 12, memtable_max=64)
     f.groove("things", object_size=32, index_fields=["field"],
              index_value_size=8)
     return storage, f
@@ -128,7 +128,7 @@ def test_oversized_run_splits_to_block_capacity():
     from tigerbeetle_tpu.lsm.manifest_log import ManifestLog
     from tigerbeetle_tpu.vsr.grid import Grid
 
-    st = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 22))
+    st = MemoryStorage(ZoneLayout(config=cfg.TEST_MIN))
     grid = Grid(st, block_size=4096, block_count=1 << 9)
     mlog = ManifestLog(grid)
     refs = [

@@ -231,8 +231,12 @@ LEAVES = {
     "sm.dev.commit.update", "sm.dev.link.fetch_wait",
     "sm.dev.link.fetch_copy", "sm.dev.finish", "vsr.commit.reply",
     "vsr.commit.beat", "vsr.reply_send", "vsr.tick", "vsr.ckpt.freeze",
-    "vsr.ckpt.finalize", "vsr.journal.sync",
+    "vsr.ckpt.finalize", "vsr.journal.sync", "vsr.replicate.send",
+    "vsr.backup.accept",
 }
+# What only a cluster's replicas open: the primary's hand-over of a
+# prepare to the backups' connections, a backup's run of prepares.
+REPLICATION_LEAVES = {"vsr.replicate.send", "vsr.backup.accept"}
 
 
 def test_the_stage_names_live_once_in_code():
@@ -331,8 +335,15 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     assert not thread.is_alive() and not failed, failed
     snap = server.registry.snapshot()
     server.close()
-    on_the_path = LEAVES - {"vsr.ckpt.freeze", "vsr.ckpt.finalize",
-                            "vsr.journal.sync"}
+    on_the_path = LEAVES - REPLICATION_LEAVES - {
+        "vsr.ckpt.freeze", "vsr.ckpt.finalize", "vsr.journal.sync"}
+    for name in REPLICATION_LEAVES:
+        assert snap[name + "_us.count"] == 0, name
+    assert snap["vsr.quorum_wait_us.count"] == 0      # a quorum of one
+    # The forest's blocks, as the data file's storage limit gives them.
+    assert snap["vsr.grid.blocks_total"] == 8189
+    assert 0 <= snap["vsr.grid.blocks_acquired"] <= snap[
+        "vsr.grid.blocks_acquired_peak"] < 8189
     for name in sorted(on_the_path):
         assert snap[name + "_us.count"] > 0, name
     assert snap["sm.dev.compile.count"] >= 0 and snap["server.uptime_us"] > 0
@@ -367,6 +378,93 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     for name in COMMIT_LEAVES:
         segments = sum(1 for e in spans if e["name"] == name)
         assert segments == snap[name + "_us.count"], name
+
+
+@pytest.mark.parametrize("metrics", ["1", "0"])
+def test_three_served_replicas_feed_the_replication_stages(
+        tmp_path, monkeypatch, metrics):
+    """`vsr.replicate.send` on the primary, `vsr.backup.accept` on the
+    backups, `vsr.quorum_wait_us` a sample a prepare on the primary:
+    in the scrape's registry, in the `start --trace` file, and (the two
+    leaves) through the annotation sink.  With TB_METRICS=0 no
+    histogram is fed and no quorum clock is read; the spans stay."""
+    from tigerbeetle_tpu import constants as cfg
+    from tigerbeetle_tpu.client import Client
+    from tigerbeetle_tpu.runtime.server import ReplicaServer, format_data_file
+    from tigerbeetle_tpu.state_machine.cpu import CpuStateMachine
+
+    monkeypatch.setenv("TB_METRICS", metrics)
+    servers, sinks = [], []
+    addresses = ["127.0.0.1:0"] * 3
+    for i in range(3):
+        path = str(tmp_path / f"r{i}.tigerbeetle")
+        format_data_file(path, cluster=5, replica_index=i, replica_count=3,
+                         config=cfg.TEST_MIN)
+        s = ReplicaServer(
+            path, cluster=5, addresses=list(addresses), replica_index=i,
+            state_machine_factory=lambda: CpuStateMachine(cfg.TEST_MIN),
+            config=cfg.TEST_MIN, trace_path=str(tmp_path / f"trace{i}.json"))
+        addresses[i] = f"127.0.0.1:{s.port}"
+        s.tracer.annotate = sink = Sink()
+        servers.append(s)
+        sinks.append(sink)
+    for s in servers:
+        s.bus.addresses = list(addresses)
+    stop = []
+
+    def loop():
+        while not stop:
+            for s in servers:
+                s.poll_once(timeout_ms=1)
+
+    thread = threading.Thread(target=loop, daemon=True)
+    thread.start()
+    c = Client(",".join(addresses), 5, client_id=12, timeout_ms=60_000)
+    assert c.create_accounts(
+        [{"id": i, "ledger": 1, "code": 1} for i in (1, 2)]) == []
+    for at in range(10):
+        assert c.create_transfers([
+            {"id": 50 + at, "debit_account_id": 1, "credit_account_id": 2,
+             "amount": 1, "ledger": 1, "code": 1}]) == []
+    c.close()
+    deadline = time.time() + 20
+    while time.time() < deadline and len(
+            {s.replica.commit_min for s in servers}) > 1:
+        time.sleep(0.05)
+    stop.append(1)
+    thread.join(timeout=30)
+    snaps = [s.registry.snapshot() for s in servers]
+    pipeline_left = [e.written_at for s in servers
+                     for e in s.replica.pipeline.values()]
+    lead = next(i for i, s in enumerate(servers) if s.replica.is_primary)
+    prepared = servers[lead].replica.op
+    assert prepared >= 12
+    for s in servers:
+        s.close()
+    for i, snap in enumerate(snaps):
+        if metrics == "0":
+            assert not any(k.startswith(("vsr.replicate.send_us", "vsr.quorum_wait_us",
+                                         "vsr.backup.accept_us")) and v
+                           for k, v in snap.items())
+            continue
+        primary = i == lead
+        assert (snap["vsr.replicate.send_us.count"] >= prepared - 1) == primary
+        assert (snap["vsr.quorum_wait_us.count"] >= prepared - 1) == primary
+        assert (snap["vsr.backup.accept_us.count"] >= prepared - 1) == (not primary)
+        if primary:
+            # A quorum waits at least for what it takes to hand the
+            # prepare over.
+            assert snap["vsr.quorum_wait_us.sum"] > snap["vsr.replicate.send_us.sum"] > 0
+    assert all(x is None for x in pipeline_left) or metrics == "1"
+    for i in range(3):
+        names = {e["name"] for e in json.load(
+            open(tmp_path / f"trace{i}.json"))["traceEvents"]}
+        labels = {name for _what, name in sinks[i].events}
+        mine = "vsr.replicate.send" if i == lead else "vsr.backup.accept"
+        other = (REPLICATION_LEAVES - {mine}).pop()
+        assert mine in names and "tb." + mine in labels
+        assert other not in names and "tb." + other not in labels
+        assert "vsr.quorum_wait" not in names       # a histogram, not a span
 
 
 def test_sigterm_on_a_served_start_with_trace_leaves_a_loadable_file(tmp_path):

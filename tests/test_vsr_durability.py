@@ -18,7 +18,7 @@ CLUSTER = 7
 
 
 def layout():
-    return ZoneLayout(config=cfg.TEST_MIN, grid_size=1 << 20)
+    return ZoneLayout(config=cfg.TEST_MIN)
 
 
 def fresh_replica(storage=None, sm=None):

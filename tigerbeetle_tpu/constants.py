@@ -45,6 +45,14 @@ class Config:
     # checkpoint (the small-state test configs).
     spill_keep_rows: int = 0
     quorum_replication_max: int = 3
+    # The data file's storage limit, in bytes: what is left of it after
+    # the fixed zones and the two snapshot regions is the LSM forest's
+    # block region (vsr/storage.py ZoneLayout.forest_block_count;
+    # reference: src/vsr/superblock.zig derives grid_blocks_max from
+    # storage_size_max the same way).  `format` records it in the
+    # superblock.  The file is sparse: a block never acquired costs no
+    # disk.
+    storage_size_limit: int = 16 << 30
 
     @property
     def message_body_size_max(self) -> int:
@@ -85,6 +93,8 @@ TEST_MIN = Config(
     pipeline_prepare_queue_max=4,
     journal_slot_count=32,
     clients_max=4,
+    # 8,189 blocks of 64 KiB behind the snapshot regions.
+    storage_size_limit=1 << 30,
 )
 
 assert PRODUCTION.batch_max_create_transfers == 8190

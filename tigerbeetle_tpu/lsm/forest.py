@@ -21,12 +21,12 @@ from tigerbeetle_tpu.lsm.manifest_log import ManifestLog
 from tigerbeetle_tpu.utils import snapshot as snapcodec
 from tigerbeetle_tpu.vsr.free_set import FreeSet
 from tigerbeetle_tpu.vsr.grid import Grid
-from tigerbeetle_tpu.vsr.storage import Storage
+from tigerbeetle_tpu.vsr.storage import BLOCK_SIZE, Storage
 
 
 class Forest:
-    def __init__(self, storage: Storage, *, block_size: int = 1 << 16,
-                 block_count: int = 1 << 12, base_offset: int | None = None,
+    def __init__(self, storage: Storage, *, block_count: int,
+                 block_size: int = BLOCK_SIZE, base_offset: int | None = None,
                  memtable_max: int = 8192,
                  cache_blocks: int | None = None) -> None:
         # The grid cache absorbs compaction's read-back of recently
@@ -160,8 +160,17 @@ class Forest:
             tree._job = None
         self._beat_cursor = 0
         state = snapcodec.decode_tree(blob)
+        held = int(state["block_count"])
+        if held > self.grid.block_count:
+            raise RuntimeError(
+                f"the checkpoint's free set spans {held} blocks; the data "
+                f"file's storage limit gives the forest "
+                f"{self.grid.block_count}"
+            )
+        # A checkpoint of fewer blocks (taken before the storage limit
+        # sized the grid) grows to the grid's count.
         self.grid.free_set = FreeSet.decode(
-            state["free_set"], state["block_count"]
+            state["free_set"], held, grow_to=self.grid.block_count
         )
         # Merge outputs that were in flight at checkpoint time: no
         # manifest entry references them — reclaim (staged; activates

@@ -27,6 +27,7 @@ from tigerbeetle_tpu import constants as cfg
 from tigerbeetle_tpu.constants import HEADER_SIZE
 from tigerbeetle_tpu.vsr import replica as vsr_format
 from tigerbeetle_tpu.vsr import wire
+from tigerbeetle_tpu.vsr.free_set import GridFull
 from tigerbeetle_tpu.vsr.multi import VsrReplica
 from tigerbeetle_tpu.vsr.storage import FileStorage, ZoneLayout
 from tigerbeetle_tpu.vsr.wire import Command
@@ -55,6 +56,10 @@ class TcpBus:
         host, port = parse_address(addresses[replica_index])
         self.port = self.native.listen(host, port)
         self.replica_conns: dict[int, int] = {}  # keyed by PROCESS index
+        # client -> the connection its latest request came in on: its
+        # own, or (on the primary) the peer connection of the backup
+        # that forwarded the request.  A reply goes back the way the
+        # request came.
         self.client_conns: dict[int, int] = {}
         self._conn_peer: dict[int, tuple[str, object]] = {}
         self._pending_connects: dict[int, int] = {}  # conn -> replica
@@ -78,6 +83,21 @@ class TcpBus:
         if conn is None:
             return
         self.native.send2(conn, header.tobytes(), body)
+
+    def relay_client(self, client: int, header: np.ndarray,
+                     body: bytes) -> bool:
+        """A backup's hop of a forwarded request's reply: pass the
+        frame on, unchanged, to a client this process holds a
+        connection OF ITS OWN to (never along another peer's: a reply
+        must not travel between replicas twice).  -> whether it went."""
+        conn = self.client_conns.get(client)
+        if conn is None or self._is_peers(conn):
+            return False
+        self.native.send2(conn, header.tobytes(), body)
+        return True
+
+    def _is_peers(self, conn: int) -> bool:
+        return self._conn_peer.get(conn, ("client", 0))[0] == "replica"
 
     def send_frames(self, dst_replica: int,
                     frames: list[tuple[np.ndarray, bytes]]) -> None:
@@ -145,8 +165,13 @@ class TcpBus:
         self._conn_peer[conn] = ("replica", process)
 
     def register_client(self, conn: int, client: int) -> None:
+        """Where `client`'s replies go from now on.  A connection known
+        as a peer's (a backup forwarded the request over it) stays the
+        peer's: prepare_ok, commit and repair traffic keep their route,
+        and the reply rides it back to the backup that relays it."""
         self.client_conns[client] = conn
-        self._conn_peer[conn] = ("client", client)
+        if not self._is_peers(conn):
+            self._conn_peer[conn] = ("client", client)
 
     def drop_conn(self, conn: int) -> None:
         self._pending_connects.pop(conn, None)
@@ -156,19 +181,23 @@ class TcpBus:
         kind, peer = kind_id
         if kind == "replica":
             self.replica_conns.pop(peer, None)
-        else:
-            self.client_conns.pop(peer, None)
+            # Clients whose requests this peer forwarded: their next
+            # request (the client resends) names a route again.
+            for client in [c for c, at in self.client_conns.items()
+                           if at == conn]:
+                del self.client_conns[client]
+        elif self.client_conns.get(peer) == conn:
+            del self.client_conns[peer]
 
 
 class ReplicaServer:
     def __init__(self, data_path: str, *, cluster: int | None = None,
                  addresses: list[str], replica_index: int,
                  state_machine_factory, config: cfg.Config = cfg.PRODUCTION,
-                 grid_size: int = 1 << 20, aof_path: str | None = None,
+                 aof_path: str | None = None,
                  trace_path: str | None = None,
                  standby_count: int = 0) -> None:
-        layout = ZoneLayout(config=config, grid_size=grid_size)
-        self.storage = FileStorage(data_path, layout)
+        self.storage = FileStorage(data_path, ZoneLayout(config=config))
         self.bus = TcpBus(addresses, replica_index, config.message_size_max)
         aof = None
         if aof_path:
@@ -897,10 +926,11 @@ class ReplicaServer:
         while True:
             try:
                 self.poll_once()
-            except AssertionError as exc:
-                # Invariant violation: capture the last moments before
-                # the crash.  The `assertion_failure` event is a flight
-                # trigger, so note() flushes the ring to disk.
+            except (AssertionError, GridFull) as exc:
+                # Invariant violation, or a full grid (a stop that names
+                # itself): capture the last moments before the crash.
+                # The `assertion_failure` event is a flight trigger, so
+                # note() flushes the ring to disk.
                 self.flight.note("assertion_failure", error=repr(exc)[:500])
                 raise
 
@@ -930,9 +960,7 @@ class ReplicaServer:
 
 def format_data_file(path: str, *, cluster: int, replica_index: int = 0,
                      replica_count: int = 1,
-                     config: cfg.Config = cfg.PRODUCTION,
-                     grid_size: int = 1 << 20) -> None:
-    layout = ZoneLayout(config=config, grid_size=grid_size)
-    storage = FileStorage(path, layout, create=True)
+                     config: cfg.Config = cfg.PRODUCTION) -> None:
+    storage = FileStorage(path, ZoneLayout(config=config), create=True)
     vsr_format.format(storage, cluster, replica_index, replica_count)
     storage.close()

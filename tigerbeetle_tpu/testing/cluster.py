@@ -387,7 +387,7 @@ class Cluster:
         self.storages: list[MemoryStorage] = []
         for i in range(replica_count + standby_count):
             storage = MemoryStorage(
-                ZoneLayout(config=config, grid_size=1 << 20), seed=seed + i
+                ZoneLayout(config=config), seed=seed + i
             )
             vsr_format.format(storage, self.cluster_id, i, replica_count)
             r = VsrReplica(

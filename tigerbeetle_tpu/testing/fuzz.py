@@ -24,8 +24,8 @@ from tigerbeetle_tpu import constants as cfg
 from tigerbeetle_tpu.vsr.storage import MemoryStorage, ZoneLayout
 
 
-def _layout(grid_size: int = 1 << 20) -> ZoneLayout:
-    return ZoneLayout(config=cfg.TEST_MIN, grid_size=grid_size)
+def _layout() -> ZoneLayout:
+    return ZoneLayout(config=cfg.TEST_MIN)
 
 
 # ---------------------------------------------------------------------------
@@ -265,7 +265,7 @@ def fuzz_tree(seed: int, rounds: int) -> None:
 
     rng = np.random.default_rng(seed)
     for case in range(max(1, rounds // 40)):
-        storage = MemoryStorage(_layout(grid_size=1 << 22), seed=seed + case)
+        storage = MemoryStorage(_layout(), seed=seed + case)
         grid = Grid(storage, block_size=4096, block_count=1 << 10)
         tree = Tree(grid, "fuzz", value_size=8, memtable_max=64)
         model: dict[bytes, bytes] = {}
@@ -330,7 +330,7 @@ def fuzz_manifest_log(seed: int, rounds: int) -> None:
         # alone can reach ~350 blocks, and a compacting checkpoint
         # holds the old log blocks (still staged for release) plus the
         # fresh snapshot concurrently.
-        storage = MemoryStorage(_layout(grid_size=1 << 24), seed=seed + case)
+        storage = MemoryStorage(_layout(), seed=seed + case)
         grid = Grid(storage, block_size=4096, block_count=1 << 12)
         mlog = ManifestLog(grid)
         model: dict[tuple, list] = {}

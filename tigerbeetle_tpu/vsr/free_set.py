@@ -17,6 +17,12 @@ import numpy as np
 from tigerbeetle_tpu.lsm import ewah
 
 
+class GridFull(RuntimeError):
+    """The forest asked for more blocks than the data file's storage
+    limit leaves free.  A stop, as the reference's is: nothing is
+    dropped, and the replica does not go on."""
+
+
 @dataclasses.dataclass
 class Reservation:
     blocks: np.ndarray  # window of block indices, fixed at reserve time
@@ -44,6 +50,15 @@ class FreeSet:
         # Blocks inside outstanding reservations (not yet acquired).
         self._reserved_mask = np.zeros(block_count, bool)
         self._reservations = 0
+        # No block below this index can be reserved (each is held,
+        # reserved or quarantined): a reservation looks from here on,
+        # so its cost follows what it asks for and not the grid's size.
+        self._scan_from = 0
+        # Blocks held now, and the most ever held (blocks return only
+        # at a checkpoint, so the high-water mark is what a limit has
+        # to cover).
+        self.acquired = 0
+        self.acquired_peak = 0
 
     def count_free(self) -> int:
         return int(self.free.sum())
@@ -57,12 +72,30 @@ class FreeSet:
         Quarantined blocks (freed by a checkpoint whose flip is still
         in flight) are excluded: the previous superblock — the durable
         recovery root until the flip lands — may reference them."""
-        candidates = np.flatnonzero(
-            self.free & ~self._reserved_mask & ~self.quarantine
-        )
-        assert blocks_needed <= len(candidates), "grid full"
-        window = candidates[:blocks_needed].copy()
-        self._reserved_mask[window] = True
+        found = [np.zeros(0, np.int64)]
+        have = 0
+        at = self._scan_from
+        span = max(4096, 4 * blocks_needed)
+        while have < blocks_needed and at < self.block_count:
+            part = slice(at, at + span)
+            candidates = at + np.flatnonzero(
+                self.free[part] & ~self._reserved_mask[part]
+                & ~self.quarantine[part]
+            )
+            found.append(candidates)
+            have += len(candidates)
+            at += span
+        if have < blocks_needed:
+            raise GridFull(
+                f"grid full: the data file's storage limit gives the forest "
+                f"{self.block_count} blocks, {self.acquired} are held and "
+                f"{have} can be reserved where {blocks_needed} are asked for "
+                f"(released blocks return at the next checkpoint)"
+            )
+        window = np.concatenate(found)[:blocks_needed]
+        if len(window):
+            self._reserved_mask[window] = True
+            self._scan_from = int(window[-1]) + 1
         self._reservations += 1
         return Reservation(blocks=window)
 
@@ -73,11 +106,15 @@ class FreeSet:
         reservation.acquired += 1
         self.free[block] = False
         self._reserved_mask[block] = False
+        self.acquired += 1
+        self.acquired_peak = max(self.acquired_peak, self.acquired)
         return block + 1
 
     def forfeit(self, reservation: Reservation) -> None:
         remainder = reservation.blocks[reservation.acquired :]
         self._reserved_mask[remainder] = False
+        if len(remainder):
+            self._scan_from = min(self._scan_from, int(remainder[0]))
         self._reservations -= 1
 
     def is_free(self, address: int) -> bool:
@@ -93,8 +130,6 @@ class FreeSet:
         blocks' frames may legitimately go stale and peers that
         already checkpointed no longer serve them — the shared
         predicate behind the scrubber's skip and the repair filter."""
-        import numpy as np
-
         idx = np.asarray(addresses, np.int64) - 1
         return self.free[idx] | self.staging[idx]
 
@@ -112,7 +147,9 @@ class FreeSet:
         # quarantine.
         self.quarantine = self.staging.copy()
         self.free |= self.staging
+        self.acquired -= int(self.staging.sum())
         self.staging[:] = False
+        self._scan_from = 0
 
     def release_quarantine(self) -> None:
         """Explicit early release — for harnesses that know no older
@@ -120,6 +157,7 @@ class FreeSet:
         fuzzers modeling a landed flip).  The replica itself never
         calls this: reuse timing must not depend on flip wall time."""
         self.quarantine[:] = False
+        self._scan_from = 0
 
     def count_reservable(self) -> int:
         return int((self.free & ~self.quarantine).sum())
@@ -133,11 +171,17 @@ class FreeSet:
         return ewah.encode(words)
 
     @classmethod
-    def decode(cls, data: bytes, block_count: int) -> "FreeSet":
-        fs = cls(block_count)
+    def decode(cls, data: bytes, block_count: int,
+               grow_to: int | None = None) -> "FreeSet":
+        """`grow_to`: the block count of the grid that opens the set,
+        where that is larger than the count it was encoded at (a data
+        file from before the storage limit sized the grid): the blocks
+        beyond were never acquired, so they join free."""
+        fs = cls(max(block_count, grow_to or 0))
         words = ewah.decode(data, (block_count + 63) // 64)
         bits = np.unpackbits(
             words.view(np.uint8), count=block_count, bitorder="little"
         )
-        fs.free = bits.astype(bool)
+        fs.free[:block_count] = bits.astype(bool)
+        fs.acquired = fs.acquired_peak = fs.block_count - fs.count_free()
         return fs

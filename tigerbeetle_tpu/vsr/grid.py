@@ -17,7 +17,7 @@ import numpy as np
 from tigerbeetle_tpu.utils.cache import SetAssociativeCache
 from tigerbeetle_tpu.vsr import wire
 from tigerbeetle_tpu.vsr.free_set import FreeSet
-from tigerbeetle_tpu.vsr.storage import Storage
+from tigerbeetle_tpu.vsr.storage import BLOCK_SIZE, Storage
 
 BLOCK_HEADER_SIZE = 64
 
@@ -40,8 +40,8 @@ class Grid:
     # refcounts — every access holds _pending_lock.
     _WORKER_SHARED = frozenset({"_pending_writes"})
 
-    def __init__(self, storage: Storage, *, block_size: int = 1 << 16,
-                 block_count: int = 1 << 12, base_offset: int | None = None,
+    def __init__(self, storage: Storage, *, block_count: int,
+                 block_size: int = BLOCK_SIZE, base_offset: int | None = None,
                  cache_blocks: int = 256) -> None:
         self.storage = storage
         self.block_size = block_size
@@ -80,6 +80,14 @@ class Grid:
             # Discarded grids (crash-recovery loops) reclaim their
             # worker thread instead of leaking it.
             weakref.finalize(self, self._writer.close)
+
+    def resize(self, block_count: int) -> None:
+        """Take the block count the data file's own storage limit gives
+        (Replica.open, before anything is restored or acquired)."""
+        if block_count != self.block_count:
+            assert self.free_set.acquired == 0, "resize of a grid in use"
+            self.block_count = block_count
+            self.free_set = FreeSet(block_count)
 
     @property
     def payload_size(self) -> int:

@@ -92,6 +92,9 @@ class PipelineEntry:
     # headers) advertise an op with one durable copy fewer than the
     # quorum promises.  flush_group_commit marks entries synced.
     synced: bool = True
+    # The tracer's clock when the primary's journal write of this
+    # prepare returned; None once its quorum is seen (or untimed).
+    written_at: int | None = None
 
 
 class VsrReplica(Replica):
@@ -385,6 +388,27 @@ class VsrReplica(Replica):
         self._st_reply_send = Stage(
             self.metrics.histogram("reply_send_us"), "vsr.reply_send"
         )
+        # Replication.  vsr.replicate.send: the primary hands a prepare
+        # to the backups' connections (a backup's forward along the
+        # ring is part of its accept).  vsr.backup.accept: a backup's
+        # run of prepares, from verified frames to the prepare_oks
+        # handed to the bus (or held for the covering sync); a sample a
+        # prepare.  vsr.quorum_wait_us is no stage (the loop does other
+        # work meanwhile): from the primary's journal write of a
+        # prepare to the prepare_ok that makes its quorum.
+        self._st_replicate = Stage(
+            self.metrics.histogram("replicate.send_us"), "vsr.replicate.send"
+        )
+        self._st_backup_accept = Stage(
+            self.metrics.histogram("backup.accept_us"), "vsr.backup.accept"
+        )
+        self._h_quorum_wait = self.metrics.histogram("quorum_wait_us")
+        # A backup's two hops for a client that addressed it: requests
+        # sent on to the primary, and replies passed back unchanged.
+        self._c_requests_forwarded = self.metrics.counter(
+            "requests_forwarded"
+        )
+        self._c_replies_relayed = self.metrics.counter("replies_relayed")
         # From a request's arrival (its drain's decode, or its enqueue
         # on the per-message path) to its leaving the queue for the
         # prepare that carries it.
@@ -694,6 +718,9 @@ class VsrReplica(Replica):
             Command.block: self._on_block,
             Command.ping: self._on_ping,
             Command.pong: self._on_pong,
+            Command.reply: self._relay_to_client,
+            Command.eviction: self._relay_to_client,
+            Command.client_busy: self._relay_to_client,
         }.get(cmd)
         if handler is not None:
             handler(header, body)
@@ -706,6 +733,7 @@ class VsrReplica(Replica):
             return
         if not self.is_primary:
             # Forward to the primary (clients may have a stale view).
+            self._c_requests_forwarded.inc()
             self.bus.send(self.primary_index(), header, body)
             return
         operation = int(header["operation"])
@@ -755,6 +783,19 @@ class VsrReplica(Replica):
             return
         self._primary_prepare(header, body)
 
+    def _relay_to_client(self, header: np.ndarray, body: bytes) -> None:
+        """What the primary answered a request this replica forwarded
+        (a reply, an eviction, a busy) comes back along the peer
+        connection the request went out on: pass it on unchanged to
+        the client, whose connection the bus holds since the request
+        came in.  Nothing is stored: the primary's client-replies zone
+        stays the at-most-once record."""
+        relay = getattr(self.bus, "relay_client", None)
+        if relay is not None and relay(
+            wire.u128(header, "client"), header, body
+        ):
+            self._c_replies_relayed.inc()
+
     def on_requests_batch(self, headers, bodies, arrived=None) -> None:
         """Columnar request intake (runtime/server.py fast drain): one
         drain's worth of client requests, headers pre-verified and
@@ -788,6 +829,7 @@ class VsrReplica(Replica):
             headers = [headers[i] for i in keep]
             bodies = [bodies[i] for i in keep]
         if not self.is_primary:
+            self._c_requests_forwarded.inc(len(headers))
             for i, h in enumerate(headers):
                 self.bus.send(self.primary_index(), h, bytes(bodies[i]))
             return False
@@ -933,6 +975,7 @@ class VsrReplica(Replica):
             if entry is None:
                 continue  # C table ahead of a just-dropped entry
             entry.ok_replicas.add(int(h["replica"]))
+            self._note_quorum(entry)
             self.anatomy.stage_h(h, "prepare_ok")
             voted = True
         if voted:
@@ -954,6 +997,12 @@ class VsrReplica(Replica):
         demote the fresh frames ahead of it.  TB_NATIVE_DRAIN=0 pins
         the per-message loop over the same seam (bit-identical
         frames)."""
+        with self.tracer.stage(self._st_backup_accept) as run:
+            run.split(len(headers))
+            self._accept_prepares(headers, bodies)
+
+    def _accept_prepares(self, headers: list[np.ndarray],
+                         bodies: list) -> None:
         split = 0
         if (
             self._drain_native
@@ -1436,6 +1485,7 @@ class VsrReplica(Replica):
         synced = not self._gc_enabled
         self.pipeline[op] = PipelineEntry(
             prepare, body, {self.replica}, subs, synced=synced,
+            written_at=self._quorum_stamp(),
         )
         if self._np is not None:
             self._np.note_prepare(prepare, synced, self.replica)
@@ -1557,11 +1607,24 @@ class VsrReplica(Replica):
             # the Python-side mirror is created here.
             self.pipeline[op] = PipelineEntry(
                 prepare, bodies[i], {self.replica}, plan[i][2],
-                synced=False,
+                synced=False, written_at=self._quorum_stamp(),
             )
             self._replicate(prepare, bodies[i])
 
+    def _quorum_stamp(self) -> int | None:
+        """Where a quorum needs another replica's vote, the clock now."""
+        if self.quorum_replication <= 1:
+            return None
+        return self.tracer.stamp(self._h_quorum_wait)
+
     def _replicate(self, prepare: np.ndarray, body: bytes) -> None:
+        if self.is_primary and self.total_count > 1:
+            with self.tracer.stage(self._st_replicate):
+                self._replicate_send(prepare, body)
+        else:
+            self._replicate_send(prepare, body)
+
+    def _replicate_send(self, prepare: np.ndarray, body: bytes) -> None:
         """Ring forwarding: send to successor only (reference:
         src/vsr/replica.zig:1532-1556).  The primary additionally
         feeds each standby directly; standbys never forward."""
@@ -1592,8 +1655,20 @@ class VsrReplica(Replica):
         # The Python set stays maintained either way — retransmit,
         # eviction, and view-change scans read it.
         entry.ok_replicas.add(int(header["replica"]))
+        self._note_quorum(entry)
         self.anatomy.stage_h(header, "prepare_ok")
         self._maybe_commit_pipeline()
+
+    def _note_quorum(self, entry: PipelineEntry) -> None:
+        """vsr.quorum_wait_us: once, by the vote that makes the quorum."""
+        if (
+            entry.written_at is not None
+            and len(entry.ok_replicas) >= self.quorum_replication
+        ):
+            self._h_quorum_wait.observe(
+                (self.tracer.clock() - entry.written_at) / 1e3
+            )
+            entry.written_at = None
 
     def _primary_requeue_uncommitted(self) -> None:
         """After a view change, the adopted-but-uncommitted tail must be

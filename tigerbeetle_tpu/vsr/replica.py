@@ -28,7 +28,7 @@ from tigerbeetle_tpu.obs import stat_property
 from tigerbeetle_tpu.state_machine import demuxer
 from tigerbeetle_tpu.vsr import wire
 from tigerbeetle_tpu.vsr.journal import Journal
-from tigerbeetle_tpu.vsr.storage import Storage, _sectors
+from tigerbeetle_tpu.vsr.storage import SNAPSHOT_SPAN, Storage, _sectors
 from tigerbeetle_tpu.vsr.superblock import SuperBlock
 from tigerbeetle_tpu.vsr.wire import Command, VsrOperation
 
@@ -41,13 +41,6 @@ def format(storage: Storage, cluster: int, replica: int = 0,
     sb.format(replica, replica_count)
     journal = Journal(storage, cluster)
     journal.write_prepare(wire.root_prepare(cluster), b"")
-
-
-# Fixed A/B checkpoint-snapshot reservation when an LSM forest shares
-# the grid zone (spilling bounds the blob well below this; asserted at
-# checkpoint).  Without a forest the regions size dynamically as before.
-SNAPSHOT_SPAN = 1 << 28
-FOREST_BLOCK_COUNT = 1 << 12
 
 
 @dataclasses.dataclass
@@ -69,8 +62,7 @@ class Replica:
     _WORKER_SHARED = frozenset({"checkpoint_op"})
 
     def __init__(self, storage: Storage, cluster: int, state_machine,
-                 replica: int = 0, replica_count: int = 1, aof=None,
-                 forest_block_count: int = FOREST_BLOCK_COUNT) -> None:
+                 replica: int = 0, replica_count: int = 1, aof=None) -> None:
         self.storage = storage
         self.cluster = cluster
         self.sm = state_machine
@@ -236,18 +228,33 @@ class Replica:
         # that support it spill frozen state there, so checkpoints stay
         # O(RAM tail) and durable state scales past host RAM —
         # reference: src/lsm/forest.zig:31).  The A/B snapshot regions
-        # get a fixed reservation ahead of the block region; the file
-        # is sparse, so unused reservation costs nothing on disk.
+        # get a fixed reservation ahead of the block region, which runs
+        # to the storage limit (the configuration's here; open() takes
+        # the one the data file records); the file is sparse, so what
+        # is reserved and unused costs nothing on disk.
         self.forest = None
         if hasattr(state_machine, "attach_forest"):
             from tigerbeetle_tpu.lsm.forest import Forest
 
             self.forest = Forest(
                 storage,
-                base_offset=storage.layout.grid_offset + 2 * SNAPSHOT_SPAN,
-                block_count=forest_block_count,
+                base_offset=storage.layout.forest_offset,
+                block_count=storage.layout.forest_block_count(),
             )
             state_machine.attach_forest(self.forest)
+            # The free set is replaced at every restore: read through
+            # the grid.
+            grid = self.forest.grid
+            self.metrics.gauge_fn(
+                "grid.blocks_total", lambda: grid.block_count
+            )
+            self.metrics.gauge_fn(
+                "grid.blocks_acquired", lambda: grid.free_set.acquired
+            )
+            self.metrics.gauge_fn(
+                "grid.blocks_acquired_peak",
+                lambda: grid.free_set.acquired_peak,
+            )
 
         self.op = 0                  # highest prepared op
         self._ckpt_interval_observed = 0  # ops between checkpoints
@@ -306,6 +313,12 @@ class Replica:
             self._install_committed(int(sb["epoch"]), members)
         self.view = int(sb["view"])
         self.checkpoint_op = int(sb["commit_min"])
+        if self.forest is not None:
+            # 0: a file formatted before the limit was recorded; it
+            # opens at the configuration's, its free set grown.
+            self.forest.grid.resize(self.storage.layout.forest_block_count(
+                int(sb["storage_size_limit"]) or None
+            ))
 
         # Restore the checkpoint snapshot (if one was ever taken).
         size = int(sb["checkpoint_size"])

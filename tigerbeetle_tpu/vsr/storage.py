@@ -36,13 +36,18 @@ def _sectors(n: int) -> int:
 SUPERBLOCK_COPIES = 4  # reference: src/vsr/superblock.zig (4-copy quorum)
 SUPERBLOCK_COPY_SIZE = SECTOR_SIZE  # one sector per copy: atomic-ish write
 
+# The grid zone: two fixed checkpoint-snapshot regions (A/B; spilling
+# bounds the blob well below a span, asserted at checkpoint), then the
+# LSM forest's blocks up to the data file's storage limit.
+SNAPSHOT_SPAN = 1 << 28
+BLOCK_SIZE = 1 << 16
+
 
 @dataclasses.dataclass(frozen=True)
 class ZoneLayout:
     """Byte offsets of every zone, derived from the cluster config."""
 
     config: Config
-    grid_size: int
 
     @property
     def superblock_offset(self) -> int:
@@ -81,8 +86,21 @@ class ZoneLayout:
         return self.client_replies_offset + self.client_replies_size
 
     @property
-    def total_size(self) -> int:
-        return self.grid_offset + self.grid_size
+    def forest_offset(self) -> int:
+        return self.grid_offset + 2 * SNAPSHOT_SPAN
+
+    def forest_block_count(self, storage_size_limit: int | None = None) -> int:
+        """Blocks the forest may hold under a storage limit: the
+        configuration's, or the one a data file's superblock records."""
+        if storage_size_limit is None:
+            storage_size_limit = self.config.storage_size_limit
+        count = (storage_size_limit - self.forest_offset) // BLOCK_SIZE
+        if count < 1:
+            raise ValueError(
+                f"a storage limit of {storage_size_limit} bytes leaves the "
+                f"forest no block behind offset {self.forest_offset}"
+            )
+        return count
 
     def prepare_slot_offset(self, slot: int) -> int:
         assert 0 <= slot < self.config.journal_slot_count

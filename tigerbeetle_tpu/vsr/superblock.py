@@ -84,8 +84,15 @@ SUPERBLOCK_DTYPE = np.dtype(
         # its offset: an old data file decodes root=0 here, which the
         # restore assert treats as "not recorded" and skips.
         ("state_root_lo", "<u8"), ("state_root_hi", "<u8"),
+        # The data file's storage limit, recorded by `format`
+        # (constants.Config.storage_size_limit): it sizes the forest's
+        # block region at every later open, whatever the build's
+        # configuration then says.  APPENDED like the root above: a
+        # file formatted before the field existed decodes 0, and opens
+        # at the configuration's limit.
+        ("storage_size_limit", "<u8"),
         ("reserved",
-         f"V{SUPERBLOCK_COPY_SIZE - 224 - VIEW_HEADERS_MAX * HEADER_SIZE}"),
+         f"V{SUPERBLOCK_COPY_SIZE - 232 - VIEW_HEADERS_MAX * HEADER_SIZE}"),
     ]
 )
 assert SUPERBLOCK_DTYPE.itemsize == SUPERBLOCK_COPY_SIZE
@@ -109,6 +116,7 @@ class SuperBlock:
         h["replica"] = replica
         h["replica_count"] = replica_count
         h["version"] = wire.VERSION
+        h["storage_size_limit"] = self.storage.layout.config.storage_size_limit
         h["commit_min"] = 0
         h["commit_max"] = 0
         root = wire.root_prepare(self.cluster)
