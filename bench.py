@@ -594,6 +594,7 @@ def _run_durable_once(n_events: int, ckpt_async: bool = True) -> dict:
         # Counter reset (and the final read below) must see a drained
         # grid write-behind queue — pending SerialWorker block writes
         # increment the counters only when they execute.
+        sm._forest.barrier()  # beats handed to the lsm-beat worker
         sm._forest.grid.flush_writes()
         storage.stat_bytes_wal = 0
         storage.stat_bytes_grid = 0
@@ -636,6 +637,9 @@ def _run_durable_once(n_events: int, ckpt_async: bool = True) -> dict:
             failed += len(reply) // 8
         r._ckpt_join()  # in-flight flip lands outside the timed window
         sm.sync()
+        # Inside it: the (at most two) beats still on the lsm-beat
+        # worker are work the timed commits caused.
+        sm._forest.barrier()
         elapsed = time.perf_counter() - t0
         # Outside the timed window (metric continuity across rounds):
         # drain the write-behind queue so the byte counters are exact.

@@ -232,7 +232,16 @@ LEAVES = {
     "sm.dev.link.fetch_copy", "sm.dev.finish", "vsr.commit.reply",
     "vsr.commit.beat", "vsr.reply_send", "vsr.tick", "vsr.ckpt.freeze",
     "vsr.ckpt.finalize", "vsr.journal.sync", "vsr.replicate.send",
-    "vsr.backup.accept",
+    "vsr.backup.accept", "lsm.beat.work",
+}
+# What runs on a worker's thread: annotated there, out of the loop's
+# sums (`server_loop_attributed_pct`, `commit_attributed_pct`).
+WORKER_LEAVES = {"vsr.ckpt.finalize", "vsr.journal.sync", "lsm.beat.work"}
+# The beat worker's instruments (lsm/beats.py), "lsm." on the scrape.
+BEAT_KEYS = {
+    "lsm.beat.work_us.count", "lsm.beat.work_us.sum", "lsm.beat.bound_waits",
+    "lsm.beat.bound_wait_us.count", "lsm.beat.bound_wait_us.sum",
+    "lsm.barrier.joins", "lsm.barrier.wait_us.sum", "lsm.beat.queued",
 }
 # What only a cluster's replicas open: the primary's hand-over of a
 # prepare to the backups' connections, a backup's run of prepares.
@@ -335,8 +344,12 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     assert not thread.is_alive() and not failed, failed
     snap = server.registry.snapshot()
     server.close()
-    on_the_path = LEAVES - REPLICATION_LEAVES - {
-        "vsr.ckpt.freeze", "vsr.ckpt.finalize", "vsr.journal.sync"}
+    # (12 small requests: the tail never reaches the 16,384 rows a
+    # spill waits for, so no beat is handed to the worker.)
+    on_the_path = LEAVES - REPLICATION_LEAVES - WORKER_LEAVES - {
+        "vsr.ckpt.freeze"}
+    assert BEAT_KEYS <= set(snap)
+    assert snap["lsm.beat.work_us.count"] == snap["lsm.beat.queued"] == 0
     for name in REPLICATION_LEAVES:
         assert snap[name + "_us.count"] == 0, name
     assert snap["vsr.quorum_wait_us.count"] == 0      # a quorum of one
@@ -378,6 +391,99 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     for name in COMMIT_LEAVES:
         segments = sum(1 for e in spans if e["name"] == name)
         assert segments == snap[name + "_us.count"], name
+
+
+def test_the_beat_stage_is_the_hand_over_and_the_work_is_the_workers(tmp_path):
+    """Full batches over FileStorage with the beat worker: every commit
+    hands a beat to the `lsm-beat` thread.  `vsr.commit.beat` stays a leaf of the commit on
+    the loop's thread (it tiles the span with the others, as
+    `commit_attributed_pct` sums them) and measures the hand-over;
+    `lsm.beat.work` runs on the worker's thread, annotated there, on
+    its own row of the trace, and lies outside the commit span: a beat
+    held 0.3 s on the worker lengthens no commit."""
+    import numpy as np
+
+    from tigerbeetle_tpu import constants as cfg
+    from tigerbeetle_tpu import types
+    from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine
+    from tigerbeetle_tpu.testing.harness import account, pack
+    from tigerbeetle_tpu.vsr import replica as vsr_replica
+    from tigerbeetle_tpu.vsr.storage import FileStorage, ZoneLayout
+
+    storage = FileStorage(str(tmp_path / "0_0.tigerbeetle"),
+                          ZoneLayout(config=cfg.PRODUCTION), create=True)
+    vsr_replica.format(storage, 30)
+    r = vsr_replica.Replica(storage, 30, TpuStateMachine(
+        cfg.PRODUCTION, account_capacity=1 << 10, transfer_capacity=1 << 16))
+    # (A lone replica keeps its beats in place; a cluster's gets the
+    # worker.  Given by hand here.)
+    from tigerbeetle_tpu.lsm.beats import BeatWorker
+
+    r.forest.beats = BeatWorker(r.forest.metrics, threaded=True)
+    r.open()
+    tracer = Tracer("json")
+    tracer.strict_leaves = True          # per thread: the worker's leaf is its own
+    on_thread = []
+
+    class ThreadSink(Sink):
+        def __call__(self, name):
+            on_thread.append((name, threading.current_thread().name))
+            return super().__call__(name)
+
+    tracer.annotate = ThreadSink()
+    r.set_tracer(tracer)
+    Op = types.Operation
+    assert r.on_request(int(Op.create_accounts), pack(
+        [account(i) for i in (1, 2, 3)])) == b""
+
+    def commit(op):
+        rows = np.zeros(8190, types.TRANSFER_DTYPE)
+        rows["id_lo"] = np.arange(1 + op * 8190, 1 + (op + 1) * 8190)
+        rows["debit_account_id_lo"] = 1 + rows["id_lo"] % 3
+        rows["credit_account_id_lo"] = 1 + (rows["id_lo"] + 1) % 3
+        rows["amount_lo"] = rows["ledger"] = rows["code"] = 1
+        assert r.on_request(int(Op.create_transfers), rows.tobytes()) == b""
+
+    for op in range(5):
+        commit(op)
+    r.forest.barrier()
+    # One more, its beat held on the worker while the commit returns.
+    work, release = r._beat_work, threading.Event()
+    r._beat_work = lambda *a: (release.wait(30), work(*a))
+    threading.Timer(0.3, release.set).start()
+    waits = r.forest.metrics.snapshot()["beat.bound_waits"]
+    span_before = r.metrics.snapshot()["commit_us.sum"]
+    commit(5)
+    held_commit_us = r.metrics.snapshot()["commit_us.sum"] - span_before
+    r.forest.barrier()
+    vsr, lsm = r.metrics.snapshot(), r.forest.metrics.snapshot()
+    r.close()
+    storage.close()
+
+    commits = vsr["commit_us.count"]
+    assert vsr["commit.beat_us.count"] == commits >= 7
+    beats = lsm["beat.work_us.count"]
+    assert beats == 4                   # commits 2 to 5: the tail past 16,384
+    assert lsm["beat.work_us.max"] >= 300_000 > held_commit_us
+    assert lsm["beat.bound_waits"] == waits and lsm["barrier.joins"] >= 1
+    # The commit's leaves (host engine: prefetch, reply, beat) tile it.
+    inside = sum(vsr[k + "_us.sum"] for k in (
+        "commit.prefetch", "commit.reply", "commit.beat"))
+    assert 0 < vsr["commit.beat_us.sum"] < inside <= vsr["commit_us.sum"]
+    # Annotated on the worker's thread, and only there.
+    where = {t for name, t in on_thread if name == "tb.lsm.beat.work"}
+    assert where == {"lsm-beat"}
+    assert {t for name, t in on_thread if name == "tb.vsr.commit.beat"} == {
+        threading.current_thread().name}
+    # Its own row in the JSON trace; the loop's leaves still never overlap.
+    events = [e for e in json.loads(tracer.dump())["traceEvents"] if e.get("ph") == "X"]
+    assert {e["tid"] for e in events if e["name"] == "lsm.beat.work"} == {3}
+    assert len([e for e in events if e["name"] == "lsm.beat.work"]) == beats
+    loop = sorted((e for e in events if e["tid"] == 0 and e["name"] in LEAVES),
+                  key=lambda e: e["ts"])
+    assert {"vsr.commit.beat", "vsr.commit.reply"} <= {e["name"] for e in loop}
+    for a, b in zip(loop, loop[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 0.002, (a, b)
 
 
 @pytest.mark.parametrize("metrics", ["1", "0"])

@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from tigerbeetle_tpu import obs
+from tigerbeetle_tpu.lsm.beats import BeatWorker
 from tigerbeetle_tpu.lsm.groove import Groove
 from tigerbeetle_tpu.lsm.manifest_log import ManifestLog
 from tigerbeetle_tpu.utils import snapshot as snapcodec
@@ -28,7 +30,8 @@ class Forest:
     def __init__(self, storage: Storage, *, block_count: int,
                  block_size: int = BLOCK_SIZE, base_offset: int | None = None,
                  memtable_max: int = 8192,
-                 cache_blocks: int | None = None) -> None:
+                 cache_blocks: int | None = None,
+                 beat_worker: bool = False) -> None:
         # The grid cache absorbs compaction's read-back of recently
         # written runs.  The file-backed default (4096 x 64KiB =
         # 256MiB) mirrors the reference's GiB-scale grid cache
@@ -39,12 +42,9 @@ class Forest:
         # (tests, fuzz clusters) keep a small cache — their reads are
         # already RAM copies, and dozens of in-process replicas must
         # not each pin 256MiB.
+        file_backed = getattr(storage, "supports_async_writeback", False)
         if cache_blocks is None:
-            cache_blocks = (
-                4096
-                if getattr(storage, "supports_async_writeback", False)
-                else 256
-            )
+            cache_blocks = 4096 if file_backed else 256
         self.grid = Grid(
             storage, block_size=block_size, block_count=block_count,
             base_offset=base_offset, cache_blocks=cache_blocks,
@@ -57,6 +57,24 @@ class Forest:
         # before open()).
         self._trees: list = []
         self._beat_cursor = 0
+        # The beats' worker (lsm/beats.py) and its instruments; the
+        # owning server attaches the registry under "lsm.".  A thread
+        # only where the owner asks for one and the storage allows it
+        # (the grid writer's switch); else beats run in place.
+        self.metrics = obs.Registry()
+        self.beats = BeatWorker(self.metrics, beat_worker and file_backed)
+
+    def barrier(self) -> None:
+        """Join the beats handed to the worker: before anything on
+        another thread reads or writes the trees, the grid's free set
+        or the manifest log."""
+        self.beats.barrier()
+
+    def close(self) -> None:
+        """Drain the beats, stop their thread, and land the block
+        writes they queued."""
+        self.beats.close()
+        self.grid.flush_writes()
 
     def groove(self, name: str, *, object_size: int,
                index_fields: list[str], index_value_size: int = 1) -> Groove:
@@ -108,6 +126,7 @@ class Forest:
         them; a restore releases them and the merge restarts from its
         (still-referenced) inputs — which is what lets checkpoints
         proceed WITHOUT draining compaction."""
+        self.barrier()
         orphans = []
         for tree in self._trees:
             if tree._job is not None:
@@ -139,6 +158,7 @@ class Forest:
         most one level merge, and the disjoint-range moves that
         dominate the big trees are metadata-only.  Remaining over-full
         levels start their merges in the next interval's beats."""
+        self.barrier()
         for tree in self._trees:
             tree.seal_memtable()
             while tree._job is not None:
@@ -151,6 +171,7 @@ class Forest:
         return self.manifest_blob()
 
     def open(self, blob: bytes) -> None:
+        self.barrier()
         # Cancel any in-flight merges from the pre-restore state: a
         # stale job would release blocks and log manifest events
         # against the RESTORED free set/manifest (double-free).  Its

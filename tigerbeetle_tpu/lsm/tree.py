@@ -347,11 +347,23 @@ class Tree:
         n = len(keys)
         n_blocks = (n + per_block - 1) // per_block
         reservation = fs.reserve(n_blocks)
-        for at in range(0, n, per_block):
+        # One native pass for the whole run, the interpreter lock
+        # released (the beat's worker shares it with the commit loop);
+        # _block_payload defines the bytes and is the fallback.
+        from tigerbeetle_tpu.runtime import fastpath
+
+        payloads = fastpath.encode_run(
+            keys, flags, vals, self.value_size, per_block,
+            self.sparse_values,
+        )
+        for i, at in enumerate(range(0, n, per_block)):
             k = keys[at : at + per_block]
-            f = flags[at : at + per_block]
-            v = vals[at : at + per_block]
-            payload = self._block_payload(k, f, v)
+            if payloads is not None:
+                payload = payloads[i]
+            else:
+                payload = self._block_payload(
+                    k, flags[at : at + per_block], vals[at : at + per_block]
+                )
             address = fs.acquire(reservation)
             self.grid.write_block(address, payload)
             blocks.append(

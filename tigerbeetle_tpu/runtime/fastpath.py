@@ -66,26 +66,54 @@ def kway_merge(streams, value_size: int):
     keys_c = [np.ascontiguousarray(s[0]) for s in streams]
     flags_c = [np.ascontiguousarray(s[1]) for s in streams]
     vals_c = [np.ascontiguousarray(s[2]) for s in streams]
-    key_ptrs = (ctypes.POINTER(ctypes.c_uint8) * k)(
-        *[a.ctypes.data_as(_U8P) for a in keys_c]
-    )
-    flag_ptrs = (ctypes.POINTER(ctypes.c_uint8) * k)(
-        *[a.ctypes.data_as(_U8P) for a in flags_c]
-    )
-    val_ptrs = (ctypes.POINTER(ctypes.c_uint8) * k)(
-        *[a.ctypes.data_as(_U8P) for a in vals_c]
-    )
+    # Plain addresses: a compaction beat makes eight of these calls
+    # with three arrays a stream, and a `data_as` cast apiece cost as
+    # much as the merges themselves.
+    ptrs = ctypes.c_void_p * k
+    key_ptrs = ptrs(*[a.ctypes.data for a in keys_c])
+    flag_ptrs = ptrs(*[a.ctypes.data for a in flags_c])
+    val_ptrs = ptrs(*[a.ctypes.data for a in vals_c])
     lens = (ctypes.c_int64 * k)(*[len(s[0]) for s in streams])
     out_keys = np.empty(total, dtype="V16")
     out_flags = np.empty(total, np.uint8)
     out_vals = np.empty((total, value_size), np.uint8)
     n = lib.tb_lsm_kway_merge(
         k, key_ptrs, flag_ptrs, val_ptrs, lens, value_size,
-        out_keys.ctypes.data_as(_U8P) if total else None,
-        out_flags.ctypes.data_as(_U8P) if total else None,
-        out_vals.ctypes.data_as(_U8P) if total else None,
+        out_keys.ctypes.data if total else None,
+        out_flags.ctypes.data if total else None,
+        out_vals.ctypes.data if total else None,
     )
     return out_keys[:n], out_flags[:n], out_vals[:n]
+
+
+def encode_run(keys, flags, vals, value_size: int, per_block: int,
+               sparse: bool):
+    """Every block payload of one run in one native pass, the
+    interpreter lock released (native/tb_lsm.inc tb_lsm_encode_run;
+    the layout is lsm/tree.py Tree._block_payload's).  Returns the
+    payloads as a list of bytes, or None when the native library is
+    unavailable."""
+    import numpy as np
+
+    lib = _load()
+    if lib is None or not hasattr(lib, "tb_lsm_encode_run"):
+        return None
+    n = len(keys)
+    keys = np.ascontiguousarray(keys)
+    flags = np.ascontiguousarray(flags, np.uint8)
+    vals = np.ascontiguousarray(vals)
+    n_blocks = (n + per_block - 1) // per_block
+    out = np.empty(n_blocks * 4 + n * (16 + 1 + 4 + value_size), np.uint8)
+    offsets = np.empty(n_blocks + 1, np.int64)
+    lib.tb_lsm_encode_run(
+        keys.ctypes.data_as(_U8P), flags.ctypes.data_as(_U8P),
+        vals.ctypes.data_as(_U8P), n, value_size, per_block,
+        1 if sparse else 0, out.ctypes.data_as(_U8P),
+        offsets.ctypes.data_as(_I64P),
+    )
+    return [
+        out[offsets[b] : offsets[b + 1]].tobytes() for b in range(n_blocks)
+    ]
 
 
 def _load():
@@ -168,10 +196,16 @@ def _load():
         lib.tb_lsm_kway_merge.restype = ctypes.c_int64
         lib.tb_lsm_kway_merge.argtypes = [
             ctypes.c_int32,
-            ctypes.POINTER(_U8P), ctypes.POINTER(_U8P),
-            ctypes.POINTER(_U8P), _I64P, ctypes.c_int32,
-            _U8P, _U8P, _U8P,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.POINTER(ctypes.c_int64), ctypes.c_int32,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ]
+        if hasattr(lib, "tb_lsm_encode_run"):  # absent from a stale .so
+            lib.tb_lsm_encode_run.restype = ctypes.c_int64
+            lib.tb_lsm_encode_run.argtypes = [
+                _U8P, _U8P, _U8P, ctypes.c_int64, ctypes.c_int32,
+                ctypes.c_int64, ctypes.c_int32, _U8P, _I64P,
+            ]
         lib.tb_fp_decode_store.argtypes = [
             _U8P, ctypes.c_uint32, ctypes.c_uint64,
             _U64P, _U64P, _U64P, _U64P, _U64P, _U64P,

@@ -297,6 +297,8 @@ class ReplicaServer:
         sm_metrics = getattr(self.replica.sm, "metrics", None)
         if sm_metrics is not None:
             self.registry.attach("sm", sm_metrics)
+        if self.replica.forest is not None:
+            self.registry.attach("lsm", self.replica.forest.metrics)
         storage = self.storage
         self.registry.gauge_fn("replica", lambda: replica_index)
         self.registry.gauge_fn(

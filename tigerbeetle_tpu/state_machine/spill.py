@@ -24,6 +24,12 @@ src/lsm/groove.zig):
 
 Spilled objects are immutable; `gather` serves reads for exists-ladder
 joins, lookup_transfers, and query materialization.
+
+`TransferSpill.spill` is the one method that runs on the forest's beat
+worker (lsm/beats.py), in commit order.  Every other method here is
+the loop's and joins the worker first (`barrier`): rows the loop has
+handed over are below `base` for it and must be in the trees before
+it reads or rewrites them.
 """
 
 from __future__ import annotations
@@ -63,6 +69,10 @@ _WIRE_FIELDS = (
 )
 
 
+def _no_barrier() -> None:
+    """A groove nobody else works on (standalone groove tests)."""
+
+
 def _row_keys(rows: np.ndarray) -> np.ndarray:
     return pack_u128(
         np.asarray(rows, np.uint64), np.zeros(len(rows), np.uint64)
@@ -73,8 +83,9 @@ class TransferSpill:
     """Spilled (immutable) transfer rows in a groove; `base` rows
     [0, base) live here, the store's RAM tail holds [base, count)."""
 
-    def __init__(self, groove, attrs_fn=None) -> None:
+    def __init__(self, groove, attrs_fn=None, barrier=_no_barrier) -> None:
         self.groove = groove
+        self.barrier = barrier
         self.base = 0
         # Account attrs accessor for id reconstruction at gather:
         # dr/cr ACCOUNT IDS are derivable from the stored slots (slots
@@ -145,6 +156,7 @@ class TransferSpill:
 
     def _lookup_raw(self, rows: np.ndarray) -> np.ndarray:
         """Raw on-disk objects (ids NOT reconstructed) for rows < base."""
+        self.barrier()
         found, vals = self.groove.object_tree.lookup_batch(_row_keys(rows))
         assert found.all(), "spilled row missing from object tree"
         return np.ascontiguousarray(vals)
@@ -174,10 +186,9 @@ class TransferSpill:
         new status (LSM overwrite; newest version wins on read).  The
         only mutable byte of a spilled object — everything else is
         immutable after spill."""
-        obj = self._lookup_raw(rows)
+        obj = self._lookup_raw(rows)  # joins the worker
         obj[:, 136] = np.asarray(statuses, np.uint8)
         self.groove.object_tree.put_batch(_row_keys(rows), obj)
-
 
     def iter_objects(self, batch: int = 8192):
         """Yield (rows, objects) over all spilled rows ascending —
@@ -185,6 +196,7 @@ class TransferSpill:
         read only the transfer id (bytes 0..16), so the account-id
         reconstruction is skipped (it would be pure per-row waste on
         every crash recovery / state sync)."""
+        self.barrier()
         at = 0
         while at < self.base:
             n = min(batch, self.base - at)
@@ -221,14 +233,16 @@ def unpack_objects(obj: np.ndarray) -> dict:
 class HistorySpill:
     """Spilled historical-balance rows keyed by transfer timestamp."""
 
-    def __init__(self, groove) -> None:
+    def __init__(self, groove, barrier=_no_barrier) -> None:
         self.groove = groove
+        self.barrier = barrier
         self.base = 0  # history rows [0, base) spilled
 
     def spill(self, cols: dict) -> None:
         n = len(cols["timestamp"])
         if n == 0:
             return
+        self.barrier()
         obj = np.zeros((n, HISTORY_OBJECT_SIZE), np.uint8)
         obj[:, 0:8] = cols["dr_id_lo"].view(np.uint8).reshape(n, 8)
         obj[:, 8:16] = cols["dr_id_hi"].view(np.uint8).reshape(n, 8)
@@ -247,6 +261,7 @@ class HistorySpill:
         self.base += n
 
     def gather_by_ts(self, ts: np.ndarray) -> tuple[np.ndarray, dict]:
+        self.barrier()
         found, obj = self.groove.object_tree.lookup_batch(
             pack_u128(np.asarray(ts, np.uint64), np.zeros(len(ts), np.uint64))
         )

@@ -847,37 +847,49 @@ class TpuStateMachine:
             index_fields=[],
         )
         self._store.spill = spill_mod.TransferSpill(
-            transfers, attrs_fn=lambda: self._attrs
+            transfers, attrs_fn=lambda: self._attrs, barrier=forest.barrier
         )
-        self._hspill = spill_mod.HistorySpill(history)
+        self._hspill = spill_mod.HistorySpill(history, barrier=forest.barrier)
 
     def spill_beat(
         self, max_rows: int = 8192, keep_min: int | None = None
-    ) -> int:
-        """Paced spill: move at most `max_rows` of the OLDEST RAM-tail
-        rows into the LSM tier, keeping the most recent `keep_min` hot
-        in RAM.  Called once per commit by the replica, so the spill
-        cost (and the compaction debt it creates) amortizes across the
-        interval instead of landing inside the checkpoint
+    ) -> tuple | None:
+        """Paced spill, the loop's half: take at most `max_rows` of the
+        OLDEST RAM-tail rows, keeping the most recent `keep_min` hot in
+        RAM, and return them as the arguments of `spill_rows` (None:
+        nothing to spill).  Called once per commit by the replica, so
+        the spill cost (and the compaction debt it creates) amortizes
+        across the interval instead of landing inside the checkpoint
         (reference: src/lsm/compaction.zig — data enters the LSM per
         beat, not per checkpoint).  Deterministic: state-dependent
-        only."""
+        only.
+
+        The rows leave the tail here and now, COPIED: `drop_prefix`
+        moves memory in place and later commits append, while
+        `spill_rows` runs behind the commit on the forest's beat
+        worker (lsm/beats.py).  From here on they are below
+        `_store.base`, and a read of them joins the worker first."""
         if self._forest is None:
-            return 0
+            return None
         if keep_min is None:
             keep_min = max(self.config.spill_keep_rows, 16_384)
         st = self._store
         if st.tail_count() <= keep_min:
-            return 0
+            return None
         take = min(max_rows, st.tail_count() - keep_min)
         rows = np.arange(st.base, st.base + take, dtype=np.int64)
-        cols = {name: st.col(name)[:take] for name in _STORE_FIELDS}
-        st.spill.spill(rows, cols, self._attrs)
+        cols = {name: st.col(name)[:take].copy() for name in _STORE_FIELDS}
         st.drop_prefix(take)
         # History spills at checkpoint only (checkpoint_spill): its
         # rows are append-only and bounded per interval, and a per-beat
         # prefix rebuild would cost more copying than it saves.
-        return take
+        return rows, cols
+
+    def spill_rows(self, rows: np.ndarray, cols: dict) -> None:
+        """The beat's half of `spill_beat`: objects, index entries and
+        seals for the rows it took (on the beat worker where there is
+        one)."""
+        self._store.spill.spill(rows, cols, self._attrs)
 
     def checkpoint_spill(self) -> None:
         """Move the whole RAM tail into the LSM tier — including live
@@ -889,6 +901,9 @@ class TpuStateMachine:
         (reference: src/vsr/replica.zig:3886-4039 checkpoint_data)."""
         if self._forest is None:
             return
+        # The beats handed over come first: the freeze sees a drained
+        # forest, so blobs stay functions of the committed state.
+        self._forest.barrier()
         st = self._store
         # Retain the hot tail across checkpoints when configured: the
         # snapshot blob carries it, so checkpoint cost is O(one beat's
@@ -3934,6 +3949,7 @@ class TpuStateMachine:
         if st.base:
             from tigerbeetle_tpu.lsm.scan_builder import ScanBuilder
 
+            self._forest.barrier()  # the index trees are the worker's
             sb = ScanBuilder(st.spill.groove)
             scans = []
             if fflags & AccountFilterFlags.debits:
@@ -4083,6 +4099,9 @@ def _tpu_restore(self, data: bytes) -> None:
     from tigerbeetle_tpu.utils import snapshot as snapcodec
 
     state = snapcodec.decode_tree(data)
+    if self._forest is not None:
+        # Beats of the state this replaces still name its stores.
+        self._forest.barrier()
     self.commit_timestamp = state["commit_timestamp"]
     self.pulse_next_timestamp = state["pulse_next_timestamp"]
     self._exp_dead = state["exp_dead"]
@@ -4110,11 +4129,13 @@ def _tpu_restore(self, data: bytes) -> None:
         self._store.spill = spill_mod.TransferSpill(
             self._forest.grooves["transfers"],
             attrs_fn=lambda: self._attrs,
+            barrier=self._forest.barrier,
         )
         self._store.spill.base = base
         self._store.base = base
         self._hspill = spill_mod.HistorySpill(
-            self._forest.grooves["account_history"]
+            self._forest.grooves["account_history"],
+            barrier=self._forest.barrier,
         )
         self._hspill.base = state["history_base"]
     else:

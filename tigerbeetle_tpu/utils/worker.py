@@ -21,6 +21,9 @@ class _Job:
         self._done = threading.Event()
         self._exc: BaseException | None = None
 
+    def done(self) -> bool:
+        return self._done.is_set()
+
     def result(self) -> None:
         self._done.wait()
         if self._exc is not None:

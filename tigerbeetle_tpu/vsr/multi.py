@@ -569,7 +569,13 @@ class VsrReplica(Replica):
         if self.status == "normal" and self._ticks % SCRUB_INTERVAL_TICKS == 0:
             self._wal_scrub_tick()
         if self.scrubber is not None and self.status == "normal":
-            if self._ticks % SCRUB_INTERVAL_TICKS == 0:
+            # The grid is the beat worker's while a beat is in flight
+            # (lsm/beats.py): a probe waits for a tick that finds it
+            # idle (the tour is paced by the probes made, and a block
+            # acquired and not yet written would read as a fault).
+            if self._ticks % SCRUB_INTERVAL_TICKS == 0 and (
+                self.forest.beats.idle()
+            ):
                 self._blocks_missing.update(self.scrubber.tick())
             if self._blocks_missing and self.replica_count > 1 and (
                 self._ticks - self._block_repair_last >= REPAIR_RETRY_TICKS
@@ -2862,6 +2868,7 @@ class VsrReplica(Replica):
         from tigerbeetle_tpu.utils import snapshot as snapcodec
 
         grid = self.forest.grid
+        self.forest.barrier()
         grid.flush_writes()  # queued async block writes must be on disk
         live = (np.flatnonzero(~grid.free_set.free) + 1).astype(np.uint64)
         raw = bytearray()
@@ -2885,9 +2892,10 @@ class VsrReplica(Replica):
 
         state = snapcodec.decode(payload)
         grid = self.forest.grid
-        # Drain OUR stale queued writes first — a pre-sync write
-        # landing after the install would silently overwrite a shipped
-        # block with old-lineage (checksum-valid) content.
+        # Drain OUR stale beats and queued writes first — a pre-sync
+        # write landing after the install would silently overwrite a
+        # shipped block with old-lineage (checksum-valid) content.
+        self.forest.barrier()
         grid.flush_writes()
         addrs = state["addrs"]
         blocks = state["blocks"]
@@ -2992,6 +3000,7 @@ class VsrReplica(Replica):
         # flagged no longer need repair (a peer that already
         # checkpointed holds them free and would silently drop the
         # request; same invariant as the scrubber's skip).
+        self.forest.barrier()
         fs = self.forest.grid.free_set
         self._blocks_missing = {
             a for a in self._blocks_missing
@@ -3026,6 +3035,7 @@ class VsrReplica(Replica):
             return
         from tigerbeetle_tpu.vsr.grid import block_frame_valid
 
+        self.forest.barrier()
         grid = self.forest.grid
         # Serve at most the sender's cap regardless of what the body
         # claims — one message must not trigger unbounded disk reads.
@@ -3068,6 +3078,7 @@ class VsrReplica(Replica):
         want = int(bh["checksum_lo"]) | (int(bh["checksum_hi"]) << 64)
         if wire.checksum(payload) != want:
             return
+        self.forest.barrier()
         grid.flush_writes()  # stale queued write must not overwrite us
         self.storage.write(grid._offset(addr), body)
         grid._cache.remove(addr)

@@ -240,6 +240,13 @@ class Replica:
                 storage,
                 base_offset=storage.layout.forest_offset,
                 block_count=storage.layout.forest_block_count(),
+                # The beat leaves the commit for a worker in a CLUSTER's
+                # replica: its loop waits for its peers (45% of the time
+                # at three replicas) and the beat runs in that wait.  A
+                # lone replica's loop at saturation never waits; beat
+                # and loop then only take turns at the interpreter lock
+                # and the hand-over costs 5% (PERF.md section 6, PR 30).
+                beat_worker=replica_count > 1,
             )
             state_machine.attach_forest(self.forest)
             # The free set is replaced at every restore: read through
@@ -523,6 +530,8 @@ class Replica:
         lifecycle)."""
         self.tracer = tracer
         self.journal.tracer = tracer
+        if self.forest is not None:
+            self.forest.beats.tracer = tracer
         if hasattr(self.sm, "set_tracer"):
             self.sm.set_tracer(tracer)
 
@@ -815,7 +824,12 @@ class Replica:
         src/lsm/compaction.zig beats): spill a bounded chunk of frozen
         state into the LSM and advance a bounded slice of merge debt,
         so checkpoints only settle a small residue instead of stalling
-        on a whole interval's worth."""
+        on a whole interval's worth.
+
+        The commit only hands the beat over (lsm/beats.py): the stage
+        measures the copy of the rows, the submit and any wait for the
+        bound; the work is `lsm.beat.work` on the worker.  On
+        MemoryStorage the hand-over runs the beat in place."""
         if self.forest is None:
             return
         with self.tracer.stage(self._st_beat):
@@ -828,25 +842,40 @@ class Replica:
         # flip lands (the previous superblock — still the durable
         # recovery root — may reference them), and beats stay a pure
         # function of commit count either way (cluster-deterministic).
-        spilled = 0
+        spill = None
         if hasattr(self.sm, "spill_beat"):
-            spilled = self.sm.spill_beat()
-        if spilled or self.forest.compaction_pending():
-            # Escalate the budget as the next checkpoint nears so
-            # in-flight merges land BEFORE the barrier instead of
-            # draining inside it as one latency spike (the p100 tail).
-            # The cadence is learned from the PREVIOUS interval
-            # (operators may checkpoint more often than
-            # vsr_checkpoint_interval — the durable benchmark does);
-            # op-count-driven, so replicas stay deterministic.
-            interval = min(
-                self.config.vsr_checkpoint_interval,
-                self._ckpt_interval_observed or (1 << 30),
-            )
-            left = self.checkpoint_op + interval - self.op
-            budget = 64 if left > 8 else 64 * (10 - max(left, 0))
-            with self.tracer.span("lsm_compact_beat", rows=spilled):
-                self.forest.compact_beat(budget)
+            spill = self.sm.spill_beat()
+        beats = self.forest.beats
+        if spill is None and beats.idle() and not (
+            self.forest.compaction_pending()
+        ):
+            # Nothing to spill, nothing pending: no call reaches the
+            # forest.  Decided here only while the worker is idle (the
+            # test reads forest state); behind a queued beat the
+            # worker decides, after it.
+            return
+        # Escalate the budget as the next checkpoint nears so
+        # in-flight merges land BEFORE the barrier instead of
+        # draining inside it as one latency spike (the p100 tail).
+        # The cadence is learned from the PREVIOUS interval
+        # (operators may checkpoint more often than
+        # vsr_checkpoint_interval — the durable benchmark does);
+        # op-count-driven, so replicas stay deterministic.
+        interval = min(
+            self.config.vsr_checkpoint_interval,
+            self._ckpt_interval_observed or (1 << 30),
+        )
+        left = self.checkpoint_op + interval - self.op
+        budget = 64 if left > 8 else 64 * (10 - max(left, 0))
+        beats.submit(self._beat_work, spill, budget)
+
+    def _beat_work(self, spill, budget: int) -> None:
+        """The beat itself, in commit order (the beat worker's thread
+        where the forest has one): today's sequence, call for call."""
+        if spill is not None:
+            self.sm.spill_rows(*spill)
+        if spill is not None or self.forest.compaction_pending():
+            self.forest.compact_beat(budget)
 
     # ------------------------------------------------------------------
     # Client replies (reference: src/vsr/client_replies.zig).
@@ -994,13 +1023,18 @@ class Replica:
 
     def close(self) -> None:
         """Join in-flight background work (async checkpoint flip, WAL
-        sync) and stop the workers.  Idempotent."""
-        self._ckpt_join()
-        self._join_wal_sync()
-        if self._ckpt_worker is not None:
-            self._ckpt_worker.close()
-        if self._wal_sync_worker is not None:
-            self._wal_sync_worker.close()
+        sync, LSM beats) and stop the workers.  Idempotent."""
+        try:
+            self._ckpt_join()
+            self._join_wal_sync()
+        finally:
+            if self._ckpt_worker is not None:
+                self._ckpt_worker.close()
+            if self._wal_sync_worker is not None:
+                self._wal_sync_worker.close()
+            if self.forest is not None:
+                # Last: a beat that failed raises here once more.
+                self.forest.close()
 
     def _checkpoint_freeze(self):
         """Foreground half: bring the LSM tier + snapshot blob to a
