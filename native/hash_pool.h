@@ -125,7 +125,7 @@ struct HashPool {
         }
     }
 
-    // Respawn to the configured lane count (rare: env/bench-driven).
+    // Respawn to the configured lane count (rare: TB_HASH_THREADS, or a test).
     // Runs WITH submit_mu held — workers never touch submit_mu, so
     // joining them here cannot deadlock, and releasing submit_mu
     // mid-resize is exactly what must never happen: two submitters

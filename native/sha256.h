@@ -110,7 +110,7 @@ struct Sha256 {
 // implementation (SHA-NI where the CPU has it), while the legacy
 // SHA256() entry goes through a compat bridge.  The dlopen fallback
 // chain is unchanged; sha256_engine() reports which tier actually
-// resolved so benches and the scalar-fallback warning can name it.
+// resolved so the scrape and the scalar-fallback warning can name it.
 typedef unsigned char* (*sha256_oneshot_fn)(const unsigned char*, size_t,
                                             unsigned char*);
 typedef int (*evp_digest_fn)(const void*, size_t, unsigned char*,
@@ -152,7 +152,7 @@ inline const Sha256Impl& sha256_impl() {
     return impl;
 }
 
-// Engine override for the --hash-only bench grid (0 = auto-resolve).
+// Engine override (0 = auto-resolve, what every caller passes).
 // Forcing a tier that did not resolve degrades to the next one down,
 // exactly as auto-resolution would.
 inline int& sha256_force() {

@@ -558,8 +558,9 @@ void tb_fp_finalize_headers(uint8_t* headers, uint32_t n,
 
 // threads: worker lanes beside the calling thread (0 = inline, the
 // 1-core default); clamped to [0, HASH_THREADS_MAX].  force_engine:
-// 0 = auto-resolve, else a Sha256Engine value for the --hash-only
-// bench grid (forcing an unresolved tier degrades down, same as auto).
+// 0 = auto-resolve (what runtime/fastpath.py passes), else a
+// Sha256Engine value (forcing an unresolved tier degrades down, same
+// as auto).
 void tb_hash_configure(int32_t threads, int32_t force_engine) {
     if (threads < 0) threads = 0;
     if (threads > tb::HASH_THREADS_MAX) threads = tb::HASH_THREADS_MAX;
@@ -569,7 +570,7 @@ void tb_hash_configure(int32_t threads, int32_t force_engine) {
 
 // Which SHA-256 tier actually resolved (Sha256Engine: 1 = EVP one-shot
 // / SHA-NI dispatch, 2 = legacy SHA256(), 3 = the 225 MB/s scalar
-// core).  The Python side names these in bench rows and raises the
+// core).  The Python side gauges it (hash.engine_code) and raises the
 // one-time scalar-fallback warning.
 int32_t tb_hash_engine(void) { return (int32_t)tb::sha256_engine(); }
 

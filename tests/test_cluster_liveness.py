@@ -1,6 +1,6 @@
 """Sustained-load cluster liveness (VERDICT r4 #6).
 
-The r4 graded bench died in exactly this regime: a 3-replica TCP
+The r4 graded run died in exactly this regime: a 3-replica TCP
 cluster under continuous client load crossing checkpoint boundaries,
 where one slow tail blew a request timeout.  This test pins the
 liveness properties that regime depends on:
@@ -14,8 +14,8 @@ liveness properties that regime depends on:
 Real TCP sockets and the real ReplicaServer event loop; TEST_MIN
 config (journal_slot_count=32 -> checkpoint every 24 ops,
 reference: src/constants.zig:55-81 arithmetic) so three checkpoint
-intervals fit a suite-friendly runtime.  The replicated bench config
-(bench.py run_replicated) drives the same server/client machinery as
+intervals fit a suite-friendly runtime.  The `bench3r-plain-c4` cell
+(`benchmarks/`) drives the same server/client machinery as
 subprocesses at production scale.
 """
 

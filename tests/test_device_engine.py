@@ -3,7 +3,7 @@
 The engine (state_machine/device_engine.py) computes create_transfers
 result codes ON the device via the semantic kernels and materializes
 replies from failure-sparse summaries.  These tests pin it to the CPU
-oracle across the bench workload shapes and adversarial cases:
+oracle across the five batch classes and adversarial cases:
 cross-batch hazards, fallback recovery, pulse interaction, and the
 checkpoint checksum tripwire.
 """
@@ -15,6 +15,7 @@ from tigerbeetle_tpu import types
 from tigerbeetle_tpu.state_machine.cpu import CpuStateMachine
 from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine
 from tigerbeetle_tpu.testing import harness as hz
+from tigerbeetle_tpu.testing import workloads
 from tigerbeetle_tpu.types import (
     AccountFlags,
     CreateTransferResult,
@@ -50,40 +51,29 @@ def transfers(rows):
     return hz.pack([hz.transfer(**r) for r in rows])
 
 
-def test_bench_config_differential():
-    """Scaled-down versions of every bench config, multi-fetch."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    os.environ["BENCH_BATCH"] = "400"
-    import importlib
-
-    import bench
-
-    importlib.reload(bench)
-    for name, gen in bench.CONFIGS.items():
-        setup, timed, sizing = gen(4000)
-        ops = setup + timed
-        sm_d = TpuStateMachine(
-            account_capacity=sizing[0], transfer_capacity=sizing[1],
-            engine="device",
-        )
-        h_d = hz.SingleNodeHarness(sm_d)
-        futs = [h_d.submit_async(op, body) for op, body in ops]
-        replies_d = [f.result() for f in futs]
-        sm_c = CpuStateMachine()
-        h_c = hz.SingleNodeHarness(sm_c)
-        for i, (op, body) in enumerate(ops):
-            assert replies_d[i] == h_c.submit(op, body), f"{name} op {i}"
-        acct_ids = bench.config_account_ids(name)
-        tids = np.arange(bench.TID0, bench.TID0 + 2000).astype(np.uint64)
-        assert bench.state_digest(h_d, acct_ids, tids) == bench.state_digest(
-            h_c, acct_ids, tids
-        ), name
-        assert sm_d._dev.stat_semantic_events > 0, name
-    os.environ.pop("BENCH_BATCH", None)
-    importlib.reload(bench)
+@pytest.mark.parametrize("name", list(workloads.CONFIGS))
+def test_config_differential(name):
+    """A scaled-down stream of each batch class, multi-fetch: every
+    reply and the final state digest against the oracle."""
+    batch = 400
+    setup, stream, sizing = workloads.CONFIGS[name](4000, batch)
+    ops = setup + stream
+    sm_d = TpuStateMachine(
+        account_capacity=sizing[0], transfer_capacity=sizing[1],
+        engine="device",
+    )
+    h_d = hz.SingleNodeHarness(sm_d)
+    futs = [h_d.submit_async(op, body) for op, body in ops]
+    replies_d = [f.result() for f in futs]
+    h_c = hz.SingleNodeHarness(CpuStateMachine())
+    for i, (op, body) in enumerate(ops):
+        assert replies_d[i] == h_c.submit(op, body), f"op {i}"
+    acct_ids = workloads.config_account_ids(name)
+    tids = np.arange(workloads.TID0, workloads.TID0 + 2000).astype(np.uint64)
+    assert workloads.state_digest(
+        h_d, acct_ids, tids, batch
+    ) == workloads.state_digest(h_c, acct_ids, tids, batch)
+    assert sm_d._dev.stat_semantic_events > 0
 
 
 def test_cross_batch_pending_reference_hazard():

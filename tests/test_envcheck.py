@@ -328,36 +328,6 @@ def test_tb_admit_queue_constraint_names_pipeline(monkeypatch):
     assert envcheck.admit_queue(8) == 1024  # default
 
 
-def test_open_loop_bench_envs_validated(monkeypatch):
-    monkeypatch.setenv("BENCH_OPEN_SECS", "fast")
-    with pytest.raises(envcheck.EnvVarError, match="BENCH_OPEN_SECS"):
-        envcheck.open_loop_secs()
-    monkeypatch.setenv("BENCH_OPEN_SECS", "0.01")
-    with pytest.raises(envcheck.EnvVarError, match="must be >= 0.1"):
-        envcheck.open_loop_secs()
-    monkeypatch.delenv("BENCH_OPEN_SECS")
-    assert envcheck.open_loop_secs() == 4.0
-
-    monkeypatch.setenv("BENCH_OPEN_BATCH", "9000")
-    with pytest.raises(envcheck.EnvVarError, match="must be <= 8190"):
-        envcheck.open_loop_batch()
-    monkeypatch.delenv("BENCH_OPEN_BATCH")
-    assert envcheck.open_loop_batch() == 256
-
-    monkeypatch.setenv("BENCH_OPEN_HOT_PCT", "150")
-    with pytest.raises(envcheck.EnvVarError, match="must be <= 100"):
-        envcheck.open_loop_hot_pct()
-    monkeypatch.setenv("BENCH_OPEN_HOT_PCT", "35")
-    assert envcheck.open_loop_hot_pct() == 35.0
-    monkeypatch.delenv("BENCH_OPEN_HOT_PCT")
-
-    monkeypatch.setenv("BENCH_OPEN_BURST", "0.5")
-    with pytest.raises(envcheck.EnvVarError, match="must be >= 1"):
-        envcheck.open_loop_burst()
-    monkeypatch.delenv("BENCH_OPEN_BURST")
-    assert envcheck.open_loop_burst() == 4.0
-
-
 def test_tenant_qos_envs_validated(monkeypatch):
     monkeypatch.setenv("TB_TENANT_QOS", "2")
     with pytest.raises(envcheck.EnvVarError, match="TB_TENANT_QOS"):
@@ -378,12 +348,6 @@ def test_tenant_qos_envs_validated(monkeypatch):
     assert envcheck.busy_backoff_ms() == 0.0  # legacy immediate retry
     monkeypatch.delenv("TB_BUSY_BACKOFF_MS")
     assert envcheck.busy_backoff_ms() == 20.0
-
-    monkeypatch.setenv("BENCH_QOS_SECS", "0.01")
-    with pytest.raises(envcheck.EnvVarError, match="BENCH_QOS_SECS"):
-        envcheck.qos_suite_secs()
-    monkeypatch.delenv("BENCH_QOS_SECS")
-    assert envcheck.qos_suite_secs() == 3.0
 
 
 def test_tenant_queue_constraint_names_global_bound(monkeypatch):
@@ -440,6 +404,48 @@ def test_no_tb_knob_bypasses_envcheck():
     )
 
 
+def test_every_envcheck_reader_has_a_caller_in_the_package():
+    """Audit: a public function of envcheck.py that nothing in the
+    package calls is a knob kept alive for a script outside it.  AST
+    walk over the package's sources (no import of the callers): a use
+    is ``<alias of the envcheck module>.<name>`` or ``from
+    tigerbeetle_tpu.envcheck import <name>``."""
+    import ast
+    import pathlib
+
+    own = pathlib.Path(envcheck.__file__)
+    # Not a reader: no variable behind it.  coord_timeout_s() calls it
+    # for its named constraint, and the test of that constraint.
+    exempt = {"view_change_budget_s"}
+    readers = {
+        node.name
+        for node in ast.parse(own.read_text()).body
+        if isinstance(node, ast.FunctionDef)
+        and not node.name.startswith("_")
+    } - exempt
+    used = set()
+    for path in own.parent.rglob("*.py"):
+        if path == own:
+            continue
+        aliases, attrs = set(), set()
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.ImportFrom):
+                if node.module == "tigerbeetle_tpu.envcheck":
+                    used.update(a.name for a in node.names)
+                elif node.module == "tigerbeetle_tpu":
+                    aliases.update(
+                        a.asname or a.name
+                        for a in node.names
+                        if a.name == "envcheck"
+                    )
+            elif isinstance(node, ast.Attribute) and isinstance(
+                node.value, ast.Name
+            ):
+                attrs.add((node.value.id, node.attr))
+        used.update(attr for name, attr in attrs if name in aliases)
+    assert sorted(readers - used) == []
+
+
 def test_tb_metrics_disables_histograms(monkeypatch):
     from tigerbeetle_tpu import obs
 
@@ -455,20 +461,6 @@ def test_tb_metrics_disables_histograms(monkeypatch):
 
 
 def test_sharded_router_envs_validated(monkeypatch):
-    monkeypatch.setenv("TB_SHARDS", "many")
-    with pytest.raises(envcheck.EnvVarError, match="TB_SHARDS"):
-        envcheck.shards()
-    monkeypatch.setenv("TB_SHARDS", "0")
-    with pytest.raises(envcheck.EnvVarError, match="must be >= 1"):
-        envcheck.shards()
-    monkeypatch.setenv("TB_SHARDS", "65")
-    with pytest.raises(envcheck.EnvVarError, match="must be <= 64"):
-        envcheck.shards()
-    monkeypatch.setenv("TB_SHARDS", "4")
-    assert envcheck.shards() == 4
-    monkeypatch.delenv("TB_SHARDS")
-    assert envcheck.shards() == 1  # default: unsharded
-
     monkeypatch.setenv("TB_ROUTER_QUEUE", "0")
     with pytest.raises(envcheck.EnvVarError, match="must be >= 1"):
         envcheck.router_queue()
@@ -502,19 +494,6 @@ def test_coord_timeout_names_view_change_constraint(monkeypatch):
     assert envcheck.coord_timeout_s() == 6
     monkeypatch.delenv("TB_COORD_TIMEOUT_S")
     assert envcheck.coord_timeout_s() == 30  # default
-
-
-def test_open_loop_read_pct_validated(monkeypatch):
-    monkeypatch.setenv("BENCH_OPEN_READ_PCT", "110")
-    with pytest.raises(envcheck.EnvVarError, match="must be <= 100"):
-        envcheck.open_loop_read_pct()
-    monkeypatch.setenv("BENCH_OPEN_READ_PCT", "-1")
-    with pytest.raises(envcheck.EnvVarError, match="must be >= 0"):
-        envcheck.open_loop_read_pct()
-    monkeypatch.setenv("BENCH_OPEN_READ_PCT", "35")
-    assert envcheck.open_loop_read_pct() == 35.0
-    monkeypatch.delenv("BENCH_OPEN_READ_PCT")
-    assert envcheck.open_loop_read_pct() == 20.0  # default
 
 
 def test_tb_state_commit_validated(monkeypatch):

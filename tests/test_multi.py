@@ -149,8 +149,8 @@ def test_group_commit_never_acks_before_covering_sync(gc_cluster):
 def test_group_commit_batches_fsyncs_under_pipelined_load(gc_cluster):
     """Concurrent sessions fill the prepare pipeline; a backup's one
     flush per step then covers several prepares — strictly fewer
-    fsyncs than prepares (the replicated bench grades the same ratio
-    from real server logs)."""
+    fsyncs than prepares (`journal_fsyncs_per_req` reads the same
+    ratio from a served cluster's scrape)."""
     c = gc_cluster
     cl = _register(c, 100)
     _setup_accounts(c, cl)

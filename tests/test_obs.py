@@ -188,7 +188,7 @@ def test_stat_property_compat_reads_and_resets():
     assert sm.stat_device_events == 0
     sm.stat_device_events += 5          # property routes to the handle
     assert sm.metrics.snapshot()["device_events"] == 5
-    sm.stat_device_events = 0           # bench-style reset
+    sm.stat_device_events = 0           # a reset through the setter
     assert sm.stat_device_events == 0
     # Version moved for every write: idle-dedup can't miss it.
     assert sm.metrics.version() >= 2
@@ -617,7 +617,7 @@ def test_spec_counters_in_registry_and_metrics_off_noop(monkeypatch):
     TB_METRICS=1 with the validation histogram populated; under
     TB_METRICS=0 the histogram is the shared no-op (no clock-derived
     samples in the snapshot) while the routing counters stay live —
-    bench accounting depends on them."""
+    routing and the scrape depend on them."""
     monkeypatch.setenv("TB_METRICS", "1")
     sm = _drive_speculative_batches(monkeypatch)
     snap = sm.metrics.snapshot()

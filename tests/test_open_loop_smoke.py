@@ -10,8 +10,7 @@ view, and SIGTERM produces a parseable flight-recorder dump.
 
 Subprocess servers (not threads): the SIGTERM flight dump needs a real
 main-thread signal handler.  CpuStateMachine + TEST_MIN keeps it
-seconds, inside the tier-1 budget; heavier sweeps live in bench.py
---open-loop."""
+seconds, inside the tier-1 budget."""
 
 import json
 import os

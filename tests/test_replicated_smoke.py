@@ -1,8 +1,8 @@
 """Tier-1 replicated smoke: a real 2-replica TCP cluster (in-process
 ReplicaServers over the native bus) driven by BENCH_REPL_SESSIONS
 concurrent client sessions — the group-commit spine exercised end to
-end in pytest, so a regression surfaces here and not only in bench
-runs.  Small stream, TEST_MIN config, CPU state machine: seconds, not
+end in pytest, so a regression surfaces here and not only in a
+cell's run.  Small stream, TEST_MIN config, CPU state machine: seconds, not
 minutes."""
 
 import os
@@ -116,8 +116,7 @@ def test_two_replica_native_drain_smoke(tmp_path, monkeypatch):
     # The ON arm crossed into C per batch seam — on BOTH roles (the
     # primary's plan+ack drains, the backup's accept drains) — and the
     # OFF arm never did.  Crossings are per RUN, so they stay bounded
-    # by the per-item work they replaced (native_calls <= items; the
-    # bench harvests the amortization ratio under real concurrency).
+    # by the per-item work they replaced (native_calls <= items).
     for s in on_snaps:
         assert s["vsr.drain.native_calls"] > 0
     primary_on, backup_on = on_snaps[0], on_snaps[1]

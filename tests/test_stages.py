@@ -400,7 +400,7 @@ def test_the_beat_stage_is_the_hand_over_and_the_work_is_the_workers(tmp_path):
     `commit_attributed_pct` sums them) and measures the hand-over;
     `lsm.beat.work` runs on the worker's thread, annotated there, on
     its own row of the trace, and lies outside the commit span: a beat
-    held 0.3 s on the worker lengthens no commit."""
+    held on the worker does not hold the commit that handed it over."""
     import numpy as np
 
     from tigerbeetle_tpu import constants as cfg
@@ -448,13 +448,13 @@ def test_the_beat_stage_is_the_hand_over_and_the_work_is_the_workers(tmp_path):
         commit(op)
     r.forest.barrier()
     # One more, its beat held on the worker while the commit returns.
-    work, release = r._beat_work, threading.Event()
-    r._beat_work = lambda *a: (release.wait(30), work(*a))
-    threading.Timer(0.3, release.set).start()
+    work, entered, release = r._beat_work, threading.Event(), threading.Event()
+    r._beat_work = lambda *a: (entered.set(), release.wait(30), work(*a))
     waits = r.forest.metrics.snapshot()["beat.bound_waits"]
-    span_before = r.metrics.snapshot()["commit_us.sum"]
     commit(5)
-    held_commit_us = r.metrics.snapshot()["commit_us.sum"] - span_before
+    # The commit is back and its beat is still held: by order, no clock.
+    assert entered.wait(30) and not release.is_set()
+    release.set()
     r.forest.barrier()
     vsr, lsm = r.metrics.snapshot(), r.forest.metrics.snapshot()
     r.close()
@@ -464,7 +464,6 @@ def test_the_beat_stage_is_the_hand_over_and_the_work_is_the_workers(tmp_path):
     assert vsr["commit.beat_us.count"] == commits >= 7
     beats = lsm["beat.work_us.count"]
     assert beats == 4                   # commits 2 to 5: the tail past 16,384
-    assert lsm["beat.work_us.max"] >= 300_000 > held_commit_us
     assert lsm["beat.bound_waits"] == waits and lsm["barrier.joins"] >= 1
     # The commit's leaves (host engine: prefetch, reply, beat) tile it.
     inside = sum(vsr[k + "_us.sum"] for k in (

@@ -329,7 +329,7 @@ def test_import_graph_sim_reachable_set():
         "tigerbeetle_tpu.utils.worker",
         # r19: SimFollower drives the follower core inside the sim,
         # so the module is clock-free (FollowerServer's wall clock is
-        # injected at the process edge, cli.py/bench.py).
+        # injected at the process edge, cli.py).
         "tigerbeetle_tpu.runtime.follower",
         "tigerbeetle_tpu.vsr.aof",
     }

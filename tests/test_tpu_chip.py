@@ -156,10 +156,10 @@ print("FLUSH_READBACK_OK")
 
 
 def test_production_b8192_kernels_on_chip(chip):
-    """The PRODUCTION event-bucket geometry (B=8192, the bench.py
+    """The PRODUCTION event-bucket geometry (B=8192, a full request's
     shape) compiles and runs on the real chip with full-batch oracle
-    parity — bench must not be the first place this geometry compiles
-    (VERDICT r4 #7).  Covers orderfree (all-success 8190-event batch),
+    parity — a served run must not be the first place this geometry
+    compiles (VERDICT r4 #7).  Covers orderfree (all-success 8190-event batch),
     linked chains, and a two-phase batch at the same bucket size."""
     code = """
 import numpy as np
@@ -179,7 +179,7 @@ rng = np.random.default_rng(7)
 ops = [(Operation.create_accounts,
         hz.pack([hz.account(i) for i in range(1, 1001)]))]
 
-# Full production batch: 8190 order-free transfers (the bench shape).
+# Full production batch: 8190 order-free transfers.
 tid = 1000
 rows = []
 for i in range(8190):

@@ -524,8 +524,8 @@ class WorkerSharedRule(Rule):
 
 class NoPrintRule(Rule):
     id = "no-print"
-    doc = ("core modules must not print; stdout belongs to CLIs and "
-           "benches (file-level allows with reasons), everything else "
+    doc = ("core modules must not print; stdout belongs to the CLI's "
+           "commands (file-level allows with reasons), everything else "
            "talks through logging or the tracer")
 
     def check(self, sf: SourceFile, ctx: Context):

@@ -160,8 +160,8 @@ class OpenLoopSession:
     + sampled flag, vsr/wire.py) and returns immediately; `poll()`
     drains completions — `reply` (committed) or `busy` (typed
     admission shed, Command.client_busy) — each with client-measured
-    latency.  bench.py's --open-loop mode and the overload smoke test
-    drive it.
+    latency.  The overload, follower and sharded smoke tests drive
+    it.
     """
 
     BUSY_RETRIES_MAX = 6  # then the busy surfaces as a completion
@@ -186,12 +186,10 @@ class OpenLoopSession:
         self.inflight: dict[int, tuple[int, int, bytes]] = {}
         # (request_number, kind "reply"|"busy", latency_s, reply_body,
         #  operation, tier) — the operation rides along so a mixed-op
-        # driver (the read-heavy open-loop bench) can grade reads and
-        # writes separately; `tier` records WHO served the completion
-        # (round 19): ("primary"|"follower", server id, claimed
+        # driver can tell reads from writes; `tier` records WHO served
+        # the completion (round 19): ("primary"|"follower", server id, claimed
         # commit_min, attested root bytes) — zero/empty for primary
-        # replies, so the bench's write-p99-flat grade can attribute
-        # interference and a client can verify follower attestations.
+        # replies, so a client can verify follower attestations.
         self.completed: list[tuple[int, str, float, bytes, int, tuple]] = []
         self.busy_replies = 0
         # Busy backoff (TB_BUSY_BACKOFF_MS; round 16): a shed request

@@ -186,8 +186,8 @@ def hash_threads() -> int:
 
 def cpu_affinity() -> str:
     """TB_CPU_AFFINITY: replica/router/follower core pinning for the
-    multi-process spawn paths (bench subprocess spawns and the
-    `tigerbeetle` server/router/follower CLIs):
+    multi-process spawn paths (the `tigerbeetle`
+    server/router/follower CLIs):
 
     - "none" (default): inherit the parent's affinity mask unchanged.
     - "auto": pin process slot i to core (i mod cpu_count) — spreads a
@@ -232,8 +232,8 @@ def drain_batch_max() -> int:
 
 def metrics_enabled() -> int:
     """TB_METRICS: 1 (default) records latency histograms in the obs
-    registry; 0 skips the clock reads (counters stay live — logic and
-    bench accounting depend on them)."""
+    registry; 0 skips the clock reads (counters stay live — logic
+    depends on them)."""
     return env_int("TB_METRICS", 1, minimum=0, maximum=1)
 
 
@@ -275,47 +275,6 @@ def admit_queue(pipeline_depth: int) -> int:
     return value
 
 
-def open_loop_secs() -> float:
-    """BENCH_OPEN_SECS: seconds per open-loop bench phase."""
-    return env_float("BENCH_OPEN_SECS", 4.0, minimum=0.1)
-
-
-def open_loop_batch() -> int:
-    """BENCH_OPEN_BATCH: transfers per open-loop request (small
-    batches make queueing dynamics visible; the closed-loop bench's
-    8190-event batches would hide them)."""
-    return env_int("BENCH_OPEN_BATCH", 256, minimum=1, maximum=8190)
-
-
-def open_loop_hot_pct() -> float:
-    """BENCH_OPEN_HOT_PCT: percentage of open-loop transfers that hit
-    one of the few hot (celebrity) accounts — the multi-tenant
-    contention mix."""
-    raw = env_float("BENCH_OPEN_HOT_PCT", 20.0, minimum=0.0)
-    if raw > 100.0:
-        _fail("BENCH_OPEN_HOT_PCT", str(raw), "must be <= 100")
-    return raw
-
-
-def open_loop_burst() -> float:
-    """BENCH_OPEN_BURST: burstiness multiplier — arrivals are Poisson
-    at the phase rate, with periodic bursts at `burst`x the rate.
-    1.0 = pure Poisson."""
-    return env_float("BENCH_OPEN_BURST", 4.0, minimum=1.0)
-
-
-def open_loop_read_pct() -> float:
-    """BENCH_OPEN_READ_PCT: read requests (lookup_accounts /
-    get_account_transfers filter queries) added ON TOP of the transfer
-    stream, as a percentage of it — the read-heavy mix.  Additive so
-    the write arrival rate (and comparability with earlier open-loop
-    baselines) is unchanged."""
-    raw = env_float("BENCH_OPEN_READ_PCT", 20.0, minimum=0.0)
-    if raw > 100.0:
-        _fail("BENCH_OPEN_READ_PCT", str(raw), "must be <= 100")
-    return raw
-
-
 # ----------------------------------------------------------------------
 # Optimistic wave execution (state_machine/waves.py; round 18).
 
@@ -334,8 +293,8 @@ def waves_speculate() -> str:
     - "1": on — like auto, with the residue-cap gate still applied.
     - "force": forced-optimistic — route EVERY window batch (including
       shapes the semantic kernels could serve) through speculation and
-      attempt it regardless of the residue gate.  Differential-test /
-      bench routing: maximizes speculative-path coverage.
+      attempt it regardless of the residue gate.  Differential-test
+      routing: maximizes speculative-path coverage.
     """
     return env_choice(
         "TB_WAVES_SPECULATE", "auto", ("auto", "0", "1", "force")
@@ -404,8 +363,8 @@ def hot_capacity() -> int:
     admission/eviction rides the write-behind lane, and the 16-byte
     state root keeps covering the whole logical table as
     fold(hot_partial, cold_partial).  Values >= the logical capacity
-    degenerate to all-resident.  Read at engine CONSTRUCTION time
-    (per-arm env changes in one bench process work); forcing tiny
+    degenerate to all-resident.  Read at engine CONSTRUCTION time;
+    forcing tiny
     values is the differential-fuzz lever."""
     return env_int("TB_HOT_CAPACITY", 0, minimum=0, maximum=1 << 31)
 
@@ -484,12 +443,6 @@ def follower_ring() -> int:
                    maximum=1 << 20)
 
 
-def read_scale_secs() -> float:
-    """BENCH_READ_SCALE_SECS: seconds per read-scale bench arm (one
-    arm per follower count)."""
-    return env_float("BENCH_READ_SCALE_SECS", 3.0, minimum=0.1)
-
-
 def read_fallback_ms() -> int:
     """TB_READ_FALLBACK_MS: how long the router waits for a follower's
     read reply before re-driving the read through the primary path.
@@ -560,13 +513,6 @@ def tenant_weights() -> dict:
         _fail("TB_TENANT_WEIGHTS", raw, str(exc))
 
 
-def qos_suite_secs() -> float:
-    """BENCH_QOS_SECS: seconds per adversarial-QoS bench arm phase
-    (bench.py --qos-suite: noisy-neighbor / cross-shard-heavy /
-    pathological-contention)."""
-    return env_float("BENCH_QOS_SECS", 3.0, minimum=0.1)
-
-
 def busy_backoff_ms() -> float:
     """TB_BUSY_BACKOFF_MS: client-side base backoff after a typed
     client_busy — capped exponential (x2 per consecutive busy, 16x
@@ -578,12 +524,6 @@ def busy_backoff_ms() -> float:
 
 # ----------------------------------------------------------------------
 # Sharded multi-cluster (runtime/router.py).
-
-
-def shards() -> int:
-    """TB_SHARDS: number of account-range shards (independent
-    consensus groups) behind the router.  1 = unsharded."""
-    return env_int("TB_SHARDS", 1, minimum=1, maximum=64)
 
 
 def router_queue() -> int:

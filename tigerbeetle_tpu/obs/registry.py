@@ -33,7 +33,7 @@ class Counter:
     """Monotonic counter (floats allowed: wall-time accumulators).
 
     `inc` is the hot-path method; `set` exists for the compatibility
-    properties (benches reset forensics counters between timed arms).
+    properties (tests reset counters through them; ROADMAP D13).
     """
 
     __slots__ = ("name", "value", "_v")
@@ -178,15 +178,6 @@ def percentile_of_counts(counts: dict, q: float) -> float:
         if acc >= rank:
             return float(Histogram.upper_of(idx))
     raise AssertionError("bucket counts disagree with total")
-
-
-def counts_delta(after: dict, before: dict) -> dict:
-    """Bucket counts accumulated between two `dict(h.counts)` copies."""
-    return {
-        idx: n - before.get(idx, 0)
-        for idx, n in after.items()
-        if n - before.get(idx, 0) > 0
-    }
 
 
 class _Timer:
@@ -381,8 +372,9 @@ class Scope:
 def stat_property(key: str) -> property:
     """Compatibility shim for migrated `stat_*` attributes: reads and
     writes route to the registry handle in `self._stats[key]`, so
-    existing `sm.stat_x += n` sites (and bench resets) keep working
-    while the canonical value lives in the registry."""
+    existing `sm.stat_x += n` sites (and tests' reads and resets)
+    keep working while the canonical value lives in the registry
+    (ROADMAP D13)."""
 
     def fget(self):
         return self._stats[key].value

@@ -6,10 +6,9 @@ no session, no consensus (each replica reports its OWN counters, which
 is exactly what fsyncs-per-prepare accounting needs).  The reply is a
 `Command.reply` whose body is the JSON-encoded snapshot dict.
 
-bench.py's replicated config and the tier-1 TCP smoke test use this
-instead of regex-parsing TB_STATS log tails; the log-tail parser
-survives only as the counter-verified fallback for kill -9'd replicas
-(which can't answer a scrape but did leave their last line behind).
+`benchmarks/`, `chip_smoke.py` and the tier-1 TCP smoke tests read a
+server through this; a kill -9'd replica can't answer a scrape but
+did leave its last TB_STATS line behind.
 """
 # tbcheck: allow-file(determinism): scrape clients poll a live TCP
 # server with wall-clock deadlines; the sim never executes them.

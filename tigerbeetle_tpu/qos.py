@@ -8,8 +8,8 @@ client_busy).  This build keys admission, scheduling, and shedding one
 level up — per tenant — so one hot ledger cannot starve the rest.
 
 Three primitives, shared by the replica's request queue
-(vsr/multi.py), the router's admission + retry sweep
-(runtime/router.py), and the bench graders:
+(vsr/multi.py) and the router's admission + retry sweep
+(runtime/router.py):
 
 - `TokenBucket`: classic rate limiter, refilled from a monotonic
   clock the CALLER supplies (deterministic in simulators, wall-clock

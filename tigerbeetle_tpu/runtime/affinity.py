@@ -1,7 +1,7 @@
 """Replica core pinning (TB_CPU_AFFINITY; round 20).
 
-Multi-process configs (replicated bench, sharded clusters, the
-server/router/follower CLIs) used to leave every Python VSR loop on
+Multi-process deployments (a cluster's replicas, sharded clusters,
+the server/router/follower CLIs) used to leave every Python VSR loop on
 the scheduler's default mask — on a small box three replicas fight
 over the same cores and the consensus pipeline serializes.  This
 module turns the validated TB_CPU_AFFINITY knob (envcheck.py) into
@@ -12,8 +12,8 @@ replica index, the shard*replicas+replica index, or 0 for routers):
 - "auto"  -> slot i pins to core (i mod cpu_count).
 - "0,1,2" -> slot i pins to the (i mod len)'th listed core.
 
-``plan`` is pure (the bench calls it to RECORD ``pinned_cores`` per
-subprocess without being the subprocess); ``apply`` performs the
+``plan`` is pure (which core a slot gets, testable without pinning
+the test's own process); ``apply`` performs the
 pinning in the target process and degrades to None on platforms
 without sched_setaffinity rather than failing the spawn.
 """
@@ -44,7 +44,7 @@ def apply(slot: int = 0, spec: str | None = None) -> tuple[int, ...] | None:
     pinned core set, or None when pinning is off / unsupported / the
     planned core does not exist on this box (a 4-core list on a
     2-core container must not kill the replica — it just runs
-    unpinned and the bench's pinned_cores record says so)."""
+    unpinned and its start-up line says so)."""
     cores = plan(slot, spec)
     if cores is None:
         return None

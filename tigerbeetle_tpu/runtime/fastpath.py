@@ -1145,18 +1145,17 @@ HASH_ENGINE_NAMES = {1: "evp", 2: "sha256-legacy", 3: "scalar"}
 _scalar_warned = False
 
 
-def configure_hash(threads: int | None = None, force_engine: int = 0) -> bool:
+def configure_hash(threads: int | None = None) -> bool:
     """(Re)apply the hash-pool lane count (default: the validated
-    TB_HASH_THREADS) and optionally force a SHA-256 engine tier for
-    the --hash-only bench grid (0 = auto).  Returns False when the
-    library is absent or lacks the r23 symbols (inline hashlib/scalar
-    hashing everywhere — nothing to configure)."""
+    TB_HASH_THREADS); the SHA-256 engine tier auto-resolves.  Returns
+    False when the library is absent or lacks the r23 symbols (inline
+    hashlib/scalar hashing everywhere — nothing to configure)."""
     lib = _load()
     if lib is None or getattr(lib, "tb_hash_configure", None) is None:
         return False
     if threads is None:
         threads = envcheck.hash_threads()
-    lib.tb_hash_configure(threads, force_engine)
+    lib.tb_hash_configure(threads, 0)
     return True
 
 
@@ -1165,8 +1164,9 @@ def hash_engine_name() -> str:
     ("evp" = libcrypto EVP one-shot / SHA-NI, "sha256-legacy" =
     libcrypto's compat entry, "scalar" = the portable ~225 MB/s core),
     or "hashlib" when no native library serves the hot path (Python's
-    hashlib — itself OpenSSL-backed).  Recorded in bench rows so a
-    number can never silently come from the wrong engine."""
+    hashlib — itself OpenSSL-backed).  The server gauges it as
+    hash.engine_code so a number can never silently come from the
+    wrong engine."""
     lib = _load()
     if lib is None or getattr(lib, "tb_hash_engine", None) is None:
         return "hashlib"

@@ -43,7 +43,7 @@ the attestation rides every reply instead of being an internal check.
 Determinism: this module runs inside the seeded simulators
 (testing/cluster.py drives FollowerCore tick-by-tick), so it reads no
 wall clocks and draws no entropy — FollowerServer takes an injected
-`clock_ns` from its process entry point (cli.py / bench.py).
+`clock_ns` from its process entry point (cli.py).
 """
 
 from __future__ import annotations

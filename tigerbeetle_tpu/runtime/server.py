@@ -288,8 +288,8 @@ class ReplicaServer:
         # state machine's registries graft in under "vsr."/"sm.", the
         # storage's fsync/byte counters ride as pull gauges, and the
         # server's own drain-loop instruments live at the top.  ONE
-        # source of truth rendered three ways: TB_STATS lines
-        # (_print_stats), the `stats` wire scrape, bench JSON.
+        # source of truth rendered two ways: TB_STATS lines
+        # (_print_stats) and the `stats` wire scrape.
         from tigerbeetle_tpu import obs
 
         self.registry = obs.Registry()
@@ -349,7 +349,8 @@ class ReplicaServer:
         )
         self._drain_batch_max = envcheck.drain_batch_max()
         # decode µs per EVENT (128-byte wire records in the drain's
-        # bodies) — the honest amortized unit the bench grades.
+        # bodies) — the amortized unit `ingress_decode_ns_per_event`
+        # reads.
         self._h_decode_ev = self.registry.histogram(
             "server.decode_us_per_event"
         )
@@ -375,7 +376,7 @@ class ReplicaServer:
         )
         # Hash-once commit path (round 23): which SHA-256 engine serves
         # the hot path (scalar fallback warned once + gauged so no
-        # bench can mistake a 225 MB/s run for a SHA-NI run), plus the
+        # run at 225 MB/s passes for a SHA-NI run), plus the
         # process-global pool stats.  hash.lanes_busy counts jobs that
         # actually ran on worker lanes — 0 under TB_HASH_THREADS=0 by
         # definition.
@@ -547,8 +548,7 @@ class ReplicaServer:
 
     # TB_STATS line schema: legacy key -> registry snapshot key.  The
     # line is a RENDERING of the registry (one source of truth with
-    # the `stats` scrape); it survives kill -9 in the log tail, which
-    # is why bench keeps a log-tail parser as fallback.
+    # the `stats` scrape); it survives kill -9 in the log tail.
     STATS_LINE_KEYS = (
         ("fsyncs", "storage.fsyncs"),
         ("prepares", "vsr.prepares_written"),
@@ -817,7 +817,7 @@ class ReplicaServer:
         # per-message cost the columnar ingest path replaces; measured
         # here so the legacy arm reports its µs honestly, including
         # the SAME per-event amortized instrument the columnar drain
-        # feeds (the TB_FASTPATH_DECODE=0/1 bench arms compare it).
+        # feeds (what a TB_FASTPATH_DECODE=0/1 A/B compares).
         t0 = time.perf_counter_ns()
         header = wire.header_from_bytes(payload[:HEADER_SIZE])
         body = payload[HEADER_SIZE:]

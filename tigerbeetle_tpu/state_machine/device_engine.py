@@ -507,7 +507,7 @@ class DeviceEngine:
             # program), and bytes _fetch brought home.
             "stat_puts": _c("link.puts"),
             "stat_fetch_bytes": _c("link.fetch_bytes"),
-            # Degraded-mode lifecycle (bench engine_health reports).
+            # Degraded-mode lifecycle.
             "stat_demotions": _c("demotions"),
             "stat_repromotions": _c("repromotions"),
             "stat_probe_failures": _c("probe_failures"),
@@ -648,7 +648,7 @@ class DeviceEngine:
         # CPU-placed (capacity, 8) table handle.
         self._degraded_cache = None
     # Compatibility properties: every stat_* above reads/writes its
-    # registry handle (bench/experiment resets included).
+    # registry handle (ROADMAP D13).
     stat_retries = obs_stat_property("stat_retries")
     stat_link_errors = obs_stat_property("stat_link_errors")
     stat_semantic_events = obs_stat_property("stat_semantic_events")
@@ -731,8 +731,8 @@ class DeviceEngine:
         """Pay the one-time per-process costs OFF the hot path: XLA
         compiles each kernel on first call, and the first upload of
         a shape may set up its transfer.
-        Callers that know their workload (bench configs) name the
-        kinds; engine construction happens during untimed setup.
+        TB_DEV_PREWARM names the kinds; engine construction happens
+        before serving.
 
         The pseudo-kind "waves" warms the HOST-fallback wave executor
         (waves.py) against this engine's table geometry: a batch the

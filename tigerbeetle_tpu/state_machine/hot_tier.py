@@ -12,7 +12,7 @@ This module owns the host-side tier state shared by both engine modes:
 
 - ``HotTier``: the logical<->hot slot maps, LRU admission/eviction over
   a fixed hot-row budget, and the hit/miss/evict/prefetch counters the
-  obs layer and bench rows read.
+  obs layer reads.
 - The shared growth-policy helpers (``grow_zero_host`` /
   ``grow_zero_device``) behind the three previously near-identical
   ``grow()`` implementations (kernel_fast / mirror / device_engine) —
@@ -102,8 +102,8 @@ def from_env(logical_capacity: int) -> "HotTier | None":
     """Build the tier for a table of `logical_capacity` rows, or None
     when TB_HOT_CAPACITY leaves the table all-resident (0/unset, or a
     budget that already covers every row).  Read at CONSTRUCTION time
-    (the envcheck knob discipline), so one bench process can compare
-    arms under different env settings."""
+    (the envcheck knob discipline), so one test process can build
+    machines under different settings."""
     budget = envcheck.hot_capacity()
     if budget <= 0 or budget >= logical_capacity:
         return None

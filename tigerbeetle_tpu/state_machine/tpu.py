@@ -323,7 +323,6 @@ class TpuStateMachine:
         account_capacity: int = 1 << 16,
         transfer_capacity: int = 1 << 16,
         engine: str | None = None,
-        prewarm: str | list | None = None,
         device_link=None,
     ) -> None:
         """Capacities follow the reference's static-allocation design:
@@ -361,8 +360,9 @@ class TpuStateMachine:
         self.pulse_next_timestamp = TIMESTAMP_MIN
 
         # Metrics registry (obs/registry.py): every stat_* forensics
-        # counter below is a registry handle behind a compatibility
-        # property (bench resets still work), the device engine's
+        # counter below is a registry handle behind a `stat_*`
+        # property (tests read and reset them by that name; ROADMAP
+        # D13), the device engine's
         # counters graft in under "dev.", and the owning ReplicaServer
         # attaches the whole tree under "sm." for TB_STATS lines and
         # the `stats` wire scrape.
@@ -371,7 +371,7 @@ class TpuStateMachine:
         self.metrics = obs.Registry()
         _c = self.metrics.counter
         self._stats = {
-            # Device/host work-split accounting (reported by bench.py):
+            # Device/host work-split accounting:
             # events whose balance effects were admitted order-free and
             # applied via device scatter-adds vs events resolved by the
             # serial exact engine (host); device-SEMANTIC split (result
@@ -484,19 +484,12 @@ class TpuStateMachine:
             self._dev.spec_stats = make_spec_stats(self.metrics)
             self._bind_tier_stats()
             # Off-hot-path warmup of the named kinds' transfer plans +
-            # scan compiles (bench passes these per config;
-            # construction happens during untimed setup).
+            # scan compiles (construction happens before serving).
             from tigerbeetle_tpu import envcheck as _envcheck
 
-            warm_kinds = prewarm or _envcheck.env_str(
-                "TB_DEV_PREWARM", ""
-            )
+            warm_kinds = _envcheck.env_str("TB_DEV_PREWARM", "")
             if warm_kinds:
-                self._dev.prewarm(
-                    warm_kinds.split(",")
-                    if isinstance(warm_kinds, str)
-                    else warm_kinds
-                )
+                self._dev.prewarm(warm_kinds.split(","))
         else:
             self._dev = kernel_fast.DeviceTable(account_capacity)
             self._dev.mirror = self._mirror
@@ -530,7 +523,7 @@ class TpuStateMachine:
         self._history = Columns(_HISTORY_FIELDS)
 
         # LSM spill tier (attach_forest): None in standalone mode —
-        # everything stays in RAM, as in the benchmark harness.  The
+        # everything stays in RAM, as under testing/harness.py.  The
         # replica attaches a Forest so state scales past host RAM.
         self._forest = None
         self._hspill = None
@@ -542,14 +535,14 @@ class TpuStateMachine:
         # Declines by reason ("plan" = admission/profitability, "mesh"
         # = unsupported sharding geometry, "shard_plan" = plan shape
         # the SPMD executors don't cover, "degraded" = engine lost the
-        # link mid-probe): measured, not guessed — bench reports it.
-        # The dict is the bench-resettable window view; cumulative
-        # per-reason registry counters ride under dev_wave.decline.*.
+        # link mid-probe): measured, not guessed.  The dict is what
+        # tests read; the scrape's cumulative per-reason counters
+        # ride under dev_wave.decline.*.
         self.stat_dev_wave_decline_reasons: dict = {}
 
     # Compatibility properties: migrated stat_* counters live in the
-    # metrics registry (reads and writes route to handles, so bench's
-    # between-arm resets keep working).
+    # metrics registry (reads and writes route to handles; ROADMAP
+    # D13).
     stat_device_events = obs_stat_property("stat_device_events")
     stat_exact_events = obs_stat_property("stat_exact_events")
     stat_host_semantic_events = obs_stat_property("stat_host_semantic_events")
@@ -1018,8 +1011,8 @@ class TpuStateMachine:
         In host-engine mode every reply resolves synchronously.  In
         device mode create_transfers batches (and lookup_accounts
         balance gathers) resolve when their summary/gather rides the
-        next ring fetch — the pipelined path the benchmark and the
-        replica drive (reference: the reference client pipelines
+        next ring fetch — the pipelined path the
+        replica drives (reference: the reference client pipelines
         batches the same way, src/clients/c/tb_client/packet.zig).
         """
         from tigerbeetle_tpu.state_machine.device_engine import ReplyFuture
@@ -1438,7 +1431,7 @@ class TpuStateMachine:
         # Forced-optimistic routing (TB_WAVES_SPECULATE=force): every
         # window batch — including shapes the semantic kernels could
         # serve — goes through the speculative wave dispatcher, the
-        # differential-fuzz / bench arm that maximizes coverage of the
+        # differential-fuzz arm that maximizes coverage of the
         # validate-and-residue machinery.
         if waves.spec_mode() == "force":
             return host_path
@@ -1601,7 +1594,7 @@ class TpuStateMachine:
     def _dev_wave_decline(self, reason: str) -> None:
         self._stats["stat_dev_wave_declined"].inc()
         # Cumulative per-reason registry counter (scrapeable) + the
-        # bench-resettable window dict.
+        # dict tests read.
         self.metrics.counter("dev_wave.decline." + reason).inc()
         reasons = self.stat_dev_wave_decline_reasons
         reasons[reason] = reasons.get(reason, 0) + 1
@@ -1703,7 +1696,7 @@ class TpuStateMachine:
         # residue-cap gate skips batches the host ALREADY knows are
         # residue-dominated (chain members, history events, serialized
         # post/voids) — a guaranteed-loss speculation; "force" takes
-        # them anyway (differential/bench routing).
+        # them anyway (differential routing).
         sm_mode = waves.spec_mode()
         speculate = sm_mode != "0" and not sharded
         if speculate and sm_mode != "force":
@@ -2332,7 +2325,7 @@ class TpuStateMachine:
         # A None return means fallback — nothing was mutated.
         # TB_WAVES=1/exact/scan bypasses every native/host fast path so
         # the JAX exact path (wave executor or B-step scan) sees the
-        # full stream (differential-test + benchmark routing).
+        # full stream (differential-test routing).
         if self._native is not None and waves.mode() not in (
             "1", "exact", "scan"
         ):

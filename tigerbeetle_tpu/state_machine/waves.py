@@ -135,8 +135,8 @@ def min_ratio() -> float:
     """Minimum step-count reduction (batch length / executed steps)
     before the wave path beats the plain scan; below it the partition
     degrades toward per-event waves and the scan's single fused
-    dispatch wins.  Read live (like mode()) so tests and bench arms
-    can toggle TB_WAVES_MIN_RATIO after import."""
+    dispatch wins.  Read live (like mode()) so tests can toggle
+    TB_WAVES_MIN_RATIO after import."""
     from tigerbeetle_tpu import envcheck
 
     return envcheck.env_float("TB_WAVES_MIN_RATIO", 2.0, minimum=0.0)
@@ -189,8 +189,7 @@ def spec_mode() -> str:
     (see envcheck.waves_speculate for the full contract): "auto"/"1"
     speculate behind the residue-cap gate, "0" keeps the pessimistic
     plan-first path, "force" routes every window batch optimistically.
-    Read live (like mode()) so tests and bench arms can toggle it
-    after import."""
+    Read live (like mode()) so tests can toggle it after import."""
     from tigerbeetle_tpu import envcheck
 
     return envcheck.waves_speculate()
@@ -207,7 +206,7 @@ def chain_max() -> int:
     """TB_WAVES_CHAIN_MAX: longest chain (in positions) a chain-wave
     segment may carry — longer chains keep the exact scan, whose cost
     is one step per member.  0 disables chain waves entirely.  Read
-    live so tests and bench arms can toggle it after import."""
+    live so tests can toggle it after import."""
     from tigerbeetle_tpu import envcheck
 
     return envcheck.env_int(
@@ -592,7 +591,7 @@ def plan_waves(
     bit-identical to the scan.
 
     Levels come from the vectorized wavefront (_levels_wavefront,
-    sorted-token segmented mins — <100 µs for bench-shaped batches) and
+    sorted-token segmented mins) and
     fall back to the per-event Python walk for regions more serial
     than _WAVEFRONT_CAP levels; ``use_walk=True`` forces the walk —
     the reference algorithm the fuzz pins the wavefront against.
@@ -2254,7 +2253,7 @@ def _prewarm_sharded(A: int, mesh, B_buckets, buckets) -> None:
 # the batch length is zeros by construction — so pending records store
 # a lossless columnar encoding and rebuild the padded dict at launch
 # (DeviceEngine.submit_waves / _exec_waves).  The engine reports the
-# retained bytes as `pending_window_bytes` (bench `device_waves`).
+# retained bytes as `pending_window_bytes`.
 
 _PER_COLUMN_OVERHEAD = 8  # name/tag bookkeeping, counted honestly
 

@@ -14,7 +14,7 @@ Fault kinds per crossing:
 - transient: raises TransientLinkError once (a retry succeeds);
 - fatal: raises FatalLinkError (classification skips the retry budget);
 - down: every crossing fails fatally until heal()/auto-heal — a lost
-  link, the BENCH_r06 failure mode;
+  link;
 - delay: sleeps a bounded jittered time first (pacing, not failure).
 """
 

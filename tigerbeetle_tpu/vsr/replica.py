@@ -540,8 +540,7 @@ class Replica:
         """The commit stage chain (reference: src/vsr/replica.zig:
         3456-3535): prefetch -> commit -> reply store.  Wrapped whole
         in the `commit` span + commit_us histogram so per-op commit
-        latency is scrapeable (bench sources its commit percentiles
-        from this, not from re-derived timings)."""
+        latency is scrapeable (`commit_span_ms_per_req` reads it)."""
         with self.tracer.stage(self._st_commit, op=int(header["op"])):
             reply = self._commit_prepare_impl(header, body, replay)
         self._c_commits.inc()
@@ -859,7 +858,7 @@ class Replica:
         # draining inside it as one latency spike (the p100 tail).
         # The cadence is learned from the PREVIOUS interval
         # (operators may checkpoint more often than
-        # vsr_checkpoint_interval — the durable benchmark does);
+        # vsr_checkpoint_interval);
         # op-count-driven, so replicas stay deterministic.
         interval = min(
             self.config.vsr_checkpoint_interval,

@@ -126,8 +126,8 @@ class Storage:
     """Backend interface: aligned read/write/sync."""
 
     layout: ZoneLayout
-    # Actual durability syscalls issued (one per fdatasync; the group
-    # -commit and async-checkpoint benches grade against this).
+    # Actual durability syscalls issued (one per fdatasync;
+    # `journal_fsyncs_per_req` reads it).
     stat_fsyncs = 0
     # True when write_prepare(sync=False) + a later covering
     # sync_wal() is crash-equivalent to per-op syncs (FileStorage).
@@ -222,9 +222,9 @@ class FileStorage(Storage):
         # from the fdatasync that follows.
         self._grid_ext_lo = None
         self._grid_ext_hi = 0
-        # Write-amplification accounting (bench durable config reports
-        # bytes/event; reference analog: devhub's datafile-size metric,
-        # src/scripts/devhub.zig:36-41).  WAL counts only the journal
+        # Write-amplification accounting (`wal_bytes_per_event`,
+        # `grid_write_bytes_per_event`; reference analog: devhub's
+        # datafile-size metric, src/scripts/devhub.zig:36-41).  WAL counts only the journal
         # rings; superblock/client-reply traffic is "control" —
         # lumping checkpoint control writes into WAL framing would
         # misdirect the exact investigation this counter serves.
