@@ -147,21 +147,30 @@ class Gate:
 # (a) equivalence.
 
 
-def test_worker_and_inline_end_byte_identical(tmp_path):
+@pytest.mark.parametrize("commits,checkpoints,index_memtable", [
+    (50, (16, 33), None), (34, (16,), PER)],
+    ids=["production", "index-trees-merge"])
+def test_worker_and_inline_end_byte_identical(
+        tmp_path, commits, checkpoints, index_memtable):
     """50 commits of 8,190 (over 40 full beats), two checkpoints: the same
     forest, the same state root, the same free set, the same bytes in
-    the `.grid` file."""
+    the `.grid` file.  The second case seals the two index trees every
+    beat (in production every eighth), so level 0 overflows three times
+    and the merges that append to level 1 run under it."""
     ends = {}
     for name, inline in (("worker", False), ("inline", True)):
         path = tmp_path / f"{name}.tigerbeetle"
         storage, r = open_replica(path, inline=inline, create=True)
+        indexes = r.forest.grooves["transfers"].indexes.values()
+        for tree in indexes:
+            tree.memtable_max = index_memtable or tree.memtable_max
         accounts(r)
         beats = 0
-        for op in range(50):
+        for op in range(commits):
             full = r.sm._store.tail_count() + PER - 16_384 >= PER
             beats += full
             commit(r, op)
-            if op in (16, 33):
+            if op in checkpoints:
                 checkpoint(r)
         r.forest.barrier()
         snap = lsm(r)
@@ -171,12 +180,18 @@ def test_worker_and_inline_end_byte_identical(tmp_path):
             "free_set": r.forest.grid.free_set.encode(),
             "spill_base": r.sm._store.spill.base,
             "commit_min": r.commit_min,
+            "merges": snap["compact.jobs"] - snap["compact.moves"],
+            "level_1": [len(tree.levels[1]) for tree in indexes],
         }
         shut(storage, r)
         threaded = not inline
         assert (snap["beat.work_us.count"] >= beats) == threaded
         assert threaded or snap["beat.work_us.count"] == 0
-    assert beats >= 40 and ends["worker"]["spill_base"] >= 45 * PER
+    assert beats >= commits - 10
+    assert ends["worker"]["spill_base"] >= (commits - 5) * PER
+    if index_memtable:
+        assert ends["worker"]["merges"] >= 6
+        assert ends["worker"]["level_1"] == [3, 3]
     for key in ends["worker"]:
         assert ends["worker"][key] == ends["inline"][key], key
     assert filecmp.cmp(tmp_path / "worker.tigerbeetle.grid",

@@ -243,6 +243,12 @@ BEAT_KEYS = {
     "lsm.beat.bound_wait_us.count", "lsm.beat.bound_wait_us.sum",
     "lsm.barrier.joins", "lsm.barrier.wait_us.sum", "lsm.beat.queued",
 }
+# What compaction did, over the forest's trees (lsm/tree.py
+# CompactionStats): four counters and a gauge.
+COMPACT_KEYS = {
+    "lsm.compact.jobs", "lsm.compact.moves", "lsm.compact.entries_in",
+    "lsm.compact.entries_out", "lsm.tree.runs_peak",
+}
 # What only a cluster's replicas open: the primary's hand-over of a
 # prepare to the backups' connections, a backup's run of prepares.
 REPLICATION_LEAVES = {"vsr.replicate.send", "vsr.backup.accept"}
@@ -348,7 +354,8 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     # spill waits for, so no beat is handed to the worker.)
     on_the_path = LEAVES - REPLICATION_LEAVES - WORKER_LEAVES - {
         "vsr.ckpt.freeze"}
-    assert BEAT_KEYS <= set(snap)
+    assert BEAT_KEYS | COMPACT_KEYS <= set(snap)
+    assert not any(snap[key] for key in COMPACT_KEYS)     # nothing sealed
     assert snap["lsm.beat.work_us.count"] == snap["lsm.beat.queued"] == 0
     for name in REPLICATION_LEAVES:
         assert snap[name + "_us.count"] == 0, name

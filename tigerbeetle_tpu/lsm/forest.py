@@ -20,6 +20,7 @@ from tigerbeetle_tpu import obs
 from tigerbeetle_tpu.lsm.beats import BeatWorker
 from tigerbeetle_tpu.lsm.groove import Groove
 from tigerbeetle_tpu.lsm.manifest_log import ManifestLog
+from tigerbeetle_tpu.lsm.tree import CompactionStats
 from tigerbeetle_tpu.utils import snapshot as snapcodec
 from tigerbeetle_tpu.vsr.free_set import FreeSet
 from tigerbeetle_tpu.vsr.grid import Grid
@@ -63,6 +64,8 @@ class Forest:
         # (the grid writer's switch); else beats run in place.
         self.metrics = obs.Registry()
         self.beats = BeatWorker(self.metrics, beat_worker and file_backed)
+        # What compaction did, over all trees (`lsm.compact.*`).
+        self.stats = CompactionStats(self.metrics)
 
     def barrier(self) -> None:
         """Join the beats handed to the worker: before anything on
@@ -88,6 +91,7 @@ class Forest:
         for tree in (g.id_tree, g.object_tree, *g.indexes.values()):
             tree.tree_id = len(self._trees)
             tree.mlog = self.mlog
+            tree.stats = self.stats
             self._trees.append(tree)
         return g
 

@@ -19,6 +19,7 @@ import dataclasses
 
 import numpy as np
 
+from tigerbeetle_tpu import obs
 from tigerbeetle_tpu.lsm.runs import KEY_DTYPE, keys_le, pack_u128
 from tigerbeetle_tpu.vsr.grid import Grid
 
@@ -71,6 +72,21 @@ class Run:
         return self.blocks[-1].key_max
 
 
+class CompactionStats:
+    """What compaction did, counted where it happens.  A forest makes
+    one on its registry (scrape: `lsm.compact.*`, `lsm.tree.runs_peak`)
+    and shares it among its trees; a tree alone counts on its own."""
+
+    def __init__(self, registry: obs.Registry) -> None:
+        self.jobs = registry.counter("compact.jobs")
+        self.moves = registry.counter("compact.moves")  # of the jobs
+        # Entries a merge read and wrote (a move reads and writes none).
+        self.entries_in = registry.counter("compact.entries_in")
+        self.entries_out = registry.counter("compact.entries_out")
+        # The most runs any one tree held: what a read may consult.
+        self.runs_peak = registry.gauge("tree.runs_peak")
+
+
 class Tree:
     def __init__(self, grid: Grid, name: str, *, value_size: int = 8,
                  memtable_max: int = 8192, sparse_values: bool = False) -> None:
@@ -87,6 +103,7 @@ class Tree:
         # rewrites (reference: src/lsm/manifest_log.zig).
         self.tree_id = 0
         self.mlog = None
+        self.stats = CompactionStats(obs.Registry(enabled=False))
         self._next_run_id = 0
         # Memtable: list of individually-sorted columnar batches
         # (keys KEY_DTYPE, flags u8, values (n, value_size) u8), newest
@@ -300,10 +317,19 @@ class Tree:
         self.memtable_count = 0
         run = self._new_run(keys, flags, vals, level=0)
         self.levels[0].append(run)
+        # Only a seal raises a tree's run count: a job takes more than
+        # it leaves.
+        runs = sum(len(level) for level in self.levels)
+        if runs > self.stats.runs_peak.value:
+            self.stats.runs_peak.set(runs)
 
     def _new_run(self, keys, flags, vals, *, level: int) -> Run:
-        run = self._write_run(keys, flags, vals)
-        run.id = self._next_run_id
+        return self._file_run(self._write_run(keys, flags, vals).blocks, level)
+
+    def _file_run(self, blocks: list[RunBlock], level: int) -> Run:
+        """A run of `blocks` (written already), the tree's newest, on
+        the manifest log at `level`; the caller puts it in `levels`."""
+        run = Run(blocks=blocks, id=self._next_run_id)
         self._next_run_id += 1
         if self.mlog is not None:
             self.mlog.run_add(
@@ -377,21 +403,22 @@ class Tree:
 
     def _level_run_max(self, level: int) -> int:
         """Constant run cap per level IS the geometric invariant here:
-        a level-L run is the merge of ~GROWTH level-(L-1) runs, so run
-        SIZE grows by GROWTH per level and a cap of GROWTH runs gives
-        each level ~GROWTH^L capacity (reference: src/config.zig
-        lsm_growth_factor; table-count-based in the reference because
-        its tables are fixed-size — ours are not)."""
+        a level-L run is the merge of the GROWTH + 1 level-(L-1) runs
+        that overflowed the cap, so run SIZE grows by ~GROWTH per level
+        and a cap of GROWTH runs gives each level ~GROWTH^L capacity
+        (reference: src/config.zig lsm_growth_factor; table-count-based
+        in the reference because its tables are fixed-size — ours are
+        not).  The last level has no cap: it merges into itself."""
         del level
         return GROWTH
 
     # -- paced compaction -------------------------------------------------
     #
-    # A merge of level L into L+1 reads both levels and rewrites them —
-    # done synchronously it is a latency cliff that grows with state.
-    # Instead an over-full level opens a resumable CompactionJob that
-    # advances a bounded number of grid blocks per beat; the replica
-    # beats every commit and drains at checkpoint
+    # A merge of level L into L+1 reads and rewrites all of level L —
+    # done synchronously it is a latency cliff that grows with the
+    # level.  Instead an over-full level opens a resumable
+    # CompactionJob that advances a bounded number of grid blocks per
+    # beat; the replica beats every commit and drains at checkpoint
     # (reference: src/lsm/compaction.zig:1-32, forest.zig:846
     # CompactionPipeline).
 
@@ -525,8 +552,14 @@ class _JobInput:
 
 
 class CompactionJob:
-    """Resumable merge of level L (+ level L+1) into one level-(L+1)
-    run, advanced a bounded number of blocks at a time.
+    """Resumable merge of level L's runs into ONE new run, appended to
+    level L+1 as its newest and advanced a bounded number of blocks at
+    a time.  The runs level L+1 already holds are neither read,
+    rewritten nor released (reads go newest first, so the new run
+    shadows them); that level overflows at `_level_run_max` like any
+    other and gets its own job into L+2.  So an entry is rewritten once
+    a level it descends, not once a merge.  Only the LAST level, with
+    nowhere deeper to overflow to, takes its own runs in and stays one.
 
     Visibility: input runs stay in `tree.levels` (reads keep working)
     until the final step, which atomically swaps them for the output
@@ -544,21 +577,45 @@ class CompactionJob:
     def __init__(self, tree: Tree, level: int) -> None:
         self.tree = tree
         self.level = level
-        # Snapshot the input run lists: new seals arriving at level 0
+        # Snapshot the input run list: new seals arriving at level 0
         # during the job are NOT part of it.
-        self.inputs_a = list(tree.levels[level])
-        self.inputs_b = list(tree.levels[level + 1])
-        # Newest first across both levels for merge precedence.
-        self.inputs = [
-            _JobInput(r) for r in reversed(self.inputs_a + self.inputs_b)
-        ]
-        self.drop_tombstones = level + 1 == LEVELS - 1 or not any(
+        self.taken = [(level, r) for r in tree.levels[level]]
+        older = tree.levels[level + 1]
+        if level + 1 == LEVELS - 1:
+            self.taken = [(level + 1, r) for r in older] + self.taken
+            older = []
+        # Newest first for merge precedence.
+        self.inputs = [_JobInput(r) for _, r in reversed(self.taken)]
+        # A tombstone goes once nothing older than the inputs is left
+        # for it to hide.
+        self.drop_tombstones = not older and not any(
             tree.levels[i] for i in range(level + 2, LEVELS)
         )
         self.out_blocks: list[RunBlock] = []
         self._buf: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
         self._buf_count = 0
         self.done = False
+        tree.stats.jobs.inc()
+
+    def _swap(self, drop: list[tuple[int, Run]],
+              blocks: list[RunBlock]) -> None:
+        """The job's one visible step: `drop` leaves its levels and
+        one run of `blocks`, the newest, joins level L+1."""
+        tree = self.tree
+        if tree.mlog is not None:
+            for lvl, run in drop:
+                tree.mlog.run_remove(tree.tree_id, lvl, run.id)
+        gone = set(id(r) for _, r in drop)
+        # New seals may have landed at `level` during the job: keep them.
+        for lvl in (self.level, self.level + 1):
+            tree.levels[lvl] = [
+                r for r in tree.levels[lvl] if id(r) not in gone
+            ]
+        if blocks:
+            tree.levels[self.level + 1].append(
+                tree._file_run(blocks, self.level + 1)
+            )
+        self.done = True
 
     def _try_move(self) -> bool:
         """Move optimization (reference: src/lsm/compaction.zig
@@ -568,39 +625,19 @@ class CompactionJob:
         row numbers — the merge is pure metadata: the SAME grid blocks
         re-file as one level-(L+1) run, no reads, no rewrites.
 
-        Only the level-L runs move; level L+1 keeps its runs untouched
-        (disjointness makes cross-level shadowing impossible).  That
-        keeps each move's manifest event O(level-L blocks): re-listing
-        an ever-growing merged L+1 run every move would be O(total
-        state) metadata per beat — the superlinear drag this bounds."""
-        runs = self.inputs_a + self.inputs_b
-        ordered = sorted(runs, key=lambda r: r.key_min)
+        Only the level-L runs move, the last level's own stay where
+        they are: a move's manifest event is O(level-L blocks), as a
+        merge's is."""
+        ordered = sorted((r for _, r in self.taken), key=lambda r: r.key_min)
         for prev, cur in zip(ordered, ordered[1:]):
             if not prev.key_max < cur.key_min:
                 return False
-        tree = self.tree
-        level = self.level
-        moved = sorted(self.inputs_a, key=lambda r: r.key_min)
-        if tree.mlog is not None:
-            for run in self.inputs_a:
-                tree.mlog.run_remove(tree.tree_id, level, run.id)
-        drop = set(id(r) for r in self.inputs_a)
-        tree.levels[level] = [
-            r for r in tree.levels[level] if id(r) not in drop
-        ]
-        out = Run(blocks=[b for r in moved for b in r.blocks])
-        out.id = tree._next_run_id
-        tree._next_run_id += 1
-        if tree.mlog is not None:
-            tree.mlog.run_add(
-                tree.tree_id, level + 1, out.id,
-                [
-                    (b.address, b.count, b.key_min, b.key_max)
-                    for b in out.blocks
-                ],
-            )
-        tree.levels[level + 1].append(out)
-        self.done = True
+        moved = [(lvl, r) for lvl, r in self.taken if lvl == self.level]
+        self._swap(moved, [
+            b for _, r in sorted(moved, key=lambda lr: lr[1].key_min)
+            for b in r.blocks
+        ])
+        self.tree.stats.moves.inc()
         return True
 
     def step(self, block_budget: int) -> int:
@@ -623,6 +660,7 @@ class CompactionJob:
                     )
                     inp.offset = 0
                     used += 1
+                    tree.stats.entries_in.inc(len(inp.keys))
                 if inp.keys is not None:
                     loaded.append(inp)
             if not loaded:
@@ -675,44 +713,16 @@ class CompactionJob:
     def _flush_block(self, per_block: int) -> int:
         keys, flags, vals = self._pop_buffered(per_block)
         self.out_blocks.append(self.tree._write_one_block(keys, flags, vals))
+        self.tree.stats.entries_out.inc(len(keys))
         return 1
 
     def _finalize(self, per_block: int) -> int:
         used = 0
         while self._buf_count:
             used += self._flush_block(per_block)
-        tree = self.tree
-        level = self.level
-        if tree.mlog is not None:
-            for lvl, runs in ((level, self.inputs_a), (level + 1, self.inputs_b)):
-                for run in runs:
-                    tree.mlog.run_remove(tree.tree_id, lvl, run.id)
-        for run in self.inputs_a + self.inputs_b:
-            tree._release_run(run)
-        # New seals may have landed at `level` during the job: keep them.
-        drop = set(id(r) for r in self.inputs_a + self.inputs_b)
-        tree.levels[level] = [
-            r for r in tree.levels[level] if id(r) not in drop
-        ]
-        survivors = [
-            r for r in tree.levels[level + 1] if id(r) not in drop
-        ]
-        if self.out_blocks:
-            out = Run(blocks=self.out_blocks)
-            out.id = tree._next_run_id
-            tree._next_run_id += 1
-            if tree.mlog is not None:
-                tree.mlog.run_add(
-                    tree.tree_id, level + 1, out.id,
-                    [
-                        (b.address, b.count, b.key_min, b.key_max)
-                        for b in out.blocks
-                    ],
-                )
-            tree.levels[level + 1] = [out] + survivors
-        else:
-            tree.levels[level + 1] = survivors
-        self.done = True
+        for _, run in self.taken:
+            self.tree._release_run(run)
+        self._swap(self.taken, self.out_blocks)
         return used
 
 

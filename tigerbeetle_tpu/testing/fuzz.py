@@ -292,6 +292,9 @@ def fuzz_tree(seed: int, rounds: int) -> None:
                 tree.seal_memtable()
             else:
                 tree.maybe_seal()
+            if rng.random() < 0.3:
+                # A paced beat: reads below land mid-merge too.
+                tree.compact_beat(int(rng.integers(1, 6)))
 
             if rng.random() < 0.15:
                 # Full batch point-lookup diff.
