@@ -275,12 +275,113 @@ def _cap_ops():
     return ops
 
 
-def test_fallback_cap_exceeded():
-    """More failures than the summary cap -> host re-execution with
-    full failure list."""
+def test_more_failures_than_the_row_holds_come_home_dense():
+    """More failures than the summary row's 60 entries -> the batch's
+    dense result codes cross too (B x u32) and the device's verdicts
+    stand: no host re-execution, no rebuilt table."""
+    from tigerbeetle_tpu.state_machine import device_kernels as dk
+
     h_d, h_c = mk_pair()
+    dev = h_d.sm._dev
+    bytes0 = dev.stat_fetch_bytes
     replay_both(h_d, h_c, _cap_ops())
-    assert h_d.sm._dev.stat_fallback_batches >= 1
+    assert dev.stat_fallback_batches == 0 and h_d.sm.stat_fallback_events == 0
+    assert dev.stat_dense_fetches == 1
+    assert dev.stat_fetch_bytes - bytes0 == 512 + 4 * dk.B
+    assert dev.stat_semantic_events == 100
+    h_d.sm.verify_device_mirror()
+
+
+def _chain_rows(first_id: int, chains: int, poor_every: int):
+    """`chains` chains of three legs over accounts 2..9; every
+    `poor_every`th chain debits the never-funded limit account 1 in
+    its middle leg: that leg answers exceeds_credits, the others
+    linked_event_failed."""
+    rows, tid = [], first_id
+    for c in range(chains):
+        for leg in range(3):
+            poor = c % poor_every == poor_every - 1 and leg == 1
+            rows.append(dict(
+                id=tid, debit_account_id=1 if poor else 2 + (c + leg) % 8,
+                credit_account_id=2 + (c + leg + 1) % 8, amount=1 + leg,
+                flags=int(TF.linked) if leg < 2 else 0))
+            tid += 1
+    return rows
+
+
+def _many_failures_linked(chains_failing: int):
+    ops = [(Operation.create_accounts,
+            accounts([1], flags=int(AF.debits_must_not_exceed_credits))
+            + accounts(range(2, 10)))]
+    ops.append((Operation.create_transfers, transfers(
+        _chain_rows(100, 4 * chains_failing, 4) if chains_failing
+        else _chain_rows(100, 8, 10**9))))
+    ops.append((Operation.lookup_accounts, hz.ids_bytes(list(range(1, 10)))))
+    ops.append((Operation.lookup_transfers, hz.ids_bytes(list(range(100, 160)))))
+    return ops
+
+
+def _many_failures_two_phase(again: int):
+    """`again` pendings, all posted, then posted or voided a second
+    time in one batch: every row of it answers already_posted."""
+    ops = [(Operation.create_accounts, accounts([1, 2]))]
+    ops.append((Operation.create_transfers, transfers(
+        [dict(id=100 + i, debit_account_id=1, credit_account_id=2,
+              amount=5 + i, flags=int(TF.pending)) for i in range(again)])))
+    ops.append((Operation.create_transfers, transfers(
+        [dict(id=300 + i, pending_id=100 + i,
+              flags=int(TF.post_pending_transfer)) for i in range(again)])))
+    ops.append((Operation.create_transfers, transfers(
+        [dict(id=500 + i, pending_id=100 + i,
+              flags=int(TF.void_pending_transfer if i % 3 else
+                        TF.post_pending_transfer)) for i in range(again)]
+        + [dict(id=900, debit_account_id=1, credit_account_id=2, amount=1)])))
+    ops.append((Operation.lookup_accounts, hz.ids_bytes([1, 2])))
+    return ops
+
+
+@pytest.mark.parametrize("ops_of,failures,kind", [
+    (lambda: _many_failures_linked(30), 90, "linked_small"),
+    (lambda: _many_failures_two_phase(80), 80, "two_phase_lo"),
+    (_cap_ops, 100, "orderfree_tight"),
+])
+def test_a_batch_of_many_failures_resolves_on_the_device(ops_of, failures, kind):
+    """`_summary` is one function: a linked, a two-phase and an
+    order-free batch with more than FAIL_CAP failures each resolve
+    from their dense codes, replies identical to the oracle's."""
+    from tigerbeetle_tpu.state_machine import device_kernels as dk
+
+    assert failures > dk.FAIL_CAP
+    h_d, h_c = mk_pair()
+    ops = ops_of()
+    replies = replay_both(h_d, h_c, ops)
+    assert max(len(r) // 8 for (op, _body), r in zip(ops, replies)
+               if op == Operation.create_transfers) >= failures
+    dev = h_d.sm._dev
+    assert dev.stat_fallback_batches == 0 and h_d.sm.stat_fallback_events == 0
+    assert dev.stat_dense_fetches == 1
+    snap = h_d.sm.metrics.snapshot()
+    assert snap[f"dev.kind.{kind}.batches"] >= 1
+    assert sum(v for k, v in snap.items()
+               if k.startswith("dev.kind.") and k.endswith(".events")
+               ) == dev.stat_semantic_events
+    h_d.sm.verify_device_mirror()
+
+
+@pytest.mark.parametrize("failing", [0, 1, 20])
+def test_a_batch_of_few_failures_crosses_512_bytes_as_before(failing):
+    """Up to FAIL_CAP failures (20 failing chains of 3 legs = 60) the
+    sparse row is the only crossing."""
+    h_d, h_c = mk_pair()
+    dev = h_d.sm._dev
+    ops = _many_failures_linked(failing)
+    replay_both(h_d, h_c, ops[:1])
+    bytes0 = dev.stat_fetch_bytes
+    replies = replay_both(h_d, h_c, ops[1:2])
+    assert len(replies[0]) // 8 == 3 * failing
+    assert dev.stat_fetch_bytes - bytes0 == 512
+    assert dev.stat_dense_fetches == 0 and dev.stat_fallback_batches == 0
+    assert h_d.sm.metrics.snapshot()["dev.linked.iters.count"] == 1
 
 
 def _precond_ops():
@@ -327,7 +428,6 @@ def test_linked_precondition_fallback():
 
 @pytest.mark.parametrize("flag,ops_of", [
     ("FLAG_OVERFLOW", _overflow_ops),
-    ("FLAG_CAP", _cap_ops),
     ("FLAG_PRECOND", _precond_ops),
 ])
 def test_a_flagged_window_recovers_from_its_own_rows(monkeypatch, flag, ops_of):
@@ -352,6 +452,26 @@ def test_a_flagged_window_recovers_from_its_own_rows(monkeypatch, flag, ops_of):
     replay_both(h_d, h_c, ops_of())
     assert any(flags & getattr(dk, flag) for flags in seen), seen
     assert h_d.sm._dev.stat_fallback_batches >= 1
+
+
+def test_a_window_of_many_failures_is_clean(monkeypatch):
+    """The row's n_fail, not a flag, says that the dense codes are
+    needed: the window stays on _resolve_clean and the batch behind
+    it is not re-dispatched."""
+    from tigerbeetle_tpu.state_machine import device_engine as de
+    from tigerbeetle_tpu.state_machine import device_kernels as dk
+
+    assert not hasattr(dk, "FLAG_CAP")
+    monkeypatch.setattr(
+        de.DeviceEngine, "_resolve_recovery",
+        lambda self, covered: pytest.fail("a clean window went to recovery"))
+    h_d, h_c = mk_pair()
+    ops = _cap_ops()
+    ops.append((Operation.create_transfers, transfers(
+        [dict(id=900, debit_account_id=1, credit_account_id=2, amount=7)])))
+    ops.append((Operation.lookup_accounts, hz.ids_bytes([1, 2])))
+    replay_both(h_d, h_c, ops)
+    assert h_d.sm._dev.stat_dense_fetches == 1
 
 
 def test_linked_fixpoint_multi_iteration():

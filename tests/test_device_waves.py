@@ -346,7 +346,7 @@ def test_degraded_admission_counts_inflight_bound(monkeypatch):
 
 
 def test_wave_records_across_exact_recovery(monkeypatch):
-    """A window holding [wave batch, cap-exceeded semantic batch, wave
+    """A window holding [wave batch, flagged semantic batch, wave
     batch]: recovery must resolve the first wave record from its
     already-computed output, host-re-execute the flagged batch, and
     RE-EXECUTE the second wave record against the rebuilt table — all
@@ -354,20 +354,22 @@ def test_wave_records_across_exact_recovery(monkeypatch):
     monkeypatch.setattr(de, "_WINDOW", 8)
     rng = np.random.default_rng(9)
     h_d, h_c = mk_pair()
-    ops = [(Operation.create_accounts, accounts(range(1, 47)))]
+    ops = [(Operation.create_accounts, accounts(range(1, 47))
+            + accounts([47], flags=int(AF.debits_must_not_exceed_credits))
+            + accounts([48]))]
     accs = np.arange(1, 41)
     rows1, tid = _pv_balancing_batch(100, accs, rng, bal_accs=list(range(41, 47)))
     ops.append((Operation.create_transfers, hz.pack(rows1)))
-    # accounts_must_be_different x100 > FAIL_CAP -> summary flag ->
-    # exact recovery (small amount bound: later admissions unaffected).
+    # One transfer of 2^62 onto a limit account: the linked kernel's
+    # u64-safe bound refuses it -> FLAG_PRECOND -> exact recovery (the
+    # bound is far under the u128 headroom: later admissions stand).
     ops.append(
         (
             Operation.create_transfers,
             hz.pack(
                 [
-                    hz.transfer(500 + i, debit_account_id=1,
-                                credit_account_id=1, amount=1)
-                    for i in range(100)
+                    hz.transfer(500, debit_account_id=48,
+                                credit_account_id=47, amount=1 << 62)
                 ]
             ),
         )

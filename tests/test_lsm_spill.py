@@ -263,3 +263,103 @@ def test_spill_restart_midstream():
     q_tpu = query_suite(r, max_tid)
     q_cpu = query_suite(r_cpu, max_tid)
     assert q_tpu == q_cpu
+
+
+def _device_replica():
+    storage = MemoryStorage(layout())
+    vsr_replica.format(storage, CLUSTER)
+    r = vsr_replica.Replica(
+        storage, CLUSTER,
+        TpuStateMachine(CONF, account_capacity=1 << 12, engine="device"),
+    )
+    r.open()
+    return r
+
+
+def test_finalisers_of_cold_pendings_on_the_device_engine():
+    """A payments switch's posts and voids arrive after their pendings
+    have left the RAM tail (PR 33): the two-phase kernel's host join
+    reads them from the object tree, the finalise rewrites their
+    status there, and a second finalise, after a seal and a compaction
+    have merged the overwritten keys, reads the NEW status back.
+    Every reply, balance and stored row against the CPU oracle."""
+    r = _device_replica()
+    storage_cpu = MemoryStorage(layout())
+    vsr_replica.format(storage_cpu, CLUSTER)
+    r_cpu = vsr_replica.Replica(storage_cpu, CLUSTER, CpuStateMachine(CONF))
+    r_cpu.open()
+    rng = np.random.default_rng(33)
+    n_acct, per, batches = 8, 400, 4
+
+    def both(op, body):
+        got = r.on_request(int(op), body)
+        assert got == r_cpu.on_request(int(op), body)
+        return got
+
+    both(Op.create_accounts, pack([account(i) for i in range(1, n_acct + 1)]))
+    pend_ids = []
+    for b in range(batches):
+        rows = []
+        for k in range(per):
+            tid = 1000 + b * per + k
+            dr = int(rng.integers(1, n_acct + 1))
+            rows.append(transfer(tid, debit_account_id=dr,
+                                 credit_account_id=dr % n_acct + 1,
+                                 amount=int(rng.integers(1, 500)),
+                                 flags=int(TF.pending)))
+            pend_ids.append(tid)
+        assert both(Op.create_transfers, pack(rows)) == b""
+
+    def settle():
+        """A spill of the whole tail, a seal of every memtable and a
+        full compaction: what beats do over a longer run."""
+        r.checkpoint()
+        for groove in r.forest.grooves.values():
+            groove.object_tree.seal_memtable()
+        r.forest.compact()
+
+    sm = r.sm
+    settle()
+    assert sm._store.base >= batches * per      # every pending is cold
+    snap = lambda: sm.metrics.snapshot()  # noqa: E731
+    cold0 = snap()["store.join_cold_rows"]
+
+    def finalisers(first_id, targets, void_every):
+        return pack([
+            transfer(first_id + k, pending_id=int(p), amount=0,
+                     flags=int(TF.void_pending_transfer if k % void_every == 0
+                               else TF.post_pending_transfer))
+            for k, p in enumerate(targets)])
+
+    first = rng.permutation(pend_ids)[:per]
+    assert both(Op.create_transfers, finalisers(5000, first, 3)) == b""
+    s = snap()
+    assert s["store.join_cold_rows"] - cold0 == per
+    assert s["store.status_overwrites"] == per
+    assert s["plan.join_cold_us.count"] >= 1
+    assert s["dev.kind.two_phase_lo.batches"] == 1
+    assert s["dev.fallback_batches"] == 0 and s["fallback_events"] == 0
+
+    # The overwritten keys now sit in a younger run than the rows they
+    # overwrite; a compaction has to keep the younger.
+    settle()
+    again = np.concatenate([first[:100], rng.permutation(
+        [p for p in pend_ids if p not in set(first.tolist())])[:per - 100]])
+    reply = both(Op.create_transfers, finalisers(7000, again, 2))
+    got = np.frombuffer(reply, types.CREATE_RESULT_DTYPE)
+    assert len(got) == 100 and (got["index"] == np.arange(100)).all()
+    R = types.CreateTransferResult
+    want = [R.pending_transfer_already_voided if k % 3 == 0
+            else R.pending_transfer_already_posted for k in range(100)]
+    assert got["result"].tolist() == [int(x) for x in want]
+    s = snap()
+    assert s["store.status_overwrites"] == 2 * per - 100
+    assert s["dev.kind.two_phase_lo.batches"] == 2
+    assert s["dev.fallback_batches"] == 0 and s["fallback_events"] == 0
+    assert s["host_semantic_events"] == 0
+
+    both(Op.lookup_accounts, ids_bytes(list(range(1, n_acct + 1))))
+    both(Op.lookup_transfers, ids_bytes(
+        [int(x) for x in first[:50]] + list(range(5000, 5050))
+        + list(range(7000, 7120))))
+    sm.verify_device_mirror()

@@ -227,7 +227,7 @@ def stage_names() -> dict[str, bool]:
 LEAVES = {
     "server.poll_wait", "server.ingress", "vsr.admit", "vsr.prepare",
     "vsr.journal.write", "vsr.gc.sync", "vsr.commit.prefetch", "sm.plan",
-    "sm.dev.scrub.cost", "sm.dev.launch", "sm.dev.dispatch",
+    "sm.plan.join_cold", "sm.dev.scrub.cost", "sm.dev.launch", "sm.dev.dispatch",
     "sm.dev.commit.update", "sm.dev.link.fetch_wait",
     "sm.dev.link.fetch_copy", "sm.dev.finish", "vsr.commit.reply",
     "vsr.commit.beat", "vsr.reply_send", "vsr.tick", "vsr.ckpt.freeze",
@@ -351,9 +351,12 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     snap = server.registry.snapshot()
     server.close()
     # (12 small requests: the tail never reaches the 16,384 rows a
-    # spill waits for, so no beat is handed to the worker.)
+    # spill waits for, so no beat is handed to the worker and no join
+    # meets a row that has left the tail.)
     on_the_path = LEAVES - REPLICATION_LEAVES - WORKER_LEAVES - {
-        "vsr.ckpt.freeze"}
+        "vsr.ckpt.freeze", "sm.plan.join_cold"}
+    assert snap["sm.plan.join_cold_us.count"] == 0
+    assert snap["sm.store.join_cold_rows"] == snap["sm.store.status_overwrites"] == 0
     assert BEAT_KEYS | COMPACT_KEYS <= set(snap)
     assert not any(snap[key] for key in COMPACT_KEYS)     # nothing sealed
     assert snap["lsm.beat.work_us.count"] == snap["lsm.beat.queued"] == 0

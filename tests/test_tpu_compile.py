@@ -25,7 +25,9 @@ import pytest
 
 A = 1 << 16       # cli.CACHE_DEFAULT: the served table
 B = 8192          # production event bucket
-KINDS = ("orderfree_tight", "linked_small", "two_phase_lo")
+# `linked` (amounts past 2^31 a batch: a funding request) is compiled as
+# a lone dispatch only; the served cells never scan it.
+KINDS = ("orderfree_tight", "linked", "linked_small", "two_phase_lo")
 
 
 @pytest.fixture(scope="module")
@@ -112,18 +114,22 @@ def test_base_kernel_compiles_for_v5e(one_chip, dk, kind):
 
     ncols, dtype = dk.PK_SPEC[kind]
     fn = {"orderfree_tight": dk.orderfree_tight,
+          "linked": dk.linked,
           "linked_small": dk.linked_small,
           "two_phase_lo": dk.two_phase_lo}[kind]
     # The link's contract: the scalars ride in the buffer's last row,
-    # and the summary row comes back as an output of its own.
+    # and the summary row comes back as an output of its own, the
+    # dense codes (which stay on the device unless the row's failures
+    # outrun its entries) as another.
     args = (*_tables(dk), _s((dk.ROWS, ncols), dtype))
     _compile(fn, one_chip, *args)
-    table, row = jax.eval_shape(fn, *args)
+    table, row, dense = jax.eval_shape(fn, *args)
     assert table.shape == (A, 8)
     assert (row.shape, row.dtype) == ((dk.SUMMARY_WORDS,), jnp.uint64)
+    assert (dense.shape, dense.dtype) == ((dk.B,), jnp.uint32)
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", [k for k in KINDS if k != "linked"])
 def test_window_scan_compiles_for_v5e(one_chip, dk, kind):
     """Sixteen batches per launch out of one uploaded stack; their
     summary rows come back as one (16, SUMMARY_WORDS) output."""
@@ -134,8 +140,9 @@ def test_window_scan_compiles_for_v5e(one_chip, dk, kind):
     fn = dk.scan_kernels[kind][16]
     args = (*_tables(dk), _s((16, dk.ROWS, ncols), dtype))
     _compile(fn, one_chip, *args)
-    _table, rows = jax.eval_shape(fn, *args)
+    _table, rows, dense = jax.eval_shape(fn, *args)
     assert (rows.shape, rows.dtype) == ((16, dk.SUMMARY_WORDS), jnp.uint64)
+    assert (dense.shape, dense.dtype) == ((16, dk.B), jnp.uint32)
 
 
 def test_speculative_wave_executor_compiles_for_v5e(one_chip):

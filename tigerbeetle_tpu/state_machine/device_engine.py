@@ -33,8 +33,14 @@ is:
             (finish callbacks), overlapped with the device crunching
             the new window.
 
+A batch whose row counts more failures than its 60 entries hold (a
+request of linked chains of which a few in a hundred fail: every leg
+of a failed chain answers a code) brings its dense result codes home
+too, B x u32 that the kernel leaves on the device beside the row: a
+second crossing for that batch alone, chosen by the n_fail in hand.
+
 A batch whose summary carries a fallback flag (balance overflow in
-play, failure-cap exceeded, precondition violated) triggers exact
+play, precondition violated) triggers exact
 recovery BEFORE the next window launches: the host re-executes that
 batch through the host engine (``fallback`` callback, which updates
 the mirror), re-uploads the corrected table, and re-dispatches every
@@ -332,15 +338,20 @@ class _InFlight:
 
 
 class _UnitOut:
-    """One dispatch unit's summary output: the device handle from
+    """One dispatch unit's output: the summary's device handle from
     dispatch to the window's fetch, then its (G, SUMMARY_WORDS) numpy
-    rows (G = 1 for a solo batch)."""
+    rows (G = 1 for a solo batch); and the unit's dense result codes,
+    which stay on the device (`dense`) unless a row counts more
+    failures than it has room for: then `codes` is their (G, B)
+    numpy copy."""
 
-    __slots__ = ("handle", "rows")
+    __slots__ = ("handle", "rows", "dense", "codes")
 
-    def __init__(self, handle) -> None:
+    def __init__(self, handle, dense) -> None:
         self.handle = handle
         self.rows = None
+        self.dense = dense
+        self.codes = None
 
 
 # Speculative-execution forensics (ISSUE r18): counters named
@@ -502,6 +513,9 @@ class DeviceEngine:
             "stat_semantic_events": _c("semantic_events"),
             "stat_fallback_batches": _c("fallback_batches"),
             "stat_fetches": _c("fetches"),
+            # Batches whose failures outran the summary row, so that
+            # their dense result codes crossed too (B x u32 each).
+            "stat_dense_fetches": _c("summary.dense_fetches"),
             # How a window crossed: arrays the engine uploaded (every
             # link.device_put and every host array handed to a
             # program), and bytes _fetch brought home.
@@ -549,6 +563,14 @@ class DeviceEngine:
             _h("link.fetch_copy_us"), "sm.dev.link.fetch_copy"
         )
         self._st_finish = tracer_mod.Stage(_h("finish_us"), "sm.dev.finish")
+        # Who resolved what: batches and events per kind, counted
+        # where semantic_events is; and the linked kernels' Jacobi
+        # iterations (their cost), from the summary's flags word.
+        self._kind_stats = {
+            kind: (_c(f"kind.{kind}.batches"), _c(f"kind.{kind}.events"))
+            for kind in _SEMANTIC_KINDS + ("waves", "spec")
+        }
+        self._h_linked_iters = _h("linked.iters")
         # Per-stage crossing-latency histograms, hoisted so _retry
         # pays one dict lookup per crossing (no string building; the
         # shared no-op instances when TB_METRICS=0).
@@ -654,6 +676,7 @@ class DeviceEngine:
     stat_semantic_events = obs_stat_property("stat_semantic_events")
     stat_fallback_batches = obs_stat_property("stat_fallback_batches")
     stat_fetches = obs_stat_property("stat_fetches")
+    stat_dense_fetches = obs_stat_property("stat_dense_fetches")
     stat_puts = obs_stat_property("stat_puts")
     stat_fetch_bytes = obs_stat_property("stat_fetch_bytes")
     stat_demotions = obs_stat_property("stat_demotions")
@@ -1368,10 +1391,10 @@ class DeviceEngine:
                 self._dispatch_aux(urecs[0])
 
     def _run_semantic(self, fn, dev_pk, urecs) -> None:
-        self.balances, rows = self._run(
+        self.balances, rows, dense = self._run(
             fn, self.balances, self.meta, dev_pk
         )
-        out = _UnitOut(rows)
+        out = _UnitOut(rows, dense)
         for g, rec in enumerate(urecs):
             rec.out = out
             rec.row = g
@@ -1555,7 +1578,11 @@ class DeviceEngine:
     def _fetch_window(self, recs) -> None:
         """Bring a launched window's outputs home: each dispatch
         unit's summary rows (512 bytes a batch) and each lookup/wave
-        handle.  Their copies started at dispatch; this waits."""
+        handle.  Their copies started at dispatch; this waits.  A
+        batch whose row counts more failures than its FAIL_CAP
+        entries hold brings its unit's dense codes home too (B x u32
+        a batch): chosen by the row in hand, so a batch of few
+        failures crosses what it always crossed."""
         if any(r.kind in _SEMANTIC_KINDS for r in recs):
             self.stat_fetches += 1
         for rec in recs:
@@ -1566,6 +1593,10 @@ class DeviceEngine:
                         -1, dk.SUMMARY_WORDS
                     )
                     out.handle = None
+                if int(out.rows[rec.row][0]) > dk.FAIL_CAP:
+                    self.stat_dense_fetches += 1
+                    if out.codes is None:
+                        out.codes = self._fetch(out.dense).reshape(-1, dk.B)
             elif rec.kind in ("lookup", "waves", "spec") and (
                 rec.handle is not None
             ):
@@ -1595,9 +1626,27 @@ class DeviceEngine:
             if rec.kind not in _SEMANTIC_KINDS:
                 continue
             flags = int(rec.out.rows[rec.row][1])
-            if flags & (dk.FLAG_OVERFLOW | dk.FLAG_CAP | dk.FLAG_PRECOND):
+            if flags & (dk.FLAG_OVERFLOW | dk.FLAG_PRECOND):
                 return False
         return True
+
+    @staticmethod
+    def _summary_of(rec: _InFlight) -> dict:
+        """A fetched semantic record's decoded summary."""
+        out = rec.out
+        return dk.unpack_summary(
+            out.rows[rec.row],
+            None if out.codes is None else out.codes[rec.row],
+        )
+
+    def _count_resolved(self, rec: _InFlight, summary=None) -> None:
+        """The device computed `rec`'s result codes."""
+        self.stat_semantic_events += rec.n
+        batches, events = self._kind_stats[rec.kind]
+        batches.inc()
+        events.inc(rec.n)
+        if rec.kind in ("linked", "linked_small"):
+            self._h_linked_iters.observe(summary["iters"])
 
     def _resolve_clean(self, recs) -> None:
         with self.tracer.stage(self._st_finish):
@@ -1611,12 +1660,12 @@ class DeviceEngine:
                 rec.future.resolve(rec.finish(rec.rows))
                 continue
             if rec.kind in ("waves", "spec"):
-                self.stat_semantic_events += rec.n
+                self._count_resolved(rec)
                 rec.future.resolve(rec.finish(rec.rows))
                 self._release_bound(rec)
                 continue
-            s = dk.unpack_summary(rec.out.rows[rec.row])
-            self.stat_semantic_events += rec.n
+            s = self._summary_of(rec)
+            self._count_resolved(rec, s)
             rec.future.resolve(rec.finish(s))
             self._release_bound(rec)
 
@@ -1679,18 +1728,18 @@ class DeviceEngine:
                     # admission proved the plan exact, so the fetched
                     # packed output (computed against the stream prefix
                     # before any LATER batch's fallback) resolves.
-                    self.stat_semantic_events += rec.n
+                    self._count_resolved(rec)
                     rec.future.resolve(rec.finish(rec.rows))
                     self._release_bound(rec)
                     continue
-                s = dk.unpack_summary(rec.out.rows[rec.row])
-                if s["overflow"] or s["cap_exceeded"] or s["precond"]:
+                s = self._summary_of(rec)
+                if s["overflow"] or s["precond"]:
                     failed_at = i
                     self.stat_fallback_batches += 1
                     rec.future.resolve(rec.fallback())
                     self._release_bound(rec)
                     break
-                self.stat_semantic_events += rec.n
+                self._count_resolved(rec, s)
                 rec.future.resolve(rec.finish(s))
                 self._release_bound(rec)
             if failed_at is None:

@@ -23,10 +23,13 @@ the packed buffer's last row (SCALAR_ROW) — no scalar argument, no
 convert program.  Down: each kernel returns, beside the new table, a
 fixed-size FAILURE-SPARSE summary row (60 failure slots + status
 flags, 512 bytes) as an output of its own; the engine starts its copy
-home at dispatch and fetches nothing else.  Batches whose failures
-exceed the cap — or that hit an overflow/precondition edge — raise a
-flag and are re-executed exactly on the host engine (the fallback
-path), so the sparse encoding never loses information.
+home at dispatch and fetches nothing else, unless the row counts more
+failures than its slots hold: the batch's dense result codes, a
+third output that otherwise never leaves the device, then come home
+too (32 KB at B = 8192), so the sparse encoding never loses
+information.  A batch that hits an overflow/precondition edge raises
+a flag and is re-executed exactly on the host engine (the fallback
+path).
 
 Input marshaling split (who computes what): the host packs raw event
 columns and *stateless byte predicates* (id == 0, id == maxInt,
@@ -74,7 +77,6 @@ FAIL_CAP = SUMMARY_WORDS - 4   # failure entries per batch summary
 
 # Summary flag bits (word [1]).
 FLAG_OVERFLOW = 1 << 0     # balance-overflow admission failed
-FLAG_CAP = 1 << 1          # more than FAIL_CAP failures
 FLAG_PRECOND = 1 << 2      # kernel precondition (u64-safety, fixpoint cap)
 ITERS_SHIFT = 16           # linked fixpoint iterations (diagnostics)
 
@@ -331,9 +333,14 @@ def _admit_apply(table, d_lo, d_hi, limb_ov):
 
 
 def _summary(results, active, flags_word, last_applied):
-    """Failure-sparse fixed-size summary row: [n_fail, flags,
-    last_applied+1, n_active, entries...] as (SUMMARY_WORDS,) u64."""
-    fail = active & (results != 0)
+    """A batch's answer, twice: the failure-sparse fixed-size summary
+    row [n_fail, flags, last_applied+1, n_active, entries...] as
+    (SUMMARY_WORDS,) u64, which is what crosses the link, and the
+    dense (B,) u32 result codes, which stay on the device unless the
+    row's n_fail says its FAIL_CAP entries do not hold them all: the
+    engine then fetches the codes too (unpack_summary's `dense`)."""
+    results = jnp.where(active, results, jnp.uint32(0))
+    fail = results != 0
     n_fail = fail.sum().astype(jnp.uint64)
     pos = jnp.cumsum(fail) - 1
     ent = (jnp.arange(B, dtype=jnp.uint64) << jnp.uint64(32)) | results.astype(
@@ -342,10 +349,6 @@ def _summary(results, active, flags_word, last_applied):
     entries = jnp.zeros(FAIL_CAP, jnp.uint64).at[
         jnp.where(fail, pos, FAIL_CAP)
     ].set(ent, mode="drop")
-    cap = n_fail > FAIL_CAP
-    flags_word = flags_word | jnp.where(
-        cap, jnp.uint64(FLAG_CAP), jnp.uint64(0)
-    )
     head = jnp.stack(
         [
             n_fail,
@@ -354,7 +357,7 @@ def _summary(results, active, flags_word, last_applied):
             active.sum().astype(jnp.uint64),
         ]
     )
-    return jnp.concatenate([head, entries])
+    return jnp.concatenate([head, entries]), results
 
 
 def _split_scalars(pkx):
@@ -460,7 +463,7 @@ def _orderfree_core(table, meta, ev, n, ts_base, lo_only):
     applied_idx = jnp.where(ok, iota, -1)
     last_applied = applied_idx.max()
     flags_word = jnp.where(ov, jnp.uint64(FLAG_OVERFLOW), jnp.uint64(0))
-    return new_table, _summary(r, active, flags_word, last_applied)
+    return (new_table, *_summary(r, active, flags_word, last_applied))
 
 
 # ---------------------------------------------------------------------------
@@ -757,7 +760,7 @@ def _linked(table, meta, pkx, small=False):
         )
         | (iters.astype(jnp.uint64) << jnp.uint64(ITERS_SHIFT))
     )
-    return new_table, _summary(results, active, flags_word, last_applied)
+    return (new_table, *_summary(results, active, flags_word, last_applied))
 
 
 # ---------------------------------------------------------------------------
@@ -982,7 +985,7 @@ def _two_phase(table, meta, pkx, lo_only=False):
 
     last_applied = jnp.where(ok, iota, -1).max()
     flags_word = jnp.where(fallback, jnp.uint64(FLAG_OVERFLOW), jnp.uint64(0))
-    return new_table, _summary(code, active, flags_word, last_applied)
+    return (new_table, *_summary(code, active, flags_word, last_applied))
 
 
 # ---------------------------------------------------------------------------
@@ -1068,14 +1071,17 @@ two_phase_lo = _jit_as("two_phase_lo", _ft.partial(_two_phase, lo_only=True))
 # one uploaded (G, ROWS, ncols) stack; lax.scan spreads an upload's
 # and a dispatch's fixed host cost (~0.25 and 0.3-0.7 ms on the v5e:
 # PERF.md section 6, PR 27) over the chunk.  The G summary rows come back
-# as ONE (G, SUMMARY_WORDS) output, in record order.
+# as ONE (G, SUMMARY_WORDS) output, in record order, and the dense
+# codes as one (G, B) output beside it.
 
 def _scan_of(kind, fn, G):
     def run(table, meta, stack):
         def step(table, pkx):
-            return fn(table, meta, pkx)
+            table, row, dense = fn(table, meta, pkx)
+            return table, (row, dense)
 
-        return jax.lax.scan(step, table, stack)
+        table, (rows, dense) = jax.lax.scan(step, table, stack)
+        return table, rows, dense
 
     return _jit_as(f"scan_{kind}_g{G}", run)
 
@@ -1289,17 +1295,22 @@ def seal_scalars(pk: np.ndarray, n: int, ts_base: int) -> np.ndarray:
     return pk
 
 
-def unpack_summary(row: np.ndarray) -> dict:
-    """Decode one (SUMMARY_WORDS,) u64 summary row."""
+def unpack_summary(row: np.ndarray, dense: np.ndarray | None = None) -> dict:
+    """Decode one (SUMMARY_WORDS,) u64 summary row; a batch with more
+    than FAIL_CAP failures needs its `dense` (B,) u32 codes too."""
     n_fail = int(row[0])
     flags = int(row[1])
-    entries = row[4 : 4 + min(n_fail, FAIL_CAP)]
-    idx = (entries >> np.uint64(32)).astype(np.int64)
-    codes = (entries & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    if n_fail > FAIL_CAP:
+        idx = np.flatnonzero(dense)
+        codes = dense[idx].astype(np.uint32)
+        assert len(idx) == n_fail, (len(idx), n_fail)
+    else:
+        entries = row[4 : 4 + n_fail]
+        idx = (entries >> np.uint64(32)).astype(np.int64)
+        codes = (entries & np.uint64(0xFFFFFFFF)).astype(np.uint32)
     return {
         "n_fail": n_fail,
         "overflow": bool(flags & FLAG_OVERFLOW),
-        "cap_exceeded": bool(flags & FLAG_CAP) or n_fail > FAIL_CAP,
         "precond": bool(flags & FLAG_PRECOND),
         "iters": flags >> ITERS_SHIFT,
         "last_applied": int(row[2]) - 1,

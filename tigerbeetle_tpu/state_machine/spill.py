@@ -83,9 +83,13 @@ class TransferSpill:
     """Spilled (immutable) transfer rows in a groove; `base` rows
     [0, base) live here, the store's RAM tail holds [base, count)."""
 
-    def __init__(self, groove, attrs_fn=None, barrier=_no_barrier) -> None:
+    def __init__(self, groove, attrs_fn=None, barrier=_no_barrier,
+                 overwrites=None) -> None:
         self.groove = groove
         self.barrier = barrier
+        # Counter of the rows update_status rewrote (the owning
+        # machine's sm.store.status_overwrites), if anyone counts.
+        self._overwrites = overwrites
         self.base = 0
         # Account attrs accessor for id reconstruction at gather:
         # dr/cr ACCOUNT IDS are derivable from the stored slots (slots
@@ -189,6 +193,8 @@ class TransferSpill:
         obj = self._lookup_raw(rows)  # joins the worker
         obj[:, 136] = np.asarray(statuses, np.uint8)
         self.groove.object_tree.put_batch(_row_keys(rows), obj)
+        if self._overwrites is not None:
+            self._overwrites.inc(len(rows))
 
     def iter_objects(self, batch: int = 8192):
         """Yield (rows, objects) over all spilled rows ascending —

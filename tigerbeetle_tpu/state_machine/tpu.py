@@ -24,6 +24,7 @@ post-processing ~ the groove inserts the reference does inline.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import time as _time
 
@@ -213,9 +214,14 @@ class TailStore:
     the expiry index, and the native library all keep global rows.
     """
 
-    def __init__(self, fields: dict, capacity: int = 1024) -> None:
+    def __init__(self, fields: dict, capacity: int = 1024,
+                 cold_join=None) -> None:
         self.ram = Columns(fields, capacity)
         self.base = 0
+        # `cold_join(n)` -> a context manager around a join of `n`
+        # rows that have spilled (the owning machine's sm.plan.join_cold
+        # stage and sm.store.join_cold_rows counter).
+        self.cold_join = cold_join or (lambda n: contextlib.nullcontext())
         # Dead physical rows at the front of `ram` (already spilled):
         # drop_prefix advances this offset in O(1) and compacts only
         # when dead rows dominate — per-beat spills must not pay an
@@ -273,7 +279,8 @@ class TailStore:
             phys = self._phys(rows)
             return {n: self.ram[n][phys] for n in names}
         cold_rows = rows[~in_ram]
-        cold = spill_mod.unpack_objects(self.spill.gather(cold_rows))
+        with self.cold_join(len(cold_rows)):
+            cold = spill_mod.unpack_objects(self.spill.gather(cold_rows))
         phys = np.maximum(self._phys(rows), 0)
         out = {}
         for n in names:
@@ -421,6 +428,16 @@ class TpuStateMachine:
         self._st_plan = tracer_mod.Stage(
             self.metrics.histogram("plan_us"), "sm.plan"
         )
+        # sm.plan.join_cold: inside it, a join on transfer rows that
+        # have left the RAM tail (a post's or a void's pending, a
+        # lookup's rows): point reads of the forest's object tree.
+        self._st_join_cold = tracer_mod.Stage(
+            self.metrics.histogram("plan.join_cold_us"), "sm.plan.join_cold"
+        )
+        self._c_join_cold_rows = _c("store.join_cold_rows")
+        # Spilled pendings whose status byte a post or a void rewrote
+        # (an LSM overwrite of the row's key).
+        self._c_status_overwrites = _c("store.status_overwrites")
         # Per-request anatomy hook (obs/anatomy.py): the owning
         # Replica shares its recorder and stamps the current prepare's
         # trace id before each commit, so commit_async can attribute
@@ -512,7 +529,8 @@ class TpuStateMachine:
         # Transfer state.
         self._tdir = RunIndex(_dir_capacity(transfer_capacity))
         self._store = TailStore(
-            _STORE_FIELDS, capacity=max(1024, transfer_capacity)
+            _STORE_FIELDS, capacity=max(1024, transfer_capacity),
+            cold_join=self._cold_join,
         )
         # expires_at index: (expires_at, row, active).  Rows are GLOBAL
         # store rows; live pendings never spill, so active entries
@@ -561,6 +579,10 @@ class TpuStateMachine:
     stat_dev_wave_steps = obs_stat_property("stat_dev_wave_steps")
     stat_dev_wave_events = obs_stat_property("stat_dev_wave_events")
     stat_dev_wave_plan_s = obs_stat_property("stat_dev_wave_plan_s")
+
+    def _cold_join(self, rows: int):
+        self._c_join_cold_rows.inc(rows)
+        return self.tracer.stage(self._st_join_cold)
 
     def set_tracer(self, tracer) -> None:
         self.tracer = tracer
@@ -840,7 +862,8 @@ class TpuStateMachine:
             index_fields=[],
         )
         self._store.spill = spill_mod.TransferSpill(
-            transfers, attrs_fn=lambda: self._attrs, barrier=forest.barrier
+            transfers, attrs_fn=lambda: self._attrs, barrier=forest.barrier,
+            overwrites=self._c_status_overwrites,
         )
         self._hspill = spill_mod.HistorySpill(history, barrier=forest.barrier)
 
@@ -1534,8 +1557,10 @@ class TpuStateMachine:
         if not (has_linked or has_pv) and not touch_limit_hist:
             submit = self._submit_device_orderfree(**common)
         elif (
-            has_linked
-            and not (has_pending or has_pv)
+            # Chains, and plain posted transfers over accounts under a
+            # balance limit (each its own chain of one): the linked
+            # kernel's fixpoint decides both in order.
+            not (has_pending or has_pv)
             and not touch_hist
             and not amount_hi.any()
         ):
@@ -4102,7 +4127,7 @@ def _tpu_restore(self, data: bytes) -> None:
 
     self._attrs = Columns(_ATTR_FIELDS)
     self._attrs.append(**state["attrs"])
-    self._store = TailStore(_STORE_FIELDS)
+    self._store = TailStore(_STORE_FIELDS, cold_join=self._cold_join)
     self._store.append(**state["store"])
     self._exp = Columns(
         {"expires_at": np.uint64, "row": np.uint32, "active": np.bool_}
@@ -4123,6 +4148,7 @@ def _tpu_restore(self, data: bytes) -> None:
             self._forest.grooves["transfers"],
             attrs_fn=lambda: self._attrs,
             barrier=self._forest.barrier,
+            overwrites=self._c_status_overwrites,
         )
         self._store.spill.base = base
         self._store.base = base
