@@ -230,6 +230,110 @@ def test_tree_scales_past_memtable():
 
 
 # ----------------------------------------------------------------------
+# lookup_batch passes over a memtable batch or a run whose key span
+# misses every unresolved key (PR 34).
+
+
+def _lookup_every_source(tree, keys):
+    """lookup_batch as it was without the span check: every memtable
+    batch and every run is searched for every unresolved key."""
+    n = len(keys)
+    found = np.zeros(n, bool)
+    resolved = np.zeros(n, bool)
+    values = np.zeros((n, tree.value_size), np.uint8)
+    for bkeys, bflags, bvals in reversed(tree.memtable):
+        todo = np.flatnonzero(~resolved)
+        pos = np.minimum(np.searchsorted(bkeys, keys[todo]), len(bkeys) - 1)
+        hit = bkeys[pos] == keys[todo]
+        resolved[todo[hit]] = True
+        live = hit & (bflags[pos] == 0)
+        found[todo[live]] = True
+        values[todo[live]] = bvals[pos[live]]
+    for run in tree._runs_newest_first():
+        todo = np.flatnonzero(~resolved)
+        if len(todo):
+            tree._run_lookup(run, keys, todo, found, resolved, values)
+    return found, values
+
+
+def _fuzzed_tree(seed):
+    """A tree as testing/fuzz.py fuzz_tree grows them: overlapping
+    runs on several levels, overwrites, tombstones, a job in flight."""
+    rng = np.random.default_rng(seed)
+    t = Tree(grid(), "fuzz", value_size=8, memtable_max=64)
+    lo, hi = 1000, 1500
+    for _ in range(400):
+        roll = rng.random()
+        if roll < 0.6:
+            ids = rng.integers(lo, hi, int(rng.integers(1, 40))).astype(np.uint64)
+            t.put_batch(keys_of(ids), rng.integers(0, 1 << 62, len(ids)).astype(np.uint64))
+        elif roll < 0.8:
+            ids = rng.integers(lo, hi, int(rng.integers(1, 20))).astype(np.uint64)
+            t.remove_batch(keys_of(ids))
+        elif roll < 0.9:
+            t.seal_memtable()
+        else:
+            t.maybe_seal()
+        if rng.random() < 0.3:
+            t.compact_beat(int(rng.integers(1, 6)))
+    return t, rng, lo, hi
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+@pytest.mark.parametrize("where", ["below", "inside", "above", "across"])
+def test_lookup_with_the_span_check_answers_as_without(seed, where):
+    t, rng, lo, hi = _fuzzed_tree(seed)
+    assert sum(len(level) for level in t.levels) >= 3
+    probe = {
+        "below": rng.integers(0, lo, 64),
+        "inside": rng.integers(lo, hi, 64),
+        "above": rng.integers(hi, 2 * hi, 64),
+        "across": rng.integers(0, 2 * hi, 64),
+    }[where].astype(np.uint64)
+    # Narrow batches too: they miss some runs and meet others.
+    for keys in (keys_of(probe), keys_of(np.sort(probe)[:5]), keys_of(probe[:1])):
+        found, values = t.lookup_batch(keys)
+        want_found, want_values = _lookup_every_source(t, keys)
+        np.testing.assert_array_equal(found, want_found)
+        np.testing.assert_array_equal(values, want_values)
+    if where in ("below", "above"):
+        assert not found.any()
+
+
+def test_lookup_reads_no_block_of_a_run_it_passes_over():
+    """Row-keyed runs (each seal covers the rows above the one before,
+    as the spill tier's object trees do): a batch of old rows meets one
+    run, the rest are passed over on their spans, none of their blocks
+    read; a key no run holds visits none at all."""
+    t = Tree(grid(), "rows", value_size=8, memtable_max=1 << 20)
+    per_run = 500
+    for r in range(6):
+        rows = np.arange(r * per_run, (r + 1) * per_run, dtype=np.uint64)
+        t.put_batch(keys_of(rows), rows * 3)
+        t.seal_memtable()
+    tail = np.arange(6 * per_run, 6 * per_run + 100, dtype=np.uint64)
+    t.put_batch(keys_of(tail), tail * 3)       # a memtable batch above all
+    runs = list(t._runs_newest_first())
+    assert len(runs) == 6
+    read = []
+    read_block = t._read_run_block
+    t._read_run_block = lambda block: (read.append(block.address), read_block(block))[1]
+
+    rows = np.arange(per_run + 10, per_run + 200, dtype=np.uint64)   # in run 1
+    found, values = t.lookup_batch(keys_of(rows))
+    assert found.all()
+    np.testing.assert_array_equal(values.view("<u8").reshape(-1), rows * 3)
+    assert t.stats.runs_consulted.value == 1
+    assert t.stats.runs_skipped.value == 4     # runs 5..2; run 0 is never reached
+    assert set(read) <= {b.address for b in t.levels[0][1].blocks}
+
+    read.clear()
+    found, _ = t.lookup_batch(keys_of(np.array([10 * per_run], np.uint64)))
+    assert not found.any() and not read
+    assert t.stats.runs_consulted.value == 1 and t.stats.runs_skipped.value == 10
+
+
+# ----------------------------------------------------------------------
 # Scan builder (lsm/scan_builder.py): condition trees over indexes.
 
 

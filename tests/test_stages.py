@@ -243,11 +243,18 @@ BEAT_KEYS = {
     "lsm.beat.bound_wait_us.count", "lsm.beat.bound_wait_us.sum",
     "lsm.barrier.joins", "lsm.barrier.wait_us.sum", "lsm.beat.queued",
 }
-# What compaction did, over the forest's trees (lsm/tree.py
-# CompactionStats): four counters and a gauge.
+# What compaction and point reads did, over the forest's trees
+# (lsm/tree.py TreeStats): six counters and a gauge.
 COMPACT_KEYS = {
     "lsm.compact.jobs", "lsm.compact.moves", "lsm.compact.entries_in",
     "lsm.compact.entries_out", "lsm.tree.runs_peak",
+    "lsm.lookup.runs_consulted", "lsm.lookup.runs_skipped",
+}
+# The posted groove (state_machine/spill.py): statuses written to it,
+# rows a read asked it about, and of them found.
+POSTED_KEYS = {
+    "sm.store.status_overwrites", "sm.store.posted_lookups",
+    "sm.store.posted_hits",
 }
 # What only a cluster's replicas open: the primary's hand-over of a
 # prepare to the backups' connections, a backup's run of prepares.
@@ -356,9 +363,9 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     on_the_path = LEAVES - REPLICATION_LEAVES - WORKER_LEAVES - {
         "vsr.ckpt.freeze", "sm.plan.join_cold"}
     assert snap["sm.plan.join_cold_us.count"] == 0
-    assert snap["sm.store.join_cold_rows"] == snap["sm.store.status_overwrites"] == 0
-    assert BEAT_KEYS | COMPACT_KEYS <= set(snap)
-    assert not any(snap[key] for key in COMPACT_KEYS)     # nothing sealed
+    assert snap["sm.store.join_cold_rows"] == 0
+    assert BEAT_KEYS | COMPACT_KEYS | POSTED_KEYS <= set(snap)
+    assert not any(snap[key] for key in COMPACT_KEYS | POSTED_KEYS)   # nothing sealed or cold
     assert snap["lsm.beat.work_us.count"] == snap["lsm.beat.queued"] == 0
     for name in REPLICATION_LEAVES:
         assert snap[name + "_us.count"] == 0, name

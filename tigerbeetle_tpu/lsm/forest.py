@@ -20,7 +20,7 @@ from tigerbeetle_tpu import obs
 from tigerbeetle_tpu.lsm.beats import BeatWorker
 from tigerbeetle_tpu.lsm.groove import Groove
 from tigerbeetle_tpu.lsm.manifest_log import ManifestLog
-from tigerbeetle_tpu.lsm.tree import CompactionStats
+from tigerbeetle_tpu.lsm.tree import TreeStats
 from tigerbeetle_tpu.utils import snapshot as snapcodec
 from tigerbeetle_tpu.vsr.free_set import FreeSet
 from tigerbeetle_tpu.vsr.grid import Grid
@@ -65,7 +65,7 @@ class Forest:
         self.metrics = obs.Registry()
         self.beats = BeatWorker(self.metrics, beat_worker and file_backed)
         # What compaction did, over all trees (`lsm.compact.*`).
-        self.stats = CompactionStats(self.metrics)
+        self.stats = TreeStats(self.metrics)
 
     def barrier(self) -> None:
         """Join the beats handed to the worker: before anything on

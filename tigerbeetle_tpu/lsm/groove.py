@@ -33,10 +33,13 @@ class Groove:
         )
         # Objects are mostly-zero wire images (reserved user_data,
         # zeroed reconstructible fields, high u128 limbs): sparse-value
-        # blocks halve the dominant seal/merge write volume.
+        # blocks halve the dominant seal/merge write volume.  A value
+        # of one 8-byte group has nothing to leave out: the mask would
+        # only add its 4 bytes an entry.
         self.object_tree = Tree(
             grid, f"{name}.object", value_size=object_size,
-            memtable_max=memtable_max, sparse_values=object_size % 8 == 0,
+            memtable_max=memtable_max,
+            sparse_values=object_size > 8 and object_size % 8 == 0,
         )
         # index_value_size=8 stores a row/object pointer per index entry
         # (the state machine's spill tier scans indexes straight to

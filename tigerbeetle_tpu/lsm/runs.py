@@ -30,6 +30,20 @@ def key_words(keys: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return w[:, 0], w[:, 1]
 
 
+def key_span(keys: np.ndarray) -> tuple[bytes, bytes]:
+    """Smallest and largest of a non-empty V16 key array, as the bytes
+    a run's `key_min`/`key_max` compare with (void dtypes have no
+    min/max; the big-endian words order as the keys do)."""
+    w0, w1 = key_words(keys)
+    lo0, hi0 = w0.min(), w0.max()
+    lo1 = w1[w0 == lo0].min()
+    hi1 = w1[w0 == hi0].max()
+    return (
+        int(lo0).to_bytes(8, "big") + int(lo1).to_bytes(8, "big"),
+        int(hi0).to_bytes(8, "big") + int(hi1).to_bytes(8, "big"),
+    )
+
+
 def keys_le(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     """Elementwise a <= b for V16 keys (void dtypes lack ordering
     ufuncs; sort/searchsorted still use memcmp order)."""

@@ -20,7 +20,7 @@ import dataclasses
 import numpy as np
 
 from tigerbeetle_tpu import obs
-from tigerbeetle_tpu.lsm.runs import KEY_DTYPE, keys_le, pack_u128
+from tigerbeetle_tpu.lsm.runs import KEY_DTYPE, key_span, keys_le, pack_u128
 from tigerbeetle_tpu.vsr.grid import Grid
 
 LEVELS = 7          # reference: src/config.zig lsm_levels
@@ -72,10 +72,11 @@ class Run:
         return self.blocks[-1].key_max
 
 
-class CompactionStats:
-    """What compaction did, counted where it happens.  A forest makes
-    one on its registry (scrape: `lsm.compact.*`, `lsm.tree.runs_peak`)
-    and shares it among its trees; a tree alone counts on its own."""
+class TreeStats:
+    """What compaction and point reads did, counted where it happens.
+    A forest makes one on its registry (scrape: `lsm.compact.*`,
+    `lsm.tree.runs_peak`, `lsm.lookup.*`) and shares it among its
+    trees; a tree alone counts on its own."""
 
     def __init__(self, registry: obs.Registry) -> None:
         self.jobs = registry.counter("compact.jobs")
@@ -85,6 +86,10 @@ class CompactionStats:
         self.entries_out = registry.counter("compact.entries_out")
         # The most runs any one tree held: what a read may consult.
         self.runs_peak = registry.gauge("tree.runs_peak")
+        # Runs a point read reached with keys unresolved: those whose
+        # key span met the keys', and those it passed over unread.
+        self.runs_consulted = registry.counter("lookup.runs_consulted")
+        self.runs_skipped = registry.counter("lookup.runs_skipped")
 
 
 class Tree:
@@ -103,7 +108,7 @@ class Tree:
         # rewrites (reference: src/lsm/manifest_log.zig).
         self.tree_id = 0
         self.mlog = None
-        self.stats = CompactionStats(obs.Registry(enabled=False))
+        self.stats = TreeStats(obs.Registry(enabled=False))
         self._next_run_id = 0
         # Memtable: list of individually-sorted columnar batches
         # (keys KEY_DTYPE, flags u8, values (n, value_size) u8), newest
@@ -182,32 +187,53 @@ class Tree:
 
         Newest wins: memtable, then level 0 runs newest-first, then
         deeper levels.  Tombstones report not-found.
+
+        A memtable batch or a run whose key span misses every
+        unresolved key holds none of them and is passed over on two
+        compares.  Row- and timestamp-keyed trees are near-monotone, so
+        that is nearly every one; the span is taken again only after
+        something resolved.
         """
         n = len(keys)
         found = np.zeros(n, bool)
         resolved = np.zeros(n, bool)
         values = np.zeros((n, self.value_size), np.uint8)
+        todo = None
 
         for bkeys, bflags, bvals in reversed(self.memtable):
-            todo = np.flatnonzero(~resolved)
-            if len(todo) == 0:
-                break
+            if todo is None:
+                todo = np.flatnonzero(~resolved)
+                if len(todo) == 0:
+                    return found, values
+                span_min, span_max = key_span(keys[todo])
+            if bkeys[-1].tobytes() < span_min or span_max < bkeys[0].tobytes():
+                continue
             sub = keys[todo]
             pos = np.searchsorted(bkeys, sub)
             pos_c = np.minimum(pos, len(bkeys) - 1)
             hit = bkeys[pos_c] == sub
+            if not hit.any():
+                continue
             hi = todo[hit]
             p = pos_c[hit]
             resolved[hi] = True
             live = bflags[p] == 0
             found[hi[live]] = True
             values[hi[live]] = bvals[p[live]]
+            todo = None
 
         for run in self._runs_newest_first():
-            todo = np.flatnonzero(~resolved)
-            if len(todo) == 0:
-                break
-            self._run_lookup(run, keys, todo, found, resolved, values)
+            if todo is None:
+                todo = np.flatnonzero(~resolved)
+                if len(todo) == 0:
+                    break
+                span_min, span_max = key_span(keys[todo])
+            if run.key_max < span_min or span_max < run.key_min:
+                self.stats.runs_skipped.inc()
+                continue
+            self.stats.runs_consulted.inc()
+            if self._run_lookup(run, keys, todo, found, resolved, values):
+                todo = None
         return found, values
 
     def _runs_newest_first(self):
@@ -215,13 +241,16 @@ class Tree:
             for run in reversed(self.levels[level]):
                 yield run
 
-    def _run_lookup(self, run: Run, keys, todo, found, resolved, values):
+    def _run_lookup(self, run: Run, keys, todo, found, resolved,
+                    values) -> int:
+        """Resolve what `run` holds of keys[todo]; -> how many."""
         fences = np.array([b.key_min for b in run.blocks], KEY_DTYPE)
         maxes = np.array([b.key_max for b in run.blocks], KEY_DTYPE)
         sub = keys[todo]
         # Candidate block per key: rightmost block whose min <= key.
         bi = np.searchsorted(fences, sub, side="right") - 1
         in_range = (bi >= 0) & keys_le(sub, maxes[np.clip(bi, 0, None)])
+        hits = 0
         for block_index in np.unique(bi[in_range]):
             mask = in_range & (bi == block_index)
             idx = todo[mask]
@@ -237,6 +266,8 @@ class Tree:
             live = bflags[p] == 0
             found[hi[live]] = True
             values[hi[live]] = bvalues[p[live]]
+            hits += len(hi)
+        return hits
 
     def _read_run_block(self, block: RunBlock):
         payload = self.grid.read_block(block.address)

@@ -21,15 +21,24 @@ src/lsm/groove.zig):
   (reference: src/state_machine.zig:931-996).
 - history tree: key = transfer timestamp (unique), value = packed
   dr/cr balance snapshots for get_account_balances.
+- posted groove (`transfers_posted`; reference: src/state_machine.zig
+  PostedGroove, value `fulfillment`): key = the PENDING's global row,
+  as the object tree's, value = 8 bytes, byte 0 the
+  TransferPendingStatus a post, a void or an expiry gave it.
 
-Spilled objects are immutable; `gather` serves reads for exists-ladder
-joins, lookup_transfers, and query materialization.
+Spilled objects are immutable: written once by `spill`, never again.
+A pending that is finalised after it has left the RAM tail keeps its
+object as it was spilled (status `pending`) and gains an entry in the
+posted groove; `gather`, the one door for exists-ladder joins,
+lookup_transfers and query materialization, lays that entry over the
+object's status byte.  So the object tree's keys only ever rise, its
+runs stay disjoint and compaction moves them.
 
 `TransferSpill.spill` is the one method that runs on the forest's beat
 worker (lsm/beats.py), in commit order.  Every other method here is
 the loop's and joins the worker first (`barrier`): rows the loop has
 handed over are below `base` for it and must be in the trees before
-it reads or rewrites them.
+it reads them or writes their status.
 """
 
 from __future__ import annotations
@@ -37,15 +46,21 @@ from __future__ import annotations
 import numpy as np
 
 from tigerbeetle_tpu.lsm.runs import pack_u128
+from tigerbeetle_tpu.types import TransferPendingStatus
 
 # Spilled transfer object layout (little-endian), 144 bytes:
 #   0..128  wire Transfer image (types.py TRANSFER_DTYPE, incl.
 #           timestamp at 120)
 # 128..132  dr_slot  i32
 # 132..136  cr_slot  i32
-# 136..137  status   u8 (TransferPendingStatus; final by spill time)
+# 136..137  status   u8 (TransferPendingStatus AS SPILLED; a `pending`
+#           finalised later has its status in the posted groove)
 # 137..144  pad
 TRANSFER_OBJECT_SIZE = 144
+_STATUS_BYTE = 136
+
+# Posted object: byte 0 the status, 7 of pad (one 8-byte group).
+POSTED_OBJECT_SIZE = 8
 
 # Spilled history object layout, 160 bytes total:
 #   0..16   dr account id (lo, hi)
@@ -81,15 +96,21 @@ def _row_keys(rows: np.ndarray) -> np.ndarray:
 
 class TransferSpill:
     """Spilled (immutable) transfer rows in a groove; `base` rows
-    [0, base) live here, the store's RAM tail holds [base, count)."""
+    [0, base) live here, the store's RAM tail holds [base, count).
+    `posted` is the groove of the statuses their finalisers gave."""
 
-    def __init__(self, groove, attrs_fn=None, barrier=_no_barrier,
-                 overwrites=None) -> None:
+    def __init__(self, groove, posted, counters, attrs_fn=None,
+                 barrier=_no_barrier) -> None:
         self.groove = groove
+        self.posted = posted
         self.barrier = barrier
-        # Counter of the rows update_status rewrote (the owning
-        # machine's sm.store.status_overwrites), if anyone counts.
-        self._overwrites = overwrites
+        # `counters`: the owning machine's registry under `sm.store.`.
+        # Rows whose status went to the posted tree; rows a read asked
+        # the posted tree about (stored as `pending`), and how many of
+        # them it held.
+        self._c_overwrites = counters.counter("status_overwrites")
+        self._c_posted_lookups = counters.counter("posted_lookups")
+        self._c_posted_hits = counters.counter("posted_hits")
         self.base = 0
         # Account attrs accessor for id reconstruction at gather:
         # dr/cr ACCOUNT IDS are derivable from the stored slots (slots
@@ -132,7 +153,7 @@ class TransferSpill:
         obj[:, 132:136] = (
             cols["cr_slot"].astype(np.int32).view(np.uint8).reshape(n, 4)
         )
-        obj[:, 136] = cols["status"].astype(np.uint8)
+        obj[:, _STATUS_BYTE] = cols["status"].astype(np.uint8)
 
         ts = cols["timestamp"].astype(np.uint64)
         self.groove.object_tree.put_batch(_row_keys(rows), obj)
@@ -152,14 +173,17 @@ class TransferSpill:
         # Seal overflowing memtables NOW: paced spill beats must turn
         # into bounded level-0 runs per beat, not one giant run at the
         # checkpoint (which would re-create the latency cliff the
-        # beats exist to remove).
+        # beats exist to remove).  The posted tree's too: its entries
+        # arrive on the loop's side, its seals happen here.
         self.groove.maybe_seal()
+        self.posted.maybe_seal()
         self.base += n
 
     # -- read ----------------------------------------------------------
 
     def _lookup_raw(self, rows: np.ndarray) -> np.ndarray:
-        """Raw on-disk objects (ids NOT reconstructed) for rows < base."""
+        """Raw on-disk objects (ids NOT reconstructed, status as
+        spilled) for rows < base."""
         self.barrier()
         found, vals = self.groove.object_tree.lookup_batch(_row_keys(rows))
         assert found.all(), "spilled row missing from object tree"
@@ -168,6 +192,18 @@ class TransferSpill:
     def gather(self, rows: np.ndarray) -> np.ndarray:
         """Global rows (< base) -> (n, TRANSFER_OBJECT_SIZE) u8."""
         vals = self._lookup_raw(rows)
+        # Only an object spilled as `pending` can have been finalised
+        # since; any other status is final where it stands.
+        pending = np.flatnonzero(
+            vals[:, _STATUS_BYTE] == int(TransferPendingStatus.pending)
+        )
+        if len(pending):
+            found, status = self.posted.object_tree.lookup_batch(
+                _row_keys(np.asarray(rows)[pending])
+            )
+            self._c_posted_lookups.inc(len(pending))
+            self._c_posted_hits.inc(int(found.sum()))
+            vals[pending[found], _STATUS_BYTE] = status[found, 0]
         if self._attrs_fn is not None:
             vals = self._reconstruct_ids(vals)
         return vals
@@ -186,15 +222,17 @@ class TransferSpill:
         return obj
 
     def update_status(self, rows: np.ndarray, statuses: np.ndarray) -> None:
-        """Finalize spilled pendings: rewrite their objects with the
-        new status (LSM overwrite; newest version wins on read).  The
-        only mutable byte of a spilled object — everything else is
-        immutable after spill."""
-        obj = self._lookup_raw(rows)  # joins the worker
-        obj[:, 136] = np.asarray(statuses, np.uint8)
-        self.groove.object_tree.put_batch(_row_keys(rows), obj)
-        if self._overwrites is not None:
-            self._overwrites.inc(len(rows))
+        """Finalize spilled pendings: one posted-tree entry each.
+        Their objects are neither read nor written."""
+        self.barrier()  # the tree is the forest's, the worker's till now
+        # Ascending rows are ascending keys: put_batch then skips its
+        # void-dtype argsort (as spill's index entries do).
+        rows = np.asarray(rows)
+        order = np.argsort(rows, kind="stable")
+        value = np.zeros((len(rows), POSTED_OBJECT_SIZE), np.uint8)
+        value[:, 0] = np.asarray(statuses, np.uint8)[order]
+        self.posted.object_tree.put_batch(_row_keys(rows[order]), value)
+        self._c_overwrites.inc(len(rows))
 
     def iter_objects(self, batch: int = 8192):
         """Yield (rows, objects) over all spilled rows ascending —
@@ -228,7 +266,7 @@ def unpack_objects(obj: np.ndarray) -> dict:
     out["cr_slot"] = (
         np.ascontiguousarray(obj[:, 132:136]).view(np.int32).reshape(n)
     )
-    out["status"] = obj[:, 136].copy()
+    out["status"] = obj[:, _STATUS_BYTE].copy()
     out["dr_id_lo"] = np.ascontiguousarray(obj[:, 16:24]).view(np.uint64).reshape(n)
     out["dr_id_hi"] = np.ascontiguousarray(obj[:, 24:32]).view(np.uint64).reshape(n)
     out["cr_id_lo"] = np.ascontiguousarray(obj[:, 32:40]).view(np.uint64).reshape(n)

@@ -199,8 +199,9 @@ class _GlobalCol:
                 rows[in_ram] - base + self._store._off
             ] = values[in_ram]
         if not in_ram.all():
-            # Spilled objects are immutable EXCEPT the pending status
-            # byte, which post/void/expiry finalize in place.
+            # Spilled objects are immutable: the status a post, a void
+            # or an expiry gives a spilled pending goes to the posted
+            # groove (spill.py), nothing else is ever written.
             assert self._name == "status", "write to spilled row"
             self._store.spill.update_status(rows[~in_ram], values[~in_ram])
 
@@ -435,9 +436,6 @@ class TpuStateMachine:
             self.metrics.histogram("plan.join_cold_us"), "sm.plan.join_cold"
         )
         self._c_join_cold_rows = _c("store.join_cold_rows")
-        # Spilled pendings whose status byte a post or a void rewrote
-        # (an LSM overwrite of the row's key).
-        self._c_status_overwrites = _c("store.status_overwrites")
         # Per-request anatomy hook (obs/anatomy.py): the owning
         # Replica shares its recorder and stamps the current prepare's
         # trace id before each commit, so commit_async can attribute
@@ -861,9 +859,18 @@ class TpuStateMachine:
             object_size=spill_mod.HISTORY_OBJECT_SIZE,
             index_fields=[],
         )
+        # The status of a pending finalised after it spilled, keyed by
+        # the pending's row.  Declared LAST: a tree's id is its place
+        # in this order, and a data file's manifest log names trees by
+        # id.
+        posted = forest.groove(
+            "transfers_posted",
+            object_size=spill_mod.POSTED_OBJECT_SIZE,
+            index_fields=[],
+        )
         self._store.spill = spill_mod.TransferSpill(
-            transfers, attrs_fn=lambda: self._attrs, barrier=forest.barrier,
-            overwrites=self._c_status_overwrites,
+            transfers, posted, self.metrics.scope("store"),
+            attrs_fn=lambda: self._attrs, barrier=forest.barrier,
         )
         self._hspill = spill_mod.HistorySpill(history, barrier=forest.barrier)
 
@@ -909,7 +916,7 @@ class TpuStateMachine:
 
     def checkpoint_spill(self) -> None:
         """Move the whole RAM tail into the LSM tier — including live
-        pendings, whose status byte stays mutable through
+        pendings, whose later status goes to the posted groove through
         TransferSpill.update_status (a stuck pending must not pin every
         later row in RAM).  Called by the replica at checkpoint —
         deterministic across replicas (state-dependent only), keeping
@@ -3705,9 +3712,13 @@ class TpuStateMachine:
         if len(changed):
             ch_rows = uniq_rows[changed]
             self._store["status"][ch_rows] = dstat[changed].astype(np.uint8)
-            timeouts = self._store["timeout"][ch_rows]
-            for row in ch_rows[np.asarray(timeouts) > 0]:
-                self._exp_deactivate(int(row))
+            # A pending with a timeout is one with an active entry in
+            # the expires index, which is in RAM: no read of the store
+            # (the rows may be cold) to learn which they are.
+            if self._exp.count > self._exp_dead:
+                timed = self._exp.col("row")[self._exp.col("active")]
+                for row in ch_rows[np.isin(ch_rows, timed)]:
+                    self._exp_deactivate(int(row))
 
         # 3. New expires entries for still-pending in-batch creations.
         pend_created = np.flatnonzero(
@@ -4146,9 +4157,10 @@ def _tpu_restore(self, data: bytes) -> None:
         self._forest.open(state["forest"])
         self._store.spill = spill_mod.TransferSpill(
             self._forest.grooves["transfers"],
+            self._forest.grooves["transfers_posted"],
+            self.metrics.scope("store"),
             attrs_fn=lambda: self._attrs,
             barrier=self._forest.barrier,
-            overwrites=self._c_status_overwrites,
         )
         self._store.spill.base = base
         self._store.base = base
