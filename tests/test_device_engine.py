@@ -1270,3 +1270,159 @@ def test_a_fault_at_each_crossing_of_a_prepare_demotes_once(stage):
     assert dev.stat_degraded_events == 3 and not dev.has_inflight()
     look = (Operation.lookup_accounts, hz.ids_bytes([1, 2, 3]))
     assert h_d.submit(*look) == h_c.submit(*look)
+
+
+# ----------------------------------------------------------------------
+# The shape `bench1r-tpcc-pay-c4` brings (PR 35): two-leg chains
+# customer -> district -> warehouse, one row party to every chain, a
+# hundredth of the chains failing statically.
+
+_HOT_WAREHOUSE, _HOT_DISTRICTS, _HOT_CUSTOMERS = 1, 5, 600
+
+
+def _hot_row_accounts():
+    n = 1 + _HOT_DISTRICTS + _HOT_CUSTOMERS
+    return (Operation.create_accounts, accounts(range(1, n + 1)))
+
+
+def _hot_row_batch(rng, first_id: int, chains: int, fail_share: float):
+    """`chains` payments: customer -> district (`linked`), district ->
+    warehouse 1; a share of them names a warehouse that does not exist
+    in its second leg.  -> (op, body), chains failing"""
+    t = np.zeros(2 * chains, types.TRANSFER_DTYPE)
+    t["id_lo"] = first_id + np.arange(2 * chains)
+    t["ledger"], t["code"] = 1, 25
+    district = 2 + rng.integers(0, _HOT_DISTRICTS, chains)
+    fails = rng.random(chains) < fail_share
+    fails[rng.integers(0, chains)] = True
+    leg1, leg2 = t[0::2], t[1::2]
+    leg1["debit_account_id_lo"] = 2 + _HOT_DISTRICTS + rng.integers(
+        0, _HOT_CUSTOMERS, chains)
+    leg1["credit_account_id_lo"] = leg2["debit_account_id_lo"] = district
+    leg1["flags"] = int(TF.linked)
+    leg2["credit_account_id_lo"] = np.where(fails, 9_999, _HOT_WAREHOUSE)
+    leg1["amount_lo"] = leg2["amount_lo"] = rng.integers(100, 500_001, chains)
+    return (Operation.create_transfers, t.tobytes()), int(fails.sum())
+
+
+@pytest.mark.parametrize("seed", [35, 2**31 + 35])
+def test_chains_through_one_hot_row_across_a_checkpoint_and_a_restore(seed):
+    """Codes, balances and the state root against the oracle, with a
+    checkpoint in the middle of the stream and the rest of it served
+    by a machine restored from that checkpoint; and the gauges and
+    histograms PR 35 added read what the stream makes them read."""
+    rng = np.random.default_rng(seed)
+    chains, batches = 128, 6
+    n_accounts = 1 + _HOT_DISTRICTS + _HOT_CUSTOMERS
+    h_d, h_c = mk_pair()
+    replay_both(h_d, h_c, [_hot_row_accounts()])
+    snap = h_d.sm.metrics.snapshot()
+    assert snap["accounts"] == n_accounts and snap["dev.table_rows"] == 1 << 12
+    # Account creation samples the histograms too (the rows whose meta
+    # changed), and so does a restore: the stream's are counted from it.
+    failing = 0
+    for b in range(batches):
+        if b == batches // 2:
+            assert h_d.sm.state_root() == h_c.sm.state_root()
+            blob = h_d.sm.snapshot()            # the barrier runs the verify
+            restored = TpuStateMachine(engine="device", account_capacity=1 << 12)
+            restored.restore(blob)
+            assert restored.state_root() == h_c.sm.state_root()
+            op = h_d.op
+            h_d = hz.SingleNodeHarness(restored)
+            h_d.op = op
+            at_restore = restored.metrics.snapshot()
+        op_body, n_fail = _hot_row_batch(rng, 10_000 + b * 1_000, chains, 0.01)
+        failing += n_fail
+        (reply,) = replay_both(h_d, h_c, [op_body])
+        codes = np.frombuffer(reply, types.CREATE_RESULT_DTYPE)
+        assert len(codes) == 2 * n_fail
+        assert set(codes["result"][0::2]) == {int(CTR.linked_event_failed)}
+        assert set(codes["result"][1::2]) == {int(CTR.credit_account_not_found)}
+    ids = list(range(1, n_accounts + 1))
+    replay_both(h_d, h_c, [(Operation.lookup_accounts, hz.ids_bytes(ids))])
+    assert h_d.sm.state_root() == h_c.sm.state_root()
+    h_d.sm.verify_device_mirror()
+    dev = h_d.sm._dev
+    assert dev.stat_fallback_batches == 0 and h_d.sm.stat_fallback_events == 0
+    assert dev.stat_semantic_events == (batches - batches // 2) * 2 * chains
+    snap = h_d.sm.metrics.snapshot()
+    assert snap["accounts"] == n_accounts and snap["dev.table_rows"] == 1 << 12
+    seen = snap["plan.rows_touched.count"] - at_restore["plan.rows_touched.count"]
+    assert seen == batches - batches // 2
+    assert snap["plan.row_legs_max.count"] == snap["plan.rows_touched.count"]
+    # The warehouse's row takes a leg of every chain that names it:
+    # over 100 of the batch's 128; a batch touches it, the 5 districts
+    # and at most 128 customers.
+    assert 100 < snap["plan.row_legs_max.max"] <= chains
+    # (The restored accounts' meta rows ride the first window's sample.)
+    touched = snap["plan.rows_touched.sum"] - at_restore["plan.rows_touched.sum"]
+    assert 100 * seen < touched <= n_accounts + seen * (1 + _HOT_DISTRICTS + chains)
+    assert "dev.plan.rows_touched.count" not in snap
+    assert failing >= batches
+
+
+def _dense_adds(dr_slot, cr_slot, amt_lo, amt_hi, is_pending):
+    """The mirror's limb sums as they were before PR 35: a float64 bin
+    a (slot, column) up to the highest slot named."""
+    mask32 = np.uint64(0xFFFFFFFF)
+    K = (int(max(dr_slot.max(), cr_slot.max())) + 1) * 4
+    idx_dr = dr_slot * 4 + np.where(is_pending, 0, 1)
+    idx_cr = cr_slot * 4 + np.where(is_pending, 2, 3)
+    acc = np.empty((4, K))
+    for i, limb in enumerate((amt_lo & mask32, amt_lo >> np.uint64(32),
+                              amt_hi & mask32, amt_hi >> np.uint64(32))):
+        w = limb.astype(np.float64)
+        acc[i] = np.bincount(idx_dr, weights=w, minlength=K)
+        acc[i] += np.bincount(idx_cr, weights=w, minlength=K)
+    at = np.flatnonzero(acc.any(axis=0))
+    c = acc[:, at].astype(np.uint64)
+    c1 = c[1] + (c[0] >> np.uint64(32))
+    c2 = c[2] + (c1 >> np.uint64(32))
+    c3 = c[3] + (c2 >> np.uint64(32))
+    assert not (c3 >> np.uint64(32)).any()
+    return ((at >> 2).astype(np.int64), (at & 3).astype(np.int64),
+            (c[0] & mask32) | ((c1 & mask32) << np.uint64(32)),
+            (c2 & mask32) | ((c3 & mask32) << np.uint64(32)))
+
+
+@pytest.mark.parametrize("seed,rows,hot", [
+    (1, 4096, 1), (2, 4096, 8), (3, 1 << 16, 3), (2**31 + 4, 600, 300),
+])
+def test_the_mirrors_adds_follow_the_batch_and_equal_the_dense_sums(
+        seed, rows, hot):
+    """`try_apply_adds` sums over the batch's own (slot, column) keys;
+    the deltas it returns and the rows it leaves are those of the
+    dense form, bit for bit: hot rows, high limbs, pendings, amounts
+    of nought and masked events included."""
+    rng = np.random.default_rng(seed)
+    n = 500
+    cr = rng.integers(0, hot, n).astype(np.int64)
+    dr = (hot + rng.integers(0, rows - hot, n)).astype(np.int64)
+    amt_lo = rng.integers(0, 1 << 63, n, dtype=np.uint64) * np.uint64(2)
+    amt_lo[rng.random(n) < 0.1] = 0
+    amt_hi = np.where(rng.random(n) < 0.2,
+                      rng.integers(0, 1 << 40, n, dtype=np.uint64), np.uint64(0))
+    amt_hi[amt_lo == 0] = 0
+    pending = rng.random(n) < 0.3
+    mask = rng.random(n) < 0.9
+    mirror, twin = BalanceMirror(rows), BalanceMirror(rows)
+    for _ in range(3):
+        got = mirror.try_apply_adds(dr, cr, amt_lo, amt_hi, pending, mask)
+        want = _dense_adds(dr[mask], cr[mask], amt_lo[mask], amt_hi[mask],
+                           pending[mask])
+        assert twin._admit_commit(*want, True)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and (g == w).all()
+        assert (mirror.lo == twin.lo).all() and (mirror.hi == twin.hi).all()
+    assert mirror.lo[:hot].any() and mirror.hi[:hot].any()
+    # A dry run proves admission and moves nothing.
+    before = mirror.lo.copy()
+    assert mirror.try_apply_adds(dr, cr, amt_lo, amt_hi, pending, mask,
+                                 commit=False) is not None
+    assert (mirror.lo == before).all()
+    # A column that would pass 2^128 is refused, mirror untouched.
+    huge = np.full(n, np.uint64((1 << 64) - 1))
+    assert mirror.try_apply_adds(dr, np.zeros(n, np.int64), huge, huge,
+                                 np.zeros(n, bool), np.ones(n, bool)) is None
+    assert (mirror.lo == before).all()

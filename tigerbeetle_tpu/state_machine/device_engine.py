@@ -380,6 +380,18 @@ def make_spec_stats(registry) -> dict:
     return st
 
 
+def make_touch_stats(registry) -> tuple:
+    """plan.rows_touched and plan.row_legs_max: the distinct account
+    rows a device batch touches and the legs on its most-touched row,
+    a sample where the digest update takes the batch's unique
+    (_commit_update_rows).  Same owning-machine-binds-handles contract
+    as make_spec_stats."""
+    return (
+        registry.histogram("plan.rows_touched"),
+        registry.histogram("plan.row_legs_max"),
+    )
+
+
 def make_tier_stats(registry) -> dict:
     """dev_tier.* handles for the hot/cold tiering (hot_tier.py) —
     same owning-machine-binds-handles contract as make_spec_stats."""
@@ -578,6 +590,12 @@ class DeviceEngine:
             stage: self.metrics.histogram(f"link.{stage}_us")
             for stage in ("h2d", "dispatch", "fetch_start", "fetch", "probe")
         }
+        # The device table's rows: the kernels' accumulation and apply
+        # visit every one, used or not (sm.accounts says how many are).
+        self.metrics.gauge_fn(
+            "table_rows",
+            lambda: self.capacity if self.hot is None else self.hot.hot_rows,
+        )
         # Cadence first-guesses as pull gauges + measured per-scrub
         # cost (ROADMAP "scrub/probe cadence tuning" carry-over): the
         # next real-link session reads the actual digest-compare cost
@@ -666,6 +684,8 @@ class DeviceEngine:
         # after restore); standalone engines build them lazily on the
         # private registry at first speculative launch.
         self.spec_stats: dict | None = None
+        # plan.rows_touched / plan.row_legs_max, bound the same way.
+        self.touch_stats: tuple | None = None
         # Degraded-mode read() cache: (mirror version, capacity) ->
         # CPU-placed (capacity, 8) table handle.
         self._degraded_cache = None
@@ -1936,10 +1956,19 @@ class DeviceEngine:
                 self._commit_update_rows(touched)
 
     def _commit_update_rows(self, slots) -> None:
-        slots = np.unique(np.asarray(slots, np.int64))
+        slots = np.asarray(slots, np.int64)
         slots = slots[(slots >= 0) & (slots < self.balances.shape[0])]
         if len(slots) == 0:
             return
+        if self.metrics.enabled:
+            slots, legs = np.unique(slots, return_counts=True)
+            st = self.touch_stats
+            if st is None:
+                st = self.touch_stats = make_touch_stats(self.metrics)
+            st[0].observe(len(slots))
+            st[1].observe(int(legs.max()))
+        else:
+            slots = np.unique(slots)
         from tigerbeetle_tpu.state_machine import commitment as _cm
 
         fns = _cm.device_fns()

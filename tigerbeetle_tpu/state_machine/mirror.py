@@ -201,34 +201,25 @@ class BalanceMirror:
             amt_lo, amt_hi = amt_lo[m], amt_hi[m]
             is_pending = is_pending[m]
 
-        # Dense limb accumulation via float64 bincount (exact: limbs
-        # < 2^32, sums < events * 2^32 << 2^53) — no sort, no concat.
-        top = int(max(dr_slot.max(), cr_slot.max())) + 1
-        K = top * 4
-        idx_dr = dr_slot * 4 + np.where(is_pending, 0, 1)
-        idx_cr = cr_slot * 4 + np.where(is_pending, 2, 3)
-        mask32 = np.uint64(0xFFFFFFFF)
-        acc = np.empty((4, K))
-        for i, limb in enumerate(
-            (amt_lo & mask32, amt_lo >> np.uint64(32),
-             amt_hi & mask32, amt_hi >> np.uint64(32))
-        ):
-            w = limb.astype(np.float64)
-            acc[i] = np.bincount(idx_dr, weights=w, minlength=K)
-            acc[i] += np.bincount(idx_cr, weights=w, minlength=K)
-
-        touched_idx = np.flatnonzero(acc.any(axis=0))
-        u_slot = (touched_idx >> 2).astype(np.int64)
-        u_col = (touched_idx & 3).astype(np.int64)
-        limbs = acc[:, touched_idx].astype(np.uint64)
-        c0 = limbs[0]
-        c1 = limbs[1] + (c0 >> np.uint64(32))
-        c2 = limbs[2] + (c1 >> np.uint64(32))
-        c3 = limbs[3] + (c2 >> np.uint64(32))
-        d_lo = (c0 & mask32) | ((c1 & mask32) << np.uint64(32))
-        d_hi = (c2 & mask32) | ((c3 & mask32) << np.uint64(32))
-        if ((c3 >> np.uint64(32)) != 0).any():
+        # Exact sums over the batch's OWN (slot, column) keys: the cost
+        # follows the batch's rows, not the highest slot it names (a
+        # float64 bin a column up to that slot cost 0.2 s a batch on a
+        # table of 2^20 rows, PERF.md section 6, PR 35).
+        u_slot, u_col, d_lo, d_hi, limb_ov = compact_deltas(
+            np.concatenate([dr_slot, cr_slot]),
+            np.concatenate(
+                [np.where(is_pending, 0, 1), np.where(is_pending, 2, 3)]
+            ),
+            np.concatenate([amt_lo, amt_lo]),
+            np.concatenate([amt_hi, amt_hi]),
+        )
+        if limb_ov.any():
             return None  # column delta alone exceeds u128
+        # A key whose amounts are all nought moves nothing.
+        moved = (d_lo | d_hi) != 0
+        if not moved.all():
+            u_slot, u_col = u_slot[moved], u_col[moved]
+            d_lo, d_hi = d_lo[moved], d_hi[moved]
         if not self._admit_commit(u_slot, u_col, d_lo, d_hi, commit):
             return None
         return (u_slot, u_col, d_lo, d_hi)

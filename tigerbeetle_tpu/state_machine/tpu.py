@@ -436,6 +436,11 @@ class TpuStateMachine:
             self.metrics.histogram("plan.join_cold_us"), "sm.plan.join_cold"
         )
         self._c_join_cold_rows = _c("store.join_cold_rows")
+        # Account rows in use (of the capacity `--cache-accounts` gives
+        # the directory, the mirror and the device table), set wherever
+        # the count moves: a pull gauge would change the snapshot under
+        # an unchanged version.
+        self._g_accounts = self.metrics.gauge("accounts")
         # Per-request anatomy hook (obs/anatomy.py): the owning
         # Replica shares its recorder and stamps the current prepare's
         # trace id before each commit, so commit_async can attribute
@@ -494,9 +499,11 @@ class TpuStateMachine:
             # carry them; the engine increments the shared handles.
             from tigerbeetle_tpu.state_machine.device_engine import (
                 make_spec_stats,
+                make_touch_stats,
             )
 
             self._dev.spec_stats = make_spec_stats(self.metrics)
+            self._dev.touch_stats = make_touch_stats(self.metrics)
             self._bind_tier_stats()
             # Off-hot-path warmup of the named kinds' transfer plans +
             # scan compiles (construction happens before serving).
@@ -1345,6 +1352,9 @@ class TpuStateMachine:
         return CAR.ok
 
     def _ensure_balance_capacity(self, slots: int) -> None:
+        """Called with the new account count by every path that moves
+        it: the count goes to its gauge, the tables grow to hold it."""
+        self._g_accounts.set(slots)
         # The engine's logical capacity, not the live array shape: a
         # degraded device engine defers widening its HBM tables until
         # re-promotion, but its committed capacity already grew.
@@ -4138,6 +4148,7 @@ def _tpu_restore(self, data: bytes) -> None:
 
     self._attrs = Columns(_ATTR_FIELDS)
     self._attrs.append(**state["attrs"])
+    self._g_accounts.set(self._attrs.count)
     self._store = TailStore(_STORE_FIELDS, cold_join=self._cold_join)
     self._store.append(**state["store"])
     self._exp = Columns(
@@ -4217,6 +4228,7 @@ def _tpu_restore(self, data: bytes) -> None:
             DeviceEngine,
             DeviceLostError,
             make_spec_stats,
+            make_touch_stats,
         )
 
         self._dev = DeviceEngine(
@@ -4227,6 +4239,7 @@ def _tpu_restore(self, data: bytes) -> None:
         # Re-bind the machine-registry dev_wave.spec.* handles — the
         # counters are process-lifetime cumulative across restores.
         self._dev.spec_stats = make_spec_stats(self.metrics)
+        self._dev.touch_stats = make_touch_stats(self.metrics)
         self._bind_tier_stats()
         try:
             if self._dev.state is types.EngineState.healthy:

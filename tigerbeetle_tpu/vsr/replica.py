@@ -98,6 +98,9 @@ class Replica:
             "stat_ckpt_async": self.metrics.counter("ckpt.async"),
             "stat_ckpt_sync": self.metrics.counter("ckpt.sync"),
         }
+        # The last freeze's blob: accounts are not in the forest, so
+        # it grows with them and must fit SNAPSHOT_SPAN.
+        self._g_ckpt_blob = self.metrics.gauge("ckpt.blob_bytes")
         self._c_commits = self.metrics.counter("commits")
         # Client requests carried by committed prepares (a coalesced
         # prepare carries several): requests_committed / commits is
@@ -1063,6 +1066,7 @@ class Replica:
                 self.sm.checkpoint_spill()
 
         blob = self._take_snapshot()
+        self._g_ckpt_blob.set(len(blob))
         # The state root is part of the frozen image: captured here —
         # the snapshot encode drained the state machine, so the
         # incremental commitment is exactly commit_min's — and flipped
