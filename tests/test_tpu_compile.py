@@ -8,9 +8,11 @@ times; those are `chip_smoke.py`'s and `tests/test_tpu_chip.py`'s.
 
 Shapes are the served path's at upstream's benchmark load: event
 bucket B=8192, the `start` default table of 65,536 rows, a 96-batch
-window.  The suite's own `device_kernels` is imported at TB_DEV_B=512
-(tests/conftest.py), so the `dk` fixture loads a second copy of the
-module at the production width.
+window; and, for the two kinds `bench1r-tpcc-pay-c4` sends, the
+1,048,576-row table that cell serves.  The suite's own
+`device_kernels` is imported at TB_DEV_B=512 (tests/conftest.py), so
+the `dk` fixture loads a second copy of the module at the production
+width.
 
 The topology is described inside a module-scoped fixture — never at
 import, where every xdist worker would race for libtpu's lock — and
@@ -24,6 +26,7 @@ import os
 import pytest
 
 A = 1 << 16       # cli.CACHE_DEFAULT: the served table
+A_TPCC = 1 << 20  # bench1r-tpcc-pay-c4's: 960,352 accounts
 B = 8192          # production event bucket
 # `linked` (amounts past 2^31 a batch: a funding request) is compiled as
 # a lone dispatch only; the served cells never scan it.
@@ -100,14 +103,18 @@ def _s(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _tables(dk):
+def _tables(dk, rows=A):
     import jax.numpy as jnp
 
-    return _s((A, 8), jnp.uint64), _s((A, 2), jnp.uint32)
+    return _s((rows, 8), jnp.uint64), _s((rows, 2), jnp.uint32)
 
 
-@pytest.mark.parametrize("kind", KINDS)
-def test_base_kernel_compiles_for_v5e(one_chip, dk, kind):
+@pytest.mark.parametrize("kind,rows", [
+    *((kind, A) for kind in KINDS),
+    ("linked_small", A_TPCC),
+    ("orderfree_tight", A_TPCC),
+])
+def test_base_kernel_compiles_for_v5e(one_chip, dk, kind, rows):
     """One batch per launch: the lone-request dispatch."""
     import jax
     import jax.numpy as jnp
@@ -121,10 +128,15 @@ def test_base_kernel_compiles_for_v5e(one_chip, dk, kind):
     # and the summary row comes back as an output of its own, the
     # dense codes (which stay on the device unless the row's failures
     # outrun its entries) as another.
-    args = (*_tables(dk), _s((dk.ROWS, ncols), dtype))
-    _compile(fn, one_chip, *args)
+    args = (*_tables(dk, rows), _s((dk.ROWS, ncols), dtype))
+    mem = _compile(fn, one_chip, *args)
+    # The sums follow the rows a batch touches: beside its copy of the
+    # table a program holds nothing that grows with it (the parent's
+    # one-hot product was (2B, rows); a (2B, 2B) one-hot materialised
+    # would be 512 MiB).
+    assert mem.temp_size_in_bytes < (1 << 28), mem
     table, row, dense = jax.eval_shape(fn, *args)
-    assert table.shape == (A, 8)
+    assert table.shape == (rows, 8)
     assert (row.shape, row.dtype) == ((dk.SUMMARY_WORDS,), jnp.uint64)
     assert (dense.shape, dense.dtype) == ((dk.B,), jnp.uint32)
 

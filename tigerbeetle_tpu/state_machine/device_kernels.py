@@ -31,6 +31,23 @@ information.  A batch that hits an overflow/precondition edge raises
 a flag and is re-executed exactly on the host engine (the fallback
 path).
 
+Cost contract (PERF.md section 6, PR 36): a kernel's work follows the
+rows its batch touches, not the table.  The legs' slots are sorted
+into DENSE RANKS (_touch: at most 2B touched rows, 4B in two_phase);
+the exact sums are one one-hot product over (ranks, legs)
+(_sum_legs), admission and the release run over the gathered rows
+(_admit, _release), and the write-back scatters the touched rows
+alone (_write_back).  The one cost that grows with the table is the
+copy the write-back starts from (the engine never donates its
+table).  Restricting the overflow flags to touched rows changes none
+of them as long as the table holds the invariant the admission
+itself maintains (no account's dp+dpo or cp+cpo total passes u128):
+an untouched row's delta is zero (_admit's docstring).  On the v5e a
+sort of 2^14 keys is ~10 us, a gather of as many 0.06-0.14 ms, a
+scatter of as many 0.5-1.5 ms and a binary search of as many 1.75 ms:
+so the kernels sort (compaction, inverse permutations, merge counts)
+where they used to scatter and search.
+
 Input marshaling split (who computes what): the host packs raw event
 columns and *stateless byte predicates* (id == 0, id == maxInt,
 debit id == credit id, ...) plus join booleans (duplicate-id found,
@@ -138,6 +155,14 @@ _MASK32 = jnp.uint64(0xFFFFFFFF)
 _U64_SAFE = np.uint64(1) << np.uint64(61)
 
 
+def _sort(operand):
+    """lax.sort by the first operand, not stable: every sort in this
+    module is of distinct keys, or of keys whose ties are told apart
+    by nothing that is read (a stable sort of 2^15 keys compiles for
+    the v5e in 17 s, this one in 3 s, and runs no slower)."""
+    return jax.lax.sort(operand, num_keys=1, is_stable=False)
+
+
 def _first_nonzero(*pairs):
     r = jnp.uint32(0)
     for cond, code in pairs:
@@ -214,32 +239,91 @@ def _static_ladder_normal(ev, meta, active):
     return jnp.where(active, r, jnp.uint32(CTR.linked_event_failed))
 
 
-def _accum_cols_multi(slot_rows, passes, A, lo_only=False):
-    """Exact per-(slot, column) u128 sums via ONE one-hot MXU matmul
-    shared across several accumulation passes.
+# A leg that names no table row sorts last and matches no touched row.
+_NO_SLOT = np.int32(0x7FFFFFFF)
+# Rows a write-back scatters per turn of its loop (see _write_back).
+_SCATTER_CHUNK = 512
+
+
+def _touch(table, slot_rows):
+    """Dense ranks of a batch's legs over the table rows they name.
+
+    `slot_rows` is one table slot per leg (2B legs, 4B in two_phase);
+    a leg whose slot is not a row of the table (-1: account not found)
+    gets the key _NO_SLOT, which sorts last.  Sorts of i32 keys do it
+    all (slots are below 2^31; a u64 sort is a variadic (u32, u32)
+    sort on the TPU, and a sort of 2^14 keys is microseconds where a
+    scatter or a binary search of as many is a millisecond): the
+    first puts equal slots side by side, so the first leg of each run
+    is that row's HEAD; the second packs the heads' slots to the
+    front, in order.  A row's RANK is its place there: dense (below
+    `rows` at any table size) and monotone in the slot.
+
+    Returns a dict of
+      key    (rows,) i32  the legs' keys, in leg order
+      rank   (rows,) i32  each leg's row's rank, in leg order (n, a
+                          rank no row has, for a leg that names none)
+      n      () i32       how many rows the batch touches
+      uslots (U,) i32     U = rows.  u < n: the slot of the row of
+                          rank u; else a value past the table, which
+                          matches no key and which a scatter drops
+      hit    (U,) bool    u < n
+      old    (U, 8) u64   table[uslots] where hit (else a clipped row,
+                          never read: every use is under `hit`)
+
+    Everything downstream (the sums, admission, the release, the
+    write-back) is over these U <= 4B ranks; nothing but the gather
+    here and the final scatter sees the table."""
+    A = table.shape[0]
+    rows = slot_rows.shape[0]
+    assert A + rows < int(_NO_SLOT), (A, rows)
+    found = (slot_rows >= 0) & (slot_rows < A)
+    key = jnp.where(found, slot_rows, _NO_SLOT).astype(jnp.int32)
+    iota = jnp.arange(rows, dtype=jnp.int32)
+    key_s, perm = _sort((key, iota))
+    named_s = key_s != _NO_SLOT
+    head = jnp.concatenate(
+        [jnp.ones(1, bool), key_s[1:] != key_s[:-1]]
+    ) & named_s
+    n = head.sum(dtype=jnp.int32)
+    uslots = _sort(jnp.where(head, key_s, A + iota))
+    # The ranks in key order are a running count of the heads; sorting
+    # them by `perm` undoes the key sort.
+    rank_s = jnp.where(named_s, jnp.cumsum(head.astype(jnp.int32)) - 1, n)
+    _, rank = _sort((perm, rank_s))
+    return {"key": key, "rank": rank, "n": n, "uslots": uslots,
+            "hit": iota < n, "old": table[jnp.minimum(uslots, A - 1)]}
+
+
+def _sum_legs(t, passes, lo_only=False):
+    """Exact per-(touched row, column) u128 sums via ONE one-hot MXU
+    matmul shared across several accumulation passes.
 
     `passes` is a list of (col_rows, amt_lo_rows, amt_hi_rows, valid)
-    over the SAME slot rows; their 8-bit-piece payloads concatenate
-    along the feature axis, so the (rows, A) one-hot — the dominant
-    HBM traffic of these kernels — is materialized once however many
-    sums a kernel needs (linked: superset admission + final apply;
-    two_phase: adds + releases).
+    over the SAME legs; their 8-bit-piece payloads concatenate along
+    the feature axis, so the (U, rows) one-hot of `_touch`'s ranks
+    (position u against leg r: uslots[u] == key[r]) is generated once
+    however many sums a kernel needs (linked: superset admission +
+    final apply; two_phase: adds + releases).  It is (rows, rows) at
+    any table size: the table's A is nowhere in it.  A row that takes
+    512 legs costs what a row that takes one does.
 
     Amounts decompose into 8-bit pieces (each < 2^8); the one-hot
-    bf16 matmul accumulates them in f32 — sums stay below
-    rows * 255 < 2^24, so every partial is exact — and a base-256
-    carry recombination rebuilds exact u128 column deltas.  Invalid
-    rows contribute ZERO payload (their slot may be clip-garbage; a
-    zero contribution to any slot is harmless).
+    bf16 matmul accumulates them in f32 — the CONTRACTION is over the
+    legs, so sums stay below rows * 255 < 2^24 and every partial is
+    exact — and a base-256 carry recombination rebuilds exact u128
+    column deltas.  Invalid legs contribute ZERO payload (a leg that
+    names no row matches no position at all).
 
     `lo_only` halves the payload (8 pieces) when every amount's high
     limb is zero — a trace-time specialization the host router
     selects (the high-limb sum is then just the carry chain's
     overflow).
 
-    Returns one (d_lo, d_hi, limb_ov) of shape (A, 4) per pass.
+    Returns one (d_lo, d_hi, limb_ov) of shape (U, 4) per pass; a
+    rank no row has (u >= n) sums to zero.
     """
-    rows = slot_rows.shape[0]
+    rows = t["key"].shape[0]
     zero = jnp.uint64(0)
     npieces = 8 if lo_only else 16
     payloads = []
@@ -257,14 +341,12 @@ def _accum_cols_multi(slot_rows, passes, A, lo_only=False):
             (colmask[:, :, None] * P[:, None, :]).reshape(rows, 4 * npieces)
         )
     payload = jnp.concatenate(payloads, axis=-1)
-    onehot = jax.nn.one_hot(
-        jnp.clip(slot_rows, 0, A - 1), A, dtype=jnp.bfloat16
-    )
+    onehot = (t["uslots"][:, None] == t["key"][None, :]).astype(jnp.bfloat16)
     acc_all = jax.lax.dot_general(
-        onehot.T, payload.astype(jnp.bfloat16),
+        onehot, payload.astype(jnp.bfloat16),
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ).reshape(A, len(passes), 4, npieces).astype(jnp.uint64)
+    ).reshape(rows, len(passes), 4, npieces).astype(jnp.uint64)
 
     out = []
     for p in range(len(passes)):
@@ -277,7 +359,7 @@ def _accum_cols_multi(slot_rows, passes, A, lo_only=False):
             d_lo = d_lo | ((c & _MASK8) << jnp.uint64(8 * k))
             carry = c >> jnp.uint64(8)
         if lo_only:
-            out.append((d_lo, carry, jnp.zeros((A, 4), bool)))
+            out.append((d_lo, carry, jnp.zeros((rows, 4), bool)))
             continue
         c = acc[:, :, 8] + carry
         d_hi = c & _MASK8
@@ -290,22 +372,30 @@ def _accum_cols_multi(slot_rows, passes, A, lo_only=False):
     return out
 
 
-def _accum_cols(slot_rows, col_rows, amt_lo_rows, amt_hi_rows, valid, A,
-                lo_only=False):
-    """Single-pass convenience wrapper over _accum_cols_multi."""
-    return _accum_cols_multi(
-        slot_rows, [(col_rows, amt_lo_rows, amt_hi_rows, valid)], A,
-        lo_only=lo_only,
-    )[0]
+def _restack(lo, hi):
+    """(rows, 4) low and high limbs -> (rows, 8) in table layout."""
+    return jnp.stack(
+        [lo[:, 0], hi[:, 0], lo[:, 1], hi[:, 1],
+         lo[:, 2], hi[:, 2], lo[:, 3], hi[:, 3]],
+        axis=-1,
+    )
 
 
-def _admit_apply(table, d_lo, d_hi, limb_ov):
-    """Admission + apply: add exact column deltas iff NO column u128
-    add overflows and no account's combined dp+dpo / cp+cpo total
-    overflows (mirrors BalanceMirror._admit_commit, which the host
-    fast path proved bit-parity for).  Returns (new_table, ov)."""
-    old_lo = table[:, 0::2]
-    old_hi = table[:, 1::2]
+def _admit(t, d_lo, d_hi, limb_ov):
+    """Admission over the touched rows: `old + delta` per column, and
+    whether ANY column's u128 add overflows or any account's combined
+    dp+dpo / cp+cpo total overflows (mirrors
+    BalanceMirror._admit_commit, which the host fast path proved
+    bit-parity for).  Returns (new_rows, ov); the caller selects.
+
+    The flags read touched rows only.  An untouched row has a zero
+    delta: its adds cannot overflow, and its totals overflow only if
+    they did before the batch, which no table holds, because this
+    check is what admits every write to it (and `_release` only
+    lowers a total).  So the restriction changes no flag."""
+    old = t["old"]
+    old_lo = old[:, 0::2]
+    old_hi = old[:, 1::2]
     new_lo = old_lo + d_lo
     cy = (new_lo < old_lo).astype(jnp.uint64)
     hi_p = old_hi + d_hi
@@ -323,13 +413,53 @@ def _admit_apply(table, d_lo, d_hi, limb_ov):
 
     dr_tot_ov = tot_ov(new_lo[:, 0], new_hi[:, 0], new_lo[:, 1], new_hi[:, 1])
     cr_tot_ov = tot_ov(new_lo[:, 2], new_hi[:, 2], new_lo[:, 3], new_hi[:, 3])
-    ov = limb_ov.any() | add_ov.any() | dr_tot_ov.any() | cr_tot_ov.any()
-    nt = jnp.stack(
-        [new_lo[:, 0], new_hi[:, 0], new_lo[:, 1], new_hi[:, 1],
-         new_lo[:, 2], new_hi[:, 2], new_lo[:, 3], new_hi[:, 3]],
-        axis=-1,
+    row_ov = (limb_ov | add_ov).any(axis=1) | dr_tot_ov | cr_tot_ov
+    return _restack(new_lo, new_hi), (row_ov & t["hit"]).any()
+
+
+def _release(t, rows8, s_lo, s_hi, s_limb):
+    """two_phase's releases over the touched rows: `rows8 - s` per
+    column, and whether any column underflows (or a release sum
+    overflowed its limbs).  Returns (new_rows, bad)."""
+    old_lo = rows8[:, 0::2]
+    old_hi = rows8[:, 1::2]
+    n_lo = old_lo - s_lo
+    borrow = (old_lo < s_lo).astype(jnp.uint64)
+    n_hi = old_hi - s_hi - borrow
+    under = (old_hi < s_hi) | ((old_hi == s_hi) & (old_lo < s_lo))
+    bad = ((under | s_limb).any(axis=1) & t["hit"]).any()
+    return _restack(n_lo, n_hi), bad
+
+
+def _write_back(table, t, rows8, fallback):
+    """The batch's new table: `rows8` scattered to the rows the batch
+    touches, or, on `fallback`, the table as it was (nothing is
+    scattered: no pass over the table selects).
+
+    A scatter on the TPU is a loop over its indices, ~0.1 us each
+    whether the index lands or is dropped, so the loop here runs over
+    the n touched rows only, _SCATTER_CHUNK at a time (their slots are
+    packed to the front of `uslots`, ascending and unique).  The table
+    is not donated (a transient fault retries the batch from the same
+    array): its one O(A) cost is the copy this loop starts from."""
+    n = jnp.where(fallback, 0, t["n"])
+    uslots = t["uslots"]
+    size = min(_SCATTER_CHUNK, uslots.shape[0])
+    assert uslots.shape[0] % size == 0, uslots.shape
+
+    def chunk(state):
+        at, table = state
+        idx = jax.lax.dynamic_slice(uslots, (at,), (size,))
+        upd = jax.lax.dynamic_slice(rows8, (at, jnp.int32(0)), (size, 8))
+        table = table.at[idx].set(
+            upd, mode="drop", unique_indices=True, indices_are_sorted=True
+        )
+        return at + size, table
+
+    _, table = jax.lax.while_loop(
+        lambda state: state[0] < n, chunk, (jnp.int32(0), table)
     )
-    return jnp.where(ov, table, nt), ov
+    return table
 
 
 def _summary(results, active, flags_word, last_applied):
@@ -342,13 +472,20 @@ def _summary(results, active, flags_word, last_applied):
     results = jnp.where(active, results, jnp.uint32(0))
     fail = results != 0
     n_fail = fail.sum().astype(jnp.uint64)
-    pos = jnp.cumsum(fail) - 1
-    ent = (jnp.arange(B, dtype=jnp.uint64) << jnp.uint64(32)) | results.astype(
-        jnp.uint64
+    # The first FAIL_CAP failing events, in order: a sort packs them
+    # to the front (microseconds; a scatter of B updates is 0.5 ms).
+    first = jnp.concatenate([
+        _sort(
+            jnp.where(fail, jnp.arange(B, dtype=jnp.uint32), jnp.uint32(B))
+        ),
+        jnp.full(FAIL_CAP, B, jnp.uint32),
+    ])[:FAIL_CAP]
+    entries = jnp.where(
+        first < B,
+        (first.astype(jnp.uint64) << jnp.uint64(32))
+        | results[jnp.minimum(first, B - 1)].astype(jnp.uint64),
+        jnp.uint64(0),
     )
-    entries = jnp.zeros(FAIL_CAP, jnp.uint64).at[
-        jnp.where(fail, pos, FAIL_CAP)
-    ].set(ent, mode="drop")
     head = jnp.stack(
         [
             n_fail,
@@ -436,7 +573,6 @@ def _orderfree_tight(table, meta, pkx):
 
 
 def _orderfree_core(table, meta, ev, n, ts_base, lo_only):
-    A = table.shape[0]
     iota = jnp.arange(B, dtype=jnp.int64)
     active = iota < n
     r = _static_ladder_normal(ev, meta, active)
@@ -455,10 +591,12 @@ def _orderfree_core(table, meta, ev, n, ts_base, lo_only):
     amt_lo2 = jnp.concatenate([ev["amt_lo"]] * 2)
     amt_hi2 = jnp.concatenate([ev["amt_hi"]] * 2)
     valid = jnp.concatenate([ok, ok])
-    d_lo, d_hi, limb_ov = _accum_cols(
-        slot_rows, col_rows, amt_lo2, amt_hi2, valid, A, lo_only=lo_only
+    t = _touch(table, slot_rows)
+    (sums,) = _sum_legs(
+        t, [(col_rows, amt_lo2, amt_hi2, valid)], lo_only=lo_only
     )
-    new_table, ov = _admit_apply(table, d_lo, d_hi, limb_ov)
+    new_rows, ov = _admit(t, *sums)
+    new_table = _write_back(table, t, new_rows, ov)
 
     applied_idx = jnp.where(ok, iota, -1)
     last_applied = applied_idx.max()
@@ -496,9 +634,15 @@ def _linked(table, meta, pkx, small=False):
         [jnp.ones(1, bool), ~linked[:-1]]
     )
     chain_id = jnp.cumsum(start.astype(jnp.int64)) - 1
-    # chain_start event per chain (segment min of index).
-    chain_start_ev = jax.ops.segment_min(iota, chain_id, num_segments=B)
-    chain_last_ev = jax.ops.segment_max(iota, chain_id, num_segments=B)
+    # Chain c starts at the c-th start event (a sort packs them to the
+    # front; B where there is no chain c) and ends where the next one
+    # starts.
+    chain_start_ev = _sort(
+        jnp.where(start, iota.astype(jnp.int32), jnp.int32(B))
+    )
+    chain_last_ev = jnp.concatenate(
+        [chain_start_ev[1:], jnp.full(1, B, jnp.int32)]
+    ) - 1
     start_of_ev = chain_start_ev[chain_id]
 
     # Unconditional per-event codes; chain_open overrides on the last
@@ -519,30 +663,22 @@ def _linked(table, meta, pkx, small=False):
     clim = (cr_flags & AF_CR_LIMIT) != 0
 
     # ---- preconditions (device-evaluated; violations -> host fallback)
+    # The u64-safety of the rows the limit entries touch is read off
+    # the entries' own gathered rows, below (ent_rows).
     precond_bad = (static_ok & (ev["amt_hi"] != 0)).any()
     ent_d = static_ok & ((dr_flags & LIM) != 0)
     ent_c = static_ok & ((cr_flags & LIM) != 0)
-    lim_touch = jnp.zeros(A + 1, bool)
-    lim_touch = lim_touch.at[jnp.where(ent_d, drc, A)].set(True, mode="drop")
-    lim_touch = lim_touch.at[jnp.where(ent_c, crc, A)].set(True, mode="drop")
-    lim_touch = lim_touch[:A]
-    hi_cols = table[:, 1::2]
-    lo_cols = table[:, 0::2]
-    precond_bad = precond_bad | (
-        lim_touch[:, None] & (hi_cols != 0)
-    ).any() | (
-        lim_touch[:, None] & (lo_cols >= jnp.uint64(_U64_SAFE))
-    ).any()
     contrib = jnp.where(static_ok, ev["amt_lo"], jnp.uint64(0))
     sum_bound = jnp.float64((1 << 31) - 1) if small else jnp.float64(_U64_SAFE)
     precond_bad = precond_bad | (
         contrib.astype(jnp.float64).sum() >= sum_bound
     )
 
-    # ---- superset overflow admission rows (static_ok events, posted
+    # ---- superset overflow admission legs (static_ok events, posted
     # cols); the sums themselves ride the SAME one-hot matmul as the
-    # final apply below (one materialization of the (2B, A) one-hot).
+    # final apply below, and the fixpoint's keys the same rank pass.
     slot_rows = jnp.concatenate([ev["dr_slot"], ev["cr_slot"]])
+    t = _touch(table, slot_rows)
     col_rows = jnp.concatenate(
         [jnp.ones(B, jnp.int32), jnp.full(B, 3, jnp.int32)]
     )
@@ -551,47 +687,39 @@ def _linked(table, meta, pkx, small=False):
     sup_valid = jnp.concatenate([static_ok, static_ok])
 
     # ---- fixpoint over (slot, event)-sorted limit entries.
-    # Entries: 2B rows (dr side then cr side); invalid rows get
+    # Entries: 2B legs (dr side then cr side); invalid legs get
     # sentinel keys that sort to the end.  The TPU sort's cost scales
-    # with operand count, so everything is PACKED into one u64 key —
-    # slot << 14 | event << 1 | side — and the per-entry columns are
-    # recovered arithmetically from the sorted keys (events are
-    # distinct within a slot because dr != cr, so the side bit never
-    # affects the required event order).
-    eslot2 = jnp.concatenate([ev["dr_slot"], ev["cr_slot"]])
-    entv = jnp.concatenate([ent_d, ent_c])
-    side2 = jnp.concatenate([jnp.zeros(B, jnp.uint64), jnp.ones(B, jnp.uint64)])
-    evs2 = jnp.concatenate([iota, iota]).astype(jnp.uint64)
-    key64 = (
-        (eslot2.astype(jnp.uint64) << jnp.uint64(14))
-        | (evs2 << jnp.uint64(1)) | side2
-    )
-    # u64 sorts as a variadic (u32, u32) pair on TPU — twice the
-    # compare/swap traffic.  The packed key needs log2(A) + 14 bits,
-    # so any table up to 2^17 rows sorts in native u32.
-    if A <= (1 << 17):
-        key = jnp.where(
-            entv, key64.astype(jnp.uint32), jnp.uint32(0xFFFFFFFF)
-        )
-        sentinel = jnp.uint32(0xFFFFFFFF)
-    else:
-        key = jnp.where(entv, key64, jnp.uint64(0xFFFFFFFFFFFFFFFF))
-        sentinel = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-    (key_s,) = jax.lax.sort([key], num_keys=1)
-    valid_s = key_s != sentinel
-    key_su = key_s.astype(jnp.uint64)
-    evs_s = jnp.where(
-        valid_s, (key_su >> jnp.uint64(1)) & jnp.uint64(B - 1), jnp.uint64(0)
-    ).astype(jnp.int32)
-    eslot_s = jnp.where(
-        valid_s, key_su >> jnp.uint64(14), jnp.uint64(0x7FFFFFFF)
-    ).astype(jnp.int32)
-    edeb_s = valid_s & ((key_su & jnp.uint64(1)) == 0)
-    eamt_s = ev["amt_lo"][evs_s]
+    # with operand count, so everything is PACKED into one u32 key —
+    # rank << 14 | event << 1 | side, 28 bits at any table size, the
+    # rank being the slot's dense rank (_touch: monotone in the slot,
+    # so the order is the order by slot) — and the per-entry
+    # columns are recovered arithmetically from the sorted keys
+    # (events are distinct within a slot because dr != cr, so the
+    # side bit never affects the required event order).
     M = 2 * B
+    entv = jnp.concatenate([ent_d, ent_c])
+    side2 = jnp.concatenate([jnp.zeros(B, jnp.uint32), jnp.ones(B, jnp.uint32)])
+    evs2 = jnp.concatenate([iota, iota]).astype(jnp.uint32)
+    sentinel = jnp.uint32(0xFFFFFFFF)
+    key = jnp.where(
+        entv,
+        (t["rank"].astype(jnp.uint32) << jnp.uint32(14))
+        | (evs2 << jnp.uint32(1)) | side2,
+        sentinel,
+    )
+    key_s = _sort(key)
+    valid_s = key_s != sentinel
+    evs_s = jnp.where(
+        valid_s, (key_s >> jnp.uint32(1)) & jnp.uint32(B - 1), jnp.uint32(0)
+    ).astype(jnp.int32)
+    erank_s = jnp.where(
+        valid_s, key_s >> jnp.uint32(14), jnp.uint32(M)
+    ).astype(jnp.int32)
+    edeb_s = valid_s & ((key_s & jnp.uint32(1)) == 0)
+    eamt_s = ev["amt_lo"][evs_s]
     jpos = jnp.arange(M)
     seg_new = jnp.concatenate(
-        [jnp.ones(1, bool), eslot_s[1:] != eslot_s[:-1]]
+        [jnp.ones(1, bool), erank_s[1:] != erank_s[:-1]]
     ) & valid_s
     seg_first = jax.lax.associative_scan(
         jnp.maximum, jnp.where(seg_new, jpos, 0)
@@ -599,21 +727,44 @@ def _linked(table, meta, pkx, small=False):
     # Chain-start boundary per entry, in the SAME packed-key encoding
     # and dtype (side bit 0 sorts before either side of the start
     # event).
-    bkey64 = (
-        (eslot_s.astype(jnp.uint64) << jnp.uint64(14))
+    bkey = jnp.where(
+        valid_s,
+        (erank_s.astype(jnp.uint32) << jnp.uint32(14))
         | (
-            start_of_ev[jnp.clip(evs_s, 0, B - 1)].astype(jnp.uint64)
-            << jnp.uint64(1)
-        )
+            start_of_ev[jnp.clip(evs_s, 0, B - 1)].astype(jnp.uint32)
+            << jnp.uint32(1)
+        ),
+        sentinel,
     )
-    bkey = jnp.where(valid_s, bkey64.astype(key_s.dtype), sentinel)
-    bpos = jnp.searchsorted(key_s, bkey, side="left")
+    # bpos: how many entries sort before each boundary.  The
+    # boundaries are in order themselves (within a row the chain starts
+    # rise with the events), so ONE merge sort of both lists, tagged in
+    # the low bit (a boundary before its equal entry), a running count
+    # of the entries, and a second sort that packs the boundaries'
+    # counts to the front do what 2B binary searches of 15 dependent
+    # gathers each did.
+    merged = _sort(jnp.concatenate([
+        jnp.where(valid_s, bkey << jnp.uint32(1), jnp.uint32(0xFFFFFFFE)),
+        jnp.where(valid_s, (key_s << jnp.uint32(1)) | jnp.uint32(1), sentinel),
+    ]))
+    is_entry = (merged & jnp.uint32(1)) != 0
+    bpos = _sort(
+        jnp.where(is_entry, sentinel, jnp.cumsum(is_entry.astype(jnp.uint32)))
+    )[:M].astype(jnp.int32)
 
-    esl = jnp.clip(eslot_s, 0, A - 1)
-    init_dp = table[esl, 0]
-    init_dpo = table[esl, 2]
-    init_cp = table[esl, 4]
-    init_cpo = table[esl, 6]
+    # Each entry's row as the batch found it, out of the gathered
+    # rows.  Precondition: a row a limit entry touches is u64-safe.
+    ent_rows = t["old"][jnp.minimum(erank_s, M - 1)]
+    precond_bad = precond_bad | (
+        valid_s[:, None] & (
+            (ent_rows[:, 1::2] != 0)
+            | (ent_rows[:, 0::2] >= jnp.uint64(_U64_SAFE))
+        )
+    ).any()
+    init_dp = ent_rows[:, 0]
+    init_dpo = ent_rows[:, 2]
+    init_cp = ent_rows[:, 4]
+    init_cpo = ent_rows[:, 6]
     evc = jnp.clip(evs_s, 0, B - 1)
     view_d = valid_s & edeb_s & dlim[evc]
     view_c = valid_s & ~edeb_s & clim[evc]
@@ -623,10 +774,13 @@ def _linked(table, meta, pkx, small=False):
     def chain_state(pass_):
         fails = (~pass_ & active).astype(jnp.int32)
         F = jnp.cumsum(fails)
-        base = (F - fails)[chain_start_ev]
-        applied_prefix = (F - base[chain_id]) == 0
+        base = (F - fails)[chain_start_ev][chain_id]
+        applied_prefix = (F - base) == 0
         chain_ok = applied_prefix[chain_last_ev]
-        return applied_prefix, chain_ok
+        # The first failing event of its chain: it fails and no event
+        # of the chain before it does.
+        first_fail = (fails != 0) & (F - fails == base)
+        return applied_prefix, chain_ok, first_fail
 
     def excl_prefix(v):
         # Exact u64 inclusive cumsum.  A direct u64 cumsum lowers to a
@@ -649,7 +803,7 @@ def _linked(table, meta, pkx, small=False):
 
     def body(state):
         pass_prev, _dr_fail, _cr_fail, it, _conv = state
-        applied_prefix, chain_ok = chain_state(pass_prev)
+        applied_prefix, chain_ok, _first = chain_state(pass_prev)
         wce = chain_ok[chain_id][evc]
         wie = applied_prefix[evc]
         Pdc = excl_prefix(jnp.where(wce, amt_d, jnp.uint64(0)))
@@ -705,15 +859,12 @@ def _linked(table, meta, pkx, small=False):
     )
     fix_failed = ~conv
 
-    applied_prefix, chain_ok = chain_state(pass_)
+    applied_prefix, chain_ok, is_ff = chain_state(pass_)
 
     # ---- result codes.
     results = jnp.zeros(B, jnp.uint32)
     bad_chain = ~chain_ok
     member_bad = bad_chain[chain_id] & active
-    fail_pos = jnp.where(active & ~pass_, iota, B)
-    first_fail = jax.ops.segment_min(fail_pos, chain_id, num_segments=B)
-    ff_of_ev = first_fail[chain_id]
     own_code = jnp.where(
         code0 != 0,
         code0,
@@ -726,7 +877,6 @@ def _linked(table, meta, pkx, small=False):
     results = jnp.where(
         member_bad, jnp.uint32(CTR.linked_event_failed), results
     )
-    is_ff = member_bad & (iota == ff_of_ev)
     results = jnp.where(is_ff, own_code, results)
     results = jnp.where(
         is_last & linked & member_bad,
@@ -739,18 +889,20 @@ def _linked(table, meta, pkx, small=False):
     # fully-passing chains).
     okev = active & (results == 0)
     ap_valid = jnp.concatenate([okev, okev])
-    (d_lo_s, d_hi_s, limb_ov_s), (d_lo, d_hi, limb_ov) = _accum_cols_multi(
-        slot_rows,
+    sup, ap = _sum_legs(
+        t,
         [
             (col_rows, amt_lo2, amt_hi2, sup_valid),
             (col_rows, amt_lo2, amt_hi2, ap_valid),
         ],
-        A, lo_only=True,
+        lo_only=True,
     )
-    _, sup_ov = _admit_apply(table, d_lo_s, d_hi_s, limb_ov_s)
+    _, sup_ov = _admit(t, *sup)
     fallback = sup_ov | precond_bad | fix_failed
-    new_table, _ov2 = _admit_apply(table, d_lo, d_hi, limb_ov)
-    new_table = jnp.where(fallback, table, new_table)
+    # The applied sums are the superset's or less: they overflow only
+    # where sup_ov has already flagged.
+    new_rows, _ov2 = _admit(t, *ap)
+    new_table = _write_back(table, t, new_rows, fallback)
 
     last_applied = jnp.where(applied_prefix & active, iota, -1).max()
     flags_word = (
@@ -776,7 +928,6 @@ def _two_phase(table, meta, pkx, lo_only=False):
     src/state_machine.zig:1608-1741)."""
     pk, n, _ts_base = _split_scalars(pkx)
     ev = _unpack(pk)
-    A = table.shape[0]
     iota = jnp.arange(B, dtype=jnp.int64)
     active = iota < n
     bits = ev["bits"]
@@ -946,7 +1097,7 @@ def _two_phase(table, meta, pkx, lo_only=False):
     )
     # Releases: winners subtract the pending amount from dp/cp (cannot
     # underflow: each live pending's amount is contained by invariant).
-    # They ride the SAME 4B-row one-hot as the adds — the release rows
+    # They ride the SAME 4B-leg one-hot as the adds — the release rows
     # are the [p_drs, p_crs] halves with their own columns and
     # validity; the [dr, cr] halves contribute zero.
     falseB = jnp.zeros(B, bool)
@@ -960,28 +1111,19 @@ def _two_phase(table, meta, pkx, lo_only=False):
     sub_amt_lo = jnp.concatenate([p_amt_lo] * 4)
     sub_amt_hi = jnp.concatenate([p_amt_hi] * 4)
     sub_valid = jnp.concatenate([falseB, falseB, win, win])
-    (d_lo, d_hi, limb_ov), (s_lo, s_hi, s_limb) = _accum_cols_multi(
-        add_slots,
+    t = _touch(table, add_slots)
+    adds, subs = _sum_legs(
+        t,
         [
             (add_cols, add_amt_lo, add_amt_hi, add_valid),
             (sub_cols, sub_amt_lo, sub_amt_hi, sub_valid),
         ],
-        A, lo_only=lo_only,
+        lo_only=lo_only,
     )
-    mid_table, ov = _admit_apply(table, d_lo, d_hi, limb_ov)
-    old_lo = mid_table[:, 0::2]
-    old_hi = mid_table[:, 1::2]
-    n_lo = old_lo - s_lo
-    borrow = (old_lo < s_lo).astype(jnp.uint64)
-    n_hi = old_hi - s_hi - borrow
-    under = (old_hi < s_hi) | ((old_hi == s_hi) & (old_lo < s_lo))
-    final = jnp.stack(
-        [n_lo[:, 0], n_hi[:, 0], n_lo[:, 1], n_hi[:, 1],
-         n_lo[:, 2], n_hi[:, 2], n_lo[:, 3], n_hi[:, 3]],
-        axis=-1,
-    )
-    fallback = ov | s_limb.any() | under.any()
-    new_table = jnp.where(fallback, table, final)
+    mid_rows, ov = _admit(t, *adds)
+    final_rows, bad_release = _release(t, mid_rows, *subs)
+    fallback = ov | bad_release
+    new_table = _write_back(table, t, final_rows, fallback)
 
     last_applied = jnp.where(ok, iota, -1).max()
     flags_word = jnp.where(fallback, jnp.uint64(FLAG_OVERFLOW), jnp.uint64(0))
@@ -1020,11 +1162,7 @@ def _apply_deltas(table, packed):
     new_lo = old_lo + dense_lo
     carry = (new_lo < old_lo).astype(jnp.uint64)
     new_hi = old_hi + dense_hi + carry
-    return jnp.stack(
-        [new_lo[:, 0], new_hi[:, 0], new_lo[:, 1], new_hi[:, 1],
-         new_lo[:, 2], new_hi[:, 2], new_lo[:, 3], new_hi[:, 3]],
-        axis=-1,
-    )
+    return _restack(new_lo, new_hi)
 
 
 def _meta_update(meta, slots, acct_flags, acct_ledger):
