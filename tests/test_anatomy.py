@@ -12,7 +12,7 @@ from tigerbeetle_tpu.obs.anatomy import (
     exemplar_trace_events,
 )
 from tigerbeetle_tpu.obs.flight import FlightRecorder
-from tigerbeetle_tpu.utils.tracer import Tracer
+from tigerbeetle_tpu.utils.tracer import Stage, Tracer
 from tigerbeetle_tpu.vsr import wire
 
 # ----------------------------------------------------------------------
@@ -238,7 +238,8 @@ def test_flight_dump_merges_into_perfetto_timeline(tmp_path):
     p1 = str(tmp_path / "flight0.json")
     fl.write(p1)
     t = Tracer("json", process_id=0)
-    with t.span("commit", op=1):
+    with t.stage(Stage(obs.Registry(enabled=False).histogram("commit_us"),
+                       "commit", leaf=False), op=1):
         pass
     p2 = str(tmp_path / "trace0.json")
     t.write(p2)

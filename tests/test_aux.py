@@ -10,7 +10,8 @@ from tigerbeetle_tpu import types
 from tigerbeetle_tpu.state_machine import CpuStateMachine
 from tigerbeetle_tpu.testing.harness import account, pack, transfer
 from tigerbeetle_tpu.utils.statsd import StatsD
-from tigerbeetle_tpu.utils.tracer import Tracer
+from tigerbeetle_tpu.obs.registry import Registry
+from tigerbeetle_tpu.utils.tracer import Stage, Tracer
 from tigerbeetle_tpu.vsr import aof as aof_mod
 from tigerbeetle_tpu.vsr import replica as vsr_replica
 from tigerbeetle_tpu.vsr.grid import Grid
@@ -18,41 +19,51 @@ from tigerbeetle_tpu.vsr.scrubber import GridScrubber
 from tigerbeetle_tpu.vsr.storage import MemoryStorage, ZoneLayout
 
 
+def _stages(reg):
+    return (Stage(reg.histogram("commit_us"), "vsr.commit", leaf=False),
+            Stage(reg.histogram("plan_us"), "sm.plan"))
+
+
 def test_tracer_spans():
-    t = Tracer(backend="json")
-    with t.span("commit"):
-        with t.span("state_machine_commit"):
+    """Nested stages leave nested spans, inner first; backend "none"
+    feeds the histograms and leaves no span."""
+    t, reg = Tracer(backend="json"), Registry(enabled=True)
+    commit, plan = _stages(reg)
+    with t.stage(commit):
+        with t.stage(plan):
             pass
     doc = json.loads(t.dump())
     names = [e["name"] for e in doc["traceEvents"]]
-    assert names == ["state_machine_commit", "commit"]
+    assert names == ["sm.plan", "vsr.commit"]
     assert all(e["dur"] >= 0 for e in doc["traceEvents"])
 
     none = Tracer(backend="none")
-    with none.span("commit"):
+    with none.stage(commit):
         pass
     assert json.loads(none.dump())["traceEvents"] == []
+    assert reg.histogram("commit_us").count == 2
 
 
-def test_tracer_counters_instants_and_bound():
-    t = Tracer(backend="json", buffer_max=10)
-    t.count("pipeline_depth", 3)
+def test_tracer_instants_stage_rows_and_bound():
+    t, reg = Tracer(backend="json", buffer_max=10), Registry(enabled=True)
     t.instant("view_change", view=2)
-    with t.span("commit", slot=5, op=77):
+    # A stage names its row of the trace (a worker's) and carries args.
+    work = Stage(reg.histogram("beat.work_us"), "lsm.beat.work", tid=3)
+    with t.stage(work, op=77):
         pass
     doc = json.loads(t.dump())
     by_name = {e["name"]: e for e in doc["traceEvents"]}
-    assert by_name["pipeline_depth"]["ph"] == "C"
-    assert by_name["pipeline_depth"]["args"]["value"] == 3
     assert by_name["view_change"]["ph"] == "i"
-    assert by_name["commit"]["tid"] == 5
-    assert by_name["commit"]["args"]["op"] == 77
+    assert by_name["view_change"]["args"] == {"view": 2}
+    assert by_name["lsm.beat.work"]["ph"] == "X"
+    assert by_name["lsm.beat.work"]["tid"] == 3
+    assert by_name["lsm.beat.work"]["args"]["op"] == 77
     # Bounded buffer: oldest events drop, drop count reported.
     for i in range(50):
-        t.count("x", i)
+        t.instant("x", i=i)
     doc = json.loads(t.dump())
     assert len(doc["traceEvents"]) == 10
-    assert doc["otherData"]["dropped_events"] == 43
+    assert doc["otherData"]["dropped_events"] == 42
 
 
 def test_server_writes_trace(tmp_path):
@@ -92,7 +103,7 @@ def test_server_writes_trace(tmp_path):
     server.close()
     doc = json.loads(open(trace).read())
     names = {e["name"] for e in doc["traceEvents"]}
-    assert "state_machine_commit" in names
+    assert {"vsr.commit", "vsr.commit.prefetch", "vsr.commit.reply"} <= names
     assert "vsr.journal.write" in names
 
 

@@ -456,8 +456,10 @@ def test_finalisers_of_cold_pendings_reach_the_posted_tree(cold):
     # Every joined row was stored as `pending`; none was finalised yet.
     assert s1["store.posted_lookups"] - s0["store.posted_lookups"] == PER
     assert s1["store.posted_hits"] == 0
-    assert s1["plan.join_cold_us.count"] >= 1
     assert s1["fallback_events"] == 0
+    # The join is a part of sm.plan: timed where that leaf is open (the
+    # device engine's plan), counted alone on the host engine's path.
+    assert (s1["plan.join_cold_us.count"] >= 1) == (cold["engine"] == "device")
     if cold["engine"] == "device":
         assert s1["dev.kind.two_phase_lo.batches"] == 1
         assert s1["dev.fallback_batches"] == 0

@@ -1,4 +1,4 @@
-"""Observability spine: tracer slot discipline, registry/histogram
+"""Observability spine: tracer stage discipline, registry/histogram
 exactness, snapshot monotonicity under chaos, trace merging, scrape
 rendering, and the hot-path overhead contract."""
 
@@ -15,44 +15,61 @@ from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine
 from tigerbeetle_tpu.testing import harness as hz
 from tigerbeetle_tpu.testing.chaos import ChaosLink
 from tigerbeetle_tpu.testing.vopr import Workload
-from tigerbeetle_tpu.utils.tracer import _NOOP_SPAN, Tracer
+from tigerbeetle_tpu.utils.tracer import NOOP_RUN, Stage, Tracer
 
 # ----------------------------------------------------------------------
-# Tracer slot discipline + buffer accounting.
+# Tracer stage discipline + buffer accounting.
 
 
-def test_tracer_double_start_asserts():
+def _leaf(name="vsr.commit.reply", **kw):
+    return Stage(obs.Registry(enabled=True).histogram(name + "_us"), name, **kw)
+
+
+def test_tracer_a_stage_open_twice_asserts_where_leaves_must_not_nest():
+    """What the slot discipline held for spans, `strict_leaves` holds
+    for stages: the same leaf opened again while it is open asserts at
+    once.  On ANOTHER thread it is the documented concurrency (a
+    worker's leaf is its own)."""
+    import threading
+
     t = Tracer("json")
-    t.start("commit", 0)
-    with pytest.raises(AssertionError, match=r"commit\[0\] already open"):
-        t.start("commit", 0)
-    # Same event on a DIFFERENT slot is the documented concurrency
-    # escape hatch.
-    t.start("commit", 1)
-    t.stop("commit", 1)
-    t.stop("commit", 0)
+    t.strict_leaves = True
+    commit = _leaf()
+    with t.stage(commit):
+        with pytest.raises(AssertionError,
+                           match="leaf vsr.commit.reply opened inside leaf vsr.commit.reply"):
+            t.stage(commit).__enter__()
+        th = threading.Thread(target=lambda: t.stage(commit).__enter__().__exit__())
+        th.start()
+        th.join(timeout=10)
+    assert commit.hist.count == 2       # the loop's and the worker's
 
 
-def test_tracer_unbalanced_end_asserts():
+def test_tracer_a_part_without_its_leaf_asserts():
+    """An end without a start cannot be written with a context manager;
+    what can go unbalanced is a part outside its leaf."""
     t = Tracer("json")
-    with pytest.raises(AssertionError, match=r"journal_write\[0\] not open"):
-        t.stop("journal_write", 0)
-    t.start("commit", 0)
-    with pytest.raises(AssertionError, match=r"commit\[3\] not open"):
-        t.stop("commit", 3)
-    t.stop("commit", 0)
+    t.strict_leaves = True
+    part = _leaf("lsm.seal.encode", part=True)
+    with pytest.raises(AssertionError, match="part lsm.seal.encode opened with no leaf"):
+        t.stage(part)
+    with t.stage(_leaf("vsr.commit.beat")):
+        with t.stage(part):
+            pass
+    with pytest.raises(AssertionError, match="opened with no leaf"):
+        t.stage(part)
+    assert part.hist.count == 1
 
 
-def test_tracer_dump_closes_open_spans_at_now_and_marks_them():
+def test_tracer_dump_closes_open_stages_at_now_and_marks_them():
     """A dump from the SIGTERM handler finds the loop wherever it
     stands: what is open is closed at now and marked, never refused,
     and the tracer goes on as it was."""
     t = Tracer("json")
-    t.start("commit")
-    doc = json.loads(t.dump())
-    (span,) = doc["traceEvents"]
-    assert span["name"] == "commit" and span["args"]["open_at_dump"] is True
-    t.stop("commit")
+    with t.stage(_leaf("vsr.commit", leaf=False)):
+        doc = json.loads(t.dump())
+        (span,) = doc["traceEvents"]
+        assert span["name"] == "vsr.commit" and span["args"]["open_at_dump"] is True
     (span,) = json.loads(t.dump())["traceEvents"]  # balanced: once, unmarked
     assert "args" not in span
 
@@ -254,14 +271,16 @@ def test_registry_snapshot_monotonic_under_chaos(_fast_lifecycle):
 # Overhead contract: backend "none" / TB_METRICS=0 cost one check.
 
 
-def test_disabled_tracer_span_is_shared_noop():
+def test_disabled_tracer_stage_is_shared_noop():
     t = Tracer("none")
     assert not t.enabled
+    off = obs.Registry(enabled=False)
+    commit = Stage(off.histogram("commit_us"), "vsr.commit", leaf=False)
+    write = Stage(off.histogram("journal.write_us"), "vsr.journal.write")
     # Identity: no per-site allocation on the disabled path.
-    assert t.span("commit", op=7) is _NOOP_SPAN
-    assert t.span("journal_write") is _NOOP_SPAN
-    t.count("queue", 3)   # all no-ops
-    t.instant("marker")
+    assert t.stage(commit, op=7) is NOOP_RUN
+    assert t.stage(write) is NOOP_RUN
+    t.instant("marker")   # a no-op too
     assert len(json.loads(t.dump())["traceEvents"]) == 0
 
 
@@ -283,10 +302,12 @@ def test_traced_site_overhead_is_one_attribute_check():
     import time
 
     t = Tracer("none")
+    commit = Stage(obs.Registry(enabled=False).histogram("commit_us"),
+                   "vsr.commit", leaf=False)
     n = 20_000
     t0 = time.perf_counter()
     for _ in range(n):
-        with t.span("commit"):
+        with t.stage(commit):
             pass
     per_site = (time.perf_counter() - t0) / n
     assert per_site < 5e-6, f"{per_site * 1e9:.0f} ns/site"
@@ -352,7 +373,7 @@ def test_merge_traces_builds_one_perfetto_timeline(tmp_path):
     paths = []
     for i in range(2):
         t = Tracer("json", process_id=0)  # deliberately colliding pids
-        with t.span("commit", op=i):
+        with t.stage(_leaf("vsr.commit", leaf=False), op=i):
             t.instant("prepare_ok", op=i)
         p = tmp_path / f"r{i}.json"
         t.write(str(p))
@@ -377,7 +398,7 @@ def test_trace_demo_produces_cross_replica_drain(tmp_path):
     # The full replicated-drain timeline, across both process tracks.
     for required in (
         "prepare", "vsr.journal.write", "vsr.gc.sync", "prepare_ok",
-        "vsr.commit", "reply", "state_machine_commit",
+        "vsr.commit", "reply", "vsr.commit.prefetch", "vsr.commit.reply",
     ):
         assert required in names, required
     assert {e["pid"] for e in data["traceEvents"]} == {0, 1}

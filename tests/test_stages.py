@@ -5,10 +5,14 @@ One site, read three ways: the stage's `<name>_us` histogram, the JSON
 span, and a `tb.<name>` annotation through the injected sink — from one
 pair of clock reads.  Leaves tile a thread's time: one that opens
 inside another suspends it, and `strict_leaves` turns that into an
-assertion for the paths whose leaves are meant never to nest.
+assertion for the paths whose leaves are meant never to nest.  A part
+is a piece of the leaf open around it: the leaf's clock runs on, its
+annotation gives way to the part's, and parts tile the leaf as leaves
+tile the thread.
 """
 
 import ast
+import gc
 import json
 import os
 import re
@@ -21,7 +25,7 @@ import time
 import pytest
 
 from tigerbeetle_tpu.obs.registry import _NOOP_HIST, Registry
-from tigerbeetle_tpu.utils.tracer import _NOOP_SPAN, Stage, Tracer
+from tigerbeetle_tpu.utils.tracer import NOOP_RUN, Stage, Tracer
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.join(_REPO, "tigerbeetle_tpu")
@@ -85,7 +89,7 @@ def test_no_clock_read_with_metrics_off_and_backend_none():
     reg = Registry(enabled=False)
     stage = Stage(reg.histogram("plan_us"), "sm.plan")
     assert stage.hist is _NOOP_HIST and not stage.timed
-    assert tracer.stage(stage) is _NOOP_SPAN
+    assert tracer.stage(stage) is NOOP_RUN
     with tracer.stage(stage) as run:
         run.split(3)
         assert run.t0 is None
@@ -199,11 +203,298 @@ def test_stamp_is_the_primitives_clock():
 
 
 # ----------------------------------------------------------------------
+# Parts.
+
+
+def parts_of(reg, leaf="plan", parts=("plan.decode", "plan.pack")):
+    return (Stage(reg.histogram(leaf + "_us"), "sm." + leaf),
+            *(Stage(reg.histogram(p + "_us"), "sm." + p, part=True)
+              for p in parts))
+
+
+def test_a_part_keeps_its_leafs_clock_and_closes_its_annotation():
+    """The leaf's histogram and JSON span stay inclusive; on the
+    profiler's host line the leaf gives way to the part and comes
+    back, so the line still tiles and a gap takes the part's name."""
+    tracer, reg, clock, sink = make()
+    leaf, decode, _pack = parts_of(reg)
+    with tracer.stage(leaf):            # 1
+        with tracer.stage(decode):      # 2..3: no read of the leaf's
+            pass
+    assert clock.reads == 4             # the leaf ends at 4
+    assert reg.histogram("plan_us").total == 3.0          # 4 - 1: inclusive
+    assert reg.histogram("plan.decode_us").total == 1.0
+    assert reg.histogram("plan_us").count == 1
+    assert sink.events == [
+        ("enter", "tb.sm.plan"), ("exit", "tb.sm.plan"),
+        ("enter", "tb.sm.plan.decode"), ("exit", "tb.sm.plan.decode"),
+        ("enter", "tb.sm.plan"), ("exit", "tb.sm.plan"),
+    ]
+    spans = json.loads(tracer.dump())["traceEvents"]
+    assert [(e["name"], e["ts"], e["dur"]) for e in spans] == [
+        ("sm.plan.decode", 2.0, 1.0), ("sm.plan", 1.0, 3.0)]
+
+
+def test_parts_tile_inside_a_leaf_and_never_sum_past_it():
+    """A part inside a part suspends the outer one, clock and
+    annotation, as leaves do to each other; a leaf that opens inside a
+    part suspends part and leaf together.  Whatever nests, the parts'
+    sums stay within their leaf's and no two annotations overlap."""
+    tracer, reg, clock, sink = make()
+    leaf, pending, cold = parts_of(reg, parts=("plan.pending", "plan.join_cold"))
+    inner = Stage(reg.histogram("dev.launch_us"), "sm.dev.launch")
+    with tracer.stage(leaf):                    # leaf from 1
+        with tracer.stage(pending):             # pending from 2
+            with tracer.stage(cold):            # pending stops 3; cold 4..5
+                pass
+            with tracer.stage(inner):           # pending resumed 6, stops 7;
+                pass                            # leaf stops 8; inner 9..10
+        # leaf resumed 11, pending resumed 12 and stops 13; leaf ends 14.
+    assert clock.reads == 14
+    h = {k: reg.histogram(k + "_us").total for k in (
+        "plan", "plan.pending", "plan.join_cold", "dev.launch")}
+    assert h == {"plan": (8 - 1) + (14 - 11), "plan.pending": 1 + 1 + 1,
+                 "plan.join_cold": 1.0, "dev.launch": 1.0}
+    assert h["plan.pending"] + h["plan.join_cold"] <= h["plan"]
+    assert all(reg.histogram(k + "_us").count == 1 for k in h)
+    # The host line: enters and exits alternate, never two open.
+    assert [what for what, _name in sink.events] == ["enter", "exit"] * (
+        len(sink.events) // 2)
+    assert [name for what, name in sink.events if what == "enter"] == [
+        "tb.sm.plan", "tb.sm.plan.pending", "tb.sm.plan.join_cold",
+        "tb.sm.plan.pending", "tb.sm.dev.launch", "tb.sm.plan.pending",
+        "tb.sm.plan"]
+
+
+def test_a_run_moves_on_from_part_to_part_and_each_gets_one_sample():
+    """`run.switch`: block after block a merge reads, merges, writes;
+    the run opens once, and each part it visited gets its total."""
+    tracer, reg, clock, sink = make()
+    leaf, read, merge, write = parts_of(
+        reg, "beat", ("compact.read", "compact.merge", "compact.write"))
+    with tracer.stage(leaf):
+        with tracer.stage(merge) as run:
+            for _block in range(3):
+                run.switch(read)
+                run.switch(merge)
+                run.switch(merge)       # where it stands: nothing
+                run.switch(write)
+                run.switch(merge)
+    for key, total in (("compact.read", 3.0), ("compact.write", 3.0),
+                       ("compact.merge", 7.0)):
+        hist = reg.histogram(key + "_us")
+        assert (hist.count, hist.total) == (1, total), key
+    assert reg.histogram("compact.read_us").total + reg.histogram(
+        "compact.write_us").total + reg.histogram(
+        "compact.merge_us").total <= reg.histogram("beat_us").total
+    names = [name for what, name in sink.events if what == "enter"]
+    assert names[1:5] == ["tb.sm.compact.merge", "tb.sm.compact.read",
+                          "tb.sm.compact.merge", "tb.sm.compact.write"]
+    assert NOOP_RUN.switch(read) is None
+
+
+def test_a_run_hands_stretches_to_other_parts_and_keeps_its_annotation():
+    """`run.add(stage, run.mark())`: a merge's reads and writes, block
+    after block, come off the merge's own time on two clock reads each;
+    the annotation stays the merge's, every part gets one sample."""
+    tracer, reg, clock, sink = make()
+    leaf, read, merge, write = parts_of(
+        reg, "beat", ("compact.read", "compact.merge", "compact.write"))
+    with tracer.stage(leaf):                    # 1
+        with tracer.stage(merge) as run:        # 2
+            for _block in range(3):
+                since = run.mark()              # 3, 7, 11
+                run.add(read, since)            # 4, 8, 12
+                since = run.mark()              # 5, 9, 13
+                run.add(write, since)           # 6, 10, 14
+        # the merge ends at 15, the leaf at 16
+    assert clock.reads == 16
+    totals = {k: (reg.histogram(k + "_us").count, reg.histogram(k + "_us").total)
+              for k in ("compact.read", "compact.write", "compact.merge", "beat")}
+    assert totals == {"compact.read": (1, 3.0), "compact.write": (1, 3.0),
+                      "compact.merge": (1, 13.0 - 6.0), "beat": (1, 15.0)}
+    assert [name for what, name in sink.events if what == "enter"] == [
+        "tb.sm.beat", "tb.sm.compact.merge", "tb.sm.beat"]
+    spans = [e["name"] for e in json.loads(tracer.dump())["traceEvents"]]
+    assert spans == ["sm.compact.merge", "sm.beat"]
+    # Where no clock is read there is nothing to hand over.
+    assert NOOP_RUN.mark() is None and NOOP_RUN.add(read, None) is None
+    off = Tracer("none")
+    off.annotate = Sink()
+    _leaf, _read, quiet, _write = parts_of(
+        Registry(enabled=False), "beat",
+        ("compact.read", "compact.merge", "compact.write"))
+    with off.stage(_leaf), off.stage(quiet) as run:
+        assert run.mark() is None
+        run.add(_read, run.mark())
+
+
+def test_a_part_with_no_leaf_open_measures_nothing_and_asserts_when_strict():
+    """Code shared with paths that run under no leaf (the host
+    engine's, a lookup's) opens its parts there too: not measured.
+    Where the leaves are meant to be there, `strict_leaves` says so."""
+    tracer, reg, clock, sink = make()
+    _leaf, decode, pack = parts_of(reg)
+    assert tracer.stage(decode) is NOOP_RUN
+    with tracer.stage(decode) as run:
+        run.switch(pack)
+    assert clock.reads == 0 and sink.events == []
+    assert reg.histogram("plan.decode_us").count == 0
+    tracer.strict_leaves = True
+    with pytest.raises(AssertionError, match="part sm.plan.decode opened with no leaf"):
+        tracer.stage(decode)
+
+
+def test_strict_leaves_asserts_when_a_part_opens_inside_a_part():
+    tracer, reg, _clock, _sink = make()
+    tracer.strict_leaves = True
+    leaf, decode, pack = parts_of(reg)
+    with tracer.stage(leaf), tracer.stage(decode):
+        with pytest.raises(AssertionError,
+                           match="part sm.plan.pack opened inside part sm.plan.decode"):
+            with tracer.stage(pack):
+                pass
+    with tracer.stage(leaf):            # one after the other they may
+        with tracer.stage(decode):
+            pass
+        with tracer.stage(pack):
+            pass
+    assert reg.histogram("plan.pack_us").count == 1
+
+
+def test_a_part_on_a_workers_thread_finds_the_workers_leaf():
+    """The spill and the seals are the same parts in the commit's beat
+    and on the beat worker: a part belongs to the leaf open on ITS
+    thread, and leaves the other thread's alone."""
+    tracer, reg, _clock, _sink = make()
+    tracer.strict_leaves = True
+    loop = Stage(reg.histogram("commit.beat_us"), "vsr.commit.beat")
+    work = Stage(reg.histogram("beat.work_us"), "lsm.beat.work", tid=3)
+    seal = Stage(reg.histogram("seal.encode_us"), "lsm.seal.encode", part=True)
+    found = []
+
+    def worker():
+        with tracer.stage(work) as leaf:
+            with tracer.stage(seal) as part:
+                found.append((part._leaf is leaf, leaf._part is part))
+            found.append(leaf._part is None)
+
+    with tracer.stage(loop) as mine:
+        th = threading.Thread(target=worker)
+        th.start()
+        th.join(timeout=10)
+        assert mine._part is None
+    assert found == [(True, True), True]
+    assert reg.histogram("seal.encode_us").count == 1
+
+
+def test_a_part_with_metrics_off_reads_no_clock():
+    clock = Clock()
+    tracer = Tracer("none", clock=clock)
+    reg = Registry(enabled=False)
+    leaf, decode, pack = parts_of(reg)
+    with tracer.stage(leaf):
+        assert tracer.stage(decode) is NOOP_RUN
+        with tracer.stage(decode) as run:
+            run.switch(pack)
+    assert clock.reads == 0
+    # With the sink on the annotations are made, and still no clock.
+    tracer.annotate = sink = Sink()
+    with tracer.stage(leaf):
+        with tracer.stage(decode) as run:
+            run.switch(pack)
+    assert clock.reads == 0
+    assert [name for what, name in sink.events if what == "enter"] == [
+        "tb.sm.plan", "tb.sm.plan.decode", "tb.sm.plan.pack", "tb.sm.plan"]
+
+
+def test_a_part_costs_under_three_microseconds():
+    """With no sink (`--trace 0`): two clock reads and one observe.
+    The best of five rounds, so that a busy machine cannot fail it."""
+    tracer, reg = Tracer("none"), Registry(enabled=True)
+    leaf, decode, pack = parts_of(reg)
+    n, best, best_switch, best_add = 20_000, 1.0, 1.0, 1.0
+    with tracer.stage(leaf):
+        for _round in range(5):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with tracer.stage(decode):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / n)
+            with tracer.stage(decode) as run:
+                t0 = time.perf_counter()
+                for _ in range(n // 2):
+                    run.switch(pack)
+                    run.switch(decode)
+                best_switch = min(best_switch, (time.perf_counter() - t0) / n)
+                t0 = time.perf_counter()
+                for _ in range(n):
+                    run.add(pack, run.mark())
+                best_add = min(best_add, (time.perf_counter() - t0) / n)
+    assert best < 3e-6, f"{best * 1e9:.0f} ns a part"
+    assert best_switch < 3e-6, f"{best_switch * 1e9:.0f} ns a switch"
+    assert best_add < 3e-6, f"{best_add * 1e9:.0f} ns a stretch handed over"
+    print(f"part {best * 1e9:.0f} ns, switch {best_switch * 1e9:.0f} ns, "
+          f"add {best_add * 1e9:.0f} ns")
+
+
+def test_the_collectors_pauses_are_counted_on_the_servers_registry():
+    """obs/process.py: one `gc.callbacks` hook; a forced collection
+    moves gen2 and the pause histogram by one; a pause over 50 ms
+    leaves an instant for the flight ring; closed, it counts no more;
+    with TB_METRICS=0 it is never hooked."""
+    from tigerbeetle_tpu.obs import process
+    from tigerbeetle_tpu.obs.flight import FlightRecorder
+
+    tracer, reg = Tracer("none"), Registry(enabled=True)
+    tracer.flight = flight = FlightRecorder(8)
+    hooks = len(gc.callbacks)
+    watch = process.ProcessWatch(reg, tracer)
+    assert len(gc.callbacks) == hooks + 1
+    before = reg.snapshot()
+    gc.collect()
+    after = reg.snapshot()
+    assert after["server.gc.collections.gen2"] == before[
+        "server.gc.collections.gen2"] + 1
+    assert after["server.gc.pause_us.count"] == before[
+        "server.gc.pause_us.count"] + 1
+    assert after["server.gc.pause_us.sum"] > before["server.gc.pause_us.sum"]
+    assert {"server.gc.collections.gen0", "server.gc.collections.gen1"} <= set(after)
+    assert after["server.cpu_us"] > 0 and after["server.minflt"] > 0
+    assert after["server.majflt"] >= 0 and after["server.nivcsw"] >= 0
+    # A long pause (the clock says 60 ms) is noted, a short one (10 ms)
+    # is not.  (The forced collection above may be either: a process
+    # that has imported JAX holds a million objects.)
+    noted = len(flight.events())
+    ticks = iter([0, 10_000_000, 20_000_000, 80_000_000])
+    slow = process.ProcessWatch(Registry(enabled=True), tracer,
+                                clock=lambda: next(ticks))
+    for _pause in range(2):
+        slow._on_gc("start", {"generation": 2})
+        slow._on_gc("stop", {"generation": 2})
+    slow.close()
+    (note,) = flight.events()[noted:]
+    assert note["name"] == "gc_pause" and note["args"] == {
+        "generation": 2, "us": 60_000}
+    watch.close()
+    watch.close()                       # idempotent
+    assert len(gc.callbacks) == hooks
+    gc.collect()
+    assert reg.snapshot()["server.gc.collections.gen2"] == after[
+        "server.gc.collections.gen2"]
+    off = process.ProcessWatch(Registry(enabled=False), tracer)
+    assert len(gc.callbacks) == hooks
+    off.close()
+
+
+# ----------------------------------------------------------------------
 # The stages in the program.
 
 
-def stage_names() -> dict[str, bool]:
-    """name -> leaf, of every `Stage(...)` the package makes."""
+def stage_kinds() -> dict[str, str]:
+    """name -> "leaf", "part" or "enclosing", of every `Stage(...)` the
+    package makes; a part is made with `part=True`, or by an owner's
+    helper `part("<name>")`."""
     found = {}
     for root, _dirs, files in os.walk(PKG):
         for f in files:
@@ -211,28 +502,71 @@ def stage_names() -> dict[str, bool]:
                 continue
             tree = ast.parse(open(os.path.join(root, f)).read())
             for node in ast.walk(tree):
-                if not (isinstance(node, ast.Call) and getattr(
-                        node.func, "attr", getattr(node.func, "id", "")) == "Stage"):
+                if not isinstance(node, ast.Call):
                     continue
-                name = next((a.value for a in node.args[1:2]
+                func = getattr(node.func, "attr", getattr(node.func, "id", ""))
+                at = {"Stage": 1, "part": 0}.get(func)
+                if at is None:
+                    continue
+                name = next((a.value for a in node.args[at:at + 1]
                              if isinstance(a, ast.Constant)), None)
                 if name is None:
                     continue
-                leaf = not any(k.arg == "leaf" and k.value.value is False
+
+                def says(key, value):
+                    return any(k.arg == key and k.value.value is value
                                for k in node.keywords)
-                found[name] = found.get(name, False) or leaf
+
+                kind = ("part" if func == "part" or says("part", True)
+                        else "enclosing" if says("leaf", False) else "leaf")
+                # (vsr.journal.sync is made twice: a leaf on the WAL
+                # worker, enclosed on the loop's thread.)
+                had = found.setdefault(name, kind)
+                assert "part" not in (had, kind) or had == kind, name
+                if kind == "leaf":
+                    found[name] = kind
     return found
+
+
+def stage_names() -> dict[str, bool]:
+    """name -> leaf, of the leaves and the enclosing stages."""
+    return {n: k == "leaf" for n, k in stage_kinds().items() if k != "part"}
 
 
 LEAVES = {
     "server.poll_wait", "server.ingress", "vsr.admit", "vsr.prepare",
     "vsr.journal.write", "vsr.gc.sync", "vsr.commit.prefetch", "sm.plan",
-    "sm.plan.join_cold", "sm.dev.scrub.cost", "sm.dev.launch", "sm.dev.dispatch",
+    "sm.dev.scrub.cost", "sm.dev.launch", "sm.dev.dispatch",
     "sm.dev.commit.update", "sm.dev.link.fetch_wait",
     "sm.dev.link.fetch_copy", "sm.dev.finish", "vsr.commit.reply",
     "vsr.commit.beat", "vsr.reply_send", "vsr.tick", "vsr.ckpt.freeze",
     "vsr.ckpt.finalize", "vsr.journal.sync", "vsr.replicate.send",
     "vsr.backup.accept", "lsm.beat.work",
+}
+# The parts, by the leaf whose question they answer (the LSM's open in
+# the commit's beat, on the beat worker and in a checkpoint's freeze).
+PLAN_PARTS = {"sm.plan." + p for p in (
+    "decode", "ids", "id_dir", "accounts", "route", "pending", "pack",
+    "join_cold")}
+FINISH_PARTS = {"sm.finish." + p for p in (
+    "codes", "mirror", "twin", "store", "ids", "native_ids", "status", "reply")}
+LSM_PARTS = {"sm.spill.take", "sm.spill.objects", "sm.spill.index",
+             "lsm.seal.concat", "lsm.seal.encode", "lsm.compact.read",
+             "lsm.compact.merge", "lsm.compact.write"}
+FREEZE_PARTS = {"sm.ckpt.drain", "sm.ckpt.verify_device",
+                "sm.ckpt.verify_host", "sm.ckpt.encode",
+                "vsr.ckpt.freeze.wrap", "vsr.ckpt.freeze.root",
+                "vsr.ckpt.freeze.write", "vsr.ckpt.freeze.checksum"}
+PARTS = PLAN_PARTS | FINISH_PARTS | LSM_PARTS | FREEZE_PARTS
+# Counters at the parts' boundaries, and the process's own pauses
+# (obs/process.py) on a server's registry.
+PART_COUNTERS = {"lsm.seal.bytes", "lsm.compact.blocks_read",
+                 "lsm.compact.blocks_written"}
+PROCESS_KEYS = {
+    "server.gc.pause_us.count", "server.gc.pause_us.sum",
+    "server.gc.pause_us.max", "server.gc.collections.gen0",
+    "server.gc.collections.gen1", "server.gc.collections.gen2",
+    "server.cpu_us", "server.minflt", "server.majflt", "server.nivcsw",
 }
 # What runs on a worker's thread: annotated there, out of the loop's
 # sums (`server_loop_attributed_pct`, `commit_attributed_pct`).
@@ -262,11 +596,21 @@ REPLICATION_LEAVES = {"vsr.replicate.send", "vsr.backup.accept"}
 
 
 def test_the_stage_names_live_once_in_code():
-    stages = stage_names()
-    assert {n for n, leaf in stages.items() if leaf} == LEAVES
-    assert {n for n, leaf in stages.items() if not leaf} == {"vsr.commit"}
+    kinds = stage_kinds()
+    assert {n for n, k in kinds.items() if k == "leaf"} == LEAVES
+    assert {n for n, k in kinds.items() if k == "enclosing"} == {"vsr.commit"}
+    assert {n for n, k in kinds.items() if k == "part"} == PARTS
     src = open(os.path.join(PKG, "utils", "tracer.py")).read()
     assert "EVENTS" not in src
+    # One system: the slot spans and the counter series are gone.
+    for gone in ("def span", "def start", "def stop", "def count", "_Span"):
+        assert gone not in src, gone
+    used = [
+        os.path.join(root, f)
+        for root, _dirs, files in os.walk(PKG) for f in files
+        if f.endswith(".py") and re.search(
+            r"tracer\.(span|start|stop|count)\(", open(os.path.join(root, f)).read())]
+    assert used == []
 
 
 def test_perf_md_has_a_row_for_every_stage():
@@ -275,9 +619,42 @@ def test_perf_md_has_a_row_for_every_stage():
     the code has not."""
     text = open(os.path.join(_REPO, "PERF.md")).read()
     table = text[text.index("### Stages"):]
-    table = table[:table.index("\n## ")]
+    table = table[:table.index("\n### Parts")]
     rows = set(re.findall(r"^\| `([a-z_.]+)_us` \|", table, re.M))
     assert rows == set(stage_names())
+
+
+def _parts_table() -> list[list[str]]:
+    text = open(os.path.join(_REPO, "PERF.md")).read()
+    table = text[text.index("### Parts"):]
+    table = table[:table.index("\n## ")]
+    return [[cell.strip() for cell in line.strip("|").split("|")]
+            for line in table.splitlines() if line.startswith("| `")]
+
+
+def test_perf_md_has_a_row_for_every_part_counter_and_gauge():
+    """PERF.md section 3's parts table is held to the code too: a row a
+    part under its scrape key, its leaves ones the code has, and in the
+    last column the metric file that reads that key; then the counters
+    at the parts' boundaries and the process's own keys."""
+    rows = _parts_table()
+    keys_of = [re.findall(r"`([a-z_.0-9]+)`", row[0]) for row in rows]
+    keys = [k for ks in keys_of for k in ks]
+    assert len(keys) == len(set(keys))
+    process = {k.removesuffix(".count") for k in PROCESS_KEYS
+               if not k.endswith((".sum", ".max"))}
+    assert set(keys) == {p + "_us" for p in PARTS} | PART_COUNTERS | process
+    for row, row_keys in zip(rows, keys_of):
+        is_part = row_keys[0][:-3] in PARTS
+        leaves = re.findall(r"`([a-z_.]+)`", row[2])
+        assert set(leaves) <= LEAVES and bool(leaves) == is_part, row_keys
+        files = re.findall(r"`([a-z_0-9]+)`", row[3])
+        assert len(files) == 1 or not is_part, row_keys
+        for name in files:
+            spec = json.load(open(os.path.join(
+                _REPO, "benchmarks", "layer_metrics", name + ".json")))
+            assert any(read == k or read.startswith(k + ".")
+                       for read in spec["keys"] for k in row_keys), (row_keys, name)
 
 
 @pytest.mark.parametrize("sub", ["vsr", "lsm", "utils", "obs"])
@@ -331,6 +708,19 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
 
     server = _device_server(tmp_path, str(tmp_path / "trace.json"))
     assert server.tracer.annotate is not None     # the engine is the device one
+    # Inside the commit: no leaf inside a leaf, no part inside a part,
+    # no part without its leaf (with join_cold a part, one rule covers
+    # all below a leaf).  Outside it the loop's leaves do nest.
+    commit = server.replica._commit_prepare
+
+    def strict_commit(*args, **kwargs):
+        server.tracer.strict_leaves = True
+        try:
+            return commit(*args, **kwargs)
+        finally:
+            server.tracer.strict_leaves = False
+
+    server.replica._commit_prepare = strict_commit
     stop, failed = [], []
 
     def loop():
@@ -361,10 +751,26 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     # spill waits for, so no beat is handed to the worker and no join
     # meets a row that has left the tail.)
     on_the_path = LEAVES - REPLICATION_LEAVES - WORKER_LEAVES - {
-        "vsr.ckpt.freeze", "sm.plan.join_cold"}
+        "vsr.ckpt.freeze"}
     assert snap["sm.plan.join_cold_us.count"] == 0
     assert snap["sm.store.join_cold_rows"] == 0
     assert BEAT_KEYS | COMPACT_KEYS | POSTED_KEYS <= set(snap)
+    # Every part, counter and gauge of PR 37 is on the scrape, opened
+    # or not; what this path opens are the plan's parts of a plain
+    # batch and the finish's, once a prepare.
+    assert {p + "_us.count" for p in PARTS} | PART_COUNTERS | PROCESS_KEYS <= set(snap)
+    opened = {p for p in PARTS if snap[p + "_us.count"]}
+    assert opened == (PLAN_PARTS - {"sm.plan.pending", "sm.plan.join_cold"}
+                      ) | FINISH_PARTS
+    for p in opened:
+        assert snap[p + "_us.count"] == 12, p
+    for leaf, parts in (("sm.plan", PLAN_PARTS), ("sm.dev.finish", FINISH_PARTS)):
+        inside = sum(snap[p + "_us.sum"] for p in parts)
+        assert 0.7 * snap[leaf + "_us.sum"] <= inside <= snap[leaf + "_us.sum"], leaf
+    assert snap["server.gc.collections.gen0"] + snap[
+        "server.gc.collections.gen1"] + snap["server.gc.collections.gen2"] == snap[
+        "server.gc.pause_us.count"]
+    assert 0 < snap["server.cpu_us"]
     assert not any(snap[key] for key in COMPACT_KEYS | POSTED_KEYS)   # nothing sealed or cold
     assert snap["lsm.beat.work_us.count"] == snap["lsm.beat.queued"] == 0
     for name in REPLICATION_LEAVES:
@@ -393,6 +799,15 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     assert 3 * fetched <= snap["sm.dev.link.puts"] < 4 * fetched + 8
 
     doc = json.load(open(tmp_path / "trace.json"))
+    # A part's span lies inside a span of its leaf (the JSON trace
+    # keeps the leaf whole; the profiler's line has it give way).
+    xs = [e for e in doc["traceEvents"] if e.get("ph") == "X" and e["tid"] == 0]
+    for leaf, parts in (("sm.plan", PLAN_PARTS), ("sm.dev.finish", FINISH_PARTS)):
+        around = [(e["ts"], e["ts"] + e["dur"]) for e in xs if e["name"] == leaf]
+        for e in xs:
+            if e["name"] in parts:
+                assert any(a - 0.002 <= e["ts"] and e["ts"] + e["dur"] <= b + 0.002
+                           for a, b in around), e
     leaves = stage_names()
     # (vsr.journal.sync is a leaf on the WAL worker only: on the loop's
     # thread the covering sync's leaf encloses it.)
@@ -474,8 +889,44 @@ def test_the_beat_stage_is_the_hand_over_and_the_work_is_the_workers(tmp_path):
     release.set()
     r.forest.barrier()
     vsr, lsm = r.metrics.snapshot(), r.forest.metrics.snapshot()
+    sm = r.sm.metrics.snapshot()
+    # A checkpoint: its freeze spills the tail and seals every tree,
+    # through the same parts, on the loop's thread now.
+    r.checkpoint()
+    vsr2, lsm2, sm2 = (r.metrics.snapshot(), r.forest.metrics.snapshot(),
+                       r.sm.metrics.snapshot())
     r.close()
     storage.close()
+
+    def total(snaps, parts, prefix):
+        return sum(snap[p[len(prefix):] + "_us.sum"] for snap in snaps
+                   for p in parts if p.startswith(prefix))
+
+    lsm_parts = LSM_PARTS - {"sm.spill.take"}
+    # The worker's leaf: objects, index entries, seals, compaction.
+    work = total([lsm], lsm_parts, "lsm.") + total([sm], lsm_parts, "sm.")
+    assert 0.7 * lsm["beat.work_us.sum"] <= work <= lsm["beat.work_us.sum"]
+    assert sm["spill.objects_us.count"] == sm["spill.index_us.count"] == 4
+    # (Four beats of 8,190 rows stay under the 32,768 a seal waits for.)
+    assert lsm["seal.bytes"] == lsm["seal.encode_us.count"] == 0
+    # The hand-over's one part is the copy of the rows out of the tail.
+    assert sm["spill.take_us.count"] == 4
+    assert 0 < sm["spill.take_us.sum"] <= vsr["commit.beat_us.sum"]
+    # The freeze: the LSM's parts again, the state machine's (a host
+    # engine drains and encodes; it verifies only where asked) and the
+    # replica's own.
+    assert vsr2["ckpt.freeze_us.count"] == 1
+    freeze = (
+        total([lsm2], lsm_parts, "lsm.") - total([lsm], lsm_parts, "lsm.")
+        + total([sm2], lsm_parts | FREEZE_PARTS, "sm.")
+        - total([sm], lsm_parts | FREEZE_PARTS, "sm.")
+        + total([vsr2], FREEZE_PARTS, "vsr."))
+    assert 0.7 * vsr2["ckpt.freeze_us.sum"] <= freeze <= vsr2["ckpt.freeze_us.sum"]
+    for key in ("wrap", "root", "write", "checksum"):
+        assert vsr2[f"ckpt.freeze.{key}_us.count"] == 1, key
+    assert sm2["ckpt.drain_us.count"] == sm2["ckpt.encode_us.count"] == 1
+    assert lsm2["seal.encode_us.count"] == lsm2["seal.concat_us.count"] >= 3
+    assert lsm2["seal.bytes"] > 32_760 * 100
 
     commits = vsr["commit_us.count"]
     assert vsr["commit.beat_us.count"] == commits >= 7
@@ -486,9 +937,16 @@ def test_the_beat_stage_is_the_hand_over_and_the_work_is_the_workers(tmp_path):
     inside = sum(vsr[k + "_us.sum"] for k in (
         "commit.prefetch", "commit.reply", "commit.beat"))
     assert 0 < vsr["commit.beat_us.sum"] < inside <= vsr["commit_us.sum"]
-    # Annotated on the worker's thread, and only there.
+    # Annotated on the worker's thread, and only there; its parts too;
+    # the freeze's seals run on the loop's.
     where = {t for name, t in on_thread if name == "tb.lsm.beat.work"}
     assert where == {"lsm-beat"}
+    assert {t for name, t in on_thread if name == "tb.sm.spill.objects"} == {
+        "lsm-beat"}
+    assert {t for name, t in on_thread if name == "tb.lsm.seal.encode"} == {
+        threading.current_thread().name}
+    assert {t for name, t in on_thread if name == "tb.sm.spill.take"} == {
+        threading.current_thread().name}
     assert {t for name, t in on_thread if name == "tb.vsr.commit.beat"} == {
         threading.current_thread().name}
     # Its own row in the JSON trace; the loop's leaves still never overlap.
@@ -564,7 +1022,15 @@ def test_three_served_replicas_feed_the_replication_stages(
     for s in servers:
         s.close()
     for i, snap in enumerate(snaps):
+        # The process's gauges and the collector's counters are on every
+        # server's scrape, the pause histogram where histograms are fed.
+        # (A CpuStateMachine has no forest and no parts.)
+        assert {"server.cpu_us", "server.minflt", "server.majflt",
+                "server.nivcsw", "server.gc.collections.gen2"} <= set(snap)
+        assert (PROCESS_KEYS <= set(snap)) == (metrics == "1")
+        assert not any(snap.get(p + "_us.count") for p in PARTS)
         if metrics == "0":
+            assert snap["server.gc.collections.gen0"] == 0      # never hooked
             assert not any(k.startswith(("vsr.replicate.send_us", "vsr.quorum_wait_us",
                                          "vsr.backup.accept_us")) and v
                            for k, v in snap.items())

@@ -64,8 +64,15 @@ class Forest:
         # (the grid writer's switch); else beats run in place.
         self.metrics = obs.Registry()
         self.beats = BeatWorker(self.metrics, beat_worker and file_backed)
-        # What compaction did, over all trees (`lsm.compact.*`).
+        # What seals and compaction did and took, over all trees
+        # (`lsm.seal.*`, `lsm.compact.*`).
         self.stats = TreeStats(self.metrics)
+
+    def set_tracer(self, tracer) -> None:
+        """The owner's tracer: the beat worker's leaf and the trees'
+        parts open through it."""
+        self.beats.tracer = tracer
+        self.stats.tracer = tracer
 
     def barrier(self) -> None:
         """Join the beats handed to the worker: before anything on
@@ -108,11 +115,13 @@ class Forest:
         deterministic."""
         used = 0
         n = len(self._trees)
-        for k in range(n):
-            if used >= block_budget:
-                break
-            tree = self._trees[(self._beat_cursor + k) % n]
-            used += tree.compact_beat(block_budget - used)
+        stats = self.stats
+        with stats.tracer.stage(stats.compact_merge) as part:
+            for k in range(n):
+                if used >= block_budget:
+                    break
+                tree = self._trees[(self._beat_cursor + k) % n]
+                used += tree.compact_beat(block_budget - used, part)
         self._beat_cursor = (self._beat_cursor + 1) % max(1, n)
         return used
 
@@ -163,10 +172,13 @@ class Forest:
         dominate the big trees are metadata-only.  Remaining over-full
         levels start their merges in the next interval's beats."""
         self.barrier()
+        stats = self.stats
         for tree in self._trees:
             tree.seal_memtable()
-            while tree._job is not None:
-                tree.compact_beat(1 << 30)
+            if tree._job is not None:
+                with stats.tracer.stage(stats.compact_merge) as part:
+                    while tree._job is not None:
+                        tree.compact_beat(1 << 30, part)
         # Log flush acquires blocks BEFORE staged releases activate, so
         # blocks referenced by the previous superblock are never
         # overwritten inside this checkpoint's crash window.

@@ -21,6 +21,8 @@ import numpy as np
 
 from tigerbeetle_tpu import obs
 from tigerbeetle_tpu.lsm.runs import KEY_DTYPE, key_span, keys_le, pack_u128
+from tigerbeetle_tpu.utils import tracer as tracer_mod
+from tigerbeetle_tpu.utils.tracer import NOOP_RUN
 from tigerbeetle_tpu.vsr.grid import Grid
 
 LEVELS = 7          # reference: src/config.zig lsm_levels
@@ -73,12 +75,37 @@ class Run:
 
 
 class TreeStats:
-    """What compaction and point reads did, counted where it happens.
-    A forest makes one on its registry (scrape: `lsm.compact.*`,
-    `lsm.tree.runs_peak`, `lsm.lookup.*`) and shares it among its
-    trees; a tree alone counts on its own."""
+    """What seals, compaction and point reads did, counted and timed
+    where it happens.  A forest makes one on its registry (scrape:
+    `lsm.seal.*`, `lsm.compact.*`, `lsm.tree.runs_peak`,
+    `lsm.lookup.*`) and shares it among its trees; a tree alone counts
+    on its own, untimed."""
 
     def __init__(self, registry: obs.Registry) -> None:
+        # Parts (utils/tracer.py) of whichever leaf the work runs in:
+        # the commit's beat, the beat worker's, a checkpoint's freeze.
+        # The forest's owner shares its tracer.
+        self.tracer = tracer_mod.NULL
+
+        def part(name: str) -> tracer_mod.Stage:
+            key = name.removeprefix("lsm.") + "_us"
+            return tracer_mod.Stage(registry.histogram(key), name, part=True)
+
+        # A seal up to the native call (the memtable's batches merged
+        # into one run), then the run's encode and its block writes.
+        self.seal_concat = part("lsm.seal.concat")
+        self.seal_encode = part("lsm.seal.encode")
+        self.seal_bytes = registry.counter("seal.bytes")
+        # A merge job's steps: ONE run of compact.merge a beat (the
+        # merge itself with the job's own bookkeeping), which hands to
+        # compact.read its input blocks read and decoded and to
+        # compact.write its output blocks encoded and written, the
+        # final swap and its manifest event.
+        self.compact_read = part("lsm.compact.read")
+        self.compact_merge = part("lsm.compact.merge")
+        self.compact_write = part("lsm.compact.write")
+        self.blocks_read = registry.counter("compact.blocks_read")
+        self.blocks_written = registry.counter("compact.blocks_written")
         self.jobs = registry.counter("compact.jobs")
         self.moves = registry.counter("compact.moves")  # of the jobs
         # Entries a merge read and wrote (a move reads and writes none).
@@ -340,22 +367,23 @@ class Tree:
         same debt across the beats of a bar)."""
         if not self.memtable:
             return
-        # Newest batch first: k_way_merge keeps the newest version.
-        keys, flags, vals = k_way_merge_flags(
-            list(reversed(self.memtable)), self.value_size
-        )
-        self.memtable.clear()
-        self.memtable_count = 0
-        run = self._new_run(keys, flags, vals, level=0)
+        stats = self.stats
+        with stats.tracer.stage(stats.seal_concat) as part:
+            # Newest batch first: k_way_merge keeps the newest version.
+            keys, flags, vals = k_way_merge_flags(
+                list(reversed(self.memtable)), self.value_size
+            )
+            self.memtable.clear()
+            self.memtable_count = 0
+            run = self._file_run(
+                self._write_run(keys, flags, vals, part).blocks, 0
+            )
         self.levels[0].append(run)
         # Only a seal raises a tree's run count: a job takes more than
         # it leaves.
         runs = sum(len(level) for level in self.levels)
         if runs > self.stats.runs_peak.value:
             self.stats.runs_peak.set(runs)
-
-    def _new_run(self, keys, flags, vals, *, level: int) -> Run:
-        return self._file_run(self._write_run(keys, flags, vals).blocks, level)
 
     def _file_run(self, blocks: list[RunBlock], level: int) -> Run:
         """A run of `blocks` (written already), the tree's newest, on
@@ -397,7 +425,9 @@ class Tree:
         )
         return (self.grid.payload_size - 4) // entry
 
-    def _write_run(self, keys, flags, vals) -> Run:
+    def _write_run(self, keys, flags, vals, part=NOOP_RUN) -> Run:
+        """`part`: the seal's open run, which goes on as the encode
+        from the native call."""
         per_block = self._per_block()
         blocks = []
         fs = self.grid.free_set
@@ -409,10 +439,12 @@ class Tree:
         # _block_payload defines the bytes and is the fallback.
         from tigerbeetle_tpu.runtime import fastpath
 
+        part.switch(self.stats.seal_encode)
         payloads = fastpath.encode_run(
             keys, flags, vals, self.value_size, per_block,
             self.sparse_values,
         )
+        encoded = 0
         for i, at in enumerate(range(0, n, per_block)):
             k = keys[at : at + per_block]
             if payloads is not None:
@@ -423,6 +455,7 @@ class Tree:
                 )
             address = fs.acquire(reservation)
             self.grid.write_block(address, payload)
+            encoded += len(payload)
             blocks.append(
                 RunBlock(
                     address=address, count=len(k),
@@ -430,6 +463,7 @@ class Tree:
                 )
             )
         fs.forfeit(reservation)
+        self.stats.seal_bytes.inc(encoded)
         return Run(blocks=blocks)
 
     def _level_run_max(self, level: int) -> int:
@@ -462,11 +496,14 @@ class Tree:
     def compaction_pending(self) -> bool:
         return self._job is not None or self._over_full_level() is not None
 
-    def compact_beat(self, block_budget: int) -> int:
+    def compact_beat(self, block_budget: int, part=NOOP_RUN) -> int:
         """Advance compaction by at most `block_budget` grid blocks
         (read + written); returns blocks actually used.  Deterministic:
         driven by commit count, never wall clock, so replicas stay
-        byte-identical."""
+        byte-identical.  `part`: the caller's open run of
+        `lsm.compact.merge`, out of which the job's steps hand their
+        reads and writes to `lsm.compact.read` and `.write` (one run a
+        beat, however many blocks)."""
         used = 0
         while used < block_budget:
             if self._job is None:
@@ -474,7 +511,7 @@ class Tree:
                 if level is None:
                     break
                 self._job = CompactionJob(self, level)
-            used += self._job.step(block_budget - used)
+            used += self._job.step(block_budget - used, part)
             if self._job.done:
                 self._job = None
         return used
@@ -671,12 +708,16 @@ class CompactionJob:
         self.tree.stats.moves.inc()
         return True
 
-    def step(self, block_budget: int) -> int:
+    def step(self, block_budget: int, part=NOOP_RUN) -> int:
+        tree = self.tree
+        stats = tree.stats
         if not self.done and not self.out_blocks and not self._buf:
             # First step: a disjoint input set moves instead of merging.
-            if self._try_move():
+            since = part.mark()
+            moved = self._try_move()
+            part.add(stats.compact_write, since)
+            if moved:
                 return 0
-        tree = self.tree
         per_block = tree._per_block()
         used = 0
         while used < block_budget and not self.done:
@@ -686,16 +727,19 @@ class CompactionJob:
                 if inp.keys is None and inp.block < len(inp.run.blocks):
                     if used >= block_budget:
                         return used
+                    since = part.mark()
                     inp.keys, inp.flags, inp.vals = tree._read_run_block(
                         inp.run.blocks[inp.block]
                     )
+                    part.add(stats.compact_read, since)
                     inp.offset = 0
                     used += 1
-                    tree.stats.entries_in.inc(len(inp.keys))
+                    stats.blocks_read.inc()
+                    stats.entries_in.inc(len(inp.keys))
                 if inp.keys is not None:
                     loaded.append(inp)
             if not loaded:
-                used += self._finalize(per_block)
+                used += self._finalize(per_block, part)
                 return used
             # bytes comparison == key order (big-endian pack).
             bound = np.frombuffer(
@@ -728,7 +772,7 @@ class CompactionJob:
                 self._buf.append((keys, flags, vals))
                 self._buf_count += len(keys)
             while self._buf_count >= per_block and used < block_budget:
-                used += self._flush_block(per_block)
+                used += self._flush_block(per_block, part)
         return used
 
     def _pop_buffered(self, count: int):
@@ -741,19 +785,25 @@ class CompactionJob:
         self._buf_count = len(rest[0])
         return take
 
-    def _flush_block(self, per_block: int) -> int:
+    def _flush_block(self, per_block: int, part) -> int:
+        stats = self.tree.stats
         keys, flags, vals = self._pop_buffered(per_block)
+        since = part.mark()
         self.out_blocks.append(self.tree._write_one_block(keys, flags, vals))
-        self.tree.stats.entries_out.inc(len(keys))
+        part.add(stats.compact_write, since)
+        stats.blocks_written.inc()
+        stats.entries_out.inc(len(keys))
         return 1
 
-    def _finalize(self, per_block: int) -> int:
+    def _finalize(self, per_block: int, part) -> int:
         used = 0
         while self._buf_count:
-            used += self._flush_block(per_block)
+            used += self._flush_block(per_block, part)
+        since = part.mark()
         for _, run in self.taken:
             self.tree._release_run(run)
         self._swap(self.taken, self.out_blocks)
+        part.add(self.tree.stats.compact_write, since)
         return used
 
 

@@ -335,6 +335,12 @@ class ReplicaServer:
         self._st_tick = Stage(
             self.replica.metrics.histogram("tick_us"), "vsr.tick"
         )
+        # The process's own pauses (obs/process.py): the collector's,
+        # and what the kernel's accounting says of CPU time, page
+        # faults and involuntary switches.
+        from tigerbeetle_tpu.obs.process import ProcessWatch
+
+        self._process_watch = ProcessWatch(self.registry, tracer)
         self._c_drains = self.registry.counter("server.drains")
         self._c_drain_rounds = self.registry.counter("server.drain_rounds")
         # Columnar ingest fast path (round 14): TB_FASTPATH_DECODE=1
@@ -956,6 +962,7 @@ class ReplicaServer:
             self.replica.aof.close()
         if self._trace_path:
             self.tracer.write(self._trace_path)
+        self._process_watch.close()
         self.bus.native.close()
         self.storage.close()
 

@@ -23,6 +23,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from tigerbeetle_tpu.utils.tracer import NOOP_RUN
+
 _MASK32 = np.uint64(0xFFFFFFFF)
 
 
@@ -120,10 +122,21 @@ class BalanceMirror:
         # without a full-table pass.  None = disabled (TB_STATE_COMMIT
         # =0), zero overhead.
         self.commitment = None
+        # The part (utils/tracer.py Stage) the twin's re-hash is timed
+        # as, where a caller hands its open run in (`part=`): the
+        # owning machine's sm.finish.twin.
+        self.twin_part = None
 
-    def _touch(self, slots) -> None:
-        if self.commitment is not None:
+    def _touch(self, slots, part=NOOP_RUN) -> None:
+        if self.commitment is None:
+            return
+        was = part.stage  # None: no run handed in
+        if was is None or self.twin_part is None:
             self.commitment.refresh(slots, self)
+            return
+        part.switch(self.twin_part)
+        self.commitment.refresh(slots, self)
+        part.switch(was)
 
     def grow(self, capacity: int) -> None:
         if capacity <= len(self.lo):
@@ -174,7 +187,7 @@ class BalanceMirror:
 
     def try_apply_adds(
         self, dr_slot, cr_slot, amt_lo, amt_hi, is_pending, mask,
-        commit: bool = True,
+        commit: bool = True, part=NOOP_RUN,
     ):
         """Fast-path admission + commit.
 
@@ -220,11 +233,12 @@ class BalanceMirror:
         if not moved.all():
             u_slot, u_col = u_slot[moved], u_col[moved]
             d_lo, d_hi = d_lo[moved], d_hi[moved]
-        if not self._admit_commit(u_slot, u_col, d_lo, d_hi, commit):
+        if not self._admit_commit(u_slot, u_col, d_lo, d_hi, commit, part):
             return None
         return (u_slot, u_col, d_lo, d_hi)
 
-    def _admit_commit(self, u_slot, u_col, d_lo, d_hi, commit: bool) -> bool:
+    def _admit_commit(self, u_slot, u_col, d_lo, d_hi, commit: bool,
+                      part=NOOP_RUN) -> bool:
         """Shared admission tail: per-column u128 overflow + combined
         dp+dpo / cp+cpo totals of every touched account, checked
         against the all-applied upper bound; mutates only when BOTH
@@ -252,10 +266,10 @@ class BalanceMirror:
             self.lo[u_slot, u_col] = new_lo
             self.hi[u_slot, u_col] = new_hi
             self.version += 1
-            self._touch(touched)
+            self._touch(touched, part)
         return True
 
-    def try_apply_deltas(self, slots, cols, amt_lo, amt_hi):
+    def try_apply_deltas(self, slots, cols, amt_lo, amt_hi, part=NOOP_RUN):
         """General checked addition over explicit (slot, col) targets
         (the two-phase resolver's mixed dp/dpo/cp/cpo adds).  Same
         admission rules as try_apply_adds, checked BEFORE any
@@ -270,11 +284,11 @@ class BalanceMirror:
         )
         if limb_ov.any():
             return None
-        if not self._admit_commit(u_slot, u_col, d_lo, d_hi, True):
+        if not self._admit_commit(u_slot, u_col, d_lo, d_hi, True, part):
             return None
         return (u_slot, u_col, d_lo, d_hi)
 
-    def apply_subs(self, slots, cols, amt_lo, amt_hi) -> None:
+    def apply_subs(self, slots, cols, amt_lo, amt_hi, part=NOOP_RUN) -> None:
         """Release amounts (pending expiry): column -= amount, exact."""
         u_slot, u_col, d_lo, d_hi, limb_ov = compact_deltas(
             slots, cols, amt_lo, amt_hi
@@ -287,4 +301,4 @@ class BalanceMirror:
         self.lo[u_slot, u_col] = new_lo
         self.hi[u_slot, u_col] = new_hi
         self.version += 1
-        self._touch(u_slot)
+        self._touch(u_slot, part)

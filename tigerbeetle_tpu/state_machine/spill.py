@@ -47,6 +47,7 @@ import numpy as np
 
 from tigerbeetle_tpu.lsm.runs import pack_u128
 from tigerbeetle_tpu.types import TransferPendingStatus
+from tigerbeetle_tpu.utils.tracer import NOOP_RUN
 
 # Spilled transfer object layout (little-endian), 144 bytes:
 #   0..128  wire Transfer image (types.py TRANSFER_DTYPE, incl.
@@ -88,6 +89,11 @@ def _no_barrier() -> None:
     """A groove nobody else works on (standalone groove tests)."""
 
 
+def _no_parts() -> tuple:
+    """A spill nobody times (standalone groove tests)."""
+    return NOOP_RUN, None
+
+
 def _row_keys(rows: np.ndarray) -> np.ndarray:
     return pack_u128(
         np.asarray(rows, np.uint64), np.zeros(len(rows), np.uint64)
@@ -100,10 +106,14 @@ class TransferSpill:
     `posted` is the groove of the statuses their finalisers gave."""
 
     def __init__(self, groove, posted, counters, attrs_fn=None,
-                 barrier=_no_barrier) -> None:
+                 barrier=_no_barrier, parts=_no_parts) -> None:
         self.groove = groove
         self.posted = posted
         self.barrier = barrier
+        # `parts()` -> (the run `spill` opens for its object build, the
+        # part it moves on to for index entries and put_batch): the
+        # owning machine's sm.spill.objects and sm.spill.index.
+        self._parts = parts
         # `counters`: the owning machine's registry under `sm.store.`.
         # Rows whose status went to the posted tree; rows a read asked
         # the posted tree about (stored as `pending`), and how many of
@@ -130,6 +140,22 @@ class TransferSpill:
         if n == 0:
             return
         assert int(rows[0]) == self.base and int(rows[-1]) == self.base + n - 1
+        run, then = self._parts()
+        with run as part:
+            self._put(rows, cols, attrs, part, then)
+        # Seal overflowing memtables NOW: paced spill beats must turn
+        # into bounded level-0 runs per beat, not one giant run at the
+        # checkpoint (which would re-create the latency cliff the
+        # beats exist to remove).  The posted tree's too: its entries
+        # arrive on the loop's side, its seals happen here.
+        self.groove.maybe_seal()
+        self.posted.maybe_seal()
+        self.base += n
+
+    def _put(self, rows, cols, attrs, part, then) -> None:
+        """Objects (the run as it comes), then index entries and the
+        trees' put_batch (the run moved on to `then`)."""
+        n = len(rows)
         obj = np.zeros((n, TRANSFER_OBJECT_SIZE), np.uint8)
         for name, off, dt in _WIRE_FIELDS:
             width = np.dtype(dt).itemsize
@@ -155,6 +181,7 @@ class TransferSpill:
         )
         obj[:, _STATUS_BYTE] = cols["status"].astype(np.uint8)
 
+        part.switch(then)
         ts = cols["timestamp"].astype(np.uint64)
         self.groove.object_tree.put_batch(_row_keys(rows), obj)
         rows_v = np.asarray(rows, np.uint64).astype("<u8").view("V8")
@@ -170,14 +197,6 @@ class TransferSpill:
         self.groove.indexes["cr_slot"].put_batch(
             pack_u128(ts[co], cr[co].astype(np.uint64)), rows_v[co]
         )
-        # Seal overflowing memtables NOW: paced spill beats must turn
-        # into bounded level-0 runs per beat, not one giant run at the
-        # checkpoint (which would re-create the latency cliff the
-        # beats exist to remove).  The posted tree's too: its entries
-        # arrive on the loop's side, its seals happen here.
-        self.groove.maybe_seal()
-        self.posted.maybe_seal()
-        self.base += n
 
     # -- read ----------------------------------------------------------
 

@@ -39,6 +39,7 @@ from tigerbeetle_tpu import types
 from tigerbeetle_tpu.lsm import pack_u128
 from tigerbeetle_tpu.obs import stat_property as obs_stat_property
 from tigerbeetle_tpu.utils import HashIndex, RunIndex
+from tigerbeetle_tpu.utils.tracer import NOOP_RUN
 from tigerbeetle_tpu.state_machine import kernel, kernel_fast, resolve, waves
 from tigerbeetle_tpu.state_machine.mirror import BalanceMirror, _sub_u128
 from tigerbeetle_tpu.state_machine.cpu import CpuStateMachine
@@ -429,12 +430,57 @@ class TpuStateMachine:
         self._st_plan = tracer_mod.Stage(
             self.metrics.histogram("plan_us"), "sm.plan"
         )
-        # sm.plan.join_cold: inside it, a join on transfer rows that
-        # have left the RAM tail (a post's or a void's pending, a
-        # lookup's rows): point reads of the forest's object tree.
-        self._st_join_cold = tracer_mod.Stage(
-            self.metrics.histogram("plan.join_cold_us"), "sm.plan.join_cold"
-        )
+        # Parts (utils/tracer.py) of the leaf open around them; under
+        # no leaf (the host engine's paths, a lookup) they measure
+        # nothing.  Of sm.plan, in the order a batch meets them: bytes
+        # to columns and what the batch holds; the unique-id check and
+        # the in-flight hazards; the id directory's lookup; the account
+        # joins; the choice of a kernel and the tier translate; a
+        # two-phase batch's join on its pendings, and inside it
+        # join_cold, the rows of that join that have left the RAM tail
+        # (point reads of the forest's object tree); the packing and
+        # `created`.
+        def part(name: str) -> tracer_mod.Stage:
+            key = name.removeprefix("sm.") + "_us"
+            return tracer_mod.Stage(
+                self.metrics.histogram(key), name, part=True
+            )
+
+        self._st_plan_decode = part("sm.plan.decode")
+        self._st_plan_ids = part("sm.plan.ids")
+        self._st_plan_id_dir = part("sm.plan.id_dir")
+        self._st_plan_accounts = part("sm.plan.accounts")
+        self._st_plan_route = part("sm.plan.route")
+        self._st_plan_pending = part("sm.plan.pending")
+        self._st_plan_pack = part("sm.plan.pack")
+        self._st_join_cold = part("sm.plan.join_cold")
+        # Of sm.dev.finish, the closures the engine resolves a batch
+        # with: result codes and masks; the mirror's adds, and inside
+        # them the commitment twin's re-hash of the rows they touched;
+        # the store's append with its columns; the id directory's
+        # insert; the native id set; pending statuses, expiries,
+        # pulses, history; the reply.
+        self._st_finish_codes = part("sm.finish.codes")
+        self._st_finish_mirror = part("sm.finish.mirror")
+        self._st_finish_twin = part("sm.finish.twin")
+        self._st_finish_store = part("sm.finish.store")
+        self._st_finish_ids = part("sm.finish.ids")
+        self._st_finish_native_ids = part("sm.finish.native_ids")
+        self._st_finish_status = part("sm.finish.status")
+        self._st_finish_reply = part("sm.finish.reply")
+        # Of the beat (vsr.commit.beat, lsm.beat.work) and of the
+        # checkpoint's freeze: the rows copied out of the tail, their
+        # objects built, their index entries and the trees' put_batch.
+        self._st_spill_take = part("sm.spill.take")
+        self._st_spill_objects = part("sm.spill.objects")
+        self._st_spill_index = part("sm.spill.index")
+        # Of vsr.ckpt.freeze, inside `snapshot`: the engine's drain;
+        # the device's digest pair fetched; the host's from-scratch
+        # digest of the mirror; the blob's encode.
+        self._st_ckpt_drain = part("sm.ckpt.drain")
+        self._st_ckpt_verify_device = part("sm.ckpt.verify_device")
+        self._st_ckpt_verify_host = part("sm.ckpt.verify_host")
+        self._st_ckpt_encode = part("sm.ckpt.encode")
         self._c_join_cold_rows = _c("store.join_cold_rows")
         # Account rows in use (of the capacity `--cache-accounts` gives
         # the directory, the mirror and the device table), set wherever
@@ -471,6 +517,7 @@ class TpuStateMachine:
                 account_capacity, meta_fn=self._commit_meta_cols
             )
             self._mirror.commitment = self._commitment
+            self._mirror.twin_part = self._st_finish_twin
         if self.engine == "device":
             from tigerbeetle_tpu.state_machine.device_engine import (
                 DeviceEngine,
@@ -589,6 +636,26 @@ class TpuStateMachine:
         self._c_join_cold_rows.inc(rows)
         return self.tracer.stage(self._st_join_cold)
 
+    def _spill_parts(self):
+        """-> (the run `TransferSpill.spill` opens, the part it moves
+        on to)."""
+        return (
+            self.tracer.stage(self._st_spill_objects), self._st_spill_index
+        )
+
+    def _transfer_spill(self):
+        """The spill handle over the forest's grooves (attach_forest,
+        and again after a restore reopened them)."""
+        from tigerbeetle_tpu.state_machine import spill as spill_mod
+
+        forest = self._forest
+        return spill_mod.TransferSpill(
+            forest.grooves["transfers"], forest.grooves["transfers_posted"],
+            self.metrics.scope("store"),
+            attrs_fn=lambda: self._attrs, barrier=forest.barrier,
+            parts=self._spill_parts,
+        )
+
     def set_tracer(self, tracer) -> None:
         self.tracer = tracer
         if hasattr(self._dev, "tracer"):
@@ -696,7 +763,7 @@ class TpuStateMachine:
         meta = self._commit_meta_cols(np.arange(n, dtype=np.int64))
         return cm.root_bytes(cm.table_digest(bal8, meta))
 
-    def verify_device_mirror(self) -> None:
+    def verify_device_mirror(self, part=NOOP_RUN) -> None:
         """Compare the device balance table against the host mirror via
         an order-independent digest; crash loudly on divergence
         (VERDICT r3 #4).  Called from the checkpoint barrier.  In
@@ -708,7 +775,10 @@ class TpuStateMachine:
         With the incremental commitment live the compare is 32 fetched
         bytes (device maintained digest + from-scratch recompute vs
         the host twin); the full-table fetch runs only to NAME the
-        diverged rows in the crash message."""
+        diverged rows in the crash message.
+
+        `part`: the snapshot's open run (sm.ckpt.verify_device as it
+        comes), moved on to sm.ckpt.verify_host for the host's pass."""
         from tigerbeetle_tpu.state_machine import device_kernels as dk
         from tigerbeetle_tpu.state_machine.device_engine import (
             DeviceLostError,
@@ -754,6 +824,7 @@ class TpuStateMachine:
                 # here, four-way-attributed.  (The host pass costs
                 # what the old checksum8 compare cost; the CHEAP
                 # 16-byte compares are scrub's and the handshake's.)
+                part.switch(self._st_ckpt_verify_host)
                 n_rows = len(self._mirror.lo)
                 bal8 = np.empty((n_rows, 8), np.uint64)
                 bal8[:, 0::2] = self._mirror.lo
@@ -870,15 +941,12 @@ class TpuStateMachine:
         # the pending's row.  Declared LAST: a tree's id is its place
         # in this order, and a data file's manifest log names trees by
         # id.
-        posted = forest.groove(
+        forest.groove(
             "transfers_posted",
             object_size=spill_mod.POSTED_OBJECT_SIZE,
             index_fields=[],
         )
-        self._store.spill = spill_mod.TransferSpill(
-            transfers, posted, self.metrics.scope("store"),
-            attrs_fn=lambda: self._attrs, barrier=forest.barrier,
-        )
+        self._store.spill = self._transfer_spill()
         self._hspill = spill_mod.HistorySpill(history, barrier=forest.barrier)
 
     def spill_beat(
@@ -907,9 +975,12 @@ class TpuStateMachine:
         if st.tail_count() <= keep_min:
             return None
         take = min(max_rows, st.tail_count() - keep_min)
-        rows = np.arange(st.base, st.base + take, dtype=np.int64)
-        cols = {name: st.col(name)[:take].copy() for name in _STORE_FIELDS}
-        st.drop_prefix(take)
+        with self.tracer.stage(self._st_spill_take):
+            rows = np.arange(st.base, st.base + take, dtype=np.int64)
+            cols = {
+                name: st.col(name)[:take].copy() for name in _STORE_FIELDS
+            }
+            st.drop_prefix(take)
         # History spills at checkpoint only (checkpoint_spill): its
         # rows are append-only and bounded per interval, and a per-beat
         # prefix rebuild would cost more copying than it saves.
@@ -1421,16 +1492,18 @@ class TpuStateMachine:
         engine's submit, or the host path, takes over.
         """
         with self.tracer.stage(self._st_plan):
-            submit = self._plan_create_transfers_device(
-                timestamp, input_bytes
-            )
+            with self.tracer.stage(self._st_plan_decode) as part:
+                submit = self._plan_create_transfers_device(
+                    timestamp, input_bytes, part
+                )
         return submit()
 
     def _plan_create_transfers_device(self, timestamp: int,
-                                      input_bytes: bytes):
+                                      input_bytes: bytes, part):
         """Decode, directory joins, routing and packing.  -> what
         resolves the batch, to be called: the engine's submit with its
-        arguments bound, or the host path."""
+        arguments bound, or the host path.  `part`: the plan's open
+        run, moved on from part to part as the batch goes."""
         from tigerbeetle_tpu.state_machine import device_kernels as dk
         from tigerbeetle_tpu.state_machine.device_engine import ReplyFuture
 
@@ -1493,6 +1566,7 @@ class TpuStateMachine:
 
         # Unique-id check (shared with the host router): ascending ids
         # prove uniqueness; else a 64-bit key mix.
+        part.switch(self._st_plan_ids)
         ascending = n == 1 or bool(
             (
                 (id_hi[1:] > id_hi[:-1])
@@ -1528,11 +1602,13 @@ class TpuStateMachine:
         if self._dev.inflight_ids_hit(probe):
             self._engine_drain()
 
+        part.switch(self._st_plan_id_dir)
         e_found, _e_row = self._tdir.lookup(id_lo, id_hi)
         if e_found.any():
             return host_path
 
         # Account joins (slots + flags for routing).
+        part.switch(self._st_plan_accounts)
         dr_lo = np.asarray(events["debit_account_id_lo"])
         dr_hi = np.asarray(events["debit_account_id_hi"])
         cr_lo = np.asarray(events["credit_account_id_lo"])
@@ -1558,12 +1634,13 @@ class TpuStateMachine:
             ((dr_flags | cr_flags) & np.uint32(AF.history)).any()
         )
 
+        part.switch(self._st_plan_route)
         common = dict(
             events=events, n=n, ts_base=ts_base, id_lo=id_lo, id_hi=id_hi,
             dr_lo=dr_lo, dr_hi=dr_hi, cr_lo=cr_lo, cr_hi=cr_hi,
             flags=flags, timeout=timeout, dr_slot=dr_slot, cr_slot=cr_slot,
             keys_sorted=keys_sorted, timestamp=timestamp,
-            input_bytes=input_bytes,
+            input_bytes=input_bytes, part=part,
         )
 
         # Each packer returns the engine's submit, bound, or None when
@@ -1847,6 +1924,7 @@ class TpuStateMachine:
     def _submit_device_orderfree(
         self, events, n, ts_base, id_lo, id_hi, dr_lo, dr_hi, cr_lo, cr_hi,
         flags, timeout, dr_slot, cr_slot, keys_sorted, timestamp, input_bytes,
+        part,
     ):
         from tigerbeetle_tpu.state_machine import device_kernels as dk
 
@@ -1857,6 +1935,7 @@ class TpuStateMachine:
         if tr is None:
             return None
         t_dr_slot, t_cr_slot = tr
+        part.switch(self._st_plan_pack)
         amount_lo = np.asarray(events["amount_lo"])
         amount_hi = np.asarray(events["amount_hi"])
         has_timeout = bool(timeout.any())
@@ -1904,21 +1983,25 @@ class TpuStateMachine:
         }
 
         def finish(summary) -> bytes:
-            results = np.zeros(n, np.uint32)
-            results[summary["fail_idx"]] = summary["fail_codes"]
-            apply_mask = results == 0
-            is_pending = (flags & np.uint32(TF.pending)) != 0
-            # Mirror bookkeeping doubles as a free admission parity
-            # check: the device admitted, so this can never refuse.
-            deltas = self._mirror.try_apply_adds(
-                dr_slot, cr_slot, amount_lo, amount_hi, is_pending,
-                apply_mask,
-            )
-            assert deltas is not None, "device/mirror admission divergence"
-            return self._finish_fast(
-                n, ts_base, id_lo, id_hi, flags, timeout, results, created,
-                last_applied=summary["last_applied"],
-            )
+            with self.tracer.stage(self._st_finish_codes) as run:
+                results = np.zeros(n, np.uint32)
+                results[summary["fail_idx"]] = summary["fail_codes"]
+                apply_mask = results == 0
+                is_pending = (flags & np.uint32(TF.pending)) != 0
+                # Mirror bookkeeping doubles as a free admission parity
+                # check: the device admitted, so this can never refuse.
+                run.switch(self._st_finish_mirror)
+                deltas = self._mirror.try_apply_adds(
+                    dr_slot, cr_slot, amount_lo, amount_hi, is_pending,
+                    apply_mask, part=run,
+                )
+                assert deltas is not None, (
+                    "device/mirror admission divergence"
+                )
+                return self._finish_fast(
+                    n, ts_base, id_lo, id_hi, flags, timeout, results,
+                    created, last_applied=summary["last_applied"], part=run,
+                )
 
         if tight:
             kind = "orderfree_tight"
@@ -1934,11 +2017,13 @@ class TpuStateMachine:
     def _submit_device_linked(
         self, events, n, ts_base, id_lo, id_hi, dr_lo, dr_hi, cr_lo, cr_hi,
         flags, timeout, dr_slot, cr_slot, keys_sorted, timestamp, input_bytes,
+        part,
     ):
         tr = self._tier_translate(dr_slot, cr_slot)
         if tr is None:
             return None
         t_dr_slot, t_cr_slot = tr
+        part.switch(self._st_plan_pack)
         pk = self._device_pack_base(
             n, events, id_lo, id_hi, dr_lo, dr_hi, cr_lo, cr_hi,
             flags, timeout, t_dr_slot, t_cr_slot,
@@ -1962,19 +2047,23 @@ class TpuStateMachine:
         }
 
         def finish(summary) -> bytes:
-            results = np.zeros(n, np.uint32)
-            results[summary["fail_idx"]] = summary["fail_codes"]
-            self.stat_linked_batches += 1
-            self.stat_resolve_iters += summary["iters"]
-            deltas = self._mirror.try_apply_adds(
-                dr_slot, cr_slot, amount_lo, amount_hi,
-                np.zeros(n, bool), results == 0,
-            )
-            assert deltas is not None, "device/mirror admission divergence"
-            return self._finish_fast(
-                n, ts_base, id_lo, id_hi, flags, timeout, results, created,
-                last_applied=summary["last_applied"],
-            )
+            with self.tracer.stage(self._st_finish_codes) as run:
+                results = np.zeros(n, np.uint32)
+                results[summary["fail_idx"]] = summary["fail_codes"]
+                self.stat_linked_batches += 1
+                self.stat_resolve_iters += summary["iters"]
+                run.switch(self._st_finish_mirror)
+                deltas = self._mirror.try_apply_adds(
+                    dr_slot, cr_slot, amount_lo, amount_hi,
+                    np.zeros(n, bool), results == 0, part=run,
+                )
+                assert deltas is not None, (
+                    "device/mirror admission divergence"
+                )
+                return self._finish_fast(
+                    n, ts_base, id_lo, id_hi, flags, timeout, results,
+                    created, last_applied=summary["last_applied"], part=run,
+                )
 
         # Small-amount specialization: a batch whose total contribution
         # fits i32 runs the one-cumsum-per-prefix fixpoint (the device
@@ -1994,12 +2083,14 @@ class TpuStateMachine:
     def _submit_device_two_phase(
         self, events, n, ts_base, id_lo, id_hi, dr_lo, dr_hi, cr_lo, cr_hi,
         flags, timeout, dr_slot, cr_slot, keys_sorted, timestamp, input_bytes,
+        part,
     ):
         """Build two-phase join columns and dispatch; None -> host path
         (same residual class the r3 host router punted to the serial
         exact engine)."""
         from tigerbeetle_tpu.state_machine import device_kernels as dk
 
+        part.switch(self._st_plan_pending)
         pend_lo = np.asarray(events["pending_id_lo"])
         pend_hi = np.asarray(events["pending_id_hi"])
         is_pv = (flags & np.uint32(TF.post_pending_transfer | TF.void_pending_transfer)) != 0
@@ -2084,6 +2175,7 @@ class TpuStateMachine:
         # packed device columns translate; ctx/finish keep LOGICAL
         # slots.  Non-found pj entries keep their 0 default — the
         # kernel reads them only under the p_found bit.
+        part.switch(self._st_plan_route)
         tr = self._tier_translate(
             dr_slot, cr_slot,
             np.where(p_found, pj_dr_slot, -1),
@@ -2092,6 +2184,7 @@ class TpuStateMachine:
         if tr is None:
             return None
         t_dr_slot, t_cr_slot, t_pj_dr, t_pj_cr = tr
+        part.switch(self._st_plan_pack)
         t_pj_dr = np.where(p_found, t_pj_dr, 0)
         t_pj_cr = np.where(p_found, t_pj_cr, 0)
         pk = self._device_pack_base(
@@ -2163,12 +2256,13 @@ class TpuStateMachine:
         )
 
         def finish(summary) -> bytes:
-            return self._finish_device_two_phase(
-                summary, events, id_lo, id_hi, flags, timeout,
-                amount_lo, amount_hi, pend_lo, pend_hi,
-                ud128_lo, ud128_hi, ud64, ud32, ledger_arr, code_arr,
-                dr_slot, cr_slot, ctx,
-            )
+            with self.tracer.stage(self._st_finish_codes) as run:
+                return self._finish_device_two_phase(
+                    summary, events, id_lo, id_hi, flags, timeout,
+                    amount_lo, amount_hi, pend_lo, pend_hi,
+                    ud128_lo, ud128_hi, ud64, ud32, ledger_arr, code_arr,
+                    dr_slot, cr_slot, ctx, run,
+                )
 
         self.stat_two_phase_batches += 1
         kind = (
@@ -2194,10 +2288,11 @@ class TpuStateMachine:
         self, summary, events, id_lo, id_hi, flags, timeout,
         amount_lo, amount_hi, pend_lo, pend_hi,
         ud128_lo, ud128_hi, ud64, ud32, ledger_arr, code_arr,
-        dr_slot, cr_slot, ctx,
+        dr_slot, cr_slot, ctx, part,
     ) -> bytes:
         """Bookkeeping from device codes (mirrors the tail of
-        _try_two_phase_fast, with verdicts arriving from the kernel)."""
+        _try_two_phase_fast, with verdicts arriving from the kernel).
+        `part`: the closure's open run (sm.finish.codes as it comes)."""
         n = ctx["n"]
         ts_base = ctx["ts_base"]
         is_pv = ctx["is_pv"]
@@ -2215,6 +2310,7 @@ class TpuStateMachine:
 
         # Mirror bookkeeping (device already applied; these asserts are
         # the admission-parity tripwire).
+        part.switch(self._st_finish_mirror)
         pend_ok = ok & pend_flag
         plain_ok = ok & ~pend_flag & ~is_pv
         post_win = winner & post
@@ -2242,7 +2338,7 @@ class TpuStateMachine:
             res_amt_hi[post_win], res_amt_hi[post_win],
         ])
         deltas = self._mirror.try_apply_deltas(
-            add_slots, add_cols, add_lo, add_hi
+            add_slots, add_cols, add_lo, add_hi, part=part
         )
         assert deltas is not None, "device/mirror admission divergence"
         n_win = int(winner.sum())
@@ -2254,9 +2350,10 @@ class TpuStateMachine:
             self._mirror.apply_subs(
                 sub_slots, sub_cols,
                 np.concatenate([p_amt_lo[winner]] * 2),
-                np.concatenate([p_amt_hi[winner]] * 2),
+                np.concatenate([p_amt_hi[winner]] * 2), part=part,
             )
 
+        part.switch(self._st_finish_store)
         ud128_set = (ud128_lo != 0) | (ud128_hi != 0)
         created = {
             "flags": flags,
@@ -2301,8 +2398,9 @@ class TpuStateMachine:
             dstat_init, dstat, uniq_rows,
             np.zeros((n, 8), np.uint64), np.zeros((n, 8), np.uint64),
             summary["last_applied"], zeros_u64, zeros_u64,
-            no_history=True,
+            no_history=True, part=part,
         )
+        part.switch(self._st_finish_reply)
         fail_idx = np.flatnonzero(results != 0)
         reply = np.zeros(len(fail_idx), dtype=CREATE_RESULT_DTYPE)
         reply["index"] = fail_idx.astype(np.uint32)
@@ -3630,13 +3728,16 @@ class TpuStateMachine:
         self, n, ts_base, id_lo, id_hi, flags, timeout, results, created,
         last_applied: int | None = None,
         inb_status: np.ndarray | None = None,
+        part=NOOP_RUN,
     ) -> bytes:
         """Shared fast-path tail (native and Python admission paths):
         expiry/pulse signals, store bookkeeping, failure reply.  Must
         stay one implementation — every fast path\'s durable state
         depends on it being identical.  `inb_status` overrides the
         default created-pending statuses when the caller finalized
-        pendings within the batch (two-phase resolver)."""
+        pendings within the batch (two-phase resolver).  `part`: a
+        finish closure's open run, moved on through sm.finish.*."""
+        part.switch(self._st_finish_store)
         apply_mask = results == 0
         is_pending = (flags & np.uint32(TF.pending)) != 0
         ts_i = np.uint64(ts_base) + np.arange(n, dtype=np.uint64)
@@ -3661,9 +3762,10 @@ class TpuStateMachine:
             np.zeros(0, np.int64),
             np.zeros((n, 8), np.uint64), np.zeros((n, 8), np.uint64),
             last_applied, pulse_create, np.zeros(n, np.uint64),
-            no_history=True,
+            no_history=True, part=part,
         )
 
+        part.switch(self._st_finish_reply)
         fail_idx = np.flatnonzero(results != 0)
         reply = np.zeros(len(fail_idx), dtype=CREATE_RESULT_DTYPE)
         reply["index"] = fail_idx.astype(np.uint32)
@@ -3675,8 +3777,9 @@ class TpuStateMachine:
         results, created_mask, created, inb_status,
         dstat_init, dstat, uniq_rows,
         hist_dr, hist_cr, last_applied, pulse_create, pulse_remove,
-        no_history: bool = False,
+        no_history: bool = False, part=NOOP_RUN,
     ) -> None:
+        part.switch(self._st_finish_store)
         ok = results == 0
         # 1. Insert created transfers into the columnar store.  When
         # the whole batch applied (the hot path), index with slices —
@@ -3704,10 +3807,12 @@ class TpuStateMachine:
                 flags=sel(flags), timestamp=ts,
                 status=sel(inb_status).astype(np.uint8),
             )
+            part.switch(self._st_finish_ids)
             self._tdir.insert(sel(id_lo), sel(id_hi), rows.astype(np.uint64))
             if self._native is not None:
                 # Keep the native duplicate-id set in lockstep (rows
                 # are contiguous, so base_row + i == row).
+                part.switch(self._st_finish_native_ids)
                 self._native.add_transfer_ids(
                     sel(id_lo), sel(id_hi), int(rows[0])
                 )
@@ -3718,6 +3823,7 @@ class TpuStateMachine:
 
         # 2. Durable pending-status updates (+ expires index removal),
         # batched: changed rows may live in the LSM spill tier.
+        part.switch(self._st_finish_status)
         changed = np.flatnonzero(dstat[: len(uniq_rows)] != dstat_init[: len(uniq_rows)])
         if len(changed):
             ch_rows = uniq_rows[changed]
@@ -4094,6 +4200,11 @@ def _tpu_snapshot(self) -> bytes:
     kernel_fast.py write-behind contract).  Fixed-layout binary
     encoding (utils/snapshot.py), NOT pickle: checkpoint blobs travel
     via state sync and must be safe to decode from untrusted bytes."""
+    with self.tracer.stage(self._st_ckpt_drain) as part:
+        return _tpu_snapshot_parts(self, part)
+
+
+def _tpu_snapshot_parts(self, part) -> bytes:
     from tigerbeetle_tpu.utils import snapshot as snapcodec
 
     if self.engine == "device":
@@ -4107,7 +4218,9 @@ def _tpu_snapshot(self) -> bytes:
     from tigerbeetle_tpu import envcheck as _envcheck
 
     if self.engine == "device" or _envcheck.env_str("TB_CKPT_VERIFY") == "1":
-        self.verify_device_mirror()
+        part.switch(self._st_ckpt_verify_device)
+        self.verify_device_mirror(part)
+    part.switch(self._st_ckpt_encode)
     count = self._attrs.count
     # prepare_timestamp is primary-only in-memory state, re-derived from
     # commit_timestamp after restore — see cpu.py snapshot note.
@@ -4166,13 +4279,7 @@ def _tpu_restore(self, data: bytes) -> None:
         # Reopen the LSM tier from its manifest, then re-point the
         # spill handles at the restored grooves.
         self._forest.open(state["forest"])
-        self._store.spill = spill_mod.TransferSpill(
-            self._forest.grooves["transfers"],
-            self._forest.grooves["transfers_posted"],
-            self.metrics.scope("store"),
-            attrs_fn=lambda: self._attrs,
-            barrier=self._forest.barrier,
-        )
+        self._store.spill = self._transfer_spill()
         self._store.spill.base = base
         self._store.base = base
         self._hspill = spill_mod.HistorySpill(
@@ -4223,6 +4330,7 @@ def _tpu_restore(self, data: bytes) -> None:
         )
         self._commitment.rebuild(self._mirror)
         self._mirror.commitment = self._commitment
+        self._mirror.twin_part = self._st_finish_twin
     if self.engine == "device":
         from tigerbeetle_tpu.state_machine.device_engine import (
             DeviceEngine,

@@ -125,6 +125,24 @@ class Replica:
         self._st_ckpt_freeze = Stage(
             hist("ckpt.freeze_us"), "vsr.ckpt.freeze"
         )
+        # Its parts on this side (the spill's and the seals' are the
+        # LSM's, the drain, the verify and the encode the state
+        # machine's): the blob wrapped with the sessions, the state
+        # root, the buffered write into the grid zone, the blob's
+        # checksum.
+        self._st_freeze_wrap = Stage(
+            hist("ckpt.freeze.wrap_us"), "vsr.ckpt.freeze.wrap", part=True
+        )
+        self._st_freeze_root = Stage(
+            hist("ckpt.freeze.root_us"), "vsr.ckpt.freeze.root", part=True
+        )
+        self._st_freeze_write = Stage(
+            hist("ckpt.freeze.write_us"), "vsr.ckpt.freeze.write", part=True
+        )
+        self._st_freeze_checksum = Stage(
+            hist("ckpt.freeze.checksum_us"), "vsr.ckpt.freeze.checksum",
+            part=True,
+        )
         # On the checkpoint worker: annotated on its own thread, out of
         # the loop's sums.
         self._st_ckpt_finalize = Stage(
@@ -534,7 +552,7 @@ class Replica:
         self.tracer = tracer
         self.journal.tracer = tracer
         if self.forest is not None:
-            self.forest.beats.tracer = tracer
+            self.forest.set_tracer(tracer)
         if hasattr(self.sm, "set_tracer"):
             self.sm.set_tracer(tracer)
 
@@ -651,12 +669,7 @@ class Replica:
                     self.sm.prefetch(
                         sm_op, events, prefetch_timestamp=timestamp
                     )
-                with self.tracer.span(
-                    "state_machine_commit", op=op, bytes=len(events)
-                ):
-                    reply = self.sm.commit(
-                        client, op, timestamp, sm_op, events
-                    )
+                reply = self.sm.commit(client, op, timestamp, sm_op, events)
                 with self.tracer.stage(self._st_reply):
                     self._store_sub_replies(header, sm_op, reply, subs)
                     if self.hash_log is not None and not replay:
@@ -666,10 +679,7 @@ class Replica:
                 return reply
             with self.tracer.stage(self._st_prefetch):
                 self.sm.prefetch(sm_op, body, prefetch_timestamp=timestamp)
-            with self.tracer.span(
-                "state_machine_commit", op=op, bytes=len(body)
-            ):
-                reply = self.sm.commit(client, op, timestamp, sm_op, body)
+            reply = self.sm.commit(client, op, timestamp, sm_op, body)
 
         self._compact_beat()
         self.commit_min = op
@@ -1002,18 +1012,17 @@ class Replica:
         base = max(self.checkpoint_op, self._ckpt_last_op)
         if self.op > base:
             self._ckpt_interval_observed = self.op - base
-        with self.tracer.span("checkpoint", op=self.commit_min):
-            with self.tracer.stage(self._st_ckpt_freeze, op=self.commit_min):
-                args = self._checkpoint_freeze()
-            self._ckpt_last_op = self.commit_min
-            if self._ckpt_worker is not None:
-                self._stats["stat_ckpt_async"].inc()
-                self._ckpt_job = self._ckpt_worker.submit(
-                    self._checkpoint_finalize, *args
-                )
-            else:
-                self._stats["stat_ckpt_sync"].inc()
-                self._checkpoint_finalize(*args)
+        with self.tracer.stage(self._st_ckpt_freeze, op=self.commit_min):
+            args = self._checkpoint_freeze()
+        self._ckpt_last_op = self.commit_min
+        if self._ckpt_worker is not None:
+            self._stats["stat_ckpt_async"].inc()
+            self._ckpt_job = self._ckpt_worker.submit(
+                self._checkpoint_finalize, *args
+            )
+        else:
+            self._stats["stat_ckpt_sync"].inc()
+            self._checkpoint_finalize(*args)
 
     def _ckpt_join(self) -> None:
         """Barrier: wait for the in-flight async flip (if any).  Must
@@ -1062,8 +1071,7 @@ class Replica:
         if self.forest is not None:
             # Spill frozen state into LSM grid blocks first so the
             # snapshot blob covers only the RAM tail (O(delta)).
-            with self.tracer.span("lsm_spill"):
-                self.sm.checkpoint_spill()
+            self.sm.checkpoint_spill()
 
         blob = self._take_snapshot()
         self._g_ckpt_blob.set(len(blob))
@@ -1073,17 +1081,21 @@ class Replica:
         # into the superblock with the rest of the checkpoint
         # references (recovery recomputes-and-asserts it; the VOPR
         # compares it cross-replica).
-        state_root = (
-            int.from_bytes(self.sm.state_root(), "little")
-            if hasattr(self.sm, "state_root")
-            else 0
-        )
-        region = int(self.superblock.working["sequence"]) % 2
-        offset = self._grid_region_offset(region, len(blob))
-        self._write_grid(offset, blob)
+        with self.tracer.stage(self._st_freeze_root) as part:
+            state_root = (
+                int.from_bytes(self.sm.state_root(), "little")
+                if hasattr(self.sm, "state_root")
+                else 0
+            )
+            part.switch(self._st_freeze_write)
+            region = int(self.superblock.working["sequence"]) % 2
+            offset = self._grid_region_offset(region, len(blob))
+            self._write_grid(offset, blob)
+            part.switch(self._st_freeze_checksum)
+            blob_checksum = wire.checksum(blob)
         return (
             self.commit_min, head_checksum, offset, len(blob),
-            wire.checksum(blob), self.view, self.epoch,
+            blob_checksum, self.view, self.epoch,
             list(self.members) if self.members is not None else None,
             state_root,
         )
@@ -1158,6 +1170,11 @@ class Replica:
         return self.storage.layout.grid_offset + region * span
 
     def _take_snapshot(self) -> bytes:
+        sm_blob = self.sm.snapshot()
+        with self.tracer.stage(self._st_freeze_wrap):
+            return self._wrap_snapshot(sm_blob)
+
+    def _wrap_snapshot(self, sm_blob: bytes) -> bytes:
         from tigerbeetle_tpu.utils import snapshot as snapcodec
 
         sessions = self.sessions
@@ -1176,7 +1193,7 @@ class Replica:
             )
         return snapcodec.encode(
             {
-                "sm": self.sm.snapshot(),
+                "sm": sm_blob,
                 "clients": cl,
                 "session_meta": meta,
                 "reply_headers": b"".join(headers),
