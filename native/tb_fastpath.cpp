@@ -96,33 +96,51 @@ struct U128Hash {
     }
 };
 
-// Id directory: run-length ranges over sequential hi==0 ids (the
-// recommended/benchmark id scheme) + hash fallback for everything else
-// (mirrors tigerbeetle_tpu/utils/hashindex.py RunIndex).
+// Id directory: run-length ranges over sequential ids (the
+// recommended/benchmark id scheme) + hash fallback for scattered ones.
+// Mirrors tigerbeetle_tpu/utils/hashindex.py RunIndex, the rule that
+// files a batch (RUN_PIECES, RUN_LIST_FREE: see there) included, so
+// that the two agree on where an id lives.
+constexpr uint64_t RUN_PIECES = 8;
+constexpr uint64_t RUN_LIST_FREE = 1ull << 16;
+
 struct IdDir {
-    // Sorted, disjoint: ids [start, start+len) -> values [val0, ...).
-    std::vector<uint64_t> starts, lens, vals;
+    // ids (hi, [start, start+len)) -> values [val, val+len).
+    struct Run {
+        uint64_t hi, start, len, val;
+        bool before(const Run& o) const {
+            return hi != o.hi ? hi < o.hi : start < o.start;
+        }
+        // `o` follows this run in ids and in values.
+        bool abuts(const Run& o) const {
+            return hi == o.hi && start + len == o.start && val + len == o.val;
+        }
+    };
+    std::vector<Run> runs;    // sorted by (hi, start), disjoint
+    uint64_t run_ids = 0;     // ids the runs hold
+    std::vector<Run> pieces;  // insert's scratch
     std::unordered_map<u128, uint64_t, U128Hash> map;
 
-    size_t range_index(uint64_t lo) const {
-        // Last range with start <= lo (or SIZE_MAX).
-        size_t n = starts.size();
-        size_t left = 0, right = n;
+    size_t range_index(uint64_t lo, uint64_t hi) const {
+        // Last run with (hi, start) <= (hi, lo) (or SIZE_MAX).
+        size_t left = 0, right = runs.size();
         while (left < right) {
             size_t mid = (left + right) / 2;
-            if (starts[mid] <= lo) left = mid + 1; else right = mid;
+            const Run& r = runs[mid];
+            if (r.hi != hi ? r.hi < hi : r.start <= lo) left = mid + 1;
+            else right = mid;
         }
         return left == 0 ? SIZE_MAX : left - 1;
     }
 
     bool lookup(uint64_t lo, uint64_t hi, uint64_t* val) const {
-        if (hi == 0 && !starts.empty()) {
-            size_t i = range_index(lo);
-            if (i != SIZE_MAX && lo - starts[i] < lens[i]) {
-                *val = vals[i] + (lo - starts[i]);
-                return true;
-            }
+        size_t i = range_index(lo, hi);
+        if (i != SIZE_MAX && runs[i].hi == hi &&
+            lo - runs[i].start < runs[i].len) {
+            *val = runs[i].val + (lo - runs[i].start);
+            return true;
         }
+        if (map.empty()) return false;
         auto it = map.find(((u128)hi << 64) | lo);
         if (it == map.end()) return false;
         *val = it->second;
@@ -134,80 +152,87 @@ struct IdDir {
         return lookup(lo, hi, &v);
     }
 
-    // Batch insert; detects contiguous runs (ids and values both +1
-    // steps, hi all zero, no u64 wrap).
+    // Batch insert (values val0, val0 + 1, ...): split where the ids
+    // stop following each other (an id of 0 follows nothing: the
+    // modular +1 of 2^64 - 1), then the runs or the hash, whole.
     void insert(const uint64_t* lo, const uint64_t* hi, uint64_t val0,
                 uint32_t n) {
-        bool run = n >= 2 && hi[0] == 0 && lo[n - 1] >= lo[0];
-        if (run) {
-            for (uint32_t i = 1; i < n; i++) {
-                if (hi[i] != 0 || lo[i] != lo[i - 1] + 1) { run = false; break; }
+        if (n == 0) return;
+        pieces.clear();
+        for (uint32_t i = 0; i < n; i++) {
+            if (i > 0 && hi[i] == hi[i - 1] && lo[i] == lo[i - 1] + 1 &&
+                lo[i] != 0) {
+                pieces.back().len++;
+            } else {
+                pieces.push_back(Run{hi[i], lo[i], 1, val0 + i});
             }
         }
-        if (run) {
-            insert_range(lo[0], n, val0);
-        } else {
+        uint64_t k = pieces.size();
+        if (k > n / RUN_PIECES &&
+            (k > RUN_PIECES ||
+             runs.size() + k >
+                 std::max((run_ids + n) / RUN_PIECES, RUN_LIST_FREE))) {
             for (uint32_t i = 0; i < n; i++) {
                 map.emplace(((u128)hi[i] << 64) | lo[i], val0 + i);
             }
+            return;
         }
+        run_ids += n;
+        file_pieces();
     }
 
-    void insert_range(uint64_t start, uint64_t len, uint64_t val0) {
-        size_t i = range_index(start);
-        // Merge with predecessor when both ids and values abut.
-        if (i != SIZE_MAX && starts[i] + lens[i] == start &&
-            vals[i] + lens[i] == val0) {
-            lens[i] += len;
-            // May now abut the successor.
-            size_t j = i + 1;
-            if (j < starts.size() && starts[i] + lens[i] == starts[j] &&
-                vals[i] + lens[i] == vals[j]) {
-                lens[i] += lens[j];
-                starts.erase(starts.begin() + j);
-                lens.erase(lens.begin() + j);
-                vals.erase(vals.begin() + j);
-            }
-            return;
+    // Place the sorted pieces in ONE pass: the runs above the highest
+    // of them shift once, as a block, the runs between them by a
+    // backward merge; then join what abuts from below the lowest piece
+    // to above the highest.
+    void file_pieces() {
+        auto before = [](const Run& a, const Run& b) { return a.before(b); };
+        if (!std::is_sorted(pieces.begin(), pieces.end(), before)) {
+            std::sort(pieces.begin(), pieces.end(), before);
         }
-        size_t at = (i == SIZE_MAX) ? 0 : i + 1;
-        // Merge with successor.
-        if (at < starts.size() && start + len == starts[at] &&
-            val0 + len == vals[at]) {
-            starts[at] = start;
-            lens[at] += len;
-            vals[at] = val0;
-            return;
+        size_t k = pieces.size(), old = runs.size();
+        size_t i = (size_t)(std::upper_bound(runs.begin(), runs.end(),
+                                             pieces.back(), before) -
+                            runs.begin());
+        runs.resize(old + k);
+        std::move_backward(runs.begin() + (ptrdiff_t)i,
+                           runs.begin() + (ptrdiff_t)old, runs.end());
+        size_t j = k, w = i + k, stop = std::min(w + 1, old + k);
+        while (j > 0) {
+            if (i > 0 && pieces[j - 1].before(runs[i - 1])) runs[--w] = runs[--i];
+            else runs[--w] = pieces[--j];
         }
-        starts.insert(starts.begin() + at, start);
-        lens.insert(lens.begin() + at, len);
-        vals.insert(vals.begin() + at, val0);
+        size_t out = i > 0 ? i - 1 : 0, s = out + 1;
+        for (; s < stop; s++) {
+            if (runs[out].abuts(runs[s])) runs[out].len += runs[s].len;
+            else if (++out != s) runs[out] = runs[s];
+        }
+        if (++out != s) {
+            runs.erase(runs.begin() + (ptrdiff_t)out, runs.begin() + (ptrdiff_t)s);
+        }
     }
 
     void remove(uint64_t lo, uint64_t hi) {
         // Remove from BOTH structures: defensive against an id that
         // was ever double-registered (map + range).
-        u128 key = ((u128)hi << 64) | lo;
-        map.erase(key);
-        if (hi != 0) return;
-        size_t i = range_index(lo);
-        if (i == SIZE_MAX || lo - starts[i] >= lens[i]) return;
-        uint64_t off = lo - starts[i];
-        uint64_t tail = lens[i] - off - 1;
+        map.erase(((u128)hi << 64) | lo);
+        size_t i = range_index(lo, hi);
+        if (i == SIZE_MAX || runs[i].hi != hi ||
+            lo - runs[i].start >= runs[i].len) return;
+        Run& r = runs[i];
+        uint64_t off = lo - r.start;
+        uint64_t tail = r.len - off - 1;
+        run_ids--;
         if (off == 0 && tail == 0) {
-            starts.erase(starts.begin() + i);
-            lens.erase(lens.begin() + i);
-            vals.erase(vals.begin() + i);
+            runs.erase(runs.begin() + (ptrdiff_t)i);
         } else if (off == 0) {
-            starts[i] += 1; vals[i] += 1; lens[i] = tail;
+            r.start += 1; r.val += 1; r.len = tail;
         } else if (tail == 0) {
-            lens[i] = off;
+            r.len = off;
         } else {
-            uint64_t ns = lo + 1, nv = vals[i] + off + 1;
-            lens[i] = off;
-            starts.insert(starts.begin() + i + 1, ns);
-            lens.insert(lens.begin() + i + 1, tail);
-            vals.insert(vals.begin() + i + 1, nv);
+            Run rest{hi, lo + 1, tail, r.val + off + 1};
+            r.len = off;
+            runs.insert(runs.begin() + (ptrdiff_t)i + 1, rest);
         }
     }
 };
@@ -310,6 +335,21 @@ void tb_fp_add_transfer_ids(Fastpath* fp, const uint64_t* id_lo,
 void tb_fp_remove_transfer_ids(Fastpath* fp, const uint64_t* id_lo,
                                const uint64_t* id_hi, uint32_t n) {
     for (uint32_t i = 0; i < n; i++) fp->transfers.remove(id_lo[i], id_hi[i]);
+}
+
+// Read-only view of the transfer-id directory, for tests and counters
+// (no commit path calls it): found[i] and values[i] for each id, and
+// counts[0] the runs it holds, counts[1] the ids in its hash.
+void tb_fp_peek_transfer_ids(const Fastpath* fp, const uint64_t* id_lo,
+                             const uint64_t* id_hi, uint32_t n,
+                             uint8_t* found, uint64_t* values,
+                             uint64_t* counts) {
+    for (uint32_t i = 0; i < n; i++) {
+        values[i] = 0;
+        found[i] = fp->transfers.lookup(id_lo[i], id_hi[i], &values[i]);
+    }
+    counts[0] = fp->transfers.runs.size();
+    counts[1] = fp->transfers.map.size();
 }
 
 // Returns 0 = applied (results/slots/deltas valid, balances updated);

@@ -244,10 +244,17 @@ def test_runindex_hash_fallback_and_mixed_lookup():
     ix = RunIndex()
     ix.insert(np.arange(10, 20, dtype=np.uint64), np.zeros(10, np.uint64),
               np.arange(10, dtype=np.uint64))
-    ix.insert(_u64(500, 7, 99), _u64(0, 0, 0), _u64(100, 101, 102))  # not a run
-    found, vals = ix.lookup(_u64(12, 7, 8), _u64(0, 0, 0))
-    assert found.tolist() == [True, True, False]
-    assert vals[0] == 2 and vals[1] == 101
+    # Three ids that do not follow: three runs of one (a batch of at
+    # most RUN_PIECES pieces is filed as the runs it is made of).
+    ix.insert(_u64(500, 7, 99), _u64(0, 0, 0), _u64(100, 101, 102))
+    assert (ix.runs, ix.hashed) == (4, 0)
+    # Scattered ids, more than that: the hash, whole.
+    scattered = np.arange(1000, 1400, 20, dtype=np.uint64)
+    ix.insert(scattered, np.zeros(20, np.uint64), np.arange(200, 220, dtype=np.uint64))
+    assert (ix.runs, ix.hashed) == (4, 20)
+    found, vals = ix.lookup(_u64(12, 7, 8, 1020, 1021), _u64(0, 0, 0, 0, 0))
+    assert found.tolist() == [True, True, False, True, False]
+    assert vals[0] == 2 and vals[1] == 101 and vals[3] == 201
 
 
 def test_runindex_remove_splits_and_empties_runs():
@@ -282,6 +289,7 @@ def test_runindex_rejects_wraparound_run():
     ix.insert(lo, _u64(7, 7), _u64(0, 1))
     found, vals = ix.lookup(lo, _u64(7, 7))
     assert found.all() and vals.tolist() == [0, 1]
+    assert ix.runs == 2  # two runs of one: 0 does not follow 2**64 - 1
 
 
 # ---------------------------------------------------------------------------

@@ -561,7 +561,8 @@ PARTS = PLAN_PARTS | FINISH_PARTS | LSM_PARTS | FREEZE_PARTS
 # Counters at the parts' boundaries, and the process's own pauses
 # (obs/process.py) on a server's registry.
 PART_COUNTERS = {"lsm.seal.bytes", "lsm.compact.blocks_read",
-                 "lsm.compact.blocks_written"}
+                 "lsm.compact.blocks_written",
+                 "sm.ids.runs_filed", "sm.ids.hashed", "sm.ids.runs"}
 PROCESS_KEYS = {
     "server.gc.pause_us.count", "server.gc.pause_us.sum",
     "server.gc.pause_us.max", "server.gc.collections.gen0",

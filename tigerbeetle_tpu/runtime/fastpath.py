@@ -162,6 +162,9 @@ def _load():
         lib.tb_fp_remove_transfer_ids.argtypes = [
             ctypes.c_void_p, _U64P, _U64P, ctypes.c_uint32,
         ]
+        lib.tb_fp_peek_transfer_ids.argtypes = [
+            ctypes.c_void_p, _U64P, _U64P, ctypes.c_uint32, _U8P, _U64P, _U64P,
+        ]
         lib.tb_fp_commit_transfers.restype = ctypes.c_int
         lib.tb_fp_commit_transfers.argtypes = [
             ctypes.c_void_p, _U8P, ctypes.c_uint32, ctypes.c_uint64,
@@ -475,6 +478,20 @@ class NativeFastpath:
         self._lib.tb_fp_remove_transfer_ids(
             self._fp, _p(id_lo, _U64P), _p(id_hi, _U64P), len(id_lo)
         )
+
+    def peek_transfer_ids(self, id_lo, id_hi):
+        """Read-only, for tests and counters: -> (found, values, runs
+        the transfer-id directory holds, ids in its hash)."""
+        id_lo = np.ascontiguousarray(id_lo, np.uint64)
+        id_hi = np.ascontiguousarray(id_hi, np.uint64)
+        found = np.zeros(len(id_lo), np.uint8)
+        values = np.zeros(len(id_lo), np.uint64)
+        counts = np.zeros(2, np.uint64)
+        self._lib.tb_fp_peek_transfer_ids(
+            self._fp, _p(id_lo, _U64P), _p(id_hi, _U64P), len(id_lo),
+            _p(found, _U8P), _p(values, _U64P), _p(counts, _U64P),
+        )
+        return found.astype(bool), values, int(counts[0]), int(counts[1])
 
     def commit_exact(self, ev: dict, field_order, dstat_init, B: int,
                      n: int, ts_base: int):

@@ -14,8 +14,9 @@ src/lsm/groove.zig):
   assigned monotonically and timestamps rise with rows, so row order ==
   timestamp order.  The id -> row map stays in the RAM run-compressed
   id directories (utils/hashindex.py RunIndex + the native IdDir) —
-  sequential-id workloads compress to O(1) ranges; the object tree
-  rebuilds them after restore.
+  sequential-id workloads compress to a range a batch, and one more
+  wherever a row failed; only scattered ids (under 8 a piece) reach
+  their hash; the object tree rebuilds them after restore.
 - dr/cr index trees: key = (account slot, timestamp), value = row —
   timestamp-ordered range scans per account for get_account_transfers
   (reference: src/state_machine.zig:931-996).

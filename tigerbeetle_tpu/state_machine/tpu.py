@@ -310,8 +310,11 @@ class TailStore:
 
 def _dir_capacity(entries: int) -> int:
     """Pow2 hash capacity holding `entries` at <=50% load (the hash is
-    the RunIndex fallback for non-sequential ids; presizing it keeps
-    random-id workloads from rehashing on the commit hot path)."""
+    the RunIndex fallback for scattered ids: a batch whose ids follow
+    each other in fewer than an eighth of its places, utils/hashindex.py
+    RUN_PIECES; a batch with a gap wherever a row failed is runs and
+    never reaches it; presizing it keeps random-id workloads from
+    rehashing on the commit hot path)."""
     return max(1 << 16, 1 << (2 * max(entries, 1)).bit_length())
 
 
@@ -482,6 +485,13 @@ class TpuStateMachine:
         self._st_ckpt_verify_host = part("sm.ckpt.verify_host")
         self._st_ckpt_encode = part("sm.ckpt.encode")
         self._c_join_cold_rows = _c("store.join_cold_rows")
+        # How the transfer-id directory filed the created batches: the
+        # runs they became, the ids that went to its hash instead
+        # (scattered ids; 0 where ids follow each other), and the runs
+        # it holds, set wherever the list moves.
+        self._c_ids_runs_filed = _c("ids.runs_filed")
+        self._c_ids_hashed = _c("ids.hashed")
+        self._g_id_runs = self.metrics.gauge("ids.runs")
         # Account rows in use (of the capacity `--cache-accounts` gives
         # the directory, the mirror and the device table), set wherever
         # the count moves: a pull gauge would change the snapshot under
@@ -3354,9 +3364,7 @@ class TpuStateMachine:
             rows = np.arange(lo, lo + n) - st._off + st.base
             id_lo = st.ram._cols["id_lo"][lo : lo + n]
             id_hi = st.ram._cols["id_hi"][lo : lo + n]
-            self._tdir.insert(id_lo, id_hi, rows.astype(np.uint64))
-            if self._native is not None:
-                self._native.add_transfer_ids(id_lo, id_hi, int(rows[0]))
+            self._index_created(id_lo, id_hi, rows)
             self.commit_timestamp = ts_base + n - 1
             return b""
 
@@ -3724,6 +3732,21 @@ class TpuStateMachine:
         reply["result"] = results[fail_idx]
         return reply.tobytes()
 
+    def _index_created(self, id_lo, id_hi, rows, part=NOOP_RUN) -> None:
+        """File a created batch's ids under its (contiguous) rows in
+        the id directory and in the native duplicate-id set, kept in
+        lockstep: the same batches, filed by the same rule."""
+        part.switch(self._st_finish_ids)
+        filed = self._tdir.insert(id_lo, id_hi, rows.astype(np.uint64))
+        if filed:
+            self._c_ids_runs_filed.inc(filed)
+        else:
+            self._c_ids_hashed.inc(len(id_lo))
+        self._g_id_runs.set(self._tdir.runs)
+        if self._native is not None:
+            part.switch(self._st_finish_native_ids)
+            self._native.add_transfer_ids(id_lo, id_hi, int(rows[0]))
+
     def _finish_fast(
         self, n, ts_base, id_lo, id_hi, flags, timeout, results, created,
         last_applied: int | None = None,
@@ -3807,15 +3830,7 @@ class TpuStateMachine:
                 flags=sel(flags), timestamp=ts,
                 status=sel(inb_status).astype(np.uint8),
             )
-            part.switch(self._st_finish_ids)
-            self._tdir.insert(sel(id_lo), sel(id_hi), rows.astype(np.uint64))
-            if self._native is not None:
-                # Keep the native duplicate-id set in lockstep (rows
-                # are contiguous, so base_row + i == row).
-                part.switch(self._st_finish_native_ids)
-                self._native.add_transfer_ids(
-                    sel(id_lo), sel(id_hi), int(rows[0])
-                )
+            self._index_created(sel(id_lo), sel(id_hi), rows, part)
             row_of_event = np.full(n, -1, np.int64)
             row_of_event[idx] = rows
         else:
@@ -4312,6 +4327,7 @@ def _tpu_restore(self, data: bytes) -> None:
         self._store.col("id_lo"), self._store.col("id_hi"),
         np.arange(base, base + self._store.tail_count(), dtype=np.uint64),
     )
+    self._g_id_runs.set(self._tdir.runs)
 
     cap = max(1 << 12, 1 << (n_acct - 1).bit_length() if n_acct else 1)
     self._mirror = BalanceMirror(cap)

@@ -154,6 +154,25 @@ class HashIndex:
         self._tombstones += removed
 
 
+# How a batch is filed (the ONE rule, in two copies: RunIndex here and
+# native/tb_fastpath.cpp IdDir, which must agree on where an id lives).
+# A batch is split wherever its ids stop following each other; it goes
+# to the run list, whole, where its pieces average at least RUN_PIECES
+# ids or are at most RUN_PIECES (a prepare of a few sessions' requests,
+# a single id), and to the hash, whole, otherwise.  8: a run costs
+# three u64s and a step of the binary search where a hashed id costs
+# 52 bytes (26 a slot, at most half of them used) and a cache miss, so
+# a list of runs that short is no smaller and no faster than the hash; the cells' gapped batches
+# average 50-200 ids a piece, scattered ids 1, nothing lies between.
+RUN_PIECES = 8
+# The "at most RUN_PIECES" door holds the LIST to the same mean once it
+# is longer than this: ids that arrive one a batch and never follow
+# each other (a client that sends random ids singly) would otherwise
+# grow it by a run, and an O(runs) shift, each.
+RUN_LIST_FREE = 1 << 16
+_NO_RUNS = np.empty((3, 0), np.uint64)
+
+
 class RunIndex:
     """Id directory with run-length compression over sequential ids.
 
@@ -162,115 +181,150 @@ class RunIndex:
     `id_order=sequential`; docs/coding/data-modeling.md time-based ids).
     Rows in the columnar stores are assigned in insert order, so a batch
     of contiguous ids maps to a contiguous row range — representable as
-    one (start_id, len, start_val) run instead of 8190 hash entries.
+    one (start_id, len, start_val) run instead of 8190 hash entries; a
+    batch with gaps (a created batch has one wherever a row failed) is
+    as many runs as it has pieces.
 
     Same contract as HashIndex (insert keys unique & absent; remove keys
-    present). Non-contiguous batches fall back to the hash; lookups
-    consult both. Runs are grouped by the high limb (virtually always a
+    present). Scattered batches (RUN_PIECES) fall back to the hash;
+    lookups ask the runs first and the hash for what they did not
+    answer. Runs are grouped by the high limb (virtually always a
     single group, id_hi == 0 or a fixed template prefix) and kept sorted
     by start for a vectorized searchsorted probe.
     """
 
     def __init__(self, capacity: int = 1 << 16) -> None:
         self._hash = HashIndex(capacity)
-        # hi (int) -> [starts u64 sorted, lens u64, vals u64]
-        self._runs: dict[int, list[np.ndarray]] = {}
-        self._run_count = 0
+        # hi (int) -> (3, R) u64: starts (sorted), lens, vals
+        self._runs: dict[int, np.ndarray] = {}
+        self._run_count = 0  # ids the runs hold
+        self.runs = 0  # runs, over all groups
 
     @property
     def count(self) -> int:
         return self._hash.count + self._run_count
 
-    def _try_run(self, lo, hi, values) -> bool:
-        n = len(lo)
-        if n < 2 or hi[0] != hi[-1] or (hi != hi[0]).any():
-            return False
-        # lo[-1] >= lo[0] rejects uint64 wraparound, which the modular
-        # diff check alone would mistake for contiguity.
-        if lo[-1] < lo[0] or values[-1] < values[0]:
-            return False
-        one = np.uint64(1)
-        if ((lo[1:] - lo[:-1]) != one).any():
-            return False
-        if ((values[1:] - values[:-1]) != one).any():
-            return False
-        h = int(hi[0])
-        start, val = lo[0], values[0]
-        g = self._runs.get(h)
-        if g is None:
-            self._runs[h] = [
-                np.array([start], np.uint64),
-                np.array([n], np.uint64),
-                np.array([val], np.uint64),
-            ]
-            self._run_count += n
-            return True
-        starts, lens, vals = g
-        i = int(np.searchsorted(starts, start))
-        # Merge with predecessor when ids AND rows are both contiguous.
-        if (
-            i > 0
-            and starts[i - 1] + lens[i - 1] == start
-            and vals[i - 1] + lens[i - 1] == val
-        ):
-            lens[i - 1] += np.uint64(n)
-            # May now abut the successor too.
-            if (
-                i < len(starts)
-                and starts[i - 1] + lens[i - 1] == starts[i]
-                and vals[i - 1] + lens[i - 1] == vals[i]
-            ):
-                lens[i - 1] += lens[i]
-                g[0] = np.delete(starts, i)
-                g[1] = np.delete(lens, i)
-                g[2] = np.delete(vals, i)
-        elif (
-            i < len(starts)
-            and start + np.uint64(n) == starts[i]
-            and val + np.uint64(n) == vals[i]
-        ):
-            starts[i] = start
-            lens[i] += np.uint64(n)
-            vals[i] = val
-        else:
-            g[0] = np.insert(starts, i, start)
-            g[1] = np.insert(lens, i, np.uint64(n))
-            g[2] = np.insert(vals, i, val)
-        self._run_count += n
-        return True
+    @property
+    def hashed(self) -> int:
+        return self._hash.count
 
-    def insert(self, lo: np.ndarray, hi: np.ndarray, values: np.ndarray) -> None:
-        if len(lo) == 0:
-            return
+    def insert(self, lo: np.ndarray, hi: np.ndarray, values: np.ndarray) -> int:
+        """Batch insert -> the runs the batch was filed as, 0 where it
+        went to the hash."""
+        n = len(lo)
+        if n == 0:
+            return 0
         lo = np.asarray(lo, np.uint64)
         hi = np.asarray(hi, np.uint64)
         values = np.asarray(values, np.uint64)
-        if not self._try_run(lo, hi, values):
+        one = np.uint64(1)
+        # A piece starts where the id, its high limb or the value does
+        # not follow the one before; an id of 0 follows nothing (the
+        # modular +1 of 2^64 - 1).
+        head = np.empty(n, bool)
+        head[0] = True
+        np.not_equal(lo[1:], lo[:-1] + one, out=head[1:])
+        head[1:] |= values[1:] != values[:-1] + one
+        head[1:] |= hi[1:] != hi[:-1]
+        head[1:] |= lo[1:] == 0
+        at = np.flatnonzero(head)
+        k = len(at)
+        if k > n // RUN_PIECES and (
+            k > RUN_PIECES
+            or self.runs + k
+            > max((self._run_count + n) // RUN_PIECES, RUN_LIST_FREE)
+        ):
             self._hash.insert(lo, hi, values)
+            return 0
+        # (3, k): the pieces' starts, lengths, values.
+        pieces = np.empty((3, k), np.uint64)
+        pieces[0] = lo[at]
+        pieces[1, :-1] = at[1:] - at[:-1]
+        pieces[1, -1] = n - at[-1]
+        pieces[2] = values[at]
+        p_hi = hi[at]
+        if k == 1 or (p_hi == p_hi[0]).all():
+            self._file(int(p_hi[0]), pieces)
+        else:
+            for h in np.unique(p_hi):
+                self._file(int(h), pieces[:, p_hi == h])
+        self._run_count += n
+        return k
+
+    def _file(self, h: int, pieces: np.ndarray) -> None:
+        """Place one group's new runs in ONE pass over its array (every
+        stretch between them moves once, as a block), then join what
+        abuts (ids and values both) across the boundaries a new run
+        touches."""
+        k = pieces.shape[1]
+        if k > 1:
+            pieces = pieces[:, np.argsort(pieces[0])]
+        old = self._runs.get(h, _NO_RUNS)
+        pos = np.searchsorted(old[0], pieces[0])
+        new = pos + np.arange(k)
+        g = np.empty((3, old.shape[1] + k), np.uint64)
+        g[:, new] = pieces
+        prev = 0
+        for j, p in enumerate(pos.tolist()):
+            g[:, prev + j : p + j] = old[:, prev:p]
+            prev = p
+        g[:, prev + k :] = old[:, prev:]
+        self.runs += k
+        b = np.concatenate((new - 1, new))
+        if k > 1:
+            b = np.unique(b)
+        b = b[(b >= 0) & (b < g.shape[1] - 1)]
+        starts, lens, vals = g
+        end = lens[b]
+        join = b[
+            (starts[b] + end == starts[b + 1]) & (vals[b] + end == vals[b + 1])
+        ]
+        if len(join):
+            # Run join[i] + 1 folds into the run before it; a chain of
+            # them folds into the chain's first.
+            first = np.ones(len(join), bool)
+            first[1:] = join[1:] != join[:-1] + 1
+            into = join[first][np.cumsum(first) - 1]
+            np.add.at(lens, into, lens[join + 1])
+            g = np.delete(g, join + 1, axis=1)
+            self.runs -= len(join)
+        self._runs[h] = g
 
     def lookup(self, lo: np.ndarray, hi: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        found, values = self._hash.lookup(lo, hi)
-        if not self._runs:
-            return found, values
+        n = len(lo)
+        if n == 0 or not self._runs:
+            return self._hash.lookup(lo, hi)
         lo = np.asarray(lo, np.uint64)
         hi = np.asarray(hi, np.uint64)
-        for h, (starts, lens, vals) in self._runs.items():
-            if not len(starts):
-                continue
-            lane = ~found & (hi == np.uint64(h))
-            if not lane.any():
-                continue
-            ls = lo[lane]
-            idx = np.searchsorted(starts, ls, side="right") - 1
-            ic = np.maximum(idx, 0)
-            hit = (idx >= 0) & (ls - starts[ic] < lens[ic])
-            if not hit.any():
-                continue
-            li = np.flatnonzero(lane)[hit]
-            off = lo[li] - starts[ic[hit]]
-            found[li] = True
-            values[li] = vals[ic[hit]] + off
+        if (hi == hi[0]).all():
+            found, values = self._probe(int(hi[0]), lo)
+        else:
+            found = np.zeros(n, bool)
+            values = np.zeros(n, np.uint64)
+            for h in np.unique(hi):
+                lane = np.flatnonzero(hi == h)
+                found[lane], values[lane] = self._probe(int(h), lo[lane])
+        # The hash is asked only for what the runs did not answer, and
+        # not at all while it is empty.
+        if self._hash.count:
+            if not found.any():
+                return self._hash.lookup(lo, hi)
+            miss = np.flatnonzero(~found)
+            found[miss], values[miss] = self._hash.lookup(lo[miss], hi[miss])
         return found, values
+
+    def _probe(self, h: int, lo: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        g = self._runs.get(h)
+        if g is None:
+            return np.zeros(len(lo), bool), np.zeros(len(lo), np.uint64)
+        starts, lens, vals = g
+        idx = np.searchsorted(starts, lo, side="right") - 1
+        ic = np.maximum(idx, 0)
+        off = lo - starts[ic]
+        hit = (idx >= 0) & (off < lens[ic])
+        if not hit.any():
+            return hit, np.zeros(len(lo), np.uint64)
+        return hit, np.where(hit, vals[ic] + off, np.uint64(0))
 
     def remove(self, lo: np.ndarray, hi: np.ndarray) -> None:
         n = len(lo)
@@ -283,7 +337,8 @@ class RunIndex:
             self._hash.remove(lo[in_hash], hi[in_hash])
         # Run splitting: rare (create_accounts chain rollback only).
         for k in np.flatnonzero(~in_hash):
-            g = self._runs.get(int(hi[k]))
+            h = int(hi[k])
+            g = self._runs.get(h)
             assert g is not None, "remove of absent key"
             starts, lens, vals = g
             i = int(np.searchsorted(starts, lo[k], side="right")) - 1
@@ -291,12 +346,11 @@ class RunIndex:
             assert 0 <= off < lens[i], "remove of absent key"
             tail = lens[i] - off - np.uint64(1)
             if off == 0 and tail == 0:
-                if len(starts) == 1:
-                    del self._runs[int(hi[k])]
+                if g.shape[1] == 1:
+                    del self._runs[h]
                 else:
-                    g[0] = np.delete(starts, i)
-                    g[1] = np.delete(lens, i)
-                    g[2] = np.delete(vals, i)
+                    self._runs[h] = np.delete(g, i, axis=1)
+                self.runs -= 1
             elif off == 0:
                 starts[i] += np.uint64(1)
                 vals[i] += np.uint64(1)
@@ -304,9 +358,8 @@ class RunIndex:
             elif tail == 0:
                 lens[i] = off
             else:
-                new_val = vals[i] + off + np.uint64(1)
+                rest = (lo[k] + np.uint64(1), tail, vals[i] + off + np.uint64(1))
                 lens[i] = off
-                g[0] = np.insert(starts, i + 1, lo[k] + np.uint64(1))
-                g[1] = np.insert(lens, i + 1, tail)
-                g[2] = np.insert(vals, i + 1, new_val)
+                self._runs[h] = np.insert(g, i + 1, rest, axis=1)
+                self.runs += 1
             self._run_count -= 1
