@@ -2,9 +2,9 @@
 `CpuStateMachine` at a small size; what a failed chain and a second
 finalise answer; the control, which has to come out as not correct;
 and the cell `bench1r-chains2p-c4` as one traced rehearsal on the CPU
-backend, whose five per-layer metric files (no manifest entry yet,
-PERF.md section 7.9) are read through a copy of the manifest that has
-the entries, `ENTRIES` being their text.
+backend, which reads its five per-layer metrics (`ENTRIES` is their
+manifest entries' text, with the cell they were written for; PR 40
+appended them).
 """
 
 import json
@@ -20,11 +20,13 @@ sys.path.insert(0, _REPO)
 
 from benchmarks.harness import compare, load, manifest as mf, wire  # noqa: E402
 from benchmarks.harness.gen import chains2p  # noqa: E402
+from manifest_checks import manifest_lists  # noqa: E402
 from test_reference import _HEALTHY, Oracle  # noqa: E402
 from tigerbeetle_tpu.types import Operation  # noqa: E402
 
 RUN = os.path.join(_REPO, "benchmarks", "run.py")
 CELL = "bench1r-chains2p-c4"
+M = mf.Manifest()
 CONFIG = {"accounts": 256, "ledger": 1}
 PARAMS = {"sessions": 4, "request_events": 120, "amount_max": 999,
           "chain_len": [1, 7], "chain_fail_share": 0.2, "void_share": 0.3,
@@ -32,17 +34,18 @@ PARAMS = {"sessions": 4, "request_events": 120, "amount_max": 999,
 SEEDS = [1, 33, 2**31 + 4321]
 ENGINE, ROUTING = "device engine", "state machine routing"
 ENTRIES = [
-    {"name": "fallback_batches_per_req", "unit": "count", "source": "program_counter",
-     "layer": ENGINE, "moves": "commit_events_per_s"},
-    {"name": "dense_fetches_per_req", "unit": "count", "source": "program_counter",
-     "layer": ENGINE, "moves": "request_p50_ms"},
-    {"name": "pending_join_cold_us_per_prepare", "unit": "us", "source": "program_span",
-     "layer": ROUTING, "moves": "commit_events_per_s"},
-    {"name": "pending_join_cold_rows_per_req", "unit": "rows", "source": "program_counter",
-     "layer": ROUTING, "moves": "commit_events_per_s"},
-    {"name": "linked_fixpoint_iters_mean", "unit": "count", "source": "program_counter",
-     "layer": "kernels", "moves": "commit_events_per_s"},
-]
+    dict(e, better="lower", workloads=[CELL]) for e in (
+        {"name": "fallback_batches_per_req", "unit": "count",
+         "source": "program_counter", "layer": ENGINE, "moves": "commit_events_per_s"},
+        {"name": "dense_fetches_per_req", "unit": "count",
+         "source": "program_counter", "layer": ENGINE, "moves": "request_p50_ms"},
+        {"name": "pending_join_cold_us_per_prepare", "unit": "us",
+         "source": "program_span", "layer": ROUTING, "moves": "commit_events_per_s"},
+        {"name": "pending_join_cold_rows_per_req", "unit": "rows",
+         "source": "program_counter", "layer": ROUTING, "moves": "commit_events_per_s"},
+        {"name": "linked_fixpoint_iters_mean", "unit": "count",
+         "source": "program_counter", "layer": "kernels", "moves": "commit_events_per_s"},
+    )]
 
 
 def _replay(seed: int, cycles: int = 3):
@@ -203,22 +206,13 @@ def test_control_lost_ack_is_not_correct(seed):
 
 
 @pytest.fixture(scope="module")
-def manifest_with_entries(tmp_path_factory):
-    doc = json.load(open(mf.MANIFEST))
-    doc["per_layer"] += [dict(e, better="lower", workloads=[CELL]) for e in ENTRIES]
-    path = tmp_path_factory.mktemp("chains2p") / "BENCHMARK.json"
-    path.write_text(json.dumps(doc))
-    return str(path)
-
-
-@pytest.fixture(scope="module")
-def rehearsed(tmp_path_factory, manifest_with_entries):
+def rehearsed(tmp_path_factory):
     """-> (the result line, the run's scrapes)"""
     run_dir = tmp_path_factory.mktemp("cell") / "run"
     proc = subprocess.run(
         [sys.executable, RUN, "--workload", CELL, "--seed", str(2**31 + 331),
          "--seconds", "4", "--trace", "1", "--rehearsal", "--keep",
-         "--manifest", manifest_with_entries, "--run-dir", str(run_dir)],
+         "--run-dir", str(run_dir)],
         capture_output=True, text=True, timeout=900, cwd=_REPO,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert proc.returncode == 3, proc.stderr[-3000:]
@@ -228,8 +222,7 @@ def rehearsed(tmp_path_factory, manifest_with_entries):
         return line, json.load(f)
 
 
-def test_the_cell_is_the_manifests(manifest_with_entries):
-    m = mf.Manifest()
+def check_the_cell_is_the_manifests(m):
     cell = m.cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "chains2p-1r", "chains2p-c4", 1)
@@ -250,12 +243,13 @@ def test_the_cell_is_the_manifests(manifest_with_entries):
     # One checkpoint in every window of 45 to 1,004 requests.
     assert 960 - traffic["phase"]["offset_ops"] == 45
     assert traffic["read_back"]["transfer_sample_requests"] == 9
-    copy = mf.Manifest(manifest_with_entries)
     for e in ENTRIES:
-        spec = copy.layer_spec(e)
-        assert spec["name"] == e["name"] and callable(mf.reader(spec).read)
+        spec = manifest_lists(m, e)
         assert spec["reader"] in ("scrape_delta_ratio", "scrape_hist_mean")
-        assert e["name"] not in m.per_layer, "then this copy is not needed"
+
+
+def test_the_cell_is_the_manifests():
+    check_the_cell_is_the_manifests(M)
 
 
 def test_the_cell_rehearsed_is_correct_on_the_device_engine(rehearsed):
@@ -283,14 +277,12 @@ def test_the_cell_rehearsed_is_correct_on_the_device_engine(rehearsed):
 
 
 @pytest.mark.parametrize("name", [e["name"] for e in ENTRIES])
-def test_a_program_without_the_keys_gives_the_readers_nothing(
-        manifest_with_entries, name):
+def test_a_program_without_the_keys_gives_the_readers_nothing(name):
     """The parent's scrapes: each reader returns None and raises
     nothing, so a result line leaves the metric out."""
-    m = mf.Manifest(manifest_with_entries)
     old = {"vsr.commit_us.count": 5, "sm.dev.link.fetch_bytes": 9}
     later = dict(old, **{"vsr.commit_us.count": 9})
     ctx = {"before": [dict(old)], "after": [dict(later)],
            "at_close": [dict(later)], "requests": 7, "trace": None}
-    spec = m.layer_spec(m.per_layer[name])
+    spec = M.layer_spec(M.per_layer[name])
     assert mf.reader(spec).read(spec, ctx) is None

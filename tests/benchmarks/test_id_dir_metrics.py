@@ -7,12 +7,11 @@ beside them.  They say how often PR 38's mechanism engages: a batch
 with a gap wherever a row failed is as many runs as it has pieces and
 none of its ids reaches the hash.
 
-The files stand without a manifest entry, as `test_part_metrics.py`'s
-do and for its reason (PERF.md section 7.9); `ENTRIES` is their text,
-for the `benchmark` PR of ROADMAP.md S0b to append.  They are read
-here from two scrapes, and from ONE traced rehearsal of the chains
-cell, the cell whose requests fail in part (its rehearsal: one session,
-requests of 500 rows).
+`ENTRIES` is their manifest entries' text, with the cells they were
+written for (PR 40 appended them).  They are read here from two
+scrapes, and from ONE traced rehearsal of the chains cell, the cell
+whose requests fail in part (its rehearsal: one session, requests of
+500 rows).
 """
 
 import json
@@ -26,10 +25,12 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__
 sys.path.insert(0, _REPO)
 
 from benchmarks.harness import manifest as mf  # noqa: E402
+from manifest_checks import manifest_lists  # noqa: E402
 
 RUN = os.path.join(_REPO, "benchmarks", "run.py")
 M = mf.Manifest()
 CELL = "bench1r-chains2p-c4"
+# The cells the entries were written for; the manifest may list more.
 CELLS = ["bench1r-small-c4", "bench1r-plain-c4", "bench3r-plain-c4",
          "bench1r-chains2p-c4", "bench1r-tpcc-pay-c4"]
 KEY = {"id_dir_runs_filed_per_prepare": "sm.ids.runs_filed",
@@ -43,28 +44,16 @@ ENTRIES = [
 NAMES = [e["name"] for e in ENTRIES]
 
 
-@pytest.fixture(scope="module")
-def manifest_with_entries(tmp_path_factory):
-    doc = json.load(open(mf.MANIFEST))
-    doc["per_layer"] += ENTRIES
-    path = tmp_path_factory.mktemp("id_dir") / "BENCHMARK.json"
-    path.write_text(json.dumps(doc))
-    return str(path)
-
-
-@pytest.mark.parametrize("entry", ENTRIES, ids=NAMES)
-def test_the_entry_names_a_file_that_reads_its_counter(entry, manifest_with_entries):
-    copy = mf.Manifest(manifest_with_entries)
-    assert entry["name"] not in M.per_layer, "then this copy is not needed"
-    assert entry["layer"] in {m["layer"] for m in M.doc["per_layer"]}
-    assert entry["moves"] in M.end_to_end
-    assert set(entry["workloads"]) == set(M.cells)
-    assert mf.NAME.match(entry["name"]) and mf.UNIT.match(entry["unit"])
-    spec = copy.layer_spec(entry)
-    assert spec["name"] == entry["name"] and callable(mf.reader(spec).read)
+def check_the_entry_names_a_file_that_reads_its_counter(m, entry):
+    spec = manifest_lists(m, entry)
     assert spec["reader"] == "scrape_delta_ratio"
     assert spec["keys"] == [KEY[entry["name"]]]
     assert spec["over"] == ["vsr.commit_us.count"]
+
+
+@pytest.mark.parametrize("entry", ENTRIES, ids=NAMES)
+def test_the_entry_names_a_file_that_reads_its_counter(entry):
+    check_the_entry_names_a_file_that_reads_its_counter(M, entry)
 
 
 @pytest.mark.parametrize("filed, hashed, prepares, want", [
@@ -72,40 +61,36 @@ def test_the_entry_names_a_file_that_reads_its_counter(entry, manifest_with_entr
     (6552, 0, 156, (42.0, 0.0)),          # 41 failed payments a request
     (0, 8190 * 4, 4, (0.0, 8190.0)),      # random ids: the hash, whole
 ])
-def test_the_files_read_a_windows_deltas(filed, hashed, prepares, want,
-                                         manifest_with_entries):
-    copy = mf.Manifest(manifest_with_entries)
+def test_the_files_read_a_windows_deltas(filed, hashed, prepares, want):
     before = {"sm.ids.runs_filed": 7, "sm.ids.hashed": 3, "vsr.commit_us.count": 11}
     after = {"sm.ids.runs_filed": 7 + filed, "sm.ids.hashed": 3 + hashed,
              "vsr.commit_us.count": 11 + prepares}
     ctx = {"before": [before], "after": [after], "at_close": [after], "requests": 9}
     got = tuple(mf.reader(spec).read(spec, ctx) for spec in (
-        copy.layer_spec(copy.per_layer[name]) for name in NAMES))
+        M.layer_spec(M.per_layer[name]) for name in NAMES))
     assert got == want
 
 
 @pytest.mark.parametrize("name", NAMES)
-def test_a_program_without_the_counters_gives_the_readers_nothing(
-        name, manifest_with_entries):
+def test_a_program_without_the_counters_gives_the_readers_nothing(name):
     """The parent's scrapes: each reader returns None and raises
     nothing, so a result line leaves the metric out."""
     old = {"vsr.commit_us.count": 5, "sm.finish.ids_us.sum": 1.0}
     later = dict(old, **{"vsr.commit_us.count": 9})
     ctx = {"before": [dict(old)], "after": [dict(later)],
            "at_close": [dict(later)], "requests": 4}
-    copy = mf.Manifest(manifest_with_entries)
-    spec = copy.layer_spec(copy.per_layer[name])
+    spec = M.layer_spec(M.per_layer[name])
     assert mf.reader(spec).read(spec, ctx) is None
 
 
 @pytest.fixture(scope="module")
-def rehearsed(tmp_path_factory, manifest_with_entries):
+def rehearsed(tmp_path_factory):
     """-> (the result line, the run's scrapes) of one traced rehearsal."""
     run_dir = tmp_path_factory.mktemp("id_dir_run") / "run"
     proc = subprocess.run(
         [sys.executable, RUN, "--workload", CELL, "--seed", str(2**31 + 381),
          "--seconds", "4", "--trace", "1", "--rehearsal", "--keep",
-         "--manifest", manifest_with_entries, "--run-dir", str(run_dir)],
+         "--run-dir", str(run_dir)],
         capture_output=True, text=True, timeout=900, cwd=_REPO,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert proc.returncode == 3, proc.stderr[-3000:]

@@ -23,46 +23,59 @@ LINE = re.compile(r"^[^\t\n]{1,200}$")
 FILE_NAME = re.compile(r"^[A-Za-z0-9_.\-/]+$")
 
 
-def test_top_level_keys_and_sizes():
-    assert set(DOC) == {"command", "paths", "run_seconds", "configs",
+# Each check takes a manifest: this one, and in `test_room.py` a copy
+# with one more configuration, cell and per-layer entry.
+
+
+def check_top_level_keys_and_sizes(m):
+    doc = m.doc
+    assert set(doc) == {"command", "paths", "run_seconds", "configs",
                         "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(_REPO, "BENCHMARK.json")) <= 64 * 1024
-    assert isinstance(DOC["run_seconds"], int) and 1 <= DOC["run_seconds"] <= 51
-    cells = len(DOC["workloads"])
-    assert 1 <= cells <= 24 and 1 <= len(DOC["configs"]) <= 24
+    assert os.path.getsize(m.path) <= 64 * 1024
+    assert isinstance(doc["run_seconds"], int) and 1 <= doc["run_seconds"] <= 51
+    cells = len(doc["workloads"])
+    assert 1 <= cells <= 24 and 1 <= len(doc["configs"]) <= 24
     # A full check with the full 24 cells has to fit the driver's limit.
     runs = 2 + 14 * 24
-    assert runs * (DOC["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
-    four = sum(1 for w in DOC["workloads"] if w["chips"] == 4)
+    assert runs * (doc["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
+    four = sum(1 for w in doc["workloads"] if w["chips"] == 4)
     assert four <= max(1, cells // 2)
 
 
-def test_command_and_paths():
-    assert 1 <= len(DOC["command"]) <= 32 and all(LINE.match(w) for w in DOC["command"])
-    assert 1 <= len(DOC["paths"]) <= 16
-    for p in DOC["paths"]:
+def test_top_level_keys_and_sizes():
+    check_top_level_keys_and_sizes(M)
+
+
+def check_command_and_paths(m):
+    doc = m.doc
+    assert 1 <= len(doc["command"]) <= 32 and all(LINE.match(w) for w in doc["command"])
+    assert 1 <= len(doc["paths"]) <= 16
+    for p in doc["paths"]:
         assert FILE_NAME.match(p) and len(p) <= 200 and not p.startswith("/")
         assert ".." not in p.split("/") and os.path.isdir(os.path.join(_REPO, p))
-    script = DOC["command"][1]
-    assert any(script.startswith(p + "/") for p in DOC["paths"])
-    for p in DOC["paths"]:
+    script = doc["command"][1]
+    assert any(script.startswith(p + "/") for p in doc["paths"])
+    for p in doc["paths"]:
         for root, dirs, files in os.walk(os.path.join(_REPO, p)):
             dirs[:] = [d for d in dirs if d != "__pycache__"]
             for f in files:
                 assert FILE_NAME.match(f), os.path.join(root, f)
 
 
-@pytest.mark.parametrize("config", DOC["configs"], ids=lambda c: c["name"])
-def test_config_entry_and_file(config):
+def test_command_and_paths():
+    check_command_and_paths(M)
+
+
+def check_config_entry_and_file(m, config):
+    doc = m.doc
     assert set(config) == {"name", "source", "file", "reduced", "why"}
     assert mf.NAME.match(config["name"])
     assert LINE.match(config["source"]) and LINE.match(config["why"])
-    assert any(config["file"].startswith(p + "/") for p in DOC["paths"])
+    assert any(config["file"].startswith(p + "/") for p in doc["paths"])
     assert len(config["reduced"]) <= 16 and all(mf.NAME.match(k) for k in config["reduced"])
-    assert sum(1 for c in DOC["configs"] if c["file"] == config["file"]) == 1
-    assert any(w["config"] == config["name"] for w in DOC["workloads"])
-    with open(os.path.join(_REPO, config["file"])) as f:
-        body = json.load(f)
+    assert sum(1 for c in doc["configs"] if c["file"] == config["file"]) == 1
+    assert any(w["config"] == config["name"] for w in doc["workloads"])
+    body = m.config({"name": "-", "config": config["name"]})
     assert body["name"] == config["name"]
     assert body["reduced"] == config["reduced"]
     for key in config["reduced"]:
@@ -73,16 +86,21 @@ def test_config_entry_and_file(config):
     assert os.path.isfile(os.path.join(_REPO, body["launcher"]))
 
 
-@pytest.mark.parametrize("cell", DOC["workloads"], ids=lambda w: w["name"])
-def test_cell_resolves_to_files_that_exist(cell):
+@pytest.mark.parametrize("config", DOC["configs"], ids=lambda c: c["name"])
+def test_config_entry_and_file(config):
+    check_config_entry_and_file(M, config)
+
+
+def check_cell_resolves_to_files_that_exist(m, cell):
+    doc = m.doc
     assert set(cell) == {"name", "config", "traffic", "chips", "why"}
     for key in ("name", "config", "traffic"):
         assert mf.NAME.match(cell[key]), cell[key]
     assert cell["chips"] in (1, 4) and LINE.match(cell["why"])
-    pairs = [(w["config"], w["traffic"]) for w in DOC["workloads"]]
+    pairs = [(w["config"], w["traffic"]) for w in doc["workloads"]]
     assert pairs.count((cell["config"], cell["traffic"])) == 1
-    config = M.config(cell)
-    traffic = M.traffic(cell)
+    config = m.config(cell)
+    traffic = m.traffic(cell)
     assert config["replicas"] * config["chips_per_replica"] <= cell["chips"]
     kind = mf.generator_kind(traffic)
     gen = kind.make(traffic, config, 1)
@@ -90,19 +108,25 @@ def test_cell_resolves_to_files_that_exist(cell):
     assert len(gen.request(0, 0)) in (sizes if isinstance(sizes, list) else [sizes])
     assert hasattr(kind, "reference")
     assert callable(mf.loop_kind(traffic).session)
-    assert traffic["trace"]["lead_s"] + traffic["trace"]["slice_s"] < DOC["run_seconds"]
-    reported = {m["name"] for m in M.end_to_end_for(cell["name"])}
+    assert traffic["trace"]["lead_s"] + traffic["trace"]["slice_s"] < doc["run_seconds"]
+    reported = {e["name"] for e in m.end_to_end_for(cell["name"])}
     assert "setup_s" in reported and len(reported) >= 2
-    assert M.per_layer_for(cell["name"])
+    assert m.per_layer_for(cell["name"])
 
 
-@pytest.mark.parametrize("name", sorted(
-    f[:-5] for f in os.listdir(os.path.join(mf.DATA_ROOT, "traffic"))))
-def test_every_traffic_file_draws_requests_its_reference_takes(name):
-    """Those that no cell has yet too: the cell is then one entry."""
-    traffic = M.traffic({"traffic": name})
+@pytest.mark.parametrize("cell", DOC["workloads"], ids=lambda w: w["name"])
+def test_cell_resolves_to_files_that_exist(cell):
+    check_cell_resolves_to_files_that_exist(M, cell)
+
+
+def traffic_names(m) -> list:
+    return sorted(f[:-5] for f in os.listdir(os.path.join(m.data_root, "traffic")))
+
+
+def check_traffic_file_draws_requests_its_reference_takes(m, name):
+    traffic = m.traffic({"traffic": name})
     assert traffic["name"] == name and mf.NAME.match(name)
-    config = M.config(DOC["workloads"][0])
+    config = m.config(m.doc["workloads"][0])
     kind = mf.generator_kind(traffic)
     gen = kind.make(traffic, config, 2**31 + 3)
     ref = kind.reference(gen)
@@ -117,41 +141,59 @@ def test_every_traffic_file_draws_requests_its_reference_takes(name):
     assert callable(mf.loop_kind(traffic).session)
 
 
-@pytest.mark.parametrize("metric", DOC["end_to_end"], ids=lambda m: m["name"])
-def test_end_to_end_metric(metric):
+@pytest.mark.parametrize("name", traffic_names(M))
+def test_every_traffic_file_draws_requests_its_reference_takes(name):
+    """Those that no cell has yet too: the cell is then one entry."""
+    check_traffic_file_draws_requests_its_reference_takes(M, name)
+
+
+def check_end_to_end_metric(m, metric):
     assert set(metric) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
     assert mf.NAME.match(metric["name"]) and mf.UNIT.match(metric["unit"])
     assert metric["better"] in ("lower", "higher")
     assert metric["source"] in ("host_clock", "device_trace")
     assert 0.01 <= metric["bound"] <= 0.25
-    assert 1 <= len(DOC["end_to_end"]) <= 16
-    assert any(m["name"] == "setup_s" for m in DOC["end_to_end"])
+    assert 1 <= len(m.doc["end_to_end"]) <= 16
+    assert any(e["name"] == "setup_s" for e in m.doc["end_to_end"])
 
 
-@pytest.mark.parametrize("metric", DOC["per_layer"], ids=lambda m: m["name"])
-def test_per_layer_metric_moves_what_its_cells_report(metric):
+@pytest.mark.parametrize("metric", DOC["end_to_end"], ids=lambda m: m["name"])
+def test_end_to_end_metric(metric):
+    check_end_to_end_metric(M, metric)
+
+
+def check_per_layer_metric_moves_what_its_cells_report(m, metric):
     assert set(metric) - {"workloads"} == {"name", "unit", "better", "source",
                                            "layer", "moves"}
     assert mf.NAME.match(metric["name"]) and mf.UNIT.match(metric["unit"])
     assert metric["better"] in ("lower", "higher") and metric["source"] in SOURCES
     assert LINE.match(metric["layer"])
-    moved = M.end_to_end[metric["moves"]]
-    cells = metric.get("workloads", list(M.cells))
+    moved = m.end_to_end[metric["moves"]]
+    cells = metric.get("workloads", list(m.cells))
     for name in cells:
-        assert name in M.cells
-        assert name in moved.get("workloads", list(M.cells))
-    spec = M.layer_spec(metric)
+        assert name in m.cells
+        assert name in moved.get("workloads", list(m.cells))
+    spec = m.layer_spec(metric)
     assert callable(mf.reader(spec).read)
     if metric["name"].endswith("_roofline") or "mfu" in metric["name"]:
         assert metric["unit"] == "%"
 
 
-def test_names_are_unique():
+@pytest.mark.parametrize("metric", DOC["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metric_moves_what_its_cells_report(metric):
+    check_per_layer_metric_moves_what_its_cells_report(M, metric)
+
+
+def check_names_are_unique(m):
     for group in ("configs", "workloads"):
-        names = [x["name"] for x in DOC[group]]
+        names = [x["name"] for x in m.doc[group]]
         assert len(names) == len(set(names))
-    names = [m["name"] for m in DOC["end_to_end"] + DOC["per_layer"]]
+    names = [e["name"] for e in m.doc["end_to_end"] + m.doc["per_layer"]]
     assert len(names) == len(set(names))
+
+
+def test_names_are_unique():
+    check_names_are_unique(M)
 
 
 def test_new_files_are_picked_up_with_no_code_edited(tmp_path):
@@ -182,12 +224,11 @@ def test_new_files_are_picked_up_with_no_code_edited(tmp_path):
                              "workloads": ["other3r-plain-c2"]})
     # A metric that lists its cells is read in those alone; the new cell
     # brings an entry of its own for what only some cells can report.
-    for name in ("backup_lag_ops", "backup_lag_ops.other3r"):
-        doc["per_layer"].append({"name": name, "unit": "ops", "better": "lower",
-                                 "source": "program_counter",
-                                 "layer": "VSR, journal, checkpoint",
-                                 "moves": "request_p95_ms",
-                                 "workloads": ["other3r-plain-c2"]})
+    doc["per_layer"].append({"name": "backup_lag_ops.other3r", "unit": "ops",
+                             "better": "lower", "source": "program_counter",
+                             "layer": "VSR, journal, checkpoint",
+                             "moves": "request_p95_ms",
+                             "workloads": ["other3r-plain-c2"]})
     shutil.copy(root / "layer_metrics" / "backup_lag_ops.json",
                 root / "layer_metrics" / "backup_lag_ops.other3r.json")
     path = tmp_path / "BENCHMARK.json"
@@ -205,7 +246,8 @@ def test_new_files_are_picked_up_with_no_code_edited(tmp_path):
            "at_close": [later, snap, later], "requests": 20, "trace": None}
     got = mf.read_layer_metrics(m, "other3r-plain-c2", ctx)
     assert got["journal_write_us_mean"] == {"value": 10.0, "unit": "us"}
-    assert got["backup_lag_ops.other3r"]["value"] == got["backup_lag_ops"]["value"] == 2.0
+    assert got["backup_lag_ops.other3r"]["value"] == 2.0
+    assert "backup_lag_ops" not in got
     # A reader that finds nothing to read leaves its metric out.
     assert "device_idle_pct" not in got and "commit_roofline_pct" not in got
     assert "journal_write_us_mean" not in mf.read_layer_metrics(
@@ -214,15 +256,19 @@ def test_new_files_are_picked_up_with_no_code_edited(tmp_path):
         m.cell("no-such-cell")
 
 
-def test_trace_readers_never_pass_the_roofline():
+def check_trace_readers_never_pass_the_roofline(m):
     from benchmarks.harness import roofline
 
     ctx = {"before": [{}], "after": [{}], "at_close": [{}], "requests": 100,
            "trace": {"busy_s": 0.5, "window_s": 4.0, "requests": 100,
                      "events": 819000, "device_kind": "TPU v5 lite"}}
-    got = mf.read_layer_metrics(M, "bench1r-small-c4", ctx)
+    got = mf.read_layer_metrics(m, "bench1r-small-c4", ctx)
     assert got["device_idle_pct"]["value"] == pytest.approx(87.5)
     assert got["device_busy_ms_per_req"]["value"] == pytest.approx(5.0)
     least = 819000 * roofline.bytes_per_event() / 819e9
     assert got["commit_roofline_pct"]["value"] == pytest.approx(100 * least / 0.5)
     assert 0 < got["commit_roofline_pct"]["value"] < 100
+
+
+def test_trace_readers_never_pass_the_roofline():
+    check_trace_readers_never_pass_the_roofline(M)

@@ -7,16 +7,13 @@ holds the checkpoint).  (`bench1r-plain-c4` and `bench3r-plain-c4` are
 in every entry's `workloads` and are not rehearsed here: each
 rehearsal more on this machine's eight cores starves the other files'
 of the requests their windows need, and `test_plain_cells.py` runs
-those two already.  On the chip both cells' result lines have all 34
-through this same manifest copy, `chiprun_out/pr37b`, `pr37d`; parts
-on a beat worker's thread are `tests/test_stages.py`'s.)
+those two already.  On the chip both cells' result lines had all 34
+through a copy of the manifest with these entries, `chiprun_out/pr37b`,
+`pr37d`; parts on a beat worker's thread are `tests/test_stages.py`'s.)
 
-The files stand without a manifest entry, as PR 27's, 28's, 33's and
-35's do: `test_stage_metrics.py` holds the list's last eighteen names
-against an append, and the driver reads an insertion as a change to
-what stood (PERF.md section 7.9).  The rehearsals read them through a
-copy of the manifest that has the entries, `ENTRIES` being their text,
-for the `benchmark` PR of ROADMAP.md S0b to append.
+`ENTRIES` is their manifest entries' text, with the cells they were
+written for (PR 40 appended them); the rehearsals read them through
+the manifest.
 """
 
 import json
@@ -30,6 +27,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__
 sys.path.insert(0, _REPO)
 
 from benchmarks.harness import manifest as mf  # noqa: E402
+from manifest_checks import manifest_lists  # noqa: E402
 
 RUN = os.path.join(_REPO, "benchmarks", "run.py")
 M = mf.Manifest()
@@ -86,26 +84,10 @@ KEY = dict(
        ("server_majflt_per_req", "server.majflt")])
 
 
-@pytest.fixture(scope="module")
-def manifest_with_entries(tmp_path_factory):
-    doc = json.load(open(mf.MANIFEST))
-    doc["per_layer"] += ENTRIES
-    path = tmp_path_factory.mktemp("parts") / "BENCHMARK.json"
-    path.write_text(json.dumps(doc))
-    return str(path)
-
-
-def test_the_entries_name_files_that_read_the_parts_keys(manifest_with_entries):
+def check_the_entries_name_files_that_read_the_parts_keys(m):
     assert len(NAMES) == len(set(NAMES)) == 8 + 8 + 7 + 8 + 3
-    copy = mf.Manifest(manifest_with_entries)
-    layers = {m["layer"] for m in M.doc["per_layer"]}
     for e in ENTRIES:
-        assert e["name"] not in M.per_layer, "then this copy is not needed"
-        assert e["layer"] in layers and e["moves"] in M.end_to_end
-        assert set(e["workloads"]) <= set(M.cells)
-        assert mf.NAME.match(e["name"]) and mf.UNIT.match(e["unit"])
-        spec = copy.layer_spec(e)
-        assert spec["name"] == e["name"] and callable(mf.reader(spec).read)
+        spec = manifest_lists(m, e)
         if e["name"].startswith("ckpt_freeze_"):
             assert spec["reader"] == "scrape_hist_mean"
             assert spec["keys"] == [KEY[e["name"]]]
@@ -117,11 +99,15 @@ def test_the_entries_name_files_that_read_the_parts_keys(manifest_with_entries):
                                     ["server.uptime_us"])
 
 
+def test_the_entries_name_files_that_read_the_parts_keys():
+    check_the_entries_name_files_that_read_the_parts_keys(M)
+
+
 _RUNS: dict = {}
 
 
 @pytest.fixture
-def rehearsed(request, tmp_path_factory, manifest_with_entries):
+def rehearsed(request, tmp_path_factory):
     """-> (the cell, the result line, the run's scrapes) of ONE traced
     rehearsal a cell, made when the first test asks for it."""
     cell = request.param
@@ -131,7 +117,7 @@ def rehearsed(request, tmp_path_factory, manifest_with_entries):
             [sys.executable, RUN, "--workload", cell, "--seed",
              str(2**31 + 370 + CELLS.index(cell)), "--seconds", "4",
              "--trace", "1", "--rehearsal", "--keep",
-             "--manifest", manifest_with_entries, "--run-dir", str(run_dir)],
+             "--run-dir", str(run_dir)],
             capture_output=True, text=True, timeout=900, cwd=_REPO,
             env=dict(os.environ, JAX_PLATFORMS="cpu"))
         assert proc.returncode == 3, proc.stderr[-3000:]
@@ -216,10 +202,9 @@ def test_a_rehearsal_reads_every_metric_its_cell_lists(rehearsed):
     assert value["server_majflt_per_req"] >= 0
 
 
-def test_the_freezes_files_read_a_window_with_a_checkpoint(manifest_with_entries):
+def check_the_freezes_files_read_a_window_with_a_checkpoint(m):
     """Two scrapes around one checkpoint: each of the eight files reads
     its own histogram's mean over the window, whatever stood before."""
-    copy = mf.Manifest(manifest_with_entries)
     before, after = {}, {}
     for i, part in enumerate(FREEZE):
         key = KEY[f"ckpt_freeze_{part}_us_mean"]
@@ -227,18 +212,21 @@ def test_the_freezes_files_read_a_window_with_a_checkpoint(manifest_with_entries
         after.update({key + ".count": 3, key + ".sum": 50.0 + 1000.0 * (i + 1)})
     ctx = {"before": [before], "after": [after], "at_close": [after], "requests": 9}
     for i, part in enumerate(FREEZE):
-        spec = copy.layer_spec(copy.per_layer[f"ckpt_freeze_{part}_us_mean"])
+        spec = m.layer_spec(m.per_layer[f"ckpt_freeze_{part}_us_mean"])
         assert mf.reader(spec).read(spec, ctx) == 1000.0 * (i + 1), part
     # A window without one: nothing, never 0.
     ctx = {"before": [after], "after": [after], "at_close": [after], "requests": 9}
     for part in FREEZE:
-        spec = copy.layer_spec(copy.per_layer[f"ckpt_freeze_{part}_us_mean"])
+        spec = m.layer_spec(m.per_layer[f"ckpt_freeze_{part}_us_mean"])
         assert mf.reader(spec).read(spec, ctx) is None, part
 
 
+def test_the_freezes_files_read_a_window_with_a_checkpoint():
+    check_the_freezes_files_read_a_window_with_a_checkpoint(M)
+
+
 @pytest.mark.parametrize("name", NAMES)
-def test_a_program_without_the_parts_gives_the_readers_nothing(
-        name, manifest_with_entries):
+def test_a_program_without_the_parts_gives_the_readers_nothing(name):
     """The parent's scrapes: each reader returns None and raises
     nothing, so a result line leaves the metric out."""
     old = {"vsr.commit_us.count": 5, "server.uptime_us": 10,
@@ -246,6 +234,5 @@ def test_a_program_without_the_parts_gives_the_readers_nothing(
     later = dict(old, **{"vsr.commit_us.count": 9, "server.uptime_us": 20})
     ctx = {"before": [dict(old)], "after": [dict(later)],
            "at_close": [dict(later)], "requests": 4}
-    copy = mf.Manifest(manifest_with_entries)
-    spec = copy.layer_spec(copy.per_layer[name])
+    spec = M.layer_spec(M.per_layer[name])
     assert mf.reader(spec).read(spec, ctx) is None

@@ -1,13 +1,10 @@
 """The two full-batch cells (PR 28): `bench1r-plain-c4` and
 `bench3r-plain-c4`, each run as a traced rehearsal on the CPU backend.
 
-Their five per-layer metrics have files (and two readers) and no
-manifest entry yet: `tests/benchmarks/test_stage_metrics.py` holds the
-list's last eighteen names, so an entry can neither be appended nor,
-by the contract's rule for lists, be put before them (PERF.md section
-7.9).  They are read here through a copy of the manifest that has the
-entries, `ENTRIES` being their text, from the scrapes of the served
-runs.
+Their five per-layer metrics have files (and two readers); `ENTRIES`
+is their manifest entries' text, with the cells they were written for
+(PR 40 appended them).  They are read here from the scrapes of the
+served runs.
 """
 
 import json
@@ -21,41 +18,38 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__
 sys.path.insert(0, _REPO)
 
 from benchmarks.harness import manifest as mf  # noqa: E402
+from manifest_checks import manifest_lists  # noqa: E402
 
 RUN = os.path.join(_REPO, "benchmarks", "run.py")
 ONE, THREE = "bench1r-plain-c4", "bench3r-plain-c4"
 VSR = "VSR, journal, checkpoint"
 ENTRIES = [
-    {"name": "backup_lag_ops", "unit": "ops", "source": "program_counter",
-     "layer": VSR, "moves": "request_p95_ms", "workloads": [THREE]},
-    {"name": "quorum_wait_us_per_prepare", "unit": "us", "source": "program_span",
-     "layer": VSR, "moves": "request_p50_ms", "workloads": [THREE]},
-    {"name": "replicate_send_us_per_prepare", "unit": "us", "source": "program_span",
-     "layer": VSR, "moves": "commit_events_per_s", "workloads": [THREE]},
-    {"name": "backup_accept_us_per_prepare", "unit": "us", "source": "program_span",
-     "layer": VSR, "moves": "commit_events_per_s", "workloads": [THREE]},
-    {"name": "grid_blocks_peak_pct", "unit": "%", "source": "program_counter",
-     "layer": "LSM spill and compaction", "moves": "commit_events_per_s",
-     "workloads": [ONE, THREE]},
+    {"name": "backup_lag_ops", "unit": "ops", "better": "lower",
+     "source": "program_counter", "layer": VSR, "moves": "request_p95_ms",
+     "workloads": [THREE]},
+    {"name": "quorum_wait_us_per_prepare", "unit": "us", "better": "lower",
+     "source": "program_span", "layer": VSR, "moves": "request_p50_ms",
+     "workloads": [THREE]},
+    {"name": "replicate_send_us_per_prepare", "unit": "us", "better": "lower",
+     "source": "program_span", "layer": VSR, "moves": "commit_events_per_s",
+     "workloads": [THREE]},
+    {"name": "backup_accept_us_per_prepare", "unit": "us", "better": "lower",
+     "source": "program_span", "layer": VSR, "moves": "commit_events_per_s",
+     "workloads": [THREE]},
+    {"name": "grid_blocks_peak_pct", "unit": "%", "better": "lower",
+     "source": "program_counter", "layer": "LSM spill and compaction",
+     "moves": "commit_events_per_s", "workloads": [ONE, THREE]},
 ]
 UNITS = {e["name"]: e["unit"] for e in ENTRIES}
+M = mf.Manifest()
 
 
-@pytest.fixture(scope="module")
-def manifest_with_entries(tmp_path_factory):
-    doc = json.load(open(mf.MANIFEST))
-    doc["per_layer"] += [dict(e, better="lower") for e in ENTRIES]
-    path = tmp_path_factory.mktemp("plain") / "BENCHMARK.json"
-    path.write_text(json.dumps(doc))
-    return str(path)
-
-
-def rehearse(cell: str, seed: int, manifest: str, run_dir) -> tuple[dict, dict]:
+def rehearse(cell: str, seed: int, run_dir) -> tuple[dict, dict]:
     """-> (the result line, the run's scrapes)"""
     proc = subprocess.run(
         [sys.executable, RUN, "--workload", cell, "--seed", str(seed),
          "--seconds", "4", "--trace", "1", "--rehearsal", "--keep",
-         "--manifest", manifest, "--run-dir", str(run_dir)],
+         "--run-dir", str(run_dir)],
         capture_output=True, text=True, timeout=900, cwd=_REPO,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert proc.returncode == 3, proc.stderr[-3000:]
@@ -66,19 +60,16 @@ def rehearse(cell: str, seed: int, manifest: str, run_dir) -> tuple[dict, dict]:
 
 
 @pytest.fixture(scope="module")
-def one(tmp_path_factory, manifest_with_entries):
-    return rehearse(ONE, 2**31 + 281, manifest_with_entries,
-                    tmp_path_factory.mktemp("one") / "run")
+def one(tmp_path_factory):
+    return rehearse(ONE, 2**31 + 281, tmp_path_factory.mktemp("one") / "run")
 
 
 @pytest.fixture(scope="module")
-def three(tmp_path_factory, manifest_with_entries):
-    return rehearse(THREE, 2**31 + 283, manifest_with_entries,
-                    tmp_path_factory.mktemp("three") / "run")
+def three(tmp_path_factory):
+    return rehearse(THREE, 2**31 + 283, tmp_path_factory.mktemp("three") / "run")
 
 
-def test_the_cells_are_the_manifests(manifest_with_entries):
-    m = mf.Manifest()
+def check_the_cells_are_the_manifests(m):
     for name, config, chips in ((ONE, "upstream-bench-1r", 1),
                                 (THREE, "upstream-bench-3r", 4)):
         cell = m.cell(name)
@@ -92,12 +83,13 @@ def test_the_cells_are_the_manifests(manifest_with_entries):
             assert traffic[key] == base[key], key
     three = m.config(m.cell(THREE))
     assert three["replicas"] == 3 and three["reduced"] == ["transfer_count", "replicas"]
-    # The entries the metric files wait for resolve to files and readers.
-    copy = mf.Manifest(manifest_with_entries)
+    # The entries resolve to files and readers.
     for e in ENTRIES:
-        spec = copy.layer_spec(e)
-        assert spec["name"] == e["name"] and callable(mf.reader(spec).read)
-        assert e["name"] not in m.per_layer, "then this copy is not needed"
+        manifest_lists(m, e)
+
+
+def test_the_cells_are_the_manifests():
+    check_the_cells_are_the_manifests(M)
 
 
 def test_one_replica_full_batches(one):
@@ -148,21 +140,18 @@ def test_three_replicas_full_batches(three):
 
 
 @pytest.mark.parametrize("name", sorted(UNITS))
-def test_a_program_without_the_keys_gives_the_readers_nothing(
-        manifest_with_entries, name):
+def test_a_program_without_the_keys_gives_the_readers_nothing(name):
     """The parent's scrapes: each reader returns None and raises
     nothing, so a result line leaves the metric out."""
-    m = mf.Manifest(manifest_with_entries)
     old = {"vsr.commit_us.count": 5, "storage.bytes_grid": 9}
     later = dict(old, **{"vsr.commit_us.count": 9})
     ctx = {"before": [dict(old)] * 3, "after": [dict(later)] * 3,
            "at_close": [dict(later)] * 3, "requests": 7, "trace": None}
-    spec = m.layer_spec(m.per_layer[name])
+    spec = M.layer_spec(M.per_layer[name])
     assert mf.reader(spec).read(spec, ctx) is None
 
 
-def test_the_new_readers_read_what_their_files_say(manifest_with_entries):
-    m = mf.Manifest(manifest_with_entries)
+def check_the_new_readers_read_what_their_files_say(m):
     a = {"vsr.backup.accept_us.count": 10, "vsr.backup.accept_us.sum": 100.0}
     slow = {"vsr.backup.accept_us.count": 30, "vsr.backup.accept_us.sum": 700.0}
     fast = {"vsr.backup.accept_us.count": 30, "vsr.backup.accept_us.sum": 300.0}
@@ -175,3 +164,7 @@ def test_the_new_readers_read_what_their_files_say(manifest_with_entries):
     assert got["grid_blocks_peak_pct"]["value"] == pytest.approx(12.5)
     one = mf.read_layer_metrics(m, ONE, dict(ctx, before=[a], after=[slow]))
     assert "backup_accept_us_per_prepare" not in one
+
+
+def test_the_new_readers_read_what_their_files_say():
+    check_the_new_readers_read_what_their_files_say(M)

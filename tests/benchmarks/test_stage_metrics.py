@@ -52,12 +52,21 @@ def served(tmp_path_factory):
     return line, scrape
 
 
-def test_the_stage_metrics_are_the_manifests_last_entries():
-    names = [m["name"] for m in M.doc["per_layer"]]
-    assert names[-len(STAGE_METRICS):] == STAGE_METRICS
-    for entry in M.doc["per_layer"][-len(STAGE_METRICS):]:
-        assert entry["workloads"] == [CELL]
+def check_the_stage_metrics_are_in_the_manifest(m):
+    """Wherever they stand in the list, in the cell they were written
+    for and in the others the chip's runs read them in (PR 40)."""
+    for name in STAGE_METRICS:
+        entry = m.per_layer[name]
         assert entry["source"] in ("program_span", "program_counter")
+        assert CELL in entry["workloads"] and set(entry["workloads"]) <= set(m.cells)
+        spec = m.layer_spec(entry)
+        assert spec["name"] == name and callable(mf.reader(spec).read)
+        assert spec["reader"] in ("scrape_delta_ratio", "scrape_hist_mean")
+        assert spec["keys"]
+
+
+def test_the_stage_metrics_are_in_the_manifest():
+    check_the_stage_metrics_are_in_the_manifest(M)
 
 
 @pytest.mark.parametrize("name", STAGE_METRICS)
@@ -96,7 +105,7 @@ def test_the_leaves_account_for_the_commit_and_never_pass_it(served):
     assert inside <= 1e3 * value["commit_span_ms_per_req"] * 1.001
 
 
-def test_a_program_without_the_stages_gives_the_metrics_nothing_to_read():
+def check_a_program_without_the_stages_gives_the_metrics_nothing_to_read(m):
     """The parent's scrape has none of the keys: every reader returns
     None and raises nothing, so the line leaves the metric out."""
     old = {"vsr.commit_us.count": 5, "vsr.commit_us.sum": 9.0, "vsr.commits": 5,
@@ -104,8 +113,12 @@ def test_a_program_without_the_stages_gives_the_metrics_nothing_to_read():
     ctx = {"before": [dict(old)], "after": [dict(old, **{"vsr.commits": 9})],
            "at_close": [old], "requests": 7, "trace": None}
     for name in STAGE_METRICS:
-        spec = M.layer_spec(M.per_layer[name])
+        spec = m.layer_spec(m.per_layer[name])
         assert mf.reader(spec).read(spec, ctx) is None, name
+
+
+def test_a_program_without_the_stages_gives_the_metrics_nothing_to_read():
+    check_a_program_without_the_stages_gives_the_metrics_nothing_to_read(M)
 
 
 # ----------------------------------------------------------------------

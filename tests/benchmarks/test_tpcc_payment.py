@@ -4,11 +4,8 @@ pinned to `CpuStateMachine` at a small size, with the sessions
 interleaved in an order drawn from the seed, and the same whatever the
 order; the control, which has to come out as not correct; and the cell
 `bench1r-tpcc-pay-c4` as one traced rehearsal on the CPU backend that
-reads its five per-layer metrics.  Their files stand without a manifest
-entry (`test_stage_metrics.py` holds the list's last eighteen names and
-the driver reads an insertion as a change to what stood): the rehearsal
-reads them through a copy of the manifest that has the entries,
-`ENTRIES` being their text, as `test_chains2p.py` does.
+reads its five per-layer metrics (`ENTRIES` is their manifest entries'
+text, with the cell they were written for; PR 40 appended them).
 """
 
 import json
@@ -24,6 +21,7 @@ sys.path.insert(0, _REPO)
 
 from benchmarks.harness import compare, load, manifest as mf, wire  # noqa: E402
 from benchmarks.harness.gen import tpcc_payment  # noqa: E402
+from manifest_checks import manifest_lists  # noqa: E402
 from test_reference import _HEALTHY, Oracle  # noqa: E402
 from tigerbeetle_tpu.types import Operation  # noqa: E402
 
@@ -39,7 +37,7 @@ PARAMS = {"sessions": 4, "request_events": 120, "amount_min": 100,
           "amount_max": 500000, "remote_share": 0.15, "keying_error_share": 0.05}
 SEEDS = [1, 35, 2**31 + 3535]
 ROUTING, VSR = "state machine routing", "VSR, journal, checkpoint"
-ENTRIES = [
+ENTRIES = [dict(e, workloads=[CELL]) for e in (
     {"name": "touched_rows_per_prepare", "unit": "rows", "better": "lower",
      "source": "program_counter", "layer": ROUTING, "moves": "commit_events_per_s"},
     {"name": "hot_row_legs_max_mean", "unit": "count", "better": "lower",
@@ -50,7 +48,7 @@ ENTRIES = [
      "source": "program_counter", "layer": VSR, "moves": "request_p95_ms"},
     {"name": "device_table_fill_pct", "unit": "%", "better": "higher",
      "source": "program_counter", "layer": "device", "moves": "commit_events_per_s"},
-]
+)]
 NEW_METRICS = [e["name"] for e in ENTRIES]
 UNITS = {e["name"]: e["unit"] for e in ENTRIES}
 
@@ -347,20 +345,12 @@ def test_control_lost_ack_is_not_correct(seed):
 # The cell, as one traced rehearsal.
 
 
-@pytest.fixture(scope="module")
-def manifest_with_entries(tmp_path_factory):
-    doc = json.load(open(mf.MANIFEST))
-    doc["per_layer"] += [dict(e, workloads=[CELL]) for e in ENTRIES]
-    path = tmp_path_factory.mktemp("tpcc_manifest") / "BENCHMARK.json"
-    path.write_text(json.dumps(doc))
-    return str(path)
-
-
-def test_the_cell_is_the_manifests(manifest_with_entries):
-    cell = M.cell(CELL)
+def check_the_cell_is_the_manifests(m):
+    cell = m.cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "tpcc-payment-1r", "tpcc-pay-c4", 1)
-    base = M.config(M.cell("bench1r-chains2p-c4"))
+    assert m.config(cell) == CONFIG and m.traffic(cell) == TRAFFIC
+    base = m.config(m.cell("bench1r-chains2p-c4"))
     for key in ("replicas", "chips_per_replica", "cluster", "ledger",
                 "event_bytes", "request_events_max", "launcher"):
         assert CONFIG[key] == base[key], key
@@ -377,29 +367,30 @@ def test_the_cell_is_the_manifests(manifest_with_entries):
     assert set(CONFIG["reduced_why"]) == set(CONFIG["reduced"])
     assert CONFIG["accounts"] == CONFIG["warehouses"] * (1 + CONFIG[
         "districts_per_warehouse"] * (1 + CONFIG["customers_per_district"]))
-    plain = M.traffic(M.cell("bench1r-plain-c4"))
+    plain = m.traffic(m.cell("bench1r-plain-c4"))
     for key in ("loop", "sessions", "request_events", "warm_requests_per_session",
                 "request_timeout_ms", "trace", "read_back"):
         assert TRAFFIC[key] == plain[key], key
     assert (TRAFFIC["remote_share"], TRAFFIC["keying_error_share"]) == (0.15, 0.01)
     assert (TRAFFIC["amount_min"], TRAFFIC["amount_max"]) == (100, 500_000)
     assert TRAFFIC["phase"]["cross_checkpoints_before"] == 1
-    copy = mf.Manifest(manifest_with_entries)
     for e in ENTRIES:
-        spec = copy.layer_spec(e)
-        assert spec["name"] == e["name"] and callable(mf.reader(spec).read)
+        spec = manifest_lists(m, e)
         assert spec["reader"] in ("scrape_hist_mean", "scrape_gauge_ratio")
-        assert e["name"] not in M.per_layer, "then this copy is not needed"
+
+
+def test_the_cell_is_the_manifests():
+    check_the_cell_is_the_manifests(M)
 
 
 @pytest.fixture(scope="module")
-def rehearsed(tmp_path_factory, manifest_with_entries):
+def rehearsed(tmp_path_factory):
     """-> (the result line, the run's scrapes)"""
     run_dir = tmp_path_factory.mktemp("tpcc") / "run"
     proc = subprocess.run(
         [sys.executable, RUN, "--workload", CELL, "--seed", str(2**31 + 351),
          "--seconds", "4", "--trace", "1", "--rehearsal", "--keep",
-         "--manifest", manifest_with_entries, "--run-dir", str(run_dir)],
+         "--run-dir", str(run_dir)],
         capture_output=True, text=True, timeout=900, cwd=_REPO,
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert proc.returncode == 3, proc.stderr[-3000:]
