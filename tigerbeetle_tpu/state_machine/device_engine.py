@@ -583,6 +583,12 @@ class DeviceEngine:
             for kind in _SEMANTIC_KINDS + ("waves", "spec")
         }
         self._h_linked_iters = _h("linked.iters")
+        # Past the fixpoint's cap: batches the exact pass by blocks
+        # decided on the chip, the rows they held, and the pass's
+        # rounds (a sample a batch that took it).
+        self._c_settle_batches = self.metrics.counter("linked.settle_batches")
+        self._c_settle_rows = self.metrics.counter("linked.settle_rows")
+        self._h_settle_rounds = _h("linked.settle_rounds")
         # Per-stage crossing-latency histograms, hoisted so _retry
         # pays one dict lookup per crossing (no string building; the
         # shared no-op instances when TB_METRICS=0).
@@ -1667,6 +1673,10 @@ class DeviceEngine:
         events.inc(rec.n)
         if rec.kind in ("linked", "linked_small"):
             self._h_linked_iters.observe(summary["iters"])
+            if summary["settled"]:
+                self._c_settle_batches.inc()
+                self._c_settle_rows.inc(rec.n)
+                self._h_settle_rounds.observe(summary["settle_rounds"])
 
     def _resolve_clean(self, recs) -> None:
         with self.tracer.stage(self._st_finish):

@@ -94,8 +94,18 @@ FAIL_CAP = SUMMARY_WORDS - 4   # failure entries per batch summary
 
 # Summary flag bits (word [1]).
 FLAG_OVERFLOW = 1 << 0     # balance-overflow admission failed
-FLAG_PRECOND = 1 << 2      # kernel precondition (u64-safety, fixpoint cap)
-ITERS_SHIFT = 16           # linked fixpoint iterations (diagnostics)
+FLAG_PRECOND = 1 << 2      # kernel precondition (u64-safety, a long chain)
+FLAG_SETTLED = 1 << 3      # linked: the exact pass past the cap ran
+ITERS_SHIFT = 16           # linked fixpoint iterations (16 bits)
+SETTLE_SHIFT = 32          # linked: the pass's rounds, over all its blocks
+
+# The linked fixpoint's cap: a batch that settles within it takes no
+# other path (_linked).
+FIXPOINT_CAP = 64
+# Events one block of the exact pass past the cap decides at once
+# (_settle_blocks); chosen on the v5e by a sweep of 32 to 1,024
+# (PERF.md section 6).
+SETTLE_BLOCK = 128
 
 # Packed input columns (u64 each, B rows).
 COL_BITS = 0
@@ -608,12 +618,17 @@ def _orderfree_core(table, meta, ev, n, ts_base, lo_only):
 # Linked-chain kernel (port of resolve.linked_resolve to device).
 
 
-def _linked(table, meta, pkx, small=False):
+def _linked(table, meta, pkx, small=False, block=SETTLE_BLOCK):
     """Linked-chain batch of plain posted transfers; limit-flag
     accounts allowed.  Jacobi fixpoint over per-account segmented
     prefix sums converges to the exact sequential verdicts (see
     resolve.py for the correctness argument; reference:
     src/state_machine.zig:1220-1306, src/tigerbeetle.zig:31-39).
+
+    A batch that has not settled in FIXPOINT_CAP full-width rounds is
+    decided exactly past the cap, in this same program: by blocks of
+    `block` events in order (_settle_blocks).  A batch that settles
+    within the cap never runs that branch.
 
     `small` is a trace-time specialization the host router selects
     when the batch's total amount contribution fits i32: each
@@ -633,17 +648,7 @@ def _linked(table, meta, pkx, small=False):
     start = jnp.concatenate(
         [jnp.ones(1, bool), ~linked[:-1]]
     )
-    chain_id = jnp.cumsum(start.astype(jnp.int64)) - 1
-    # Chain c starts at the c-th start event (a sort packs them to the
-    # front; B where there is no chain c) and ends where the next one
-    # starts.
-    chain_start_ev = _sort(
-        jnp.where(start, iota.astype(jnp.int32), jnp.int32(B))
-    )
-    chain_last_ev = jnp.concatenate(
-        [chain_start_ev[1:], jnp.full(1, B, jnp.int32)]
-    ) - 1
-    start_of_ev = chain_start_ev[chain_id]
+    chain_id, chain_start_ev, chain_last_ev = _chain_layout(start)
 
     # Unconditional per-event codes; chain_open overrides on the last
     # active event when it still carries the linked flag.
@@ -686,90 +691,28 @@ def _linked(table, meta, pkx, small=False):
     amt_hi2 = jnp.concatenate([ev["amt_hi"]] * 2)
     sup_valid = jnp.concatenate([static_ok, static_ok])
 
-    # ---- fixpoint over (slot, event)-sorted limit entries.
-    # Entries: 2B legs (dr side then cr side); invalid legs get
-    # sentinel keys that sort to the end.  The TPU sort's cost scales
-    # with operand count, so everything is PACKED into one u32 key —
-    # rank << 14 | event << 1 | side, 28 bits at any table size, the
-    # rank being the slot's dense rank (_touch: monotone in the slot,
-    # so the order is the order by slot) — and the per-entry
-    # columns are recovered arithmetically from the sorted keys
-    # (events are distinct within a slot because dr != cr, so the
-    # side bit never affects the required event order).
+    # ---- fixpoint over (slot, event)-sorted limit entries
+    # (_limit_entries), a Jacobi round at a time (_limit_round).
     M = 2 * B
-    entv = jnp.concatenate([ent_d, ent_c])
-    side2 = jnp.concatenate([jnp.zeros(B, jnp.uint32), jnp.ones(B, jnp.uint32)])
-    evs2 = jnp.concatenate([iota, iota]).astype(jnp.uint32)
-    sentinel = jnp.uint32(0xFFFFFFFF)
-    key = jnp.where(
-        entv,
-        (t["rank"].astype(jnp.uint32) << jnp.uint32(14))
-        | (evs2 << jnp.uint32(1)) | side2,
-        sentinel,
+    e = _limit_entries(
+        jnp.concatenate([ent_d, ent_c]), t["rank"], ev["amt_lo"],
+        jnp.concatenate([dlim, clim]), chain_start_ev[chain_id], M,
     )
-    key_s = _sort(key)
-    valid_s = key_s != sentinel
-    evs_s = jnp.where(
-        valid_s, (key_s >> jnp.uint32(1)) & jnp.uint32(B - 1), jnp.uint32(0)
-    ).astype(jnp.int32)
-    erank_s = jnp.where(
-        valid_s, key_s >> jnp.uint32(14), jnp.uint32(M)
-    ).astype(jnp.int32)
-    edeb_s = valid_s & ((key_s & jnp.uint32(1)) == 0)
-    eamt_s = ev["amt_lo"][evs_s]
-    jpos = jnp.arange(M)
-    seg_new = jnp.concatenate(
-        [jnp.ones(1, bool), erank_s[1:] != erank_s[:-1]]
-    ) & valid_s
-    seg_first = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(seg_new, jpos, 0)
-    )
-    # Chain-start boundary per entry, in the SAME packed-key encoding
-    # and dtype (side bit 0 sorts before either side of the start
-    # event).
-    bkey = jnp.where(
-        valid_s,
-        (erank_s.astype(jnp.uint32) << jnp.uint32(14))
-        | (
-            start_of_ev[jnp.clip(evs_s, 0, B - 1)].astype(jnp.uint32)
-            << jnp.uint32(1)
-        ),
-        sentinel,
-    )
-    # bpos: how many entries sort before each boundary.  The
-    # boundaries are in order themselves (within a row the chain starts
-    # rise with the events), so ONE merge sort of both lists, tagged in
-    # the low bit (a boundary before its equal entry), a running count
-    # of the entries, and a second sort that packs the boundaries'
-    # counts to the front do what 2B binary searches of 15 dependent
-    # gathers each did.
-    merged = _sort(jnp.concatenate([
-        jnp.where(valid_s, bkey << jnp.uint32(1), jnp.uint32(0xFFFFFFFE)),
-        jnp.where(valid_s, (key_s << jnp.uint32(1)) | jnp.uint32(1), sentinel),
-    ]))
-    is_entry = (merged & jnp.uint32(1)) != 0
-    bpos = _sort(
-        jnp.where(is_entry, sentinel, jnp.cumsum(is_entry.astype(jnp.uint32)))
-    )[:M].astype(jnp.int32)
+    ch = {"valid": active, "sok": static_ok,
+          "start_of": chain_start_ev[chain_id],
+          "last_of": chain_last_ev[chain_id]}
 
     # Each entry's row as the batch found it, out of the gathered
     # rows.  Precondition: a row a limit entry touches is u64-safe.
-    ent_rows = t["old"][jnp.minimum(erank_s, M - 1)]
+    ent_rows = t["old"][jnp.minimum(e["rank"], M - 1)]
     precond_bad = precond_bad | (
-        valid_s[:, None] & (
+        e["valid"][:, None] & (
             (ent_rows[:, 1::2] != 0)
             | (ent_rows[:, 0::2] >= jnp.uint64(_U64_SAFE))
         )
     ).any()
-    init_dp = ent_rows[:, 0]
-    init_dpo = ent_rows[:, 2]
-    init_cp = ent_rows[:, 4]
-    init_cpo = ent_rows[:, 6]
-    evc = jnp.clip(evs_s, 0, B - 1)
-    view_d = valid_s & edeb_s & dlim[evc]
-    view_c = valid_s & ~edeb_s & clim[evc]
-    amt_d = jnp.where(edeb_s & valid_s, eamt_s, jnp.uint64(0))
-    amt_c = jnp.where(~edeb_s & valid_s, eamt_s, jnp.uint64(0))
+    rows = {"dp": ent_rows[:, 0], "dpo": ent_rows[:, 2],
+            "cp": ent_rows[:, 4], "cpo": ent_rows[:, 6]}
 
     def chain_state(pass_):
         fails = (~pass_ & active).astype(jnp.int32)
@@ -782,67 +725,17 @@ def _linked(table, meta, pkx, small=False):
         first_fail = (fails != 0) & (F - fails == base)
         return applied_prefix, chain_ok, first_fail
 
-    def excl_prefix(v):
-        # Exact u64 inclusive cumsum.  A direct u64 cumsum lowers to a
-        # variadic (u32, u32) reduce-window that blows XLA:TPU's
-        # scoped vmem inside while_loop bodies
-        # (tests/test_tpu_compile.py compiles this for the v5e).
-        # small: the verified
-        # < 2^31 total makes one i32 cumsum exact.  General: four
-        # 16-bit-piece i32 cumsums (totals < 2^61 by the
-        # precondition; piece sums < M * 2^16 < 2^31).
-        if small:
-            return (
-                jnp.cumsum(v.astype(jnp.int32)).astype(jnp.uint64) - v
-            )
-        cs = jnp.uint64(0)
-        for k in range(4):
-            p = ((v >> jnp.uint64(16 * k)) & _MASK16).astype(jnp.int32)
-            cs = cs + (jnp.cumsum(p).astype(jnp.uint64) << jnp.uint64(16 * k))
-        return cs - v  # exclusive prefix at each position
+    prefix = _prefix_i32 if small else _prefix_u64
 
     def body(state):
-        pass_prev, _dr_fail, _cr_fail, it, _conv = state
-        applied_prefix, chain_ok, _first = chain_state(pass_prev)
-        wce = chain_ok[chain_id][evc]
-        wie = applied_prefix[evc]
-        Pdc = excl_prefix(jnp.where(wce, amt_d, jnp.uint64(0)))
-        Pcc = excl_prefix(jnp.where(wce, amt_c, jnp.uint64(0)))
-        Pdi = excl_prefix(jnp.where(wie, amt_d, jnp.uint64(0)))
-        Pci = excl_prefix(jnp.where(wie, amt_c, jnp.uint64(0)))
-
-        def seg_diff(P, at):
-            # inclusive-exclusive segmented windows: P is the exclusive
-            # prefix, so P[b] - P[a] sums entries [a, b).
-            return P[at]
-
-        deb_before = (
-            seg_diff(Pdc, bpos) - seg_diff(Pdc, seg_first)
-        ) + (Pdi[jpos] - seg_diff(Pdi, bpos))
-        cred_before = (
-            seg_diff(Pcc, bpos) - seg_diff(Pcc, seg_first)
-        ) + (Pci[jpos] - seg_diff(Pci, bpos))
-        bad_d = view_d & (
-            init_dp + init_dpo + deb_before + eamt_s
-            > init_cpo + cred_before
-        )
-        bad_c = view_c & (
-            init_cp + init_cpo + cred_before + eamt_s
-            > init_dpo + deb_before
-        )
-        dr_fail = jnp.zeros(B, bool).at[jnp.where(bad_d, evc, B)].set(
-            True, mode="drop"
-        )
-        cr_fail = jnp.zeros(B, bool).at[jnp.where(bad_c, evc, B)].set(
-            True, mode="drop"
-        )
-        pass_ = static_ok & ~dr_fail & ~cr_fail
+        pass_prev, _dr_fail, _cr_fail, it, _conv, _before = state
+        pass_, dr_fail, cr_fail = _limit_round(pass_prev, ch, e, rows, prefix)
         conv = (pass_ == pass_prev).all()
-        return pass_, dr_fail, cr_fail, it + 1, conv
+        return pass_, dr_fail, cr_fail, it + 1, conv, pass_prev
 
     def cond(state):
-        _p, _d, _c, it, conv = state
-        return (~conv) & (it < 64)
+        _p, _d, _c, it, conv, _b = state
+        return (~conv) & (it < FIXPOINT_CAP)
 
     init = (
         static_ok,
@@ -850,14 +743,33 @@ def _linked(table, meta, pkx, small=False):
         jnp.zeros(B, bool),
         jnp.int32(0),
         jnp.bool_(False),
+        static_ok,
     )
     # One unconditional iteration then loop to convergence: matches the
     # host resolver's "verdict of event 0 is unconditional" induction.
     state = body(init)
-    pass_, dr_fail, cr_fail, iters, conv = jax.lax.while_loop(
+    pass_, dr_fail, cr_fail, iters, conv, before = jax.lax.while_loop(
         cond, body, state
     )
     fix_failed = ~conv
+
+    # ---- past the cap: the exact pass by blocks (a branch that a batch
+    # settled within the cap never executes).
+    settle = fix_failed & ~precond_bad
+
+    def by_blocks(_):
+        return _settle_blocks(
+            min(block, B), prefix, ch, n, start, chain_start_ev,
+            ent_d, ent_c, t["rank"], ev["amt_lo"], dlim, clim, t["old"],
+            pass_, dr_fail, cr_fail, before,
+        )
+
+    def as_is(_):
+        return pass_, dr_fail, cr_fail, jnp.int32(0), jnp.bool_(False)
+
+    pass_, dr_fail, cr_fail, settle_rounds, long_chain = jax.lax.cond(
+        settle, by_blocks, as_is, None
+    )
 
     applied_prefix, chain_ok, is_ff = chain_state(pass_)
 
@@ -898,7 +810,10 @@ def _linked(table, meta, pkx, small=False):
         lo_only=True,
     )
     _, sup_ov = _admit(t, *sup)
-    fallback = sup_ov | precond_bad | fix_failed
+    # Past the cap the batch is decided unless the pass met a chain
+    # longer than a block (or the batch broke a precondition anyway).
+    undecided = precond_bad | long_chain
+    fallback = sup_ov | undecided
     # The applied sums are the superset's or less: they overflow only
     # where sup_ov has already flagged.
     new_rows, _ov2 = _admit(t, *ap)
@@ -907,12 +822,297 @@ def _linked(table, meta, pkx, small=False):
     last_applied = jnp.where(applied_prefix & active, iota, -1).max()
     flags_word = (
         jnp.where(sup_ov, jnp.uint64(FLAG_OVERFLOW), jnp.uint64(0))
-        | jnp.where(
-            precond_bad | fix_failed, jnp.uint64(FLAG_PRECOND), jnp.uint64(0)
-        )
+        | jnp.where(undecided, jnp.uint64(FLAG_PRECOND), jnp.uint64(0))
+        | jnp.where(settle, jnp.uint64(FLAG_SETTLED), jnp.uint64(0))
         | (iters.astype(jnp.uint64) << jnp.uint64(ITERS_SHIFT))
+        | (settle_rounds.astype(jnp.uint64) << jnp.uint64(SETTLE_SHIFT))
     )
     return (new_table, *_summary(results, active, flags_word, last_applied))
+
+
+def _chain_layout(start):
+    """(chain_id, chain_start_ev, chain_last_ev) of E events from their
+    chain-start flags: chain c starts at the c-th start event (a sort
+    packs them to the front; E where there is no chain c) and ends
+    where the next one starts."""
+    E = start.shape[0]
+    chain_id = jnp.cumsum(start.astype(jnp.int32)) - 1
+    chain_start_ev = _sort(
+        jnp.where(start, jnp.arange(E, dtype=jnp.int32), jnp.int32(E))
+    )
+    chain_last_ev = jnp.concatenate(
+        [chain_start_ev[1:], jnp.full(1, E, jnp.int32)]
+    ) - 1
+    return chain_id, chain_start_ev, chain_last_ev
+
+
+def _limit_entries(ent, rank, amt, lim, start_of, no_rank):
+    """The limit entries of E events (a batch, or a block of one),
+    sorted by (row, event).  `ent`, `rank` and `lim` are over the 2E
+    legs, debit legs first: whether the leg is an entry (its row
+    carries a limit), its row's dense rank (_touch: monotone in the
+    slot, so the order is the order by slot), and whether the limit
+    checks the leg's side; `start_of` is each event's chain's first
+    event, `no_rank` a rank no row has.
+
+    The TPU sort's cost scales with operand count, so the order is ONE
+    packed u32 key — rank << (log2 E + 1) | event << 1 | side, 28 bits
+    at E = B — and the per-entry columns are recovered arithmetically
+    from the sorted keys (events are distinct within a row because
+    dr != cr, so the side bit never affects the required event order);
+    invalid legs get sentinel keys that sort to the end.  `at` undoes
+    the sort: where each leg's entry sorted to, so that a round gathers
+    its events' verdicts."""
+    E = amt.shape[0]
+    M = 2 * E
+    sh = jnp.uint32(E.bit_length())
+    jpos = jnp.arange(M, dtype=jnp.int32)
+    iota = jnp.arange(E, dtype=jnp.uint32)
+    sentinel = jnp.uint32(0xFFFFFFFF)
+    key = jnp.where(
+        ent,
+        (rank.astype(jnp.uint32) << sh)
+        | (jnp.concatenate([iota, iota]) << jnp.uint32(1))
+        | jnp.concatenate([jnp.zeros(E, jnp.uint32), jnp.ones(E, jnp.uint32)]),
+        sentinel,
+    )
+    key_s, perm = _sort((key, jpos))
+    _, at = _sort((perm, jpos))
+    valid = key_s != sentinel
+    evs = jnp.where(
+        valid, (key_s >> jnp.uint32(1)) & jnp.uint32(E - 1), jnp.uint32(0)
+    ).astype(jnp.int32)
+    erank = jnp.where(valid, key_s >> sh, jnp.uint32(no_rank)).astype(jnp.int32)
+    deb = valid & ((key_s & jnp.uint32(1)) == 0)
+    eamt = amt[evs]
+    seg_new = jnp.concatenate(
+        [jnp.ones(1, bool), erank[1:] != erank[:-1]]
+    ) & valid
+    seg_first = jax.lax.associative_scan(
+        jnp.maximum, jnp.where(seg_new, jpos, 0)
+    )
+    # Chain-start boundary per entry, in the SAME packed-key encoding
+    # and dtype (side bit 0 sorts before either side of the start
+    # event).  bpos: how many entries sort before each boundary.  The
+    # boundaries are in order themselves (within a row the chain starts
+    # rise with the events), so ONE merge sort of both lists, tagged in
+    # the low bit (a boundary before its equal entry), a running count
+    # of the entries, and a second sort that packs the boundaries'
+    # counts to the front do what 2E binary searches did.
+    bkey = jnp.where(
+        valid,
+        (erank.astype(jnp.uint32) << sh)
+        | (start_of[evs].astype(jnp.uint32) << jnp.uint32(1)),
+        sentinel,
+    )
+    merged = _sort(jnp.concatenate([
+        jnp.where(valid, bkey << jnp.uint32(1), jnp.uint32(0xFFFFFFFE)),
+        jnp.where(valid, (key_s << jnp.uint32(1)) | jnp.uint32(1), sentinel),
+    ]))
+    is_entry = (merged & jnp.uint32(1)) != 0
+    bpos = _sort(
+        jnp.where(is_entry, sentinel, jnp.cumsum(is_entry.astype(jnp.uint32)))
+    )[:M].astype(jnp.int32)
+    view = valid & lim[perm]
+    zero = jnp.uint64(0)
+    return {
+        "valid": valid, "deb": deb, "ev": evs, "rank": erank, "amt": eamt,
+        "seg_first": seg_first, "bpos": bpos, "at": at,
+        "view_d": view & deb, "view_c": view & ~deb,
+        "amt_d": jnp.where(deb, eamt, zero),
+        "amt_c": jnp.where(valid & ~deb, eamt, zero),
+    }
+
+
+def _prefix_i32(v):
+    """Exclusive prefix sums of amounts whose total fits i32 (linked
+    `small`: the device verifies the bound), as i32: ONE cumsum, and a
+    gather of it is one 32-bit word where a u64 is two."""
+    v = v.astype(jnp.int32)
+    return jnp.cumsum(v) - v
+
+
+def _prefix_u64(v):
+    """Exact u64 exclusive prefix sums.  A direct u64 cumsum lowers to
+    a variadic (u32, u32) reduce-window that blows XLA:TPU's scoped
+    vmem inside while_loop bodies (tests/test_tpu_compile.py compiles
+    this for the v5e): four 16-bit-piece i32 cumsums instead (totals
+    < 2^61 by the precondition; piece sums < 2B * 2^16 < 2^31)."""
+    cs = jnp.uint64(0)
+    for k in range(4):
+        p = ((v >> jnp.uint64(16 * k)) & _MASK16).astype(jnp.int32)
+        cs = cs + (jnp.cumsum(p).astype(jnp.uint64) << jnp.uint64(16 * k))
+    return cs - v
+
+
+def _chain_verdicts(pass_, ch):
+    """(applied_prefix, chain_ok) a event: whether every event of its
+    chain up to it passes, and whether its whole chain does."""
+    fails = (~pass_ & ch["valid"]).astype(jnp.int32)
+    F = jnp.cumsum(fails)
+    applied_prefix = (F - (F - fails)[ch["start_of"]]) == 0
+    return applied_prefix, applied_prefix[ch["last_of"]]
+
+
+def _limit_round(pass_prev, ch, e, rows, prefix):
+    """One Jacobi round over limit entries (_limit_entries): each
+    entry's row as the verdicts `pass_prev` give the events before it
+    leave it — `rows` plus an earlier chain's legs where the whole
+    chain passes, its own chain's where every event of it so far does
+    — and the verdicts that follow.  -> (pass_, dr_fail, cr_fail)"""
+    applied_prefix, chain_ok = _chain_verdicts(pass_prev, ch)
+    # Both bits of an entry's event in one gather.
+    w = ((chain_ok.astype(jnp.int32) << 1)
+         | applied_prefix.astype(jnp.int32))[e["ev"]]
+    wce, wie = (w & 2) != 0, (w & 1) != 0
+    zero = jnp.uint64(0)
+    Pdc = prefix(jnp.where(wce, e["amt_d"], zero))
+    Pcc = prefix(jnp.where(wce, e["amt_c"], zero))
+    Pdi = prefix(jnp.where(wie, e["amt_d"], zero))
+    Pci = prefix(jnp.where(wie, e["amt_c"], zero))
+
+    def before(Pc, Pi):
+        # Exclusive prefixes: P[b] - P[a] sums entries [a, b).  The
+        # earlier chains' legs are [seg_first, bpos), the own chain's
+        # [bpos, here); exact in wrapping arithmetic.
+        return (
+            (Pc - Pi)[e["bpos"]] - Pc[e["seg_first"]] + Pi
+        ).astype(jnp.uint64)
+
+    deb_before = before(Pdc, Pdi)
+    cred_before = before(Pcc, Pci)
+    bad_d = e["view_d"] & (
+        rows["dp"] + rows["dpo"] + deb_before + e["amt"]
+        > rows["cpo"] + cred_before
+    )
+    bad_c = e["view_c"] & (
+        rows["cp"] + rows["cpo"] + cred_before + e["amt"]
+        > rows["dpo"] + deb_before
+    )
+    # An event's debit entry is where its debit leg sorted to, its
+    # credit entry where its credit leg did.
+    E = pass_prev.shape[0]
+    dr_fail = bad_d[e["at"][:E]]
+    cr_fail = bad_c[e["at"][E:]]
+    return ch["sok"] & ~dr_fail & ~cr_fail, dr_fail, cr_fail
+
+
+def _settle_blocks(W, prefix, ch, n, start, chain_start_ev, ent_d, ent_c,
+                   rank, amt, dlim, clim, old, pass_, dr_fail, cr_fail,
+                   before):
+    """The exact pass past the linked fixpoint's cap: Gauss-Seidel by
+    blocks.  The batch is taken in order, in blocks of at most W
+    events that never split a chain; each block runs the same Jacobi
+    round (_limit_round) over its own limit entries alone (arrays of
+    2W, where a full-width round is over 2B), starting from the rows
+    as the earlier blocks' applied chains left them.  A block of w
+    events settles within w + 1 rounds (event k's verdict reads only
+    earlier events', so it is final after k + 1 rounds), so no block
+    needs a cap or a split: the dependency depth is paid a block at a
+    time.
+
+    The full-width rounds already run are not wasted: every event
+    before the first whose verdict changed in the last of them is
+    final (its verdict reads only earlier events', which did not
+    change), so the pass starts at that event's chain.
+
+    Returns (pass_, dr_fail, cr_fail, rounds, long_chain): the exact
+    verdicts, the blocks' rounds summed, and whether the pass met a
+    chain longer than a block, which it leaves to the host (the
+    verdicts are then not final)."""
+    Bn = pass_.shape[0]
+    M = 2 * Bn
+    n = n.astype(jnp.int32)
+    iota = jnp.arange(Bn, dtype=jnp.int32)
+    rank_d, rank_c = rank[:Bn], rank[Bn:]
+
+    changed = (pass_ != before) & ch["valid"]
+    s0 = ch["start_of"][jnp.argmax(changed)]
+    # Each rank's applied debits and credits from the final chains.
+    _ap, chain_ok = _chain_verdicts(pass_, ch)
+    applied = chain_ok & ch["valid"] & (iota < s0)
+    dc = jnp.zeros((M, 2), jnp.uint64)
+    dc = dc.at[jnp.where(ent_d & applied, rank_d, M), 0].add(amt, mode="drop")
+    dc = dc.at[jnp.where(ent_c & applied, rank_c, M), 1].add(amt, mode="drop")
+    rows_lo = old[:, 0::2]      # dp, dpo, cp, cpo
+
+    def pad(x, fill):
+        return jnp.concatenate([x, jnp.full(W, fill, x.dtype)])
+
+    cols = {
+        "sok": pad(ch["sok"], False), "start": pad(start, True),
+        "ent_d": pad(ent_d, False), "ent_c": pad(ent_c, False),
+        "rank_d": pad(rank_d, M), "rank_c": pad(rank_c, M),
+        "amt": pad(amt, 0), "dlim": pad(dlim, False), "clim": pad(clim, False),
+    }
+    li = jnp.arange(W, dtype=jnp.int32)
+
+    def solve(s, valid, guess, dc):
+        """The block of events s + li where `valid`: -> (pass, dr_fail,
+        cr_fail, rounds, settled, the block's applied legs as (rank,
+        column, amount))."""
+        c = {k: jax.lax.dynamic_slice(v, (s,), (W,)) for k, v in cols.items()}
+        both = jnp.concatenate([valid, valid])
+        st = c["start"] | (li == 0) | ~valid
+        cid, cstart, clast = _chain_layout(st)
+        bch = {"valid": valid, "sok": c["sok"] & valid,
+               "start_of": cstart[cid], "last_of": clast[cid]}
+        e = _limit_entries(
+            jnp.concatenate([c["ent_d"], c["ent_c"]]) & both,
+            jnp.concatenate([c["rank_d"], c["rank_c"]]), c["amt"],
+            jnp.concatenate([c["dlim"], c["clim"]]), bch["start_of"], M,
+        )
+        # Each entry's row as the earlier blocks left it: the batch's
+        # row, plus what their applied chains posted.
+        erc = jnp.minimum(e["rank"], M - 1)
+        r = rows_lo[erc]
+        rows = {"dp": r[:, 0], "dpo": r[:, 1] + dc[erc, 0],
+                "cp": r[:, 2], "cpo": r[:, 3] + dc[erc, 1]}
+
+        def body(state):
+            pass_prev, _d, _c, it, _conv = state
+            p, d, cr = _limit_round(pass_prev, bch, e, rows, prefix)
+            return p, d, cr, it + 1, (p == pass_prev).all()
+
+        init = (guess & bch["sok"], jnp.zeros(W, bool), jnp.zeros(W, bool),
+                jnp.int32(0), jnp.bool_(False))
+        pass_l, dr_f, cr_f, rounds, conv = jax.lax.while_loop(
+            lambda st: ~st[4] & (st[3] <= W + 1), body, body(init)
+        )
+        _ap, chain_ok = _chain_verdicts(pass_l, bch)
+        app = e["valid"] & chain_ok[e["ev"]]
+        adds = (jnp.where(e["valid"], e["rank"], M),
+                jnp.where(e["deb"], 0, 1),
+                jnp.where(app, e["amt"], jnp.uint64(0)))
+        return pass_l, dr_f, cr_f, rounds, conv, adds
+
+    def step(state):
+        s, pass_p, dr_p, cr_p, dc, rounds, _long = state
+        # The block: whole chains from s, W events at most.
+        lim = s + W
+        k = (chain_start_ev <= lim).sum(dtype=jnp.int32)
+        e_ = jnp.where(n <= lim, n, chain_start_ev[k - 1])
+        long_chain = e_ <= s
+        e_ = jnp.maximum(e_, s)
+        valid = li < (e_ - s)
+
+        def put(x_p, v):
+            keep = jax.lax.dynamic_slice(x_p, (s,), (W,))
+            return jax.lax.dynamic_update_slice(
+                x_p, jnp.where(valid, v, keep), (s,))
+
+        guess = jax.lax.dynamic_slice(pass_p, (s,), (W,))
+        pass_l, dr_f, cr_f, r, conv, (rk, col, add) = solve(s, valid, guess, dc)
+        dc = dc.at[rk, col].add(add, mode="drop")
+        return (e_, put(pass_p, pass_l), put(dr_p, dr_f), put(cr_p, cr_f),
+                dc, rounds + r, long_chain | ~conv)
+
+    state = (s0, pad(pass_, False), pad(dr_fail, False), pad(cr_fail, False),
+             dc, jnp.int32(0), jnp.bool_(False))
+    _s, pass_p, dr_p, cr_p, _dc, rounds, long_chain = jax.lax.while_loop(
+        lambda st: (st[0] < n) & ~st[6], step, state
+    )
+    return pass_p[:Bn], dr_p[:Bn], cr_p[:Bn], rounds, long_chain
 
 
 # ---------------------------------------------------------------------------
@@ -1450,7 +1650,9 @@ def unpack_summary(row: np.ndarray, dense: np.ndarray | None = None) -> dict:
         "n_fail": n_fail,
         "overflow": bool(flags & FLAG_OVERFLOW),
         "precond": bool(flags & FLAG_PRECOND),
-        "iters": flags >> ITERS_SHIFT,
+        "iters": (flags >> ITERS_SHIFT) & 0xFFFF,
+        "settled": bool(flags & FLAG_SETTLED),
+        "settle_rounds": flags >> SETTLE_SHIFT,
         "last_applied": int(row[2]) - 1,
         "n_active": int(row[3]),
         "fail_idx": idx,
