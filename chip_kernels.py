@@ -23,21 +23,32 @@ never imports JAX), at the production bucket B = 8,192, for tables of
          seventh of the customers may not owe more than they are owed
          and owe already, so their payments fail `exceeds_credits`, the
          chains with them, and the failures outrun the summary row
-  zipf   `linked_small` alone, on the 65,536-row table only: a request
+  zipf   the two linked kinds alone, on the first table only: a request
          of `bench1r-zipf-c4` (`benchmarks/harness/gen/hub_zipf.py`,
          one ledger's 100 participants) over the positions its
          reference holds after `--zipf-after` requests; its limit
          checks need over 64 Jacobi rounds, so the exact pass by blocks
-         decides it past the cap; with `--settle-blocks` once more for
-         each block size given (a tree whose kernel takes no block size
-         runs it once)
+         decides it after the rounds; with `--settle-blocks` once more
+         for each block size given (a tree whose kernel takes no block
+         size runs it once)
+  chains the two linked kinds alone, on the first table only: the first
+         request of chains of `bench1r-chains2p-c4` (session 0, after
+         its funding and its pendings) over the positions its reference
+         holds then
 
-`--only zipf` runs the zipf batches alone.  Device time is the `XLA
+On the first table the linked batches (zipf, chains and the full
+payment batch, as `linked_small` and as `linked`) run twice where the
+tree's kernel has the gate: as served, and with the cap alone
+(`<kind>_cap`); each prints the rounds it ran (`iters`), and the last
+lines each kind's cost of a full-width round and of a block of the pass
+on the zipf batch, from which `ROUND_BLOCKS` is read.  `--only zipf`
+runs those linked batches alone.  Device time is the `XLA
 Modules` line of a profiler trace over RUNS dispatches (median), wall time the host clock around a dispatch that
-ends in `block_until_ready`.  The digest (the new table's checksum and
-the summary row) of a tree must equal the other tree's: the last line
-says whether it did.  Without a chip this is a rehearsal: run it with
-`JAX_PLATFORMS=cpu TB_DEV_B=512 --rows 4096`; its times mean nothing.
+ends in `block_until_ready`.  The digest (the new table's checksum, the
+summary row with its flags word's verdict bits alone, and the codes) of
+a tree must equal the other tree's, and with the cap alone the one the
+gate gave: the last line says whether it did.  Without a chip this is a rehearsal: run it with
+`JAX_PLATFORMS=cpu TB_DEV_B=512 --rows 16384`; its times mean nothing.
 """
 
 from __future__ import annotations
@@ -53,6 +64,7 @@ import sys
 import time
 
 RUNS = 20
+LINKED = ("linked_small", "linked")
 WAREHOUSES, DISTRICTS = 8, 10
 
 
@@ -136,42 +148,73 @@ def _batches(dk, np, seed, rows):
     return both
 
 
+def _config(name: str, cell_file: str) -> tuple[dict, dict]:
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "benchmarks", "configs", name)) as f:
+        config = json.load(f)
+    with open(os.path.join(here, "benchmarks", "traffic", cell_file)) as f:
+        return config, json.load(f)
+
+
+def _request(gen, t, positions: dict, np) -> dict:
+    """A request `t` over `positions` (dp, dpo, cp, cpo: an account's
+    debits and credits, pending and posted, by id from 1), as
+    `_linked_batch` packs it."""
+    z = {
+        "dr": t["debit_account_id_lo"].astype(np.int64) - 1,
+        "cr": t["credit_account_id_lo"].astype(np.int64) - 1,
+        "amount": t["amount_lo"].copy(), "ids": t["id_lo"].copy(),
+        "tflags": t["flags"].astype(np.uint32),
+        "flags": gen.accounts()["flags"].astype(np.uint32),
+    }
+    for col, values in positions.items():
+        z[col] = np.array(values[1:], np.uint64)
+    return z
+
+
 def zipf_request(after: int, seed: int) -> dict:
     """A `bench1r-zipf-c4` request of session 0 and the positions its
     reference holds before it (this checkout's generator: no JAX)."""
-    import json
-
     import numpy as np
 
     from benchmarks.harness.gen import hub_zipf
 
-    here = os.path.dirname(os.path.abspath(__file__))
-    config = json.load(open(os.path.join(
-        here, "benchmarks", "configs", "hub100-zipf-1r.json")))
-    traffic = json.load(open(os.path.join(
-        here, "benchmarks", "traffic", "zipf-c4.json")))
+    config, traffic = _config("hub100-zipf-1r.json", "zipf-c4.json")
     gen = hub_zipf.make(traffic, config, seed)
     ref = hub_zipf.reference(gen)
     for index in range(after):
         ref.apply(gen.request(0, index))
     t = gen.request(0, after)
-    z = {
-        "dr": t["debit_account_id_lo"].astype(np.int64) - 1,
-        "cr": t["credit_account_id_lo"].astype(np.int64) - 1,
-        "amount": t["amount_lo"].copy(), "ids": t["id_lo"].copy(),
-        "debits": np.array(ref.debits[1:], np.uint64),
-        "credits": np.array(ref.credits[1:], np.uint64),
-        "flags": gen.accounts()["flags"].astype(np.uint32),
-    }
+    none = [0] * len(ref.debits)
+    z = _request(gen, t, {"dp": none, "dpo": ref.debits, "cp": none,
+                          "cpo": ref.credits}, np)
     z["codes"] = ref.codes(t)       # (applies the request)
     return z
 
 
-def _zipf(dk, np, z, rows, blocks):
-    """{(kind, batch): (fn, table, meta, packed input)} of the zipf request."""
-    import functools
-    import inspect
+def chains_request(seed: int) -> dict:
+    """The first request of chains of `bench1r-chains2p-c4`'s session 0
+    and the positions its reference holds before it."""
+    import numpy as np
 
+    from benchmarks.harness.gen import chains2p
+
+    config, traffic = _config("chains2p-1r.json", "chains2p-c4.json")
+    gen = chains2p.make(traffic, config, seed)
+    ref = chains2p.reference(gen)
+    index = 0
+    while gen.klass(0, index) != "C":
+        ref.apply(gen.request(0, index))
+        index += 1
+    t = gen.request(0, index)
+    z = _request(gen, t, {"dp": ref.debits_pending, "dpo": ref.debits_posted,
+                          "cp": ref.credits_pending, "cpo": ref.credits_posted}, np)
+    z["codes"] = ref.codes(t)
+    return z
+
+
+def _linked_batch(dk, np, z, rows):
+    """(table, meta, packed input) of a request from `_request`."""
     n = min(len(z["dr"]), dk.B)     # a rehearsal's bucket holds less
     z = {k: v[:n] if v.shape == z["dr"].shape else v for k, v in z.items()}
     zero = np.zeros(n, np.uint64)
@@ -179,21 +222,35 @@ def _zipf(dk, np, z, rows, blocks):
     pk = dk.seal_scalars(dk.pack_base(
         n, z["ids"], zero, (z["dr"] + 1).astype(np.uint64), zero,
         (z["cr"] + 1).astype(np.uint64), zero, zero, zero, z["amount"], zero,
-        np.zeros(n, np.uint32), ones, ones * 7, np.zeros(n, np.uint32),
+        z["tflags"], ones, ones * 7, np.zeros(n, np.uint32),
         np.zeros(n, bool), z["dr"], z["cr"], np.zeros(n, bool)), n, 1 << 40)
     table = np.zeros((rows, 8), np.uint64)
-    accounts = len(z["debits"])
-    table[:accounts, 2] = z["debits"]
-    table[:accounts, 6] = z["credits"]
+    accounts = len(z["flags"])
+    for col, name in ((0, "dp"), (2, "dpo"), (4, "cp"), (6, "cpo")):
+        table[:accounts, col] = z[name]
     meta = np.zeros((rows, 2), np.uint32)
     meta[:, 1] = 1
     meta[:accounts, 0] = z["flags"]
-    out = {("linked_small", "zipf"): (dk.linked_small, table, meta, pk)}
-    if "block" in inspect.signature(dk._linked).parameters:
+    return table, meta, pk
+
+
+def _linked_kernels(dk, blocks):
+    """{name: kernel} of the linked kinds beside the served ones: each
+    with the cap alone, where the tree's kernel has the gate, and
+    `linked_small` at each of `blocks`, where it takes a block size."""
+    import functools
+    import inspect
+
+    params = inspect.signature(dk._linked).parameters
+    out = {}
+    if "gate" in params:
+        for kind in LINKED:
+            out[f"{kind}_cap"] = dk._jit_as(f"{kind}_cap", functools.partial(
+                dk._linked, small=kind == "linked_small", gate=False))
+    if "block" in params:
         for b in blocks:
-            fn = dk._jit_as(f"linked_small_b{b}", functools.partial(
-                dk._linked, small=True, block=b))
-            out[f"linked_small_b{b}", "zipf"] = (fn, table, meta, pk)
+            out[f"linked_small_b{b}"] = dk._jit_as(
+                f"linked_small_b{b}", functools.partial(dk._linked, small=True, block=b))
     return out
 
 
@@ -224,7 +281,7 @@ def _device_ms(trace_dir):
 
 
 def child(tree: str, out_path: str, seed: int, tables: list[int],
-          zipf_path: str | None = None, blocks: tuple = (),
+          requests_path: str | None = None, blocks: tuple = (),
           only: str | None = None) -> None:
     sys.path.insert(0, os.path.abspath(tree))
     import numpy as np
@@ -236,9 +293,19 @@ def child(tree: str, out_path: str, seed: int, tables: list[int],
     dev = jax.devices()[0]
     result = {
         "tree": tree, "platform": dev.platform,
-        "device_kind": dev.device_kind, "B": dk.B, "seed": seed, "cells": [],
+        "device_kind": dev.device_kind, "B": dk.B, "seed": seed,
+        "settle_block": getattr(dk, "SETTLE_BLOCK", None), "cells": [],
     }
     trace_root = os.path.splitext(out_path)[0] + "_trace"
+    requests = {}
+    if requests_path:
+        with np.load(requests_path) as f:
+            for key in f.files:
+                batch, name = key.split(".", 1)
+                requests.setdefault(batch, {})[name] = f[key]
+    # The flags word's verdict bits: the rest says which path a batch
+    # took, which the digest leaves out.
+    verdict_bits = np.uint64(dk.FLAG_OVERFLOW | dk.FLAG_PRECOND)
     for rows in tables:
         table_h = np.zeros((rows, 8), np.uint64)
         table_h[:, 0::2] = np.arange(rows, dtype=np.uint64)[:, None] + 7
@@ -251,14 +318,26 @@ def child(tree: str, out_path: str, seed: int, tables: list[int],
         limits_h[hot:, 0] = np.where(
             np.arange(hot, rows) % 7 == 3, dk.AF_DR_LIMIT, 0)
         limits = jax.device_put(limits_h)
-        todo = {} if only == "zipf" else {
+        todo = {
             (kind, batch): (getattr(dk, kind), table,
                             limits if batch == "limits" else plain, pk_h)
-            for (kind, batch), pk_h in _batches(dk, np, seed, rows).items()}
-        if zipf_path and rows == 65536:
-            z = dict(np.load(zipf_path))
-            for key, (fn, t_h, m_h, pk_h) in _zipf(dk, np, z, rows, blocks).items():
-                todo[key] = (fn, jax.device_put(t_h), jax.device_put(m_h), pk_h)
+            for (kind, batch), pk_h in _batches(dk, np, seed, rows).items()
+            if only != "zipf" or (kind, batch) == ("linked_small", "full")}
+        if requests_path and rows == tables[0]:
+            extra = _linked_kernels(dk, blocks)
+            pay = todo["linked_small", "full"][3]
+            todo["linked", "full"] = (dk.linked, table, plain, pay)
+            for name, fn in extra.items():
+                if name.endswith("_cap"):
+                    todo[name, "full"] = (fn, table, plain, pay)
+            for batch, z in requests.items():
+                t_h, m_h, pk_h = _linked_batch(dk, np, z, rows)
+                t_d, m_d = jax.device_put(t_h), jax.device_put(m_h)
+                for kind in LINKED:
+                    todo[kind, batch] = (getattr(dk, kind), t_d, m_d, pk_h)
+                for name, fn in extra.items():
+                    if batch == "zipf" or name.endswith("_cap"):
+                        todo[name, batch] = (fn, t_d, m_d, pk_h)
         for (kind, batch), (fn, tab, meta, pk_h) in todo.items():
             pk = jax.device_put(pk_h)
             t0 = time.perf_counter()
@@ -279,9 +358,11 @@ def child(tree: str, out_path: str, seed: int, tables: list[int],
             name = next((p for p in programs if kind in p), None)
             new_table, row, dense = outs
             row_h = np.asarray(row)
+            verdict_row = row_h.copy()
+            verdict_row[1] &= verdict_bits
             digest = hashlib.sha256(
                 np.asarray(dk.checksum(new_table)).tobytes()
-                + row_h.tobytes() + np.asarray(dense).tobytes()
+                + verdict_row.tobytes() + np.asarray(dense).tobytes()
             ).hexdigest()[:16]
             stats = dev.memory_stats() or {}
             result["cells"].append({
@@ -300,16 +381,18 @@ def child(tree: str, out_path: str, seed: int, tables: list[int],
             })
             print(json.dumps({k: v for k, v in result["cells"][-1].items()
                               if k != "ops_ms"}), flush=True)
-            if batch == "zipf":
+            if kind.startswith("linked"):
                 summary = dk.unpack_summary(row_h, np.asarray(dense))
                 result["cells"][-1].update(
                     iters=summary["iters"], settled=summary.get("settled"),
                     settle_rounds=summary.get("settle_rounds"),
-                    precond=summary["precond"],
-                    failed_want=int((z["codes"][:dk.B] != 0).sum()))
-                print(json.dumps({k: result["cells"][-1][k] for k in (
-                    "kind", "iters", "settled", "settle_rounds", "precond",
-                    "n_fail", "failed_want")}), flush=True)
+                    precond=summary["precond"])
+                if batch in requests:
+                    result["cells"][-1]["failed_want"] = int(
+                        (requests[batch]["codes"][:dk.B] != 0).sum())
+                print(json.dumps({k: result["cells"][-1].get(k) for k in (
+                    "kind", "batch", "iters", "settled", "settle_rounds",
+                    "precond", "n_fail", "failed_want")}), flush=True)
             del outs, new_table, row, dense, pk, tab
         del table, plain, limits, todo
     with open(out_path, "w") as f:
@@ -329,19 +412,22 @@ def main() -> int:
                     help="block sizes of the exact pass, comma-separated")
     ap.add_argument("--only", choices=("zipf",))
     ap.add_argument("--child", help=argparse.SUPPRESS)
-    ap.add_argument("--zipf", help=argparse.SUPPRESS)
+    ap.add_argument("--requests", help=argparse.SUPPRESS)
     args = ap.parse_args()
     blocks = tuple(int(b) for b in args.settle_blocks.split(",") if b)
     if args.child:
         child(args.trees[0], args.child, args.seed,
-              [int(r) for r in args.rows.split(",")], args.zipf, blocks,
+              [int(r) for r in args.rows.split(",")], args.requests, blocks,
               args.only)
         return 0
     os.makedirs(args.out, exist_ok=True)
     import numpy as np
 
-    zipf_path = os.path.join(args.out, "zipf_request.npz")
-    np.savez(zipf_path, **zipf_request(args.zipf_after, args.seed))
+    requests_path = os.path.join(args.out, "requests.npz")
+    np.savez(requests_path, **{
+        f"{batch}.{k}": v for batch, z in (
+            ("zipf", zipf_request(args.zipf_after, args.seed)),
+            ("chains", chains_request(args.seed))) for k, v in z.items()})
     results = []
     for tree in args.trees:
         label = os.path.basename(os.path.abspath(tree)).strip(".") or "tree"
@@ -349,7 +435,7 @@ def main() -> int:
         rc = subprocess.call([
             sys.executable, os.path.abspath(__file__), tree, "--child", path,
             "--out", args.out, "--seed", str(args.seed),
-            "--rows", args.rows, "--zipf", zipf_path,
+            "--rows", args.rows, "--requests", requests_path,
             "--settle-blocks", args.settle_blocks,
             *(["--only", args.only] if args.only else []),
         ])
@@ -364,22 +450,62 @@ def main() -> int:
     same = True
     keys = list(dict.fromkeys((c["kind"], c["batch"], c["table_rows"])
                               for r in results for c in r["cells"]))
+    digests: dict = {}
     for key in keys:
         cells = [c for r in results for c in r["cells"]
                  if (c["kind"], c["batch"], c["table_rows"]) == key]
-        # A tree without the pass past the cap flags the zipf batch for
-        # the host: its answer is another by design.
-        if key[1] != "zipf":
-            same &= len(cells) == len(results) and len(
-                {c["digest"] for c in cells}) == 1
+        # A kernel beside the served ones (the cap alone, a block size)
+        # is in the trees that have it; every linked kernel answers a
+        # batch as the others do.
+        linked = key[0].startswith("linked")
+        same &= (linked and key[0] not in LINKED) or len(cells) == len(results)
+        family = ("linked" if linked else key[0],) + key[1:]
+        digests.setdefault(family, set()).update(c["digest"] for c in cells)
         print(f"{key[0]:18} {key[1]:6} {key[2]:8}  " + "  ".join(
             f"{c['device_ms'] or c['wall_ms']:12.3f}" for c in cells))
+    same &= all(len(d) == 1 for d in digests.values())
+    for r in results:
+        for kind in LINKED:
+            costs = _round_costs(r, kind, np)
+            if costs:
+                print(json.dumps({"tree": r["tree"], "kind": kind, **costs}))
     print(json.dumps({
         "device": first["device_kind"], "platform": first["platform"],
         "trees": [r["tree"] for r in results], "digests_equal": same,
         "peak_bytes": [r["cells"][-1]["peak_bytes_so_far"] for r in results],
     }))
     return 0 if same else 1
+
+
+def _round_costs(result: dict, kind: str, np) -> dict | None:
+    """The zipf batch's cost of one full-width round and of a block of
+    the pass, in ms, out of three runs of `kind`: the zipf batch
+    with the gate and with the cap alone, and the full payment batch (one
+    round, no pass).  Each is a fixed part, its rounds and its pass,
+    whose cost is taken to follow the pass's rounds; the blocks are the
+    most the batch holds (the pass may start later).  -> None where the
+    tree ran no such three."""
+    c: dict = {}
+    for x in result["cells"]:
+        c.setdefault((x["kind"], x["batch"]), x)     # the first table's
+    on, cap, one = (c.get((kind, "zipf")), c.get((f"{kind}_cap", "zipf")),
+                    c.get((kind, "full")))
+    if not (on and cap and one) or cap["iters"] == on["iters"]:
+        return None
+
+    def ms(x):
+        return x["device_ms"] or x["wall_ms"]
+
+    rounds, pass_rounds = np.linalg.solve(
+        [[cap["iters"] - on["iters"], cap["settle_rounds"] - on["settle_rounds"]],
+         [on["iters"] - one["iters"], on["settle_rounds"]]],
+        [ms(cap) - ms(on), ms(on) - ms(one)])
+    blocks = -(-on["n_active"] // result["settle_block"])
+    block_ms = pass_rounds * on["settle_rounds"] / blocks
+    return {"exit_round": on["iters"], "cap_round": cap["iters"],
+            "round_ms": rounds, "pass_ms": pass_rounds * on["settle_rounds"],
+            "blocks_at_most": blocks, "block_ms_at_least": block_ms,
+            "round_blocks_at_most": rounds / block_ms}
 
 
 if __name__ == "__main__":

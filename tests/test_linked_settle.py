@@ -1,10 +1,13 @@
-"""The linked kernels past their fixpoint's cap: batches whose limit
-checks need more than FIXPOINT_CAP Jacobi rounds are decided exactly on
-the device by the pass by blocks (`device_kernels._settle_blocks`), and
-batches that settle within the cap take the path they always took.
+"""The linked kernels past their fixpoint's rounds: batches whose limit
+checks have not settled when the rounds stop, at FIXPOINT_CAP or at the
+gate that weighs them against the pass, are decided exactly on the
+device by the pass by blocks (`device_kernels._settle_blocks`), and
+batches that settle in their rounds take the path they always took.
 
-Every case is a differential against `CpuStateMachine`: the replies,
-then every account row and every transfer the batches named.
+The served cases are differentials against `CpuStateMachine`: the
+replies, then every account row and every transfer the batches named.
+The kernel's own cases hold the gate to the cap alone: the same table,
+codes and summary, but for the flags word's iterations and settle bits.
 """
 
 import functools
@@ -95,15 +98,49 @@ def _served(accounts, bodies, kernel=None, monkeypatch=None,
 def _snap(h_d) -> dict:
     snap = h_d.sm.metrics.snapshot()
     return {k: snap.get(f"dev.{k}", 0) for k in (
-        "linked.settle_batches", "linked.settle_rows",
+        "linked.settle_batches", "linked.settle_rows", "linked.gate_exits",
         "linked.settle_rounds.count", "linked.settle_rounds.sum",
         "linked.iters.count", "linked.iters.max", "fallback_batches",
         "kind.linked_small.batches", "kind.linked.batches")}
 
 
-def _blocks(block, kind="linked_small"):
-    return dk._jit_as(f"{kind}_b{block}", functools.partial(
-        dk._linked, small=kind == "linked_small", block=block))
+@functools.lru_cache(maxsize=None)
+def _blocks(block, kind="linked_small", gate=True):
+    name = f"{kind}_b{block}" + ("" if gate else "_cap")
+    return dk._jit_as(name, functools.partial(
+        dk._linked, small=kind == "linked_small", block=block, gate=gate))
+
+
+def _packed(accounts, body):
+    """The batch `body` over `accounts` (in slots 0, 1, ...) packed as
+    the engine packs it.  -> (table, meta, packed input)"""
+    a = np.frombuffer(accounts, ACCOUNT_DTYPE)
+    t = np.frombuffer(body, TRANSFER_DTYPE)
+    slot = {int(i): k for k, i in enumerate(a["id_lo"])}
+    dr = np.array([slot[int(i)] for i in t["debit_account_id_lo"]], np.int64)
+    cr = np.array([slot[int(i)] for i in t["credit_account_id_lo"]], np.int64)
+    n = len(t)
+    zero = np.zeros(n, np.uint64)
+    ones = np.ones(n, np.uint32)
+    pk = dk.seal_scalars(dk.pack_base(
+        n, t["id_lo"].copy(), zero, (dr + 1).astype(np.uint64), zero,
+        (cr + 1).astype(np.uint64), zero, zero, zero, t["amount_lo"].copy(),
+        zero, t["flags"].astype(np.uint32), ones, ones,
+        np.zeros(n, np.uint32), np.zeros(n, bool), dr, cr,
+        np.zeros(n, bool)), n, 1 << 40)
+    table = np.zeros((512, 8), np.uint64)
+    meta = np.zeros((512, 2), np.uint32)
+    meta[:, 1] = 1                  # the ledger
+    meta[:len(a), 0] = a["flags"]
+    return table, meta, pk
+
+
+def _both_ways(block, kind, table, meta, pk):
+    """-> (gate on, cap alone): each (table, summary row, codes)."""
+    import jax
+
+    return [jax.device_get(_blocks(block, kind, gate)(table, meta, pk))
+            for gate in (True, False)]
 
 
 @pytest.mark.parametrize("depth,chain_len,block,kind", [
@@ -131,7 +168,8 @@ def test_a_relay_past_the_cap_is_decided_on_the_device(
     assert s["fallback_batches"] == 0 and h_d.sm.stat_fallback_events == 0
     assert s["linked.settle_batches"] == 1
     assert s["linked.settle_rows"] == len(rows)
-    assert s["linked.iters.max"] == dk.FIXPOINT_CAP
+    assert s["linked.iters.max"] < dk.FIXPOINT_CAP
+    assert s["linked.gate_exits"] == s["linked.settle_batches"]
     assert s["linked.settle_rounds.count"] == 1
     assert s["linked.settle_rounds.sum"] > 0
     assert h_d.sm._dev.stat_semantic_events == len(rows)
@@ -145,6 +183,22 @@ def test_a_chain_longer_than_a_block_leaves_the_batch_to_the_host(monkeypatch):
     h_d, _replies = _served(accounts, [body], _blocks(32), monkeypatch)
     s = _snap(h_d)
     assert s["fallback_batches"] == 1 and s["linked.settle_batches"] == 0
+
+
+def test_a_chain_longer_than_a_block_keeps_the_gate_shut(monkeypatch):
+    """A relay of 40 among fillers with a chain of 41 events in its
+    middle, blocks of 32: the gate would hand it to a pass that leaves
+    the chain to the host, so the rounds run on and settle it on the
+    device, as the cap alone does: the same flags word."""
+    accounts, body = _relay(40, linked=range(30, 70))
+    h_d, _replies = _served(accounts, [body], _blocks(32), monkeypatch)
+    s = _snap(h_d)
+    assert s["linked.iters.max"] > 2 and s["fallback_batches"] == 0
+    assert s["linked.settle_batches"] == s["linked.gate_exits"] == 0
+    on, cap = _both_ways(32, "linked_small", *_packed(accounts, body))
+    for a, b in zip(on, cap):
+        assert (a == b).all()
+    assert dk.unpack_summary(on[1], on[2])["iters"] == s["linked.iters.max"]
 
 
 def _zipf_batches(requests: int):
@@ -167,6 +221,7 @@ def test_zipf_batches_of_the_cells_rehearsal_are_decided_on_the_device():
     assert all(len(r) for r in replies[1:])   # exceeds_credits in each
     assert s["fallback_batches"] == 0
     assert s["linked.settle_batches"] >= 1
+    assert s["linked.gate_exits"] == s["linked.settle_batches"]
     assert s["linked.settle_rows"] == s["linked.settle_batches"] * len(
         np.frombuffer(batches[1], TRANSFER_DTYPE))
 
@@ -174,30 +229,55 @@ def test_zipf_batches_of_the_cells_rehearsal_are_decided_on_the_device():
 @pytest.mark.parametrize("block", [dk.SETTLE_BLOCK, 32])
 def test_a_batch_that_settles_within_the_cap_takes_the_path_it_took(
         monkeypatch, block):
-    """The gate: a relay of 40 settles in 41 rounds.  Its summary carries
-    the iterations alone (no settle bit, no settle rounds, no
+    """A relay of 1 among 119 fillers, in chains of 3, settles in 2
+    rounds, before the gate is consulted.  Its summary carries the
+    iterations alone (no settle bit, no settle rounds, no
     precondition), the pass's counters stay at 0, and the block size,
-    which only the pass reads, changes nothing the batch returns."""
-    accounts, body = _relay(40, chain_len=3)
+    which only the pass and the gate read, changes nothing the batch
+    returns."""
+    accounts, body = _relay(1, chain_len=3, fillers=119)
     h_d, _r = _served(accounts, [body],
-                            None if block == dk.SETTLE_BLOCK else _blocks(block),
-                            monkeypatch)
+                      None if block == dk.SETTLE_BLOCK else _blocks(block),
+                      monkeypatch)
     s = _snap(h_d)
-    assert s["linked.iters.max"] == 41 and s["linked.iters.count"] == 1
+    assert s["linked.iters.max"] == 2 and s["linked.iters.count"] == 1
     assert s["linked.settle_batches"] == s["linked.settle_rows"] == 0
+    assert s["linked.gate_exits"] == 0
     assert s["linked.settle_rounds.count"] == 0 and s["fallback_batches"] == 0
 
 
+@pytest.mark.parametrize("block", [dk.SETTLE_BLOCK, 32])
+def test_a_relay_of_40_leaves_its_rounds_at_the_gate(monkeypatch, block):
+    """A relay of 40 in chains of 3 would settle in 41 rounds; after 2
+    the pass over the 117 events left is one block of 128 (four of 32),
+    which costs less than the 2 rounds run, so the gate hands it over:
+    the oracle's replies and rows, and the codes and table of the cap
+    alone."""
+    accounts, body = _relay(40, chain_len=3)
+    h_d, _r = _served(accounts, [body],
+                      None if block == dk.SETTLE_BLOCK else _blocks(block),
+                      monkeypatch)
+    s = _snap(h_d)
+    assert s["linked.iters.max"] == 2 and s["linked.iters.count"] == 1
+    assert s["linked.settle_batches"] == s["linked.gate_exits"] == 1
+    assert s["fallback_batches"] == 0
+    on, cap = _both_ways(block, "linked_small", *_packed(accounts, body))
+    assert dk.unpack_summary(on[1], on[2])["iters"] == 2
+    assert dk.unpack_summary(cap[1], cap[2])["iters"] == 41
+    assert (on[0] == cap[0]).all() and (on[2] == cap[2]).all()
+
+
 def test_the_gate_returns_what_the_cap_returned():
-    """One packed relay of 40 through the kernel at two block sizes:
+    """One packed batch of 120 that settles in 2 rounds (one limit
+    account debited, unfunded) through the kernel at two block sizes:
     the same table, summary row and codes, and the flags word holds
     the iterations alone."""
     import jax
 
     n = 120
     slots = np.arange(n, dtype=np.int64)
-    dr = np.where(slots % 3 == 0, slots // 3, 200)
-    cr = np.where(slots % 3 == 0, slots // 3 + 1, 201)
+    dr = np.where(slots == 0, 0, 200)
+    cr = np.where(slots == 0, 1, 201)
     zero = np.zeros(n, np.uint64)
     ids = np.arange(1, n + 1, dtype=np.uint64)
     pk = dk.seal_scalars(dk.pack_base(
@@ -213,6 +293,36 @@ def test_the_gate_returns_what_the_cap_returned():
     for a, b in zip(*outs):
         assert (a == b).all()
     row = dk.unpack_summary(outs[0][1], outs[0][2])
-    assert row["iters"] == 41 and not row["settled"] and not row["precond"]
-    assert int(outs[0][1][1]) == 41 << dk.ITERS_SHIFT
-    assert row["n_fail"] == 40
+    assert row["iters"] == 2 and not row["settled"] and not row["precond"]
+    assert int(outs[0][1][1]) == 2 << dk.ITERS_SHIFT
+    assert row["n_fail"] == 1
+
+
+# The flags word's bits that only say which path the batch took: the
+# settle flag, and every bit from the iterations up (the pass's rounds
+# lie above them).
+_PATH_BITS = dk.FLAG_SETTLED | -(1 << dk.ITERS_SHIFT)
+
+
+@pytest.mark.parametrize("block", [32, 128])
+@pytest.mark.parametrize("kind", ["linked_small", "linked"])
+@pytest.mark.parametrize("chain_len", [1, 3, 5])
+@pytest.mark.parametrize("depth", [3, 40, 65, 300])
+def test_the_gate_and_the_cap_alone_decide_alike(depth, chain_len, kind, block):
+    """The relay family through the kernel with the gate and with the
+    cap alone: the same table, codes, failure entries and last applied
+    event; the flags words differ in the iterations and settle bits
+    alone, and the gate never runs more rounds."""
+    accounts, body = _relay(depth, chain_len, fillers=0 if depth > 150 else 2,
+                            filler_amount=1 << 40 if kind == "linked" else 3)
+    on, cap = _both_ways(block, kind, *_packed(accounts, body))
+    assert (on[0] == cap[0]).all() and (on[2] == cap[2]).all()
+    assert on[1][0] == cap[1][0] and (on[1][2:] == cap[1][2:]).all()
+    flags_on, flags_cap = int(on[1][1]), int(cap[1][1])
+    assert (flags_on ^ flags_cap) & ~_PATH_BITS == 0
+    assert not flags_on & (dk.FLAG_PRECOND | dk.FLAG_OVERFLOW)
+    got, want = dk.unpack_summary(on[1], on[2]), dk.unpack_summary(cap[1], cap[2])
+    assert got["n_fail"] > 0
+    # The gate opens by the round the pass over the whole batch costs.
+    rows = len(np.frombuffer(body, TRANSFER_DTYPE))
+    assert got["iters"] <= min(want["iters"], max(2, -(-rows // (block * dk.ROUND_BLOCKS))))

@@ -583,12 +583,14 @@ class DeviceEngine:
             for kind in _SEMANTIC_KINDS + ("waves", "spec")
         }
         self._h_linked_iters = _h("linked.iters")
-        # Past the fixpoint's cap: batches the exact pass by blocks
-        # decided on the chip, the rows they held, and the pass's
-        # rounds (a sample a batch that took it).
+        # Unsettled by the fixpoint's rounds: batches the exact pass by
+        # blocks decided on the chip, the rows they held, and the pass's
+        # rounds (a sample a batch that took it); of those batches, the
+        # ones whose rounds stopped at the gate before the cap.
         self._c_settle_batches = self.metrics.counter("linked.settle_batches")
         self._c_settle_rows = self.metrics.counter("linked.settle_rows")
         self._h_settle_rounds = _h("linked.settle_rounds")
+        self._c_gate_exits = self.metrics.counter("linked.gate_exits")
         # Per-stage crossing-latency histograms, hoisted so _retry
         # pays one dict lookup per crossing (no string building; the
         # shared no-op instances when TB_METRICS=0).
@@ -1677,6 +1679,8 @@ class DeviceEngine:
                 self._c_settle_batches.inc()
                 self._c_settle_rows.inc(rec.n)
                 self._h_settle_rounds.observe(summary["settle_rounds"])
+                if summary["iters"] < dk.FIXPOINT_CAP:
+                    self._c_gate_exits.inc()
 
     def _resolve_clean(self, recs) -> None:
         with self.tracer.stage(self._st_finish):

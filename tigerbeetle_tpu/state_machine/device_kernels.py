@@ -95,17 +95,21 @@ FAIL_CAP = SUMMARY_WORDS - 4   # failure entries per batch summary
 # Summary flag bits (word [1]).
 FLAG_OVERFLOW = 1 << 0     # balance-overflow admission failed
 FLAG_PRECOND = 1 << 2      # kernel precondition (u64-safety, a long chain)
-FLAG_SETTLED = 1 << 3      # linked: the exact pass past the cap ran
+FLAG_SETTLED = 1 << 3      # linked: the exact pass by blocks ran
 ITERS_SHIFT = 16           # linked fixpoint iterations (16 bits)
 SETTLE_SHIFT = 32          # linked: the pass's rounds, over all its blocks
 
-# The linked fixpoint's cap: a batch that settles within it takes no
-# other path (_linked).
+# The linked fixpoint's cap: no batch runs more full-width rounds
+# (_linked).
 FIXPOINT_CAP = 64
-# Events one block of the exact pass past the cap decides at once
+# Events one block of the exact pass by blocks decides at once
 # (_settle_blocks); chosen on the v5e by a sweep of 32 to 1,024
 # (PERF.md section 6).
 SETTLE_BLOCK = 128
+# Blocks of that pass one full-width round costs, measured on the v5e
+# (PERF.md section 6): the fixpoint leaves for the pass once its rounds
+# have cost what the pass over the unsettled rest would (_linked).
+ROUND_BLOCKS = 4
 
 # Packed input columns (u64 each, B rows).
 COL_BITS = 0
@@ -618,17 +622,24 @@ def _orderfree_core(table, meta, ev, n, ts_base, lo_only):
 # Linked-chain kernel (port of resolve.linked_resolve to device).
 
 
-def _linked(table, meta, pkx, small=False, block=SETTLE_BLOCK):
+def _linked(table, meta, pkx, small=False, block=SETTLE_BLOCK, gate=True):
     """Linked-chain batch of plain posted transfers; limit-flag
     accounts allowed.  Jacobi fixpoint over per-account segmented
     prefix sums converges to the exact sequential verdicts (see
     resolve.py for the correctness argument; reference:
     src/state_machine.zig:1220-1306, src/tigerbeetle.zig:31-39).
 
-    A batch that has not settled in FIXPOINT_CAP full-width rounds is
-    decided exactly past the cap, in this same program: by blocks of
-    `block` events in order (_settle_blocks).  A batch that settles
-    within the cap never runs that branch.
+    A batch that has not settled in its full-width rounds is decided
+    exactly in this same program: by blocks of `block` events in order
+    (_settle_blocks), from the chain of the first event the last round
+    changed.  The rounds stop at FIXPOINT_CAP, or with `gate` as soon
+    as the rounds run (two at least) have cost what the pass over the
+    rest would, ROUND_BLOCKS blocks a round: the cost of a batch that
+    would settle in more rounds is then at most about twice the cheaper
+    path's.  The gate stays shut for a batch with a chain longer than a
+    block, which the pass leaves to the host.  Either way the verdicts
+    are the exact ones; only the flags word's iterations and settle
+    bits tell the paths apart.  `gate=False` is the cap alone.
 
     `small` is a trace-time specialization the host router selects
     when the batch's total amount contribution fits i32: each
@@ -726,16 +737,28 @@ def _linked(table, meta, pkx, small=False, block=SETTLE_BLOCK):
         return applied_prefix, chain_ok, first_fail
 
     prefix = _prefix_i32 if small else _prefix_u64
+    W = min(block, B)
+    n32 = n.astype(jnp.int32)
+    # The gate opens only where the pass decides every chain itself.
+    longest = jnp.where(
+        active, jnp.minimum(ch["last_of"], n32 - 1) - ch["start_of"] + 1, 0
+    ).max()
+    gate_ok = gate & (longest <= W)
 
     def body(state):
-        pass_prev, _dr_fail, _cr_fail, it, _conv, _before = state
+        pass_prev, _dr_fail, _cr_fail, it, _conv, _s = state
         pass_, dr_fail, cr_fail = _limit_round(pass_prev, ch, e, rows, prefix)
-        conv = (pass_ == pass_prev).all()
-        return pass_, dr_fail, cr_fail, it + 1, conv, pass_prev
+        changed = (pass_ != pass_prev) & active
+        # Every event before the first that changed is final (its
+        # verdict reads only earlier events'): s is its chain's start.
+        s = ch["start_of"][jnp.argmax(changed)]
+        return pass_, dr_fail, cr_fail, it + 1, ~changed.any(), s
 
     def cond(state):
-        _p, _d, _c, it, conv, _b = state
-        return (~conv) & (it < FIXPOINT_CAP)
+        _p, _d, _c, it, conv, s = state
+        pass_blocks = (n32 - s + W - 1) // W
+        gated = gate_ok & (it >= 2) & (it * ROUND_BLOCKS >= pass_blocks)
+        return (~conv) & (it < FIXPOINT_CAP) & ~gated
 
     init = (
         static_ok,
@@ -743,25 +766,24 @@ def _linked(table, meta, pkx, small=False, block=SETTLE_BLOCK):
         jnp.zeros(B, bool),
         jnp.int32(0),
         jnp.bool_(False),
-        static_ok,
+        jnp.int32(0),
     )
     # One unconditional iteration then loop to convergence: matches the
     # host resolver's "verdict of event 0 is unconditional" induction.
     state = body(init)
-    pass_, dr_fail, cr_fail, iters, conv, before = jax.lax.while_loop(
+    pass_, dr_fail, cr_fail, iters, conv, s0 = jax.lax.while_loop(
         cond, body, state
     )
-    fix_failed = ~conv
 
-    # ---- past the cap: the exact pass by blocks (a branch that a batch
-    # settled within the cap never executes).
-    settle = fix_failed & ~precond_bad
+    # ---- unsettled: the exact pass by blocks (a branch that a batch
+    # settled in the rounds never executes).
+    settle = ~conv & ~precond_bad
 
     def by_blocks(_):
         return _settle_blocks(
-            min(block, B), prefix, ch, n, start, chain_start_ev,
+            W, prefix, ch, n32, start, chain_start_ev,
             ent_d, ent_c, t["rank"], ev["amt_lo"], dlim, clim, t["old"],
-            pass_, dr_fail, cr_fail, before,
+            pass_, dr_fail, cr_fail, s0,
         )
 
     def as_is(_):
@@ -810,8 +832,8 @@ def _linked(table, meta, pkx, small=False, block=SETTLE_BLOCK):
         lo_only=True,
     )
     _, sup_ov = _admit(t, *sup)
-    # Past the cap the batch is decided unless the pass met a chain
-    # longer than a block (or the batch broke a precondition anyway).
+    # The pass decides the batch unless it met a chain longer than a
+    # block (or the batch broke a precondition anyway).
     undecided = precond_bad | long_chain
     fallback = sup_ov | undecided
     # The applied sums are the superset's or less: they overflow only
@@ -998,9 +1020,8 @@ def _limit_round(pass_prev, ch, e, rows, prefix):
 
 
 def _settle_blocks(W, prefix, ch, n, start, chain_start_ev, ent_d, ent_c,
-                   rank, amt, dlim, clim, old, pass_, dr_fail, cr_fail,
-                   before):
-    """The exact pass past the linked fixpoint's cap: Gauss-Seidel by
+                   rank, amt, dlim, clim, old, pass_, dr_fail, cr_fail, s0):
+    """The exact pass after the linked fixpoint's rounds: Gauss-Seidel by
     blocks.  The batch is taken in order, in blocks of at most W
     events that never split a chain; each block runs the same Jacobi
     round (_limit_round) over its own limit entries alone (arrays of
@@ -1014,7 +1035,7 @@ def _settle_blocks(W, prefix, ch, n, start, chain_start_ev, ent_d, ent_c,
     The full-width rounds already run are not wasted: every event
     before the first whose verdict changed in the last of them is
     final (its verdict reads only earlier events', which did not
-    change), so the pass starts at that event's chain.
+    change), so the pass starts at that event's chain, `s0`.
 
     Returns (pass_, dr_fail, cr_fail, rounds, long_chain): the exact
     verdicts, the blocks' rounds summed, and whether the pass met a
@@ -1022,12 +1043,9 @@ def _settle_blocks(W, prefix, ch, n, start, chain_start_ev, ent_d, ent_c,
     verdicts are then not final)."""
     Bn = pass_.shape[0]
     M = 2 * Bn
-    n = n.astype(jnp.int32)
     iota = jnp.arange(Bn, dtype=jnp.int32)
     rank_d, rank_c = rank[:Bn], rank[Bn:]
 
-    changed = (pass_ != before) & ch["valid"]
-    s0 = ch["start_of"][jnp.argmax(changed)]
     # Each rank's applied debits and credits from the final chains.
     _ap, chain_ok = _chain_verdicts(pass_, ch)
     applied = chain_ok & ch["valid"] & (iota < s0)
