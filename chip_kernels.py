@@ -42,7 +42,20 @@ tree's kernel has the gate: as served, and with the cap alone
 (`<kind>_cap`); each prints the rounds it ran (`iters`), and the last
 lines each kind's cost of a full-width round and of a block of the pass
 on the zipf batch, from which `ROUND_BLOCKS` is read.  `--only zipf`
-runs those linked batches alone.  Device time is the `XLA
+runs those linked batches alone.
+
+`--only mixed` runs no lone kernel: it drives each tree's device-engine
+state machine (a table of 65,536 rows) with `bench1r-mixed-c16`'s
+traffic (`benchmarks/harness/gen/chains2p.py`, 16 sessions), its
+requests coalesced seven to a prepare in session order, as the primary
+packs them: MIXED_WARM prepares, then MIXED_PREPARES more whose
+commits are timed one by one (host clock around the commit and its
+reply; the device programs each ran, in order, from a profiler trace
+of that commit alone).  A tree with the cut shows a prepare's runs,
+one program each beside the digest's update; one without it, the path
+the same prepare took there (a wave plan, or the drained host path).
+The digest (every reply, the account table, the measured prepares'
+stored transfers) must be the other tree's.  Device time is the `XLA
 Modules` line of a profiler trace over RUNS dispatches (median), wall time the host clock around a dispatch that
 ends in `block_until_ready`.  The digest (the new table's checksum, the
 summary row with its flags word's verdict bits alone, and the codes) of
@@ -66,6 +79,8 @@ import time
 RUNS = 20
 LINKED = ("linked_small", "linked")
 WAREHOUSES, DISTRICTS = 8, 10
+MIXED_WARM, MIXED_PREPARES, MIXED_PER_PREPARE = 12, 6, 7
+REQUEST_MAX = 8190      # the most events or ids a request carries
 
 
 def _payments(rng, np, n_pay, rows):
@@ -399,6 +414,116 @@ def child(tree: str, out_path: str, seed: int, tables: list[int],
         json.dump(result, f, indent=1)
 
 
+def mixed_prepares(seed: int, events_max: int):
+    """(gen, [[(session, index), ...] a prepare]): `bench1r-mixed-c16`'s
+    requests, every session's in its own order, seven to a prepare as
+    the primary coalesces them (fewer where the bucket holds fewer:
+    a rehearsal takes the traffic's rehearsal sizes)."""
+    from benchmarks.harness.gen import chains2p
+
+    config, traffic = _config("switch16-mixed-1r.json", "mixed-c16.json")
+    if events_max < traffic["request_events"] * MIXED_PER_PREPARE:
+        config = {**config, **config["rehearsal"]}
+        traffic = {**traffic, **traffic["rehearsal"]}
+    gen = chains2p.make(traffic, config, seed)
+    per = max(1, min(MIXED_PER_PREPARE, events_max // gen.n))
+    order = [(s, i) for i in range(MIXED_WARM + MIXED_PREPARES)
+             for s in range(gen.sessions)]
+    prepares = [order[at:at + per] for at in range(0, len(order), per)]
+    return gen, prepares[:MIXED_WARM + MIXED_PREPARES]
+
+
+def mixed_child(tree: str, out_path: str, seed: int) -> None:
+    sys.path.insert(0, os.path.abspath(tree))
+    import numpy as np
+
+    import jax
+
+    from tigerbeetle_tpu.state_machine import device_kernels as dk
+    from tigerbeetle_tpu.state_machine.tpu import TpuStateMachine
+    from tigerbeetle_tpu.testing import harness as hz
+    from tigerbeetle_tpu.types import Operation
+
+    dev = jax.devices()[0]
+    gen, prepares = mixed_prepares(seed, dk.B)
+    sm = TpuStateMachine(engine="device", account_capacity=1 << 16)
+    h = hz.SingleNodeHarness(sm)
+    digest = hashlib.sha256()
+    accounts = gen.accounts()
+    for at in range(0, len(accounts), REQUEST_MAX):
+        digest.update(h.submit(Operation.create_accounts,
+                               accounts[at:at + REQUEST_MAX].tobytes()))
+    trace_root = os.path.splitext(out_path)[0] + "_trace"
+    result = {"tree": tree, "platform": dev.platform,
+              "device_kind": dev.device_kind, "B": dk.B, "seed": seed,
+              "prepares": []}
+    for at, prepare in enumerate(prepares):
+        batch = np.concatenate([gen.request(s, i) for s, i in prepare])
+        body = batch.tobytes()
+        timed = at >= MIXED_WARM
+        if timed:
+            trace_dir = os.path.join(trace_root, str(at))
+            jax.profiler.start_trace(trace_dir)
+        t0 = time.perf_counter()
+        reply = h.submit(Operation.create_transfers, body)
+        wall_ms = (time.perf_counter() - t0) * 1e3
+        digest.update(reply)
+        if not timed:
+            continue
+        jax.profiler.stop_trace()
+        programs, _ops = _device_ms(trace_dir)
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        ran = sorted(((name, ms) for name, times in programs.items()
+                      for ms in times), key=lambda x: x[0])
+        stored = h.submit(Operation.lookup_transfers,
+                          hz.ids_bytes(batch["id_lo"].tolist()))
+        digest.update(stored)
+        result["prepares"].append({
+            "requests": [gen.klass(s, i) for s, i in prepare],
+            "events": len(batch), "wall_ms": wall_ms,
+            "programs": ran, "device_ms": sum(ms for _n, ms in ran),
+            "failures": len(reply) // 8,
+        })
+        print(json.dumps(result["prepares"][-1]), flush=True)
+    ids = list(range(1, gen.n_accounts + 1))
+    for at in range(0, len(ids), REQUEST_MAX):
+        digest.update(h.submit(Operation.lookup_accounts,
+                               hz.ids_bytes(ids[at:at + REQUEST_MAX])))
+    snap = sm.metrics.snapshot()
+    result["counters"] = {k: snap[k] for k in (
+        "plan.runs", "plan.cut_prepares", "host_semantic_events",
+        "dev_wave.batches") if k in snap}
+    result["digest"] = digest.hexdigest()[:16]
+    with open(out_path, "w") as f:
+        json.dump(result, f, indent=1)
+
+
+def main_mixed(args) -> int:
+    results = []
+    for tree in args.trees:
+        label = os.path.basename(os.path.abspath(tree)).strip(".") or "tree"
+        path = os.path.join(args.out, f"{label}.json")
+        rc = subprocess.call([
+            sys.executable, os.path.abspath(__file__), tree, "--child", path,
+            "--out", args.out, "--seed", str(args.seed), "--only", "mixed"])
+        if rc != 0:
+            print(f"{tree}: exit {rc}", file=sys.stderr)
+            return rc
+        with open(path) as f:
+            results.append(json.load(f))
+    for r in results:
+        walls = [p["wall_ms"] for p in r["prepares"]]
+        busy = [p["device_ms"] for p in r["prepares"]]
+        print(json.dumps({"tree": r["tree"], "wall_ms_median": statistics.median(walls),
+                          "device_ms_median": statistics.median(busy),
+                          "counters": r["counters"]}))
+    same = len({r["digest"] for r in results}) == 1
+    print(json.dumps({
+        "device": results[0]["device_kind"], "platform": results[0]["platform"],
+        "trees": [r["tree"] for r in results], "digests_equal": same}))
+    return 0 if same else 1
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trees", nargs="*", default=["."])
@@ -410,17 +535,22 @@ def main() -> int:
                     help="requests of the zipf batch's session before it")
     ap.add_argument("--settle-blocks", default="",
                     help="block sizes of the exact pass, comma-separated")
-    ap.add_argument("--only", choices=("zipf",))
+    ap.add_argument("--only", choices=("zipf", "mixed"))
     ap.add_argument("--child", help=argparse.SUPPRESS)
     ap.add_argument("--requests", help=argparse.SUPPRESS)
     args = ap.parse_args()
     blocks = tuple(int(b) for b in args.settle_blocks.split(",") if b)
+    if args.child and args.only == "mixed":
+        mixed_child(args.trees[0], args.child, args.seed)
+        return 0
     if args.child:
         child(args.trees[0], args.child, args.seed,
               [int(r) for r in args.rows.split(",")], args.requests, blocks,
               args.only)
         return 0
     os.makedirs(args.out, exist_ok=True)
+    if args.only == "mixed":
+        return main_mixed(args)
     import numpy as np
 
     requests_path = os.path.join(args.out, "requests.npz")

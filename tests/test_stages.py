@@ -547,7 +547,7 @@ LEAVES = {
 # the commit's beat, on the beat worker and in a checkpoint's freeze).
 PLAN_PARTS = {"sm.plan." + p for p in (
     "decode", "ids", "id_dir", "accounts", "route", "pending", "pack",
-    "join_cold")}
+    "join_cold", "cut")}
 FINISH_PARTS = {"sm.finish." + p for p in (
     "codes", "mirror", "twin", "store", "ids", "native_ids", "status", "reply")}
 LSM_PARTS = {"sm.spill.take", "sm.spill.objects", "sm.spill.index",
@@ -562,7 +562,8 @@ PARTS = PLAN_PARTS | FINISH_PARTS | LSM_PARTS | FREEZE_PARTS
 # (obs/process.py) on a server's registry.
 PART_COUNTERS = {"lsm.seal.bytes", "lsm.compact.blocks_read",
                  "lsm.compact.blocks_written",
-                 "sm.ids.runs_filed", "sm.ids.hashed", "sm.ids.runs"}
+                 "sm.ids.runs_filed", "sm.ids.hashed", "sm.ids.runs",
+                 "sm.plan.runs", "sm.plan.cut_prepares"}
 PROCESS_KEYS = {
     "server.gc.pause_us.count", "server.gc.pause_us.sum",
     "server.gc.pause_us.max", "server.gc.collections.gen0",
@@ -758,11 +759,13 @@ def test_on_the_plain_served_path_leaves_tile_the_loop_and_fill_the_commit(
     assert BEAT_KEYS | COMPACT_KEYS | POSTED_KEYS <= set(snap)
     # Every part, counter and gauge of PR 37 is on the scrape, opened
     # or not; what this path opens are the plan's parts of a plain
-    # batch and the finish's, once a prepare.
+    # batch and the finish's, once a prepare (a plain batch is one
+    # kernel's whole: no cut).
     assert {p + "_us.count" for p in PARTS} | PART_COUNTERS | PROCESS_KEYS <= set(snap)
     opened = {p for p in PARTS if snap[p + "_us.count"]}
-    assert opened == (PLAN_PARTS - {"sm.plan.pending", "sm.plan.join_cold"}
-                      ) | FINISH_PARTS
+    assert opened == (PLAN_PARTS - {"sm.plan.pending", "sm.plan.join_cold",
+                                    "sm.plan.cut"}) | FINISH_PARTS
+    assert snap["sm.plan.runs"] == 12 and snap["sm.plan.cut_prepares"] == 0
     for p in opened:
         assert snap[p + "_us.count"] == 12, p
     for leaf, parts in (("sm.plan", PLAN_PARTS), ("sm.dev.finish", FINISH_PARTS)):

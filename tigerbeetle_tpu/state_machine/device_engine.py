@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from tigerbeetle_tpu import envcheck
 from tigerbeetle_tpu.obs import stat_property as obs_stat_property
 from tigerbeetle_tpu.state_machine import device_kernels as dk
-from tigerbeetle_tpu.types import EngineState
+from tigerbeetle_tpu.types import CREATE_RESULT_DTYPE, EngineState
 from tigerbeetle_tpu.utils import tracer as tracer_mod
 
 _WINDOW = envcheck.env_int("TB_DEV_WINDOW", 96, minimum=1)
@@ -289,6 +289,31 @@ class ReplyFuture:
                 "drain", "reply never materialized and no host replay ran"
             )
         return self._value
+
+
+class PrepareReply:
+    """The reply of a prepare whose batch the router cut into runs
+    (`tpu.py _plan_runs`), each submitted as a batch of its own: the
+    runs' futures with their offsets in the batch, in order.  Done
+    when every run's is; the reply is theirs in order, each failure's
+    index moved on by its run's offset: the whole batch's reply, byte
+    for byte."""
+
+    __slots__ = ("_runs",)
+
+    def __init__(self, runs: list) -> None:
+        self._runs = runs   # [(offset, ReplyFuture)]
+
+    def done(self) -> bool:
+        return all(fut.done() for _at, fut in self._runs)
+
+    def result(self) -> bytes:
+        out = []
+        for at, fut in self._runs:
+            reply = np.frombuffer(fut.result(), CREATE_RESULT_DTYPE).copy()
+            reply["index"] += np.uint32(at)
+            out.append(reply.tobytes())
+        return b"".join(out)
 
 
 class _InFlight:
@@ -1575,12 +1600,14 @@ class DeviceEngine:
     # ------------------------------------------------------------------
     # Hazard probe: does any probe id match an in-flight batch's ids?
 
-    def inflight_ids_hit(self, keys: np.ndarray) -> bool:
-        """keys: u128-packed (V16) id probes, any order."""
+    def inflight_ids_hit(self, keys: np.ndarray, is_sorted: bool = False) -> bool:
+        """keys: u128-packed (V16) id probes, any order (`is_sorted`:
+        ascending already)."""
         stream = self._launched + self._pending
         if not stream or len(keys) == 0:
             return False
-        keys = np.sort(keys)
+        if not is_sorted:
+            keys = np.sort(keys)
         # V16 keys order numerically by their bytes; scalar compares go
         # through .tobytes() (numpy void scalars lack ufunc ordering).
         lo = keys[0].tobytes()

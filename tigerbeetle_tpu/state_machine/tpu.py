@@ -116,6 +116,81 @@ def _amount_bound_total(amount_lo: np.ndarray, amount_hi: np.ndarray) -> int:
     )
 
 
+# What a batch holds, as the router reads it: a bit for each of the
+# predicates that send a batch to a kernel, or keep it from one.
+(_HAS_LINKED, _HAS_PENDING, _HAS_PV, _TOUCH_LIMH, _TOUCH_HIST, _AMOUNT_HI,
+ _TIMEOUT) = (1 << i for i in range(7))
+# What each kernel cannot take (two_phase needs a post or a void too).
+_ORDERFREE_NOT = _HAS_LINKED | _HAS_PV | _TOUCH_LIMH
+_LINKED_NOT = _HAS_PENDING | _HAS_PV | _TOUCH_HIST | _AMOUNT_HI
+_TWO_PHASE_NOT = _HAS_LINKED | _TIMEOUT | _TOUCH_LIMH
+
+
+def _kernel_class(bits: int) -> str | None:
+    """The kernel a batch whose events hold `bits` goes to: orderfree
+    takes plain transfers and pendings; linked, chains and plain
+    transfers on accounts under a limit; two_phase, posts and voids
+    with no chain.  None: no kernel takes the batch whole."""
+    if not bits & _ORDERFREE_NOT:
+        return "orderfree"
+    if not bits & _LINKED_NOT:
+        return "linked"
+    if bits & _HAS_PV and not bits & _TWO_PHASE_NOT:
+        return "two_phase"
+    return None
+
+
+def _cut_runs(flags16, acct_flags, amount_hi, timeout):
+    """A batch that no kernel takes whole, cut into runs that one
+    kernel each takes: -> [(start, end, kernel)], the runs in order,
+    or None where some chain is taken by no kernel even alone.
+
+    A cut falls only after an event whose `linked` flag is clear, so no
+    chain is split, and an open chain at the batch's end stays in the
+    last run.  What a kernel cannot take only accumulates as a run
+    grows: the first chain a kernel cannot take ends its reach, and a
+    run, formed greedily from the front, ends where the longest reach
+    of the three ends (the fewest runs).  A run without a post or a
+    void that two_phase reaches, orderfree reaches as far."""
+    u8 = np.uint8
+    linked = (flags16 & np.uint16(TF.linked)) != 0
+    pv = np.uint16(TF.post_pending_transfer | TF.void_pending_transfer)
+    limh = np.uint32(
+        AF.debits_must_not_exceed_credits
+        | AF.credits_must_not_exceed_debits
+        | AF.history
+    )
+    ev = (
+        linked.view(u8) * u8(_HAS_LINKED)
+        | ((flags16 & np.uint16(TF.pending)) != 0).view(u8) * u8(_HAS_PENDING)
+        | ((flags16 & pv) != 0).view(u8) * u8(_HAS_PV)
+        | ((acct_flags & limh) != 0).view(u8) * u8(_TOUCH_LIMH)
+        | ((acct_flags & np.uint32(AF.history)) != 0).view(u8) * u8(_TOUCH_HIST)
+        | (amount_hi != 0).view(u8) * u8(_AMOUNT_HI)
+        | (timeout != 0).view(u8) * u8(_TIMEOUT)
+    )
+    n = len(ev)
+    ends = np.flatnonzero(~linked)
+    if len(ends) == 0 or ends[-1] != n - 1:
+        ends = np.append(ends, n - 1)
+    starts = np.concatenate(([0], ends[:-1] + 1))
+    chain = np.bitwise_or.reduceat(ev, starts)
+    m = len(chain)
+    # The chains each kernel cannot take, and a sentinel past the last.
+    stops = [np.append(np.flatnonzero(chain & u8(cannot)), m)
+             for cannot in (_ORDERFREE_NOT, _LINKED_NOT, _TWO_PHASE_NOT)]
+    runs = []
+    i = 0
+    while i < m:
+        j = max(int(stop[np.searchsorted(stop, i)]) for stop in stops)
+        if j == i:
+            return None
+        kernel = _kernel_class(int(np.bitwise_or.reduce(chain[i:j])))
+        runs.append((int(starts[i]), int(ends[j - 1]) + 1, kernel))
+        i = j
+    return runs
+
+
 def _zeros_touched(shape, dtype) -> np.ndarray:
     """Zeroed array with pages faulted in up front: appends write into
     fresh pages, and eager sequential touching is ~4x cheaper than
@@ -457,6 +532,9 @@ class TpuStateMachine:
         self._st_plan_pending = part("sm.plan.pending")
         self._st_plan_pack = part("sm.plan.pack")
         self._st_join_cold = part("sm.plan.join_cold")
+        # A batch that no kernel takes whole: its events classified and
+        # the cuts found that split it into runs a kernel each takes.
+        self._st_plan_cut = part("sm.plan.cut")
         # Of sm.dev.finish, the closures the engine resolves a batch
         # with: result codes and masks; the mirror's adds, and inside
         # them the commitment twin's re-hash of the rows they touched;
@@ -492,6 +570,10 @@ class TpuStateMachine:
         self._c_ids_runs_filed = _c("ids.runs_filed")
         self._c_ids_hashed = _c("ids.hashed")
         self._g_id_runs = self.metrics.gauge("ids.runs")
+        # The device runs a create_transfers prepare was committed as (1
+        # where a kernel took it whole), and the prepares that were cut.
+        self._c_plan_runs = _c("plan.runs")
+        self._c_cut_prepares = _c("plan.cut_prepares")
         # Account rows in use (of the capacity `--cache-accounts` gives
         # the directory, the mirror and the device table), set wherever
         # the count moves: a pull gauge would change the snapshot under
@@ -1515,32 +1597,11 @@ class TpuStateMachine:
         arguments bound, or the host path.  `part`: the plan's open
         run, moved on from part to part as the batch goes."""
         from tigerbeetle_tpu.state_machine import device_kernels as dk
-        from tigerbeetle_tpu.state_machine.device_engine import ReplyFuture
 
         events = np.frombuffer(input_bytes, dtype=TRANSFER_DTYPE)
         n = len(events)
         ts_base = timestamp - n + 1
-
-        def host_path() -> ReplyFuture:
-            # Batches the semantic kernels cannot express first try
-            # WAVE DISPATCH inside the device window (TB_DEV_WAVES):
-            # the wave plan executes against the authoritative HBM
-            # table instead of draining the stream to the host mirror.
-            # On decline the decode/ladder work is handed to the host
-            # path (it is drain-stale-proof: wire bytes + the
-            # synchronously-maintained account attrs only), so a
-            # persistently declining deployment does not pay it twice.
-            fut, decoded = self._try_submit_device_waves(
-                events, n, timestamp, input_bytes
-            )
-            if fut is not None:
-                return fut
-            self._engine_drain()
-            return ReplyFuture(
-                value=self._commit_create_transfers(
-                    timestamp, input_bytes, decoded=decoded
-                )
-            )
+        host_path = self._host_path(events, timestamp, input_bytes)
 
         # A degraded engine serves every batch through the exact host
         # path (bit-identical replies) until commit_async's lifecycle
@@ -1601,6 +1662,7 @@ class TpuStateMachine:
         # of reading a stale status join (double-finalize hazard).
         keys = pack_u128(id_lo, id_hi)
         probe = keys
+        ref = None
         if has_pv:
             # Only real references: pending_id == 0 means "no
             # reference" and must not alias across batches.
@@ -1608,7 +1670,13 @@ class TpuStateMachine:
             phi = np.asarray(events["pending_id_hi"])
             ref = (plo != 0) | (phi != 0)
             probe = np.concatenate([probe, pack_u128(plo[ref], phi[ref])])
-        keys_sorted = np.sort(probe) if (has_pv or not ascending) else keys
+        # (The order, not just the sorted keys: a cut batch picks each
+        # run's keys out of it.)
+        order = None
+        keys_sorted = keys
+        if has_pv or not ascending:
+            order = np.argsort(probe)
+            keys_sorted = probe[order]
         if self._dev.inflight_ids_hit(probe):
             self._engine_drain()
 
@@ -1653,25 +1721,139 @@ class TpuStateMachine:
             input_bytes=input_bytes, part=part,
         )
 
+        kernel = _kernel_class(
+            _HAS_LINKED * has_linked | _HAS_PENDING * has_pending
+            | _HAS_PV * has_pv | _TOUCH_LIMH * touch_limit_hist
+            | _TOUCH_HIST * touch_hist | _AMOUNT_HI * bool(amount_hi.any())
+            | _TIMEOUT * bool(timeout.any())
+        )
+        if kernel is None:
+            # Mixed classes (a prepare that coalesced requests of
+            # several): cut into runs, one kernel each.
+            part.switch(self._st_plan_cut)
+            runs = _cut_runs(flags16, dr_flags | cr_flags, amount_hi, timeout)
+            if runs is None:
+                return host_path
+            return self._plan_runs(runs, common, order, ref, host_path)
         # Each packer returns the engine's submit, bound, or None when
         # the batch cannot run on device — under tiering, a
         # touched-account set the hot window cannot hold (tier_prefetch
         # declined) — and the exact host path takes over.
-        submit = None
-        if not (has_linked or has_pv) and not touch_limit_hist:
-            submit = self._submit_device_orderfree(**common)
-        elif (
-            # Chains, and plain posted transfers over accounts under a
-            # balance limit (each its own chain of one): the linked
-            # kernel's fixpoint decides both in order.
-            not (has_pending or has_pv)
-            and not touch_hist
-            and not amount_hi.any()
-        ):
-            submit = self._submit_device_linked(**common)
-        elif has_pv and not has_linked and not timeout.any() and not touch_limit_hist:
-            submit = self._submit_device_two_phase(**common)
-        return submit if submit is not None else host_path
+        submit = self._submitter(kernel)(**common)
+        if submit is None:
+            return host_path
+        self._c_plan_runs.inc()
+        return submit
+
+    def _submitter(self, kernel: str):
+        return {
+            "orderfree": self._submit_device_orderfree,
+            "linked": self._submit_device_linked,
+            "two_phase": self._submit_device_two_phase,
+        }[kernel]
+
+    def _host_path(self, events, timestamp: int, input_bytes: bytes):
+        """What resolves a batch no kernel takes: the engine's submit
+        of a wave plan, or the drained host path."""
+        from tigerbeetle_tpu.state_machine.device_engine import ReplyFuture
+
+        def run() -> ReplyFuture:
+            # Batches the semantic kernels cannot express first try
+            # WAVE DISPATCH inside the device window (TB_DEV_WAVES):
+            # the wave plan executes against the authoritative HBM
+            # table instead of draining the stream to the host mirror.
+            # On decline the decode/ladder work is handed to the host
+            # path (it is drain-stale-proof: wire bytes + the
+            # synchronously-maintained account attrs only), so a
+            # persistently declining deployment does not pay it twice.
+            fut, decoded = self._try_submit_device_waves(
+                events, len(events), timestamp, input_bytes
+            )
+            if fut is not None:
+                return fut
+            self._engine_drain()
+            return ReplyFuture(
+                value=self._commit_create_transfers(
+                    timestamp, input_bytes, decoded=decoded
+                )
+            )
+
+        return run
+
+    def _plan_runs(self, runs, common, order, ref, host_path):
+        """A cut batch's runs, each planned and submitted as a batch of
+        its own, in order: its events keep the timestamps they have in
+        the whole batch, and its failures come home indexed from its
+        first event, moved on by its offset (`PrepareReply`).  Exact by
+        the argument that makes two consecutive prepares exact: a run's
+        joins read what the runs before it left, because where one of
+        its pending references names an id of a run still in flight the
+        stream drains first (the hazard probe).  The first run is
+        planned here, in the plan's open part `common["part"]`; the
+        others once the runs before them were submitted, each in a
+        sm.plan of its own.  -> what resolves the batch: the first
+        run's plan failing (a tier punt, a join the kernel declines)
+        leaves the whole batch to `host_path`; a later run's, the rest
+        of the batch, as a prepare of its own would be."""
+        from tigerbeetle_tpu.state_machine.device_engine import PrepareReply
+
+        n, ts_base = common["n"], common["ts_base"]
+        events, input_bytes = common["events"], common["input_bytes"]
+        size = TRANSFER_DTYPE.itemsize
+        keys_sorted = common["keys_sorted"]
+        # Each run's sorted keys (the engine's hazard probes), picked
+        # out of the batch's in their order: its events' ids, and for
+        # a batch with posts and voids their pendings' ids after them.
+        if order is None:
+            run_keys = [keys_sorted[a:b] for a, b, _kernel in runs]
+        else:
+            owner = np.arange(n)
+            if ref is not None:
+                owner = np.concatenate([owner, np.flatnonzero(ref)])
+            starts = np.array([a for a, _b, _kernel in runs])
+            run_of = np.searchsorted(starts, owner[order], side="right") - 1
+            run_keys = [keys_sorted[run_of == r] for r in range(len(runs))]
+        sliced = [k for k, v in common.items()
+                  if isinstance(v, np.ndarray) and k != "keys_sorted"]
+
+        def plan(r: int, part):
+            a, b, kernel = runs[r]
+            args = {k: common[k][a:b] for k in sliced}
+            args.update(
+                n=b - a, ts_base=ts_base + a, keys_sorted=run_keys[r],
+                timestamp=ts_base + b - 1,
+                input_bytes=input_bytes[a * size:b * size], part=part,
+            )
+            return self._submitter(kernel)(**args)
+
+        first = plan(0, common["part"])
+        if first is None:
+            return host_path
+
+        def submit() -> PrepareReply:
+            replies = [(0, first())]
+            on_device = 1
+            for r in range(1, len(runs)):
+                a, _b, kernel = runs[r]
+                if kernel == "two_phase" and self._dev.inflight_ids_hit(
+                    run_keys[r], is_sorted=True
+                ):
+                    self._engine_drain()
+                with self.tracer.stage(self._st_plan):
+                    with self.tracer.stage(self._st_plan_route) as part:
+                        go = plan(r, part)
+                if go is None:
+                    replies.append((a, self._host_path(
+                        events[a:], common["timestamp"], input_bytes[a * size:]
+                    )()))
+                    break
+                replies.append((a, go()))
+                on_device += 1
+            self._c_plan_runs.inc(on_device)
+            self._c_cut_prepares.inc()
+            return PrepareReply(replies)
+
+        return submit
 
     def _device_pack_base(
         self, n, events, id_lo, id_hi, dr_lo, dr_hi, cr_lo, cr_hi,
